@@ -15,7 +15,7 @@
 
 PYTHON ?= python
 
-.PHONY: test-fast test-models test-subproc test-multiprocess test-all test-nightly chaos quality serve-demo bench-trajectory loadtest
+.PHONY: test-fast test-models test-subproc test-multiprocess test-all test-nightly chaos quality serve-demo loadtest chip-smoke
 
 test-fast:
 	$(PYTHON) -m pytest -q $$($(PYTHON) tests/lanes.py fast)
@@ -41,12 +41,12 @@ chaos:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest -q -m "" tests/test_serving_supervisor.py
 
 quality:
-	$(PYTHON) -m compileall -q accelerate_tpu bench.py bench_watch.py __graft_entry__.py
+	$(PYTHON) -m compileall -q accelerate_tpu bench.py chip_smoke.py __graft_entry__.py
 
-# Fold every BENCH_rNN.json round artifact into BENCH_TRAJECTORY.json
-# (guard keys only) so perf regressions across PRs diff in one file.
-bench-trajectory:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --trajectory
+# The quickest proof that the trainer and the server start on the chip
+# (one TPU v5e; exits non-zero anywhere else). See README "Running on the chip".
+chip-smoke:
+	$(PYTHON) chip_smoke.py
 
 # Open-loop SSE load against a self-hosted tiny fleet (asyncio front
 # end): heavy-tailed arrivals, goodput/TTFT/conformance JSON report,

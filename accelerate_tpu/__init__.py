@@ -9,29 +9,6 @@ and quantization. See SURVEY.md for the capability blueprint.
 
 __version__ = "0.2.0"
 
-import os as _os
-
-if _os.environ.get("ACCELERATE_TPU_PLATFORM") or _os.environ.get("JAX_PLATFORMS"):
-    # Honor the documented platform env vars even under site customizations
-    # that register their own PJRT plugin and ignore JAX_PLATFORMS: mirror
-    # the env var into jax.config before any backend query can run. Skip the
-    # mirror when the config already differs from the env var — that means
-    # the user overrode the platform explicitly (e.g. pinned CPU for tests)
-    # and their choice must win over the environment.
-    import jax as _jax
-
-    _ours = _os.environ.get("ACCELERATE_TPU_PLATFORM")
-    _envv = _os.environ.get("JAX_PLATFORMS", "")
-    try:
-        _cur = getattr(_jax.config, "jax_platforms", None)
-        # _cur == the env-derived default means nobody overrode the config
-        # explicitly; only then do we mirror. The full comma list is kept so
-        # "tpu,cpu"-style fallback chains survive the mirror.
-        if _cur in (None, "", _envv):
-            _jax.config.update("jax_platforms", (_ours or _envv).strip().lower())
-    except Exception:  # already initialized on another platform: leave it be
-        pass
-
 from .accelerator import AcceleratedModel, Accelerator, Model
 from .adapters import (
     AdapterBank,

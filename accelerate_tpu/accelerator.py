@@ -27,7 +27,6 @@ import contextlib
 import functools
 import inspect
 import math
-import os
 import warnings
 from typing import Any, Callable, Optional
 
@@ -221,13 +220,11 @@ class Accelerator:
         if project_dir is not None and self.project_configuration.project_dir is None:
             self.project_configuration.set_directories(project_dir)
 
-        # Opt-in persistent compile cache: a relaunched trainer (preemption,
-        # --max_restarts) skips recompilation entirely. Env-gated so library
-        # import never mutates global jax config uninvited.
-        if os.environ.get("ACCELERATE_TPU_COMPILATION_CACHE"):
-            from .utils.platforms import enable_compilation_cache
+        # Persistent compile cache: a relaunched trainer (preemption,
+        # --max_restarts) skips recompilation entirely.
+        from .utils.platforms import enable_compilation_cache
 
-            enable_compilation_cache()
+        enable_compilation_cache()
 
         # kwargs handlers (reference: accelerator.py:347-381)
         self.autocast_handler: Optional[AutocastKwargs] = None
@@ -269,7 +266,6 @@ class Accelerator:
         self.step_scheduler_with_optimizer = step_scheduler_with_optimizer
         self.rng_types = rng_types or ["numpy", "python"]
         self.jit_config = jit_config or JitConfig()
-        self.jit_config.apply()
 
         self.policy = policy_for(self.state.mixed_precision)
         self._use_loss_scaling = self.state.mixed_precision == "fp16"
@@ -995,6 +991,28 @@ class Accelerator:
                 repl = replicated_sharding(self.mesh)
                 zero_p_sh = jax.tree_util.tree_map(lambda _: repl, model.params)
 
+        # Without ZeRO the update still pins params and optimizer state to the
+        # layouts they have now: left free, GSPMD hands some leaves back laid
+        # out differently (replicated norm scales come out tp-sharded), the
+        # next call sees new input shardings, and the whole step compiles a
+        # second time at step 2. Host-offloaded state changes memory kind
+        # across the update and keeps its own path.
+        pinned = None
+        if zero_sh is None and not offload and self.mesh.devices.size > 1:
+            from jax.sharding import NamedSharding
+
+            def _layouts(tree):
+                return [x.sharding if isinstance(getattr(x, "sharding", None), NamedSharding)
+                        else None for x in jax.tree_util.tree_leaves(tree)]
+
+            pinned = (_layouts(model.params), _layouts(optimizer.opt_state))
+
+        def _pin(tree, layouts):
+            leaves, treedef = jax.tree_util.tree_flatten(tree)
+            return jax.tree_util.tree_unflatten(treedef, [
+                x if s is None else jax.lax.with_sharding_constraint(x, s)
+                for x, s in zip(leaves, layouts)])
+
         def update_phase(params, opt_state, loss_scale, grads, loss):
             import optax
 
@@ -1055,6 +1073,9 @@ class Accelerator:
             if zero_sh is not None:
                 new_params = jax.lax.with_sharding_constraint(new_params, zero_p_sh)
                 new_opt_state = jax.lax.with_sharding_constraint(new_opt_state, zero_sh)
+            elif pinned is not None:
+                new_params = _pin(new_params, pinned[0])
+                new_opt_state = _pin(new_opt_state, pinned[1])
             return new_params, new_opt_state, new_scale, metrics
 
         def train_step(params, opt_state, loss_scale, batch, rng):
@@ -1085,26 +1106,14 @@ class Accelerator:
                 optimizer._steps_applied += 1
             return metrics
 
-        # ZeRO steps stay out of the persistent compile cache on the CPU
-        # backend (sharding.py zero_step_compile_cache_guard). The in-memory
-        # jit cache still holds the executable after the first call, so only
-        # compiles (first call and any new batch shape) pay the toggle.
-        _zero_nocache = zero_sh is not None and jax.default_backend() == "cpu"
-
-        def _call_uncached(fn, *args):
-            from .parallel.sharding import zero_step_compile_cache_guard
-
-            with zero_step_compile_cache_guard(_zero_nocache):
-                return fn(*args)
-
         if not offload:
             jitted = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
             def step(batch):
                 _check_accum_shape(batch)
                 rng = self.next_rng_key()
-                new_params, new_opt_state, new_scale, metrics = _call_uncached(
-                    jitted, model.params, optimizer.opt_state, optimizer.loss_scale, batch, rng
+                new_params, new_opt_state, new_scale, metrics = jitted(
+                    model.params, optimizer.opt_state, optimizer.loss_scale, batch, rng
                 )
                 model.params = new_params
                 optimizer.opt_state = new_opt_state
@@ -1130,8 +1139,8 @@ class Accelerator:
             rng = self.next_rng_key()
             grads, loss = jitted_grads(model.params, optimizer.loss_scale, batch, rng)
             opt_in = to_device(optimizer.opt_state, self.mesh)
-            new_params, new_opt_state, new_scale, metrics = _call_uncached(
-                jitted_update, model.params, opt_in, optimizer.loss_scale, grads, loss
+            new_params, new_opt_state, new_scale, metrics = jitted_update(
+                model.params, opt_in, optimizer.loss_scale, grads, loss
             )
             model.params = new_params
             optimizer.opt_state = to_host(new_opt_state, self.mesh)

@@ -141,6 +141,17 @@ class AdapterBank:
             self.stacks = self._write(self.stacks, jnp.int32(0),
                                       self._identity())
 
+    def commit(self, device) -> None:
+        """Pin the bank to one device (single-chip engines): the stacks are
+        committed there, so every later row write runs — and leaves its
+        result — on the engine's own chip rather than on jax's default
+        device. The identity re-write compiles the row-write program for
+        the committed layout now, not at the first tenant load."""
+        with self._lock:
+            self.stacks = jax.device_put(self.stacks, device)
+            self.stacks = self._write(self.stacks, jnp.int32(0),
+                                      self._identity())
+
     # ------------------------------------------------------------------
     # host registry
     # ------------------------------------------------------------------

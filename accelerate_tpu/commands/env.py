@@ -9,44 +9,27 @@ from .config.config_args import default_config_file, load_config_from_file
 
 
 def env_command(args) -> int:
-    import os
-
     import jax
 
     import accelerate_tpu
-    from accelerate_tpu.utils.platforms import force_cpu_platform, probe_backend_info
 
-    # Initializing the default backend can hang in-process when the platform
-    # plugin's transport is down, so all device facts come from a probed
-    # subprocess (bounded by --probe_timeout); the in-process fallback only
-    # ever runs on a pinned-CPU platform. This command always terminates.
-    pin = os.environ.get("ACCELERATE_TPU_PLATFORM") or os.environ.get("JAX_PLATFORMS") or ""
-    if pin.split(",")[0].strip().lower() == "cpu":
-        # A CPU pin (mirrored into jax.config by accelerate_tpu/__init__)
-        # cannot hang: in-process queries are safe. Any accelerator platform
-        # still goes through the out-of-process probe below.
-        info = {
-            "platform": jax.default_backend(),
-            "device_count": jax.device_count(),
-            "devices": [str(d) for d in jax.devices()],
-            "process_count": jax.process_count(),
-        }
-    else:
-        info = probe_backend_info(timeout=float(args.probe_timeout))
-        if info is None:
-            force_cpu_platform()
-            info = {
-                "platform": f"cpu (default backend unusable within {args.probe_timeout}s)",
-                "device_count": jax.device_count(),
-                "devices": [str(d) for d in jax.devices()],
-                "process_count": jax.process_count(),
-            }
+    # Reports the device jax gives this process. On a host whose chip a
+    # trainer or server already holds, run it with JAX_PLATFORMS=cpu: a chip
+    # belongs to one process at a time.
+    info = {
+        "platform": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": jax.device_count(),
+        "devices": [str(d) for d in jax.devices()],
+        "process_count": jax.process_count(),
+    }
     lines = {
         "accelerate_tpu version": accelerate_tpu.__version__,
         "Platform": platform.platform(),
         "Python version": platform.python_version(),
         "jax version": jax.__version__,
         "Backend": info["platform"],
+        "Device kind": info["device_kind"],
         "Device count": info["device_count"],
         "Devices": ", ".join(info["devices"]),
         "Process count": info["process_count"],
@@ -88,10 +71,6 @@ def env_command_parser(subparsers=None):
     else:
         parser = argparse.ArgumentParser("accelerate-tpu env", description=description)
     parser.add_argument("--config_file", default=None)
-    parser.add_argument(
-        "--probe_timeout", default=60, type=float,
-        help="Seconds to wait for the accelerator backend before reporting CPU",
-    )
     if subparsers is not None:
         parser.set_defaults(func=env_command)
     return parser

@@ -6,8 +6,9 @@ Two ways to point it at a model:
 * ``--model tiny`` — a randomly initialised tiny llama (CPU-friendly):
   the demo/smoke path, enough to exercise the full HTTP surface.
 * ``--model pkg.mod:factory`` — an import path to a zero-arg callable
-  returning ``(model, params)``; every replica shares the returned
-  params (one host copy), each gets its own engine.
+  returning ``(model, params)``; each replica gets its own engine, with
+  its copy of the params and its KV cache on its own device
+  (``--replicas N``: local device ``i % n``; ``--tp T``: a T-chip slice).
 
 The process serves until SIGTERM/SIGINT, then drains gracefully: readyz
 goes 503, in-flight streams finish, replicas shut down (flushing any
@@ -76,36 +77,24 @@ def _parse_tenant_floats(specs, flag: str, what: str):
     return out or None
 
 
-def serve_command(args) -> int:
-    from ..serving import (
-        FleetSupervisor,
-        GatewayConfig,
-        ReplicaSet,
-        ServingEngine,
-        ServingGateway,
-    )
+def build_fleet(args, model, params):
+    """The :class:`~accelerate_tpu.serving.ReplicaSet` the parsed ``serve``
+    arguments describe, over an already-resolved ``(model, params)``: plain
+    replicas (``--replicas N``, one per local device) or tp-wide mesh slices
+    (``--tp T``), warmed up, with any ``--adapter`` preloaded. Everything
+    ``serve`` does between resolving the model and binding the port — kept
+    separate so ``chip_smoke.py`` drives exactly this path."""
+    from ..serving import ReplicaSet, ServingEngine
 
-    # Validate cheap usage errors before any model build/warmup.
+    # Validate cheap usage errors before any engine build/warmup.
     # --autoscale-max turns the fixed fleet into a min..max elastic one:
     # `autoscale_min` replicas run, the rest sit PARKED (factory retained,
     # no engine) until the supervisor's autoscaler unparks them.
     autoscale = args.autoscale_max is not None
-    if autoscale:
-        autoscale_min = (args.autoscale_min if args.autoscale_min is not None
-                         else 1)
-        if autoscale_min < 1:
-            raise SystemExit("--autoscale-min must be >= 1")
-        if args.autoscale_max < autoscale_min:
-            raise SystemExit("--autoscale-max must be >= --autoscale-min")
-        n_build = args.autoscale_max if args.tp > 1 else autoscale_min
-    else:
-        autoscale_min = args.replicas
-        n_build = args.replicas
-    rate_limits = _parse_tenant_floats(args.rate_limit, "--rate-limit", "RPS")
-    fair_share = _parse_tenant_floats(args.fair_share, "--fair-share",
-                                      "WEIGHT")
+    autoscale_min = _autoscale_min(args)
+    n_build = (args.autoscale_max if autoscale and args.tp > 1
+               else autoscale_min)
 
-    model, params = _resolve_model(args.model, args)
     adapter_specs = _parse_adapter_specs(args.adapter)
     max_adapters = args.max_adapters
     if adapter_specs and max_adapters < 2:
@@ -177,6 +166,9 @@ def serve_command(args) -> int:
             for i in range(autoscale_min, args.autoscale_max):
                 replica_set.park_replica(i)
     else:
+        # Plain replicas: replica i's params and KV cache live on local
+        # device i % n (ReplicaSet.from_factory), one chip each when the
+        # host has enough of them.
         replica_set = ReplicaSet.from_factory(factory, n_build)
         if autoscale:
             for _ in range(args.autoscale_max - autoscale_min):
@@ -189,13 +181,53 @@ def serve_command(args) -> int:
             replica_set.register_adapter(name, adapter)
             print(f"registered adapter {name!r} from {path} "
                   f"(rank {meta.get('rank', '?')})", flush=True)
-    gateway = ServingGateway(
-        replica_set,
-        config=GatewayConfig(host=args.host, port=args.port,
-                             default_max_new_tokens=args.default_max_new_tokens,
-                             max_connections=args.max_connections,
-                             rate_limits=rate_limits,
-                             fair_share_weights=fair_share))
+    return replica_set
+
+
+def gateway_config(args):
+    """The :class:`~accelerate_tpu.serving.GatewayConfig` the parsed
+    ``serve`` arguments describe (usage errors in the tenant flags surface
+    here)."""
+    from ..serving import GatewayConfig
+
+    return GatewayConfig(
+        host=args.host, port=args.port,
+        default_max_new_tokens=args.default_max_new_tokens,
+        max_connections=args.max_connections,
+        rate_limits=_parse_tenant_floats(args.rate_limit, "--rate-limit",
+                                         "RPS"),
+        fair_share_weights=_parse_tenant_floats(
+            args.fair_share, "--fair-share", "WEIGHT"))
+
+
+def _autoscale_min(args) -> int:
+    """Replicas kept running: ``--replicas`` for a fixed fleet, else the
+    elastic floor (validated against ``--autoscale-max``)."""
+    if args.autoscale_max is None:
+        return args.replicas
+    floor = args.autoscale_min if args.autoscale_min is not None else 1
+    if floor < 1:
+        raise SystemExit("--autoscale-min must be >= 1")
+    if args.autoscale_max < floor:
+        raise SystemExit("--autoscale-max must be >= --autoscale-min")
+    return floor
+
+
+def serve_command(args) -> int:
+    from ..serving import FleetSupervisor, ServingGateway
+    from ..utils.platforms import enable_compilation_cache
+
+    # A restarted server finds its warm-up programs in the persistent cache.
+    enable_compilation_cache()
+
+    # Cheap usage errors surface before any model build/warmup.
+    autoscale = args.autoscale_max is not None
+    autoscale_min = _autoscale_min(args)
+    config = gateway_config(args)
+
+    model, params = _resolve_model(args.model, args)
+    replica_set = build_fleet(args, model, params)
+    gateway = ServingGateway(replica_set, config=config)
     gateway.start()
     gateway.install_signal_handlers()
     supervisor = None

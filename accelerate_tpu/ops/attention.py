@@ -18,12 +18,9 @@ import jax.numpy as jnp
 
 
 def flash_attention_available(q=None) -> bool:
-    """True when the Pallas TPU lowering can run (real TPU backend) and the
-    shapes are tileable."""
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
+    """True when the backend is the TPU and the shapes are tileable. A test
+    that compiles for a described chip patches this function."""
+    if jax.default_backend() != "tpu":
         return False
     if q is not None:
         # Kernel wants seq divisible by block size and head_dim <= 256.
@@ -111,6 +108,51 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128, block_k: i
                                  logit_softcap=logit_softcap)
     from .flash_pallas import pallas_flash_attention
 
-    return pallas_flash_attention(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                                  sliding_window=sliding_window, segment_ids=segment_ids,
-                                  sm_scale=sm_scale, logit_softcap=logit_softcap)
+    kernel = functools.partial(
+        pallas_flash_attention, causal=causal, block_q=block_q, block_k=block_k,
+        sliding_window=sliding_window, sm_scale=sm_scale, logit_softcap=logit_softcap)
+    sharded = _per_shard_specs(q, k)
+    if sharded is None:
+        return kernel(q, k, v, segment_ids=segment_ids)
+    # A Mosaic kernel cannot be partitioned by GSPMD ("Mosaic kernels cannot
+    # be automatically partitioned. Please wrap the call in a shard_map"), so
+    # on a mesh the kernel runs once per shard: attention is independent per
+    # batch row and per head, and each device keeps the rows and heads it
+    # already holds — no q/k/v gather at the boundary.
+    mesh, qkv_spec, seg_spec = sharded
+    segments = () if segment_ids is None else (segment_ids,)
+    return jax.shard_map(
+        lambda q, k, v, *seg: kernel(q, k, v, segment_ids=seg[0] if seg else None),
+        mesh=mesh, in_specs=(qkv_spec,) * 3 + (seg_spec,) * len(segments),
+        out_specs=qkv_spec, check_vma=False)(q, k, v, *segments)
+
+
+def _per_shard_specs(q, k):
+    """``(mesh, qkv_spec, segment_spec)`` for running the flash kernel per
+    shard of global ``[B, S, H, D]`` arrays, or None when there is nothing
+    to split over: no ambient mesh, a one-device mesh, or a caller that is
+    already inside a ``shard_map`` body (the context-parallel paths).
+
+    Batch splits over the data axes (``dp``, ``fsdp``) and heads over
+    ``tp`` — the layout ``ops/ring_attention._qkv_spec`` uses — each only
+    as far as it divides the dimension (GQA: both the query and the KV head
+    counts). An axis that does not divide stays unsplit, which is correct
+    and costs a gather."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..state import current_mesh
+
+    mesh = current_mesh()
+    if (mesh is None or mesh.devices.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+    batch_axes, ways = [], 1
+    for ax in ("dp", "fsdp"):
+        n = mesh.shape.get(ax, 1)
+        if n > 1 and q.shape[0] % (ways * n) == 0:
+            batch_axes.append(ax)
+            ways *= n
+    tp = mesh.shape.get("tp", 1)
+    head_ax = "tp" if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
+    batch = tuple(batch_axes) or None
+    return mesh, P(batch, None, head_ax, None), P(batch, None)

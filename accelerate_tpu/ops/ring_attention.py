@@ -34,17 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.6 promotes shard_map to jax.shard_map and renames the replication
-# check check_rep -> check_vma; older releases only ship the experimental
-# spelling. Resolve once so both call sites stay version-agnostic.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax < 0.6 installs
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 _BIG_NEG = -1e30
 
 
@@ -226,7 +215,7 @@ def _ring_fn(mesh, axis_name: str, axis_size: int, causal: bool, inner_chunk: in
     for eager callers.
     """
     spec = _qkv_spec(mesh, axis_name)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_shard, axis_name=axis_name, axis_size=axis_size, causal=causal,
             inner_chunk=inner_chunk,
@@ -234,7 +223,7 @@ def _ring_fn(mesh, axis_name: str, axis_size: int, causal: bool, inner_chunk: in
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -299,7 +288,7 @@ def ulysses_attention(
             f"ulysses_attention: seq len {q.shape[1]} not divisible by {axis_name}={axis_size}"
         )
     spec = _qkv_spec(mesh, axis_name)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ulysses_shard,
             axis_name=axis_name,
@@ -309,7 +298,7 @@ def ulysses_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
     return fn(q, k, v)
 

@@ -108,6 +108,19 @@ class AcceleratedOptimizer:
             self.opt_state = init(params)
         else:
             self.opt_state = self.tx.init(params)
+        if self.mesh is not None:
+            # Leaves with no data dependence on params (Adam's step count)
+            # come back uncommitted on one device, while the fused step
+            # returns them committed and replicated over the mesh — a
+            # different jit cache key, i.e. a second full compile of the
+            # train step at step 2. Commit them now.
+            from .parallel.sharding import replicated_sharding
+
+            repl = replicated_sharding(self.mesh)
+            self.opt_state = jax.tree_util.tree_map(
+                lambda x: (jax.device_put(x, repl)
+                           if isinstance(x, jax.Array) and not x.committed else x),
+                self.opt_state)
         if self.zero_sharding and self.mesh is not None and (
             self.mesh.shape.get("dp", 1) > 1 or self.mesh.shape.get("fsdp", 1) > 1
         ):
@@ -258,14 +271,9 @@ class AcceleratedOptimizer:
             opt_in = to_device(self.opt_state, self.mesh)
         else:
             opt_in = self.opt_state
-        from .parallel.sharding import zero_step_compile_cache_guard
-
-        with zero_step_compile_cache_guard(
-            self.opt_state_shardings is not None and jax.default_backend() == "cpu"
-        ):
-            params, opt_state, new_scale, finite = self._apply_jit(
-                self._model.params, opt_in, self.acc_grads, self.loss_scale, inv_scale
-            )
+        params, opt_state, new_scale, finite = self._apply_jit(
+            self._model.params, opt_in, self.acc_grads, self.loss_scale, inv_scale
+        )
         if self.offload_to_host:
             opt_state = to_host(opt_state, self.mesh)
         self._grads_already_unscaled = False

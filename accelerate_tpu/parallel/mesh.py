@@ -139,9 +139,9 @@ class MeshConfig:
                         allow_split_physical_axes=self.allow_split_physical_axes,
                     )
                 except (ValueError, NotImplementedError, AssertionError) as e:
-                    # Exotic/tunneled topologies where topology-aware placement
-                    # is unavailable; fall back but say so — placement affects
-                    # ICI hop counts on real slices.
+                    # A device subset that is not a box of the torus (e.g. a
+                    # diagonal pair of a 2x2) has no topology-aware placement;
+                    # fall back but say so — placement affects ICI hop counts.
                     import logging
 
                     logging.getLogger(__name__).warning(

@@ -18,7 +18,6 @@ Policies:
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import re
 from typing import Any, Callable, Optional
@@ -307,33 +306,6 @@ def infer_opt_state_shardings(
          + (", ..." if len(fallbacks) > 4 else "") + ")") if fallbacks else "",
     )
     return shardings
-
-
-@contextlib.contextmanager
-def zero_step_compile_cache_guard(active: bool = True):
-    """Keep ZeRO update executables out of the persistent compile cache.
-
-    The reduce-scatter -> shard-local-update -> all-gather program the ZeRO
-    step lowers to crashes the CPU runtime after an executable
-    serialize/deserialize round-trip (jaxlib 0.4.37; TPU round-trips fine),
-    so compiles under this context skip the on-disk cache. ``reset_cache()``
-    on both edges is load-bearing: jax latches the is-cache-used decision
-    once per process, so a bare config flip is silently ignored.
-    """
-    if not active:
-        yield
-        return
-    import jax
-    from jax._src import compilation_cache as _cc
-
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    _cc.reset_cache()
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        _cc.reset_cache()
 
 
 def replicated_sharding(mesh):

@@ -834,6 +834,19 @@ class ServingEngine:
         self._spec = None
         self._draft_chunk = None
         if self._exec is None:
+            # Single-chip: commit params, state, draft and bank to the device
+            # the state above was just created on — jax's default device,
+            # which ReplicaSet.from_factory sets per replica. Committed
+            # arguments pin every program the engine thread later dispatches
+            # to that device, whatever the default is on that thread. Arrays
+            # already there are aliased, not copied.
+            device = next(iter(self._state["pos"].devices()))
+            self.params = params = jax.device_put(params, device)
+            self._state = jax.device_put(self._state, device)
+            if self._draft_params is not None:
+                self._draft_params = jax.device_put(self._draft_params, device)
+            if adapters is not None:
+                adapters.commit(device)
             if self._paged:
                 self._decode = jax.jit(self._paged_decode_fn,
                                        donate_argnums=donate)

@@ -267,6 +267,26 @@ class FleetRequest:
                 f"tokens={len(self.tokens)}, trail={self.replica_trail})")
 
 
+def _on_replica_device(factory: Callable[[], ServingEngine],
+                       index: int) -> Callable[[], ServingEngine]:
+    """``factory`` run with replica ``index``'s device as jax's default:
+    replica ``i`` of a plain (non-mesh) fleet lives on local device
+    ``i % n``, so N replicas on an N-chip host each get a chip of their own
+    instead of all landing on device 0. The single-chip engine commits its
+    params and KV state to the default device in effect while it is built
+    (see ``ServingEngine``), so its programs stay there afterwards. A
+    rebuild (restart, unpark) goes through the same wrapper and returns to
+    the same device."""
+    def build() -> ServingEngine:
+        import jax
+
+        devices = jax.local_devices()
+        with jax.default_device(devices[index % len(devices)]):
+            return factory()
+
+    return build
+
+
 class ReplicaSet:
     """N serving-engine replicas behind one submit surface.
 
@@ -346,13 +366,18 @@ class ReplicaSet:
                      num_replicas: int, **kwargs) -> "ReplicaSet":
         """Build ``num_replicas`` engines by calling ``factory()`` that
         many times (each call should construct an independent engine —
-        sharing params between them is fine and saves host memory). The
-        factory is RETAINED per replica, so a :class:`~.supervisor.
-        FleetSupervisor` can rebuild a dead replica from it."""
+        sharing params between them is fine: each engine places its own
+        copy). Replica ``i`` is built on local device ``i % n_devices``,
+        so every replica's params and KV cache live on its own chip when
+        the host has enough of them. The factory is RETAINED per replica,
+        so a :class:`~.supervisor.FleetSupervisor` can rebuild a dead
+        replica from it, on the same device."""
         if num_replicas < 1:
             raise ValueError(f"num_replicas must be >= 1 (got {num_replicas})")
-        return cls([factory() for _ in range(num_replicas)],
-                   factories=[factory] * num_replicas, **kwargs)
+        factories = [_on_replica_device(factory, i)
+                     for i in range(num_replicas)]
+        return cls([build() for build in factories],
+                   factories=factories, **kwargs)
 
     @classmethod
     def from_mesh(cls, model, params=None, *, tp: int,
@@ -669,13 +694,14 @@ class ReplicaSet:
         """Append a PARKED engine-less replica slot holding only
         ``factory`` — headroom the autoscaler can later spawn into
         without the fleet ever paying for an engine it hasn't needed yet.
-        Returns the new replica's index."""
+        Like ``from_factory``, the engine is later built on local device
+        ``index % n_devices``. Returns the new replica's index."""
         with self._lock:
             index = len(self._replicas)
             r = _Replica(index, None)
             r.state = ReplicaState.PARKED
             self._replicas.append(r)
-            self._factories.append(factory)
+            self._factories.append(_on_replica_device(factory, index))
         return index
 
     # -- projected pressure (gateway shed inputs) -------------------------

@@ -19,8 +19,9 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import warnings
 from contextlib import contextmanager
-from functools import partial, wraps
+from functools import cache, partial, wraps
 from typing import Any, Callable, Optional
 
 from .parallel.mesh import MeshConfig
@@ -38,13 +39,24 @@ logger = logging.getLogger(__name__)
 # Only used on the host-platform testing path.
 _CPU_DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
-# One-time flag: the ambient-mesh probe failed (jax internals moved).
-_mesh_probe_warned = False
-
 
 def is_initialized() -> bool:
     """Whether a PartialState has been constructed (reference: state.py:102)."""
     return PartialState._shared_state != {}
+
+
+@cache
+def _thread_resources():
+    """jax's per-thread record of the active ``with mesh:`` context.
+
+    jax 0.9.0 has no public accessor for it (``jax.sharding.get_mesh()``
+    only covers ``jax.set_mesh``), so this keeps the alias deprecated since
+    0.8.2 and silences only its import warning, once per process.
+    """
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        from jax.interpreters.pxla import thread_resources
+    return thread_resources
 
 
 def current_mesh(mesh=None):
@@ -57,28 +69,9 @@ def current_mesh(mesh=None):
     """
     if mesh is not None:
         return mesh
-    try:
-        # jax.interpreters.pxla.thread_resources is the closest thing to a
-        # public accessor for the `with mesh:` context (deprecated alias of
-        # jax._src.mesh.thread_resources; get_abstract_mesh() only covers
-        # use_mesh, not the context manager).
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters.pxla import thread_resources
-
-        phys = thread_resources.env.physical_mesh
-        if phys is not None and not phys.empty:
-            return phys
-    except Exception:
-        global _mesh_probe_warned
-        if not _mesh_probe_warned:
-            _mesh_probe_warned = True
-            logger.warning(
-                "cannot resolve the ambient `with mesh:` context on this jax "
-                "version; pass mesh= explicitly to mesh-aware ops"
-            )
+    phys = _thread_resources().env.physical_mesh
+    if not phys.empty:
+        return phys
     if AcceleratorState._shared_state:
         m = AcceleratorState().mesh
         if m is not None:
@@ -138,7 +131,7 @@ class PartialState:
         self.device = self.local_devices[0]
         self.backend = jax.default_backend()
 
-        if self.backend == "tpu" or any("TPU" in str(d.device_kind) for d in self.devices):
+        if self.backend == "tpu":
             self.distributed_type = DistributedType.TPU if self.num_devices > 1 else DistributedType.NO
         elif self.backend == "cpu" and self.num_devices > 1:
             self.distributed_type = DistributedType.MULTI_CPU

@@ -347,21 +347,11 @@ class JitConfig(KwargsHandler):
 
     backend: ComputeBackend = ComputeBackend.JIT
     donate_state: bool = True            # donate params/opt-state buffers to the step
-    persistent_cache_dir: Optional[str] = None  # jax compilation cache directory
     remat_policy: Optional[str] = None   # None|"full"|"dots_saveable"|"nothing_saveable"
 
     def __post_init__(self):
         if isinstance(self.backend, str):
             self.backend = ComputeBackend(self.backend.lower())
-        if self.persistent_cache_dir is None:
-            self.persistent_cache_dir = os.environ.get(env_var("COMPILE_CACHE"), None)
-
-    def apply(self):
-        """Apply this handler's settings to the ambient jax config."""
-        if self.persistent_cache_dir:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", self.persistent_cache_dir)
 
 
 # ---------------------------------------------------------------------------

@@ -143,30 +143,16 @@ def is_boto3_available() -> bool:
 def _jax_backend() -> str:
     import jax
 
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - no backend at all
-        return "cpu"
+    return jax.default_backend()
 
 
-def is_tpu_available(check_device: bool = True) -> bool:
+def is_tpu_available() -> bool:
     """True when the default JAX backend drives real TPU chips."""
     if not is_jax_available():
         return False
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         return False
-    backend = _jax_backend()
-    if backend == "tpu":
-        return True
-    # Tunneled/experimental TPU platforms still expose TPU device kind.
-    if check_device:
-        try:
-            import jax
-
-            return any("TPU" in str(d.device_kind) for d in jax.devices())
-        except Exception:
-            return False
-    return False
+    return _jax_backend() == "tpu"
 
 
 def is_gpu_available() -> bool:

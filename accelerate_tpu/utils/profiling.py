@@ -104,12 +104,10 @@ class CompileWatcher:
             self._registered = False
             dur, evt = self._dur_listener, self._evt_listener
             self._dur_listener = self._evt_listener = None
-        # There is no public unregister API; the tests this class
-        # replaces used the same private hooks.
-        from jax._src import monitoring as _mon
+        import jax.monitoring
 
-        _mon._unregister_event_duration_listener_by_callback(dur)
-        _mon._unregister_event_listener_by_callback(evt)
+        jax.monitoring.unregister_event_duration_listener(dur)
+        jax.monitoring.unregister_event_listener(evt)
 
     def __enter__(self) -> "CompileWatcher":
         return self.start()

@@ -87,14 +87,14 @@ def train(layout: str, use_fp8: bool, steps: int = 12):
 
 
 def main() -> int:
-    from accelerate_tpu.utils.platforms import resolve_backend
+    from accelerate_tpu.utils.platforms import device_kind, request_virtual_cpu_devices
 
-    platform = resolve_backend(prefer_accelerator=True)
-    if platform == "cpu":
-        from accelerate_tpu.utils.platforms import request_virtual_cpu_devices
-
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu":
+        # Asked for the CPU: the dp/fsdp layouts need more than one device.
         request_virtual_cpu_devices(8)
+    import jax
 
+    platform = f"{jax.default_backend()} ({device_kind()})"
     rows, ok = [], True
     print(f"fp8 vs bf16 training parity ({platform})\n")
     print("| layout | bf16 final loss | fp8 final loss | rel gap | pass |")

@@ -27,6 +27,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
     "fast": (5, [
         "test_accelerator.py",
         "test_bench.py",
+        "test_bringup.py",
         "test_checkpointing.py",
         "test_data_loader.py",
         "test_env_memory_utils.py",
@@ -44,6 +45,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_precision.py",
         "test_ring_attention.py",
         "test_state.py",
+        "test_tpu_compile.py",
         "test_tracking.py",
         "test_zero_sharding.py",
     ]),
@@ -71,9 +73,9 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_serving_supervisor.py",
     ]),
     "subproc": (12, [
+        "test_chip_smoke.py",
         "test_cli.py",
-        "test_cli_deadbackend.py",
-        "test_watch_rehearsal.py",
+        "test_cli_hostonly.py",
         "test_examples.py",
     ]),
     "multiprocess": (8, [

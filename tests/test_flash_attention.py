@@ -316,3 +316,69 @@ def test_softcap_with_window_and_gqa_backward():
     for a, b, name in zip(g_flash, (gq, gk, gv), ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=7e-4, rtol=7e-4,
                                    err_msg=f"{name} mismatch")
+
+
+class TestPerShardOnAMesh:
+    """``flash_attention`` on a mesh runs the kernel per shard under a
+    shard_map (Mosaic kernels cannot be partitioned by GSPMD — see
+    tests/test_tpu_compile.py). Here the interpreter checks the arithmetic of
+    that wrapper on the virtual CPU mesh: batch over fsdp, heads over tp."""
+
+    @pytest.fixture
+    def flash_on(self, monkeypatch):
+        from accelerate_tpu.ops import attention
+
+        monkeypatch.setattr(attention, "flash_attention_available", lambda q=None: True)
+
+    @staticmethod
+    def _mesh():
+        from accelerate_tpu import MeshConfig
+
+        return MeshConfig(fsdp=2, tp=2, devices=jax.devices()[:4]).build()
+
+    @staticmethod
+    def _gqa_qkv():
+        ks = jax.random.split(jax.random.PRNGKey(3), 3)
+        q = jax.random.normal(ks[0], (2, 128, 4, 32), jnp.float32)
+        k = jax.random.normal(ks[1], (2, 128, 2, 32), jnp.float32)
+        v = jax.random.normal(ks[2], (2, 128, 2, 32), jnp.float32)
+        return q, k, v
+
+    def test_specs_follow_the_mesh(self, flash_on):
+        from jax.sharding import PartitionSpec as P
+
+        from accelerate_tpu.ops.attention import _per_shard_specs
+
+        q, k, _ = self._gqa_qkv()
+        assert _per_shard_specs(q, k) is None  # no ambient mesh: plain call
+        with self._mesh() as mesh:
+            got_mesh, qkv, seg = _per_shard_specs(q, k)
+            assert got_mesh is mesh
+            assert qkv == P(("fsdp",), None, "tp", None) and seg == P(("fsdp",), None)
+            # 3 batch rows do not split over fsdp=2; 1 KV head not over tp=2.
+            _, qkv, _ = _per_shard_specs(q[:1].repeat(3, 0), k[:, :, :1])
+            assert qkv == P(None, None, None, None)
+
+    @pytest.mark.parametrize("segments", [False, True], ids=["plain", "segment_ids"])
+    def test_forward_and_grads_match_einsum(self, flash_on, segments):
+        from accelerate_tpu.ops.attention import flash_attention
+
+        q, k, v = self._gqa_qkv()
+        seg = (jnp.asarray(np.repeat([[0, 1], [0, 0]], 64, axis=1), jnp.int32)
+               if segments else None)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+        flash = lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=64,  # noqa: E731
+                                                block_k=64, segment_ids=seg)
+        ref = lambda q, k, v: _einsum_attention(q, k, v, causal=True,  # noqa: E731
+                                                segment_ids=seg)
+        with self._mesh():
+            out = jax.jit(flash)(q, k, v)
+            grads = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        for a, b, name in zip(grads, jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v), "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4,
+                                       err_msg=f"d{name} mismatch")

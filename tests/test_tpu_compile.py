@@ -1,0 +1,156 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for a
+described (not attached) v5e chip, at the widths the chip runs them.
+
+Interpret-mode tests prove the arithmetic; only Mosaic says whether a kernel
+lowers: tiling alignment, VMEM budget, block shapes. ``libtpu`` is installed
+in the sandbox and compiles for a topology that is merely described, so these
+guard every later PR at no chip time. A compile that passes here is a
+compile, not a run.
+
+Everything that touches the TPU library happens inside fixtures of THIS file
+(never at import, in conftest, in a skipif or in parametrize arguments): only
+one process may load libtpu, xdist workers all import every test file, and
+only the worker that runs this file may load it. Keep these tests in this one
+file for the same reason, and compile in the test's own process.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from accelerate_tpu.ops import attention, flash_pallas
+
+# name -> kernel shapes/options. H/G are query/KV heads. The first row is the
+# trainer's attention in chip_smoke.py (Mistral-7B widths, sequence 4096).
+CASES = {
+    "gqa32x8_d128_bf16_s4096": dict(S=4096, H=32, G=8, D=128, dtype=jnp.bfloat16),
+    "gqa32x8_d128_fp32_s2048": dict(S=2048, H=32, G=8, D=128, dtype=jnp.float32),
+    "window1024_s4096": dict(S=4096, H=32, G=8, D=128, dtype=jnp.bfloat16,
+                             sliding_window=1024),
+    "segment_ids_s2048": dict(S=2048, H=32, G=8, D=128, dtype=jnp.bfloat16, segments=True),
+    "d256_softcap_s2048": dict(S=2048, H=16, G=8, D=256, dtype=jnp.bfloat16,
+                               logit_softcap=50.0),
+    "blocks512_s4096": dict(S=4096, H=32, G=8, D=128, dtype=jnp.bfloat16,
+                            block_q=512, block_k=512),
+    "d96_s2048": dict(S=2048, H=32, G=8, D=96, dtype=jnp.bfloat16),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e 2x2, with the persistent compile cache off around the
+    whole module: an executable compiled for a described chip is written to
+    the cache but cannot be read back without one, and would warn on every
+    later run."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe means "cannot test here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Steer the code that asks ``jax.default_backend()`` (the CPU, here) onto
+    its TPU branch: Mosaic lowering, and the flash path available."""
+    monkeypatch.setattr(flash_pallas, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        attention, "flash_attention_available",
+        lambda q=None: q is None or (q.shape[1] >= 128 and q.shape[1] % 128 == 0
+                                     and q.shape[-1] <= 256))
+
+
+def _shapes(case, sharding, seg_sharding=None, batch=1):
+    S, H, G, D, dtype = case["S"], case["H"], case["G"], case["D"], case["dtype"]
+    q = jax.ShapeDtypeStruct((batch, S, H, D), dtype, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((batch, S, G, D), dtype, sharding=sharding)
+    seg = (jax.ShapeDtypeStruct((batch, S), jnp.int32, sharding=seg_sharding or sharding)
+           if case.get("segments") else None)
+    return q, kv, seg
+
+
+def _kernel(case):
+    opts = {k: case[k] for k in ("sliding_window", "logit_softcap", "block_q", "block_k")
+            if k in case}
+
+    def fwd(q, k, v, seg=None):
+        return flash_pallas.pallas_flash_attention(q, k, v, causal=True, segment_ids=seg,
+                                                   **opts)
+
+    return fwd
+
+
+def _with_backward(fwd):
+    def fwd_bwd(q, k, v, seg=None):
+        out, vjp = jax.vjp(lambda q, k, v: fwd(q, k, v, seg), q, k, v)
+        return vjp(out)
+
+    return fwd_bwd
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("name", list(CASES))
+def test_flash_kernel_compiles_for_v5e(name, direction, one_chip, mosaic):
+    case = CASES[name]
+    q, kv, seg = _shapes(case, one_chip)
+    fn = _kernel(case) if direction == "fwd" else _with_backward(_kernel(case))
+    args = (q, kv, kv) + ((seg,) if seg is not None else ())
+    compiled = jax.jit(fn).lower(*args).compile()
+    calls = compiled.as_text().count("tpu_custom_call")
+    # fwd is one Mosaic call; bwd re-runs fwd for residuals, then dq and dk/dv.
+    assert calls >= (1 if direction == "fwd" else 3), f"{calls} Mosaic calls in the HLO"
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_runs_per_shard_on_a_mesh(direction, topo, mosaic):
+    """Under fsdp=2 x tp=2 the entry point the models call wraps the kernel in
+    a shard_map (Mosaic refuses GSPMD partitioning): each chip's call sees one
+    batch row of two and half the heads, and q/k/v are not gathered."""
+    from accelerate_tpu import MeshConfig
+
+    mesh = MeshConfig(fsdp=2, tp=2, devices=list(topo.devices)).build()
+    case = CASES["gqa32x8_d128_bf16_s4096"]
+    q, kv, _ = _shapes(case, NamedSharding(mesh, P("fsdp", None, "tp", None)), batch=2)
+
+    def fwd(q, k, v, seg=None):
+        return attention.flash_attention(q, k, v, causal=True)
+
+    fn = fwd if direction == "fwd" else _with_backward(fwd)
+    with mesh:
+        compiled = jax.jit(fn).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # Per-shard operand shapes in [B, H, S, D] kernel layout: 1 row, 16 of 32
+    # query heads, 4 of 8 KV heads.
+    assert "bf16[1,16,4096,128]" in text and "bf16[1,4,4096,128]" in text
+    assert "bf16[2,32,4096,128]" not in text, "q was gathered to its global shape"
+
+
+def test_unsharded_call_on_a_mesh_is_refused_by_mosaic(topo, mosaic):
+    """What the wrapper is for: the bare kernel on GSPMD-sharded operands does
+    not lower. If this starts passing, jax learned to partition Mosaic calls
+    and ``ops.attention._per_shard_specs`` can go."""
+    from accelerate_tpu import MeshConfig
+
+    mesh = MeshConfig(fsdp=2, tp=2, devices=list(topo.devices)).build()
+    case = CASES["gqa32x8_d128_bf16_s4096"]
+    q, kv, _ = _shapes(case, NamedSharding(mesh, P("fsdp", None, "tp", None)), batch=2)
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        jax.jit(_kernel(case)).lower(q, kv, kv).compile()
