@@ -25,6 +25,18 @@ Perfetto JSON (``chrome://tracing``, https://ui.perfetto.dev) via
 Timestamps are ``time.monotonic()`` microseconds: within one process all
 tracers share the clock, so per-replica traces merge into one aligned
 fleet timeline (:func:`merge_chrome_traces`).
+
+**One timeline with the device.** A ``with tracer.span(name)`` region is
+also a ``jax.profiler.TraceAnnotation`` named ``"atpu:" + name``: while a
+jax profiler session runs (``jax.profiler.start_trace``) the same region
+lands on the ``/host:CPU`` plane of the ``.xplane.pb`` that holds the
+device's ``XLA Modules`` / ``XLA Ops`` lines — the profiler's clock, one
+line per thread — so a device idle gap can be booked to what the host
+was doing in it (``chipbench/host_spans.py``, which also corrects the
+millisecond or so by which a TPU trace's device plane lags its host
+plane). With no session the annotation costs well under a
+microsecond; a disabled tracer hands back one shared no-op span and
+makes neither record nor annotation.
 """
 
 from __future__ import annotations
@@ -108,15 +120,41 @@ class _Ring:
         return out
 
 
-class TraceSpan:
-    """Context manager emitting one complete span on exit.
+#: Prefix of every span's name on the profiler's host plane (the
+#: benchmark's ``host_spans`` reader and ``utils.profiling.annotate``'s
+#: caller use the same one).
+ANNOTATION_PREFIX = "atpu:"
 
-    Returned by :meth:`Tracer.span`; ``args`` may be extended inside the
-    ``with`` block via :meth:`note` (e.g. recording a hit count that is
-    only known at the end of the timed region).
+_annotation_cls = None                  # jax.profiler.TraceAnnotation, lazily
+_annotation_names: Dict[str, str] = {}  # span name -> prefixed, built once
+
+
+def _annotation(name: str, trace_id: Optional[str]):
+    """The ``jax.profiler.TraceAnnotation`` for one span (jax is imported
+    on first use, so importing this module stays jax-free)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    full = _annotation_names.get(name)
+    if full is None:
+        full = _annotation_names.setdefault(name, ANNOTATION_PREFIX + name)
+    if trace_id is None:
+        return _annotation_cls(full)
+    return _annotation_cls(full, trace_id=trace_id)
+
+
+class TraceSpan:
+    """Context manager emitting one complete span on exit — a ring record
+    on the monotonic clock and, for the same region, a profiler
+    annotation (see the module docstring).
+
+    Returned by :meth:`Tracer.span`.
     """
 
-    __slots__ = ("_tracer", "name", "cat", "trace_id", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "trace_id", "args", "_t0",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  trace_id: Optional[str], args: Optional[Dict[str, Any]]):
@@ -126,21 +164,36 @@ class TraceSpan:
         self.trace_id = trace_id
         self.args = args
         self._t0 = 0.0
-
-    def note(self, **fields: Any) -> None:
-        if self.args is None:
-            self.args = {}
-        self.args.update(fields)
+        self._annotation = None
 
     def __enter__(self) -> "TraceSpan":
+        self._annotation = _annotation(self.name, self.trace_id)
+        self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer.emit(self.name, self._t0,
-                          time.monotonic() - self._t0,
+        dur = time.monotonic() - self._t0
+        self._annotation.__exit__(*exc)
+        self._tracer.emit(self.name, self._t0, dur,
                           trace_id=self.trace_id, cat=self.cat,
                           args=self.args)
+
+
+class _NoopSpan:
+    """What a disabled tracer's :meth:`Tracer.span` hands back: one shared
+    object, no record, no annotation, no allocation per span."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NOOP_SPAN = _NoopSpan()
 
 
 _PID_LOCK = threading.Lock()
@@ -158,10 +211,14 @@ class Tracer:
     """Bounded, lock-light span sink with Chrome-trace export.
 
     One tracer per replica (engine) or per training session. Each
-    emitting thread gets its own :class:`_Ring` of ``capacity`` records;
-    the registry lock is taken only on a thread's *first* emit. With
-    ``enabled=False`` every emit is a cheap early return, so call sites
-    never need their own guards.
+    emitting thread gets its own :class:`_Ring` of ``capacity`` records
+    *per category*, so a high-rate category (the serving loop's ``phase``
+    spans, several per tick and per idle poll) never evicts a low-rate
+    one (a request's lifecycle records, which ``/debug/trace?id=`` reads
+    long after the request finished); the registry lock is taken only on
+    a thread's *first* emit in a category. With ``enabled=False`` every
+    emit is a cheap early return, so call sites never need their own
+    guards.
 
     Record layout (immutable tuple):
     ``(t0_monotonic_s, dur_s_or_None, name, cat, trace_id, args)`` —
@@ -176,7 +233,7 @@ class Tracer:
         self.enabled = bool(enabled)
         self.name = name
         self.pid = _next_pid()
-        self._rings: Dict[int, _Ring] = {}
+        self._rings: Dict[Tuple[int, str], _Ring] = {}
         self._local = threading.local()
         self._lock = threading.Lock()
 
@@ -187,9 +244,10 @@ class Tracer:
         """Record one span (``dur_s`` seconds) or instant (``dur_s=None``)."""
         if not self.enabled:
             return
-        ring = getattr(self._local, "ring", None)
+        rings = getattr(self._local, "rings", None)
+        ring = rings.get(cat) if rings is not None else None
         if ring is None:
-            ring = self._register_ring()
+            ring = self._register_ring(cat)
         ring.append((t0, dur_s, name, cat, trace_id, args))
 
     def instant(self, name: str, *, trace_id: Optional[str] = None,
@@ -200,21 +258,27 @@ class Tracer:
 
     def span(self, name: str, *, trace_id: Optional[str] = None,
              cat: str = "serving",
-             args: Optional[Dict[str, Any]] = None) -> TraceSpan:
+             args: Optional[Dict[str, Any]] = None):
+        """A ``with`` region that is both a ring record and a profiler
+        annotation; the shared no-op span when the tracer is disabled."""
+        if not self.enabled:
+            return _NOOP_SPAN
         return TraceSpan(self, name, cat, trace_id, args)
 
-    def _register_ring(self) -> _Ring:
+    def _register_ring(self, cat: str) -> _Ring:
         ring = _Ring(self.capacity)
-        self._local.ring = ring
+        if getattr(self._local, "rings", None) is None:
+            self._local.rings = {}
+        self._local.rings[cat] = ring
         with self._lock:
             if len(self._rings) >= 32:
                 # Short-lived emitters (e.g. per-connection HTTP handler
                 # threads calling submit) would otherwise leak one ring
                 # per dead thread; prune rings whose thread is gone.
                 live = {t.ident for t in threading.enumerate()}
-                for tid in [t for t in self._rings if t not in live]:
-                    del self._rings[tid]
-            self._rings[threading.get_ident()] = ring
+                for key in [k for k in self._rings if k[0] not in live]:
+                    del self._rings[key]
+            self._rings[(threading.get_ident(), cat)] = ring
         return ring
 
     # -- export --------------------------------------------------------
@@ -224,7 +288,7 @@ class Tracer:
         with self._lock:
             rings = list(self._rings.items())
         out = []
-        for tid, ring in rings:
+        for (tid, _), ring in rings:
             for rec in ring.snapshot():
                 if trace_id is None or rec[4] == trace_id:
                     out.append((tid,) + rec)

@@ -216,11 +216,100 @@ class _TickFlight:
         self.lookup_hits = lookup_hits
 
 
+class _Phase:
+    """One named phase of the engine thread's loop, as a ``with`` region:
+    a tracer span (ring record + profiler annotation; nothing when
+    tracing is off) and, always, a ``(sum_s, max_s, count)`` accumulator
+    in plain engine-thread floats. One reusable object per name — a
+    phase never nests inside itself. ``last_s`` is the duration of the
+    region that just closed."""
+
+    __slots__ = ("name", "is_wait", "last_s", "_owner", "_span", "_t0")
+
+    def __init__(self, owner: "_HostPhases", name: str, is_wait: bool):
+        self.name = name
+        self.is_wait = is_wait       # the host blocked on the device
+        self.last_s = 0.0
+        self._owner = owner
+        self._span = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._span = self._owner.tracer.span(self.name, cat="phase")
+        self._span.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.last_s = dt = time.monotonic() - self._t0
+        self._span.__exit__(*exc)
+        owner = self._owner
+        owner.add(self.name, dt)
+        if not self.is_wait:
+            owner.covered_s += dt
+
+
+class _HostPhases:
+    """The engine loop's phases (module docstring of ``serving.metrics``:
+    ``HOST_PHASES``), measured where the work happens, plus the
+    ``chunk_to_dispatch`` interval. Everything accumulates here without
+    a lock and is handed over by :meth:`drain` to the ``record_tick`` /
+    ``record_prefill_chunk`` calls that take the stats lock anyway.
+
+    ``covered_s`` is the named non-wait time since the last reconcile
+    barrier: ``host_us_per_tick`` minus it is ``host_us/other``."""
+
+    def __init__(self, tracer: Tracer):
+        self.tracer = tracer
+        self.covered_s = 0.0
+        self._pending: dict = {}             # name -> [sum_s, max_s, count]
+        self._chunk_ready_t: Optional[float] = None
+        self.sweep = _Phase(self, "sweep", False)
+        self.admit = _Phase(self, "admit", False)
+        self.prefill_launch = _Phase(self, "prefill_launch", False)
+        self.prefill_wait = _Phase(self, "prefill_wait", True)
+        self.prefill_commit = _Phase(self, "prefill_commit", False)
+        self.tick_launch = _Phase(self, "tick_launch", False)
+        self.tick_wait = _Phase(self, "tick_wait", True)
+        self.tick_commit = _Phase(self, "tick_commit", False)
+        self.idle = _Phase(self, "idle", False)
+
+    def add(self, name: str, dt: float) -> None:
+        entry = self._pending.get(name)
+        if entry is None:
+            self._pending[name] = [dt, dt, 1]
+        else:
+            entry[0] += dt
+            if dt > entry[1]:
+                entry[1] = dt
+            entry[2] += 1
+
+    def chunk_ready(self) -> None:
+        """A prefill chunk's result is on the host: until the next device
+        launch returns, the device has nothing queued."""
+        self._chunk_ready_t = time.monotonic()
+
+    def launched(self) -> None:
+        """A device launch returned (or the loop went idle with nothing
+        to launch): closes an open ``chunk_to_dispatch`` interval."""
+        if self._chunk_ready_t is not None:
+            self.add("chunk_to_dispatch",
+                     time.monotonic() - self._chunk_ready_t)
+            self._chunk_ready_t = None
+
+    def drain(self) -> dict:
+        """What accumulated since the last drain, for ``ServingStats``."""
+        out, self._pending = self._pending, {}
+        return out
+
+
 class _TokenEmitter:
     """Off-thread ``on_token`` delivery: the engine thread enqueues
     (request, token) pairs — and a ``None``-token finish sentinel AFTER a
     retiring request's last token, the drain-on-retire barrier — and one
-    daemon thread drains them in order. A raising callback is recorded on
+    daemon thread drains them in order (each drained batch is one ``emit``
+    span; its wall and every token's commit→callback-return lag go to
+    the stats as ``emit`` / ``emit_lag``). A raising callback is recorded on
     the request (``_emit_error``); the engine's loop-top sweep turns that
     into the same FAILED retirement an inline callback failure produces.
     The queue is unbounded here; the ENGINE bounds it per request by
@@ -229,8 +318,11 @@ class _TokenEmitter:
     ``close()`` drains everything already queued, then joins — shutdown
     and failover never drop buffered tokens."""
 
-    def __init__(self, max_pending: int):
+    def __init__(self, max_pending: int, stats: ServingStats,
+                 tracer: Tracer):
         self.max_pending = int(max_pending)
+        self._stats = stats
+        self._tracer = tracer
         self._q: collections.deque = collections.deque()
         self._cv = threading.Condition()
         self._closed = False
@@ -249,15 +341,16 @@ class _TokenEmitter:
 
     def put(self, req, token: int):
         req._emit_pending += 1
+        committed_t = time.monotonic()
         with self._cv:
-            self._q.append((req, token))
+            self._q.append((req, token, committed_t))
             self._cv.notify()
 
     def finish(self, req):
         """Queue the completion sentinel — ``req._complete()`` runs only
         after every callback queued before it has been delivered."""
         with self._cv:
-            self._q.append((req, None))
+            self._q.append((req, None, 0.0))
             self._cv.notify()
 
     def close(self, timeout: Optional[float] = None):
@@ -276,19 +369,31 @@ class _TokenEmitter:
                     return  # closed and fully drained
                 batch = list(self._q)
                 self._q.clear()
-            for req, token in batch:
-                if token is None:
-                    req._complete()
-                    continue
-                if req._emit_error is None and req.on_token is not None:
-                    try:
-                        req.on_token(token)
-                    except BaseException as e:
-                        # Recorded, not raised: error isolation — the
-                        # engine retires THIS request FAILED at its next
-                        # sweep; the emitter keeps serving other streams.
-                        req._emit_error = e
-                req._emit_pending -= 1
+            t0 = time.monotonic()
+            lag_sum = lag_max = 0.0
+            tokens = 0
+            with self._tracer.span("emit", cat="phase"):
+                for req, token, committed_t in batch:
+                    if token is None:
+                        req._complete()
+                        continue
+                    if req._emit_error is None and req.on_token is not None:
+                        try:
+                            req.on_token(token)
+                        except BaseException as e:
+                            # Recorded, not raised: error isolation — the
+                            # engine retires THIS request FAILED at its
+                            # next sweep; the emitter keeps serving other
+                            # streams.
+                            req._emit_error = e
+                    req._emit_pending -= 1
+                    lag = time.monotonic() - committed_t
+                    lag_sum += lag
+                    lag_max = max(lag_max, lag)
+                    tokens += 1
+            dt = time.monotonic() - t0
+            self._stats.record_host({"emit": (dt, dt, 1),
+                                     "emit_lag": (lag_sum, lag_max, tokens)})
 
 
 class ServingEngine:
@@ -1047,6 +1152,8 @@ class ServingEngine:
         # is what isolates host_us_per_tick.
         self._blocked_s = 0.0
         self._last_complete_t: Optional[float] = None
+        self._phases = _HostPhases(self._tracer)
+        self._idle_entries = 0      # engine-thread writes; warmup() reads
         # Next decode tick that emits a tick_profile flight event (the
         # warmup reset re-arms it so a warmed engine still profiles its
         # first real tick instead of waiting out the 128-tick cadence).
@@ -1849,7 +1956,8 @@ class ServingEngine:
         self._heartbeat = (self._loop_iters, time.monotonic())
         self._heartbeat_frozen = False
         if self._async and (self._emitter is None or not self._emitter.alive):
-            self._emitter = _TokenEmitter(self._emission_queue)
+            self._emitter = _TokenEmitter(self._emission_queue,
+                                          self._stats, self._tracer)
         self._thread = threading.Thread(target=self._run,
                                         name="serving-engine", daemon=True)
         self._thread.start()
@@ -1881,6 +1989,13 @@ class ServingEngine:
                     raise TimeoutError("engine warmup did not finish "
                                        f"within {timeout}s")
                 self._raise_if_failed(r)
+        # The loop hands its last phase timings to the stats when it goes
+        # idle: reset only after that, or they would open the new window.
+        settled = self._idle_entries
+        deadline = time.monotonic() + timeout
+        while (self._idle_entries == settled and self.running
+               and time.monotonic() < deadline):
+            time.sleep(0.001)
         self._stats.reset()
         if self._prefix_cache is not None:
             self._prefix_cache.clear()
@@ -2363,47 +2478,49 @@ class ServingEngine:
         # DISPATCH tick N+1 → RECONCILE tick N — so every piece of host
         # work between the two barriers overlaps tick N+1's device time.
         flight: Optional[_TickFlight] = None
+        phases = self._phases
         try:
             while not self._stop:
-                # Liveness first: apply any scripted chaos (which may set
-                # the fail injection we check next), then publish the
-                # heartbeat — unless a chaos hang suppresses it, in which
-                # case a watchdog sees exactly what a wedged compiled call
-                # looks like while the loop itself keeps serving.
-                self._loop_iters += 1
-                if self._chaos is not None:
-                    self._chaos.apply(self)
-                if not self._heartbeat_frozen:
-                    self._heartbeat = (self._loop_iters, time.monotonic())
-                if self._fail_injection is not None:
-                    # Routed through the normal engine-fatal path below, so
-                    # an injected fault is indistinguishable from a real one
-                    # to everything downstream (router fencing included).
-                    raise self._fail_injection
-                if (self._accelerator is not None
-                        and getattr(self._accelerator, "preemption_requested", False)
-                        and not (self._drain or self._abort_queue)):
-                    # Preemption drain: stop admitting, let in-flight
-                    # requests finish, cancel the queue — the notice window
-                    # is for flushing work, not for taking more.
-                    self._accepting = False
-                    self._abort_queue = True
-                now = time.monotonic()
-                for _, req in self._slots.active():
-                    if req._emit_error is not None:
-                        # A streaming callback raised on the emitter
-                        # thread: same FAILED retirement (slot freed,
-                        # batch untouched) an inline failure produces.
-                        self._retire(req, RequestStatus.FAILED,
-                                     req._emit_error)
-                    elif req.cancel_requested:
-                        self._retire(req, RequestStatus.CANCELLED)
-                    elif req._deadline_passed(now):
-                        self._retire(req, RequestStatus.TIMED_OUT)
-                if self._abort_queue:
-                    for req in self._queue.drain():
-                        self._finish_req(req, RequestStatus.CANCELLED)
-                        self._stats.record_finish(req.status)
+                with phases.sweep:
+                    # Liveness first: apply any scripted chaos (which may set
+                    # the fail injection we check next), then publish the
+                    # heartbeat — unless a chaos hang suppresses it, in which
+                    # case a watchdog sees exactly what a wedged compiled call
+                    # looks like while the loop itself keeps serving.
+                    self._loop_iters += 1
+                    if self._chaos is not None:
+                        self._chaos.apply(self)
+                    if not self._heartbeat_frozen:
+                        self._heartbeat = (self._loop_iters, time.monotonic())
+                    if self._fail_injection is not None:
+                        # Routed through the normal engine-fatal path below, so
+                        # an injected fault is indistinguishable from a real one
+                        # to everything downstream (router fencing included).
+                        raise self._fail_injection
+                    if (self._accelerator is not None
+                            and getattr(self._accelerator, "preemption_requested", False)
+                            and not (self._drain or self._abort_queue)):
+                        # Preemption drain: stop admitting, let in-flight
+                        # requests finish, cancel the queue — the notice window
+                        # is for flushing work, not for taking more.
+                        self._accepting = False
+                        self._abort_queue = True
+                    now = time.monotonic()
+                    for _, req in self._slots.active():
+                        if req._emit_error is not None:
+                            # A streaming callback raised on the emitter
+                            # thread: same FAILED retirement (slot freed,
+                            # batch untouched) an inline failure produces.
+                            self._retire(req, RequestStatus.FAILED,
+                                         req._emit_error)
+                        elif req.cancel_requested:
+                            self._retire(req, RequestStatus.CANCELLED)
+                        elif req._deadline_passed(now):
+                            self._retire(req, RequestStatus.TIMED_OUT)
+                    if self._abort_queue:
+                        for req in self._queue.drain():
+                            self._finish_req(req, RequestStatus.CANCELLED)
+                            self._stats.record_finish(req.status)
                 # Bounded admission: spend at most chunks_per_tick chunk
                 # calls, ALTERNATING one continuation of the PREFILLING
                 # backlog (round-robin) with one new admission — so with a
@@ -2414,10 +2531,12 @@ class ServingEngine:
                 # the behavior this PR A/Bs against.
                 if self._chunk is None:
                     while self._slots.has_free():
-                        req = self._queue.get_nowait()
+                        with phases.admit:
+                            req = self._queue.get_nowait()
+                            ok = req is not None and self._screen(req, now)
                         if req is None:
                             break
-                        if self._screen(req, now):
+                        if ok:
                             self._admit(req)
                 else:
                     budget = self._chunks_per_tick
@@ -2427,16 +2546,21 @@ class ServingEngine:
                             budget -= 1
                             progressed = True
                         if budget > 0 and self._slots.has_free():
-                            req = self._queue.get_nowait()
+                            with phases.admit:
+                                req = self._queue.get_nowait()
+                                placed = (req is not None
+                                          and self._screen(req, now)
+                                          and self._begin_prefill(req))
                             if req is not None:
                                 progressed = True
-                                if self._screen(req, now):
-                                    budget = self._begin_prefill(req, budget)
-                                    if budget is None:
-                                        # Paged admission gate: the request
-                                        # went back to the queue front; stop
-                                        # admitting until decode frees pages.
-                                        break
+                                if placed is None:
+                                    # Paged admission gate: the request
+                                    # went back to the queue front; stop
+                                    # admitting until decode frees pages.
+                                    break
+                                if placed:
+                                    self._run_chunk(req)
+                                    budget -= 1
                         if not progressed:
                             break
                 running = [(slot, req) for slot, req in self._slots.active()
@@ -2471,7 +2595,9 @@ class ServingEngine:
                             # controlled or preempted) and nothing in
                             # flight: yield so consumers can drain
                             # instead of hot-spinning the loop.
-                            time.sleep(min(self._idle_poll_s, 0.001))
+                            self._go_idle()
+                            with phases.idle:
+                                time.sleep(min(self._idle_poll_s, 0.001))
                     else:
                         # Sync A/B fallback: dispatch and immediately
                         # reconcile — the strictly tick-synchronous
@@ -2488,6 +2614,7 @@ class ServingEngine:
                     flight = None
                     continue
                 self._last_complete_t = None   # ITL intervals restart
+                phases.covered_s = 0.0
                 if self._slots.active_slots:
                     pass  # prefill-only batch: loop again without idling
                 elif self._drain and not len(self._queue):
@@ -2500,12 +2627,20 @@ class ServingEngine:
                     # through the SAME screen as the busy path — one
                     # cancelled or deadline-expired while the engine idled
                     # must not be prefilled (or billed in stats).
-                    req = self._queue.get(timeout=self._idle_poll_s)
-                    if req is not None and self._screen(req, time.monotonic()):
+                    self._go_idle()
+                    with phases.idle:
+                        req = self._queue.get(timeout=self._idle_poll_s)
+                    if req is None:
+                        continue
+                    with phases.admit:
+                        placed = (self._screen(req, time.monotonic())
+                                  and (self._chunk is None
+                                       or self._begin_prefill(req)))
+                    if placed:
                         if self._chunk is None:
                             self._admit(req)
                         else:
-                            self._begin_prefill(req, self._chunks_per_tick)
+                            self._run_chunk(req)
         except BaseException as e:  # engine-fatal: fail everything loudly
             self._error = e
             # Black-box capture at the moment of death: the fatal event
@@ -2537,6 +2672,15 @@ class ServingEngine:
                 # failover handlers (``_on_finish``) all fire before the
                 # engine thread exits.
                 self._emitter.close()
+
+    def _go_idle(self):
+        """Before the loop waits with nothing launched: an open
+        ``chunk_to_dispatch`` interval ends here (waiting for a request is
+        not the device waiting for the host), and the phase timings still
+        held go to the stats now, since no tick will carry them."""
+        self._phases.launched()
+        self._stats.record_host(self._phases.drain())
+        self._idle_entries += 1
 
     def _screen(self, req: Request, now: float) -> bool:
         """The check-then-admit gate both pop paths share: a request whose
@@ -2762,15 +2906,17 @@ class ServingEngine:
         return max(min(_bucket128(S), self._chunk_limit), S)
 
     # -- chunked prefill ------------------------------------------------
-    def _begin_prefill(self, req: Request, budget: int) -> Optional[int]:
-        """Assign a slot, restore the longest cached chunk-aligned prefix
-        (restores are not billed against the chunk budget — they are why
-        the cache pays), and run the request's first live chunk. Returns
-        the remaining budget — or ``None`` when the paged admission gate
-        refuses: the prompt needs more pages than are free or reclaimable,
-        so the request goes back to the queue FRONT and the caller stops
-        admitting until decode progress frees pages (admitting anyway
-        would just trigger preemption thrash).
+    def _begin_prefill(self, req: Request) -> Optional[bool]:
+        """Assign a slot and restore the longest cached chunk-aligned
+        prefix (restores are not billed against the chunk budget — they
+        are why the cache pays); the caller then runs the request's first
+        live chunk (``_run_chunk``) outside its ``admit`` phase. Returns
+        True when the request is placed, False when it was retired
+        instead (its adapter could not be acquired) — or ``None`` when the
+        paged admission gate refuses: the prompt needs more pages than
+        are free or reclaimable, so the request goes back to the queue
+        FRONT and the caller stops admitting until decode progress frees
+        pages (admitting anyway would just trigger preemption thrash).
 
         A paged engine prefills ``req._serve_ids`` — the original prompt,
         or prompt + committed tokens after a preemption — so the same code
@@ -2793,7 +2939,7 @@ class ServingEngine:
                     self._stats.record_finish(req.status)
                 return None
         if not self._acquire_adapter(req):
-            return budget
+            return False
         req.admitted_at = time.monotonic()
         slot = self._slots.assign(req)
         self._flight.record("admission", trace_id=req.trace_id, slot=slot,
@@ -2876,8 +3022,7 @@ class ServingEngine:
                               "bytes": restored_bytes})
                 req._next_chunk = len(blocks)
         self._prefilling.append(req)
-        self._run_chunk(req)
-        return budget - 1
+        return True
 
     def _prefix_keys(self, prompt_ids, n_full: int,
                      adapter: Optional[str] = None) -> list[bytes]:
@@ -2952,51 +3097,67 @@ class ServingEngine:
         few already-prefilled positions writes bit-identical KV. Full
         chunks feed the prefix cache with the block the executable already
         returned."""
-        i = req._next_chunk
-        C = self._chunk
-        S = req._serve_ids.shape[1]
-        final = i == req._chunks_total - 1
-        offset = min(i * C, self._chunk_cap) if final else i * C
-        ids_c = req._serve_ids[:, offset:offset + C]
-        if ids_c.shape[1] < C:
-            ids_c = np.pad(ids_c, ((0, 0), (0, C - ids_c.shape[1])),
-                           mode="edge")
-        t0 = time.monotonic()
-        if self._paged:
-            # Cover the chunk's whole write span (including the edge-pad
-            # tail — decode writes land there next) before the call; the
-            # program scatters only into these table entries.
-            if not self._ensure_pages(req, offset + C - 1):
-                raise RuntimeError(
-                    "page pool exhausted mid-prefill with no preemptable "
-                    "stream — the submit page bound should make this "
-                    "impossible")
-            extra = self._adapter_args(req)
-            if self._spec_mode == "draft":
-                if not self._ensure_draft_pages(req, offset + C - 1):
+        phases = self._phases
+        with phases.prefill_launch:
+            i = req._next_chunk
+            C = self._chunk
+            S = req._serve_ids.shape[1]
+            final = i == req._chunks_total - 1
+            offset = min(i * C, self._chunk_cap) if final else i * C
+            ids_c = req._serve_ids[:, offset:offset + C]
+            if ids_c.shape[1] < C:
+                ids_c = np.pad(ids_c, ((0, 0), (0, C - ids_c.shape[1])),
+                               mode="edge")
+            t0 = time.monotonic()
+            if self._paged:
+                # Cover the chunk's whole write span (including the
+                # edge-pad tail — decode writes land there next) before the
+                # call; the program scatters only into these table entries.
+                if not self._ensure_pages(req, offset + C - 1):
                     raise RuntimeError(
-                        "page pool exhausted mid-prefill for draft KV — "
-                        "the admission gate's draft factor should make "
-                        "this impossible")
-                extra += (self._draft_params, self._dtable[req.slot].copy())
-            self._state, tok, block = self._prefill_chunk(
-                self.params, self._state, ids_c, np.int32(req.slot),
-                self._table[req.slot].copy(), np.int32(offset), np.int32(S),
-                req._rng_key, *extra)
-        else:
-            self._state, tok, block = self._prefill_chunk(
-                self.params, self._state, ids_c, np.int32(req.slot),
-                np.int32(offset), np.int32(S), req._rng_key,
-                *self._adapter_args(req))
-        tb = time.monotonic()
-        tok.block_until_ready()  # honest chunk timing, paced dispatch
+                        "page pool exhausted mid-prefill with no "
+                        "preemptable stream — the submit page bound should "
+                        "make this impossible")
+                extra = self._adapter_args(req)
+                if self._spec_mode == "draft":
+                    if not self._ensure_draft_pages(req, offset + C - 1):
+                        raise RuntimeError(
+                            "page pool exhausted mid-prefill for draft KV — "
+                            "the admission gate's draft factor should make "
+                            "this impossible")
+                    extra += (self._draft_params,
+                              self._dtable[req.slot].copy())
+                self._state, tok, block = self._prefill_chunk(
+                    self.params, self._state, ids_c, np.int32(req.slot),
+                    self._table[req.slot].copy(), np.int32(offset),
+                    np.int32(S), req._rng_key, *extra)
+            else:
+                self._state, tok, block = self._prefill_chunk(
+                    self.params, self._state, ids_c, np.int32(req.slot),
+                    np.int32(offset), np.int32(S), req._rng_key,
+                    *self._adapter_args(req))
+            phases.launched()
+        with phases.prefill_wait:
+            tok.block_until_ready()  # honest chunk timing, paced dispatch
+        phases.chunk_ready()
         # The wait is device time (this chunk, plus any in-flight tick it
         # queued behind) — excluded from host_us_per_tick.
-        self._blocked_s += time.monotonic() - tb
+        self._blocked_s += phases.prefill_wait.last_s
+        with phases.prefill_commit:
+            self._commit_chunk(req, i, offset, final, tok, block, t0)
+
+    def _commit_chunk(self, req: Request, i: int, offset: int, final: bool,
+                      tok, block, t0: float):
+        """The host side of a finished chunk (phase ``prefill_commit``):
+        stats and the request-scoped span, the prefix-cache put, and on
+        the final chunk the first-token commit."""
+        C = self._chunk
+        S = req._serve_ids.shape[1]
         dt_ms = (time.monotonic() - t0) * 1e3
         backlog = sum(1 for r in self._prefilling
                       if r.status is RequestStatus.PREFILLING)
-        self._stats.record_prefill_chunk(dt_ms, backlog=backlog)
+        self._stats.record_prefill_chunk(dt_ms, backlog=backlog,
+                                         host=self._phases.drain())
         self._tracer.emit(
             "prefill_chunk", t0, dt_ms / 1e3, trace_id=req.trace_id,
             args={"chunk": i, "of": req._chunks_total, "offset": offset,
@@ -3085,9 +3246,14 @@ class ServingEngine:
         (the in-flight commit's write). A stream that instead retires on
         EOS at the in-flight tick stays masked in — its lane advances
         once more and the stray token is discarded by the reconcile
-        validity check (exactly-once emission)."""
-        if self._spec_k is not None:
-            return self._dispatch_spec(running, ahead)
+        validity check (exactly-once emission). The whole of it is the
+        host phase ``tick_launch``."""
+        with self._phases.tick_launch:
+            if self._spec_k is not None:
+                return self._dispatch_spec(running, ahead)
+            return self._dispatch_dense(running, ahead)
+
+    def _dispatch_dense(self, running, ahead: bool) -> Optional[_TickFlight]:
         live = []
         for slot, req in running:
             if ahead and req.max_new_tokens - len(req.tokens) <= 1:
@@ -3126,6 +3292,7 @@ class ServingEngine:
         if self._adapters is not None:
             args.append(self._adapters.stacks)
         self._state, toks, dones = self._decode(*args)
+        self._phases.launched()
         return _TickFlight(
             entries=[(slot, req, req._preempted) for slot, req in live],
             t_dispatch=t0, toks=toks, dones=dones)
@@ -3149,15 +3316,27 @@ class ServingEngine:
             w, self._wedge_s = self._wedge_s, 0.0
             time.sleep(w)
         spec = flight.emit is not None
-        tb = time.monotonic()
-        if spec:
-            emit = np.asarray(flight.emit)
-            ns = np.asarray(flight.ns)
-        else:
-            toks = np.asarray(flight.toks)
-            dones = np.asarray(flight.dones)
-        t1 = time.monotonic()
-        self._blocked_s += t1 - tb
+        phases = self._phases
+        emit = ns = toks = dones = None
+        with phases.tick_wait:
+            if spec:
+                emit = np.asarray(flight.emit)
+                ns = np.asarray(flight.ns)
+            else:
+                toks = np.asarray(flight.toks)
+                dones = np.asarray(flight.dones)
+            t1 = time.monotonic()
+        self._blocked_s += phases.tick_wait.last_s
+        with phases.tick_commit:
+            self._commit_tick(flight, t1, emit, ns, toks, dones)
+
+    def _commit_tick(self, flight: _TickFlight, t1: float, emit, ns, toks,
+                     dones):
+        """The host side of a settled tick (phase ``tick_commit``): the
+        timing split, the commit loop, retirements, emitter puts, stats
+        and page samples."""
+        spec = emit is not None
+        phases = self._phases
         if not self._heartbeat_frozen:
             # Reconcile-barrier heartbeat: between loop tops the engine
             # may sit in this block for a whole device tick — republish
@@ -3168,6 +3347,8 @@ class ServingEngine:
         self._last_complete_t = t1
         host_s = max(0.0, interval - self._blocked_s)
         self._blocked_s = 0.0
+        other_s = max(0.0, host_s - phases.covered_s)
+        phases.covered_s = 0.0
         committed = accepted = n_valid = 0
         for slot, req, epoch in flight.entries:
             if (req.status is not RequestStatus.RUNNING
@@ -3213,7 +3394,9 @@ class ServingEngine:
         self._stats.record_tick(active_slots=len(flight.entries),
                                 committed_tokens=committed,
                                 max_slots=self.max_slots, seconds=interval,
-                                host_us=host_s * 1e6)
+                                host_us=host_s * 1e6,
+                                other_us=other_s * 1e6,
+                                host=phases.drain())
         tracer = self._tracer
         if tracer.enabled:
             targs = {"active": len(flight.entries), "committed": committed,
@@ -3325,6 +3508,7 @@ class ServingEngine:
                 self.params, self._draft_params, self._state,
                 jnp.asarray(mask), self._table.copy(), self._dtable.copy(),
                 remaining, *bank)
+        self._phases.launched()
         return _TickFlight(
             entries=[(slot, req, req._preempted) for slot, req in live],
             t_dispatch=t0, emit=emit, ns=ns, lookup_hits=lookup_hits)

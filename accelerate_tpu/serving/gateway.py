@@ -79,7 +79,8 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 from ..adapters.registry import AdapterBankFull
-from ..observability import clean_trace_id, new_trace_id
+from ..observability import (Tracer, clean_trace_id, merge_chrome_traces,
+                             new_trace_id)
 from .engine import ServingEngine
 from .metrics import HISTOGRAM_NAMES, GatewayStats
 from .request import RequestStatus
@@ -244,6 +245,24 @@ _METRIC_HELP = {
         "non-device share of ITL the async host runtime overlaps.",
     "accelerate_tpu_serving_host_us_per_tick_max":
         "Worst observed host scheduling+commit wall for one tick (us).",
+    "accelerate_tpu_serving_host_us":
+        "Host wall per named phase of the serving loop (us): mean per "
+        "prefill chunk for prefill_*, per decode tick for the others; "
+        "phase=other is the part of host_us_per_tick no phase covered.",
+    "accelerate_tpu_serving_host_us_max":
+        "Longest single span of each host phase (us) — a stalled prefill "
+        "chunk shows as prefill_launch, prefill_wait or prefill_commit.",
+    "accelerate_tpu_serving_chunk_to_dispatch_ms":
+        "Mean time from a prefill chunk's result being ready on the host "
+        "to the return of the engine's next device launch (ms): the device "
+        "has nothing queued meanwhile.",
+    "accelerate_tpu_serving_chunk_to_dispatch_ms_max":
+        "Longest chunk-ready to next-launch interval (ms).",
+    "accelerate_tpu_serving_emit_lag_ms":
+        "Mean time from a token's commit on the engine thread to the "
+        "return of its on_token callback on the emitter thread (ms).",
+    "accelerate_tpu_serving_emit_lag_ms_max":
+        "Longest commit to on_token-return lag of one token (ms).",
     "accelerate_tpu_serving_emission_stalls":
         "Decode-tick skips of streams whose bounded emission queue was "
         "full (slow on_token consumer flow-controlled).",
@@ -437,6 +456,11 @@ class ServingGateway:
         if stats is None and accelerator is not None:
             stats = getattr(accelerator, "gateway_stats", None)
         self.stats = stats if stats is not None else GatewayStats()
+        # The wire layer's spans (gw.accept / gw.route / gw.sse_write /
+        # gw.done): synchronous sections only, merged into /debug/trace
+        # beside the replicas' and, under a jax profiler session, onto
+        # the device trace's clock.
+        self.tracer = Tracer(name="gateway")
         # Tenant policy (control plane): built once from config; both
         # front ends consult them through submit_or_error only.
         from .control import FairShareAdmission, TenantRateLimiter
@@ -593,7 +617,8 @@ class ServingGateway:
         return clamp_retry_after(cfg, deficit / rate)
 
     def submit_or_error(self, spec: dict, trace_id: str, on_token=None):
-        """Admit one parsed completion spec: ``(fleet, None)`` on success,
+        """Admit one parsed completion spec (span ``gw.accept``; the
+        router's replica choice inside it is ``gw.route``): ``(fleet, None)`` on success,
         ``(None, (code, payload, extra_headers))`` on any refusal —
         rate-limit 429, fair-share 429, projected-pressure 429,
         queue-full 429, unknown-adapter 404, no-healthy-replica 503, or
@@ -607,6 +632,10 @@ class ServingGateway:
         released exactly once via the fleet request's done callback —
         including failure/cancel terminals), then the fleet-pressure and
         submit paths exactly as before."""
+        with self.tracer.span("gw.accept", trace_id=trace_id):
+            return self._submit_or_error(spec, trace_id, on_token)
+
+    def _submit_or_error(self, spec: dict, trace_id: str, on_token):
         cfg = self.config
         retry_headers = {"Retry-After": f"{cfg.retry_after_s:g}"}
         tenant = tenant_of(spec)
@@ -651,15 +680,16 @@ class ServingGateway:
                                "retry later"},
                 {"Retry-After": f"{retry_in:g}"}))
         try:
-            fleet = self.replica_set.submit(
-                spec["prompt_ids"],
-                max_new_tokens=spec["max_new_tokens"],
-                seed=spec["seed"], timeout=spec["timeout"],
-                ignore_eos=spec["ignore_eos"],
-                adapter=spec["adapter"],
-                priority=spec.get("priority"),
-                trace_id=trace_id,
-                on_token=on_token)
+            with self.tracer.span("gw.route", trace_id=trace_id):
+                fleet = self.replica_set.submit(
+                    spec["prompt_ids"],
+                    max_new_tokens=spec["max_new_tokens"],
+                    seed=spec["seed"], timeout=spec["timeout"],
+                    ignore_eos=spec["ignore_eos"],
+                    adapter=spec["adapter"],
+                    priority=spec.get("priority"),
+                    trace_id=trace_id,
+                    on_token=on_token)
         except QueueFull:
             return _refuse((429, {"error": "all replicas saturated; "
                                            "retry later"}, retry_headers))
@@ -680,6 +710,45 @@ class ServingGateway:
                 lambda _f, fs=self.fair_share, t=tenant: fs.release(t))
         return fleet, None
 
+    # -- SSE frames and traces (shared by both front ends) -----------------
+    def write_sse_token(self, write, fleet, tok) -> None:
+        """One token event through ``write`` (span ``gw.sse_write``)."""
+        with self.tracer.span("gw.sse_write", trace_id=fleet.trace_id):
+            write(f"data: {json.dumps({'token': int(tok)})}\n\n".encode())
+
+    def write_sse_done(self, write, fleet) -> int:
+        """The final summary event through ``write`` (span ``gw.done``):
+        the terminal status (and failover count) lets a client tell a
+        complete stream from a truncated one. Returns the HTTP code the
+        exchange is accounted under."""
+        with self.tracer.span("gw.done", trace_id=fleet.trace_id):
+            code, status = _STATUS_HTTP[fleet.status]
+            final = summary_payload(fleet, status)
+            final["done"] = True
+            if fleet.status is not RequestStatus.COMPLETED:
+                final["error"] = (str(fleet.error)
+                                  if fleet.error is not None else status)
+            write(f"data: {json.dumps(final)}\n\n".encode())
+        return code
+
+    def debug_trace(self, query: dict) -> tuple:
+        """``GET /debug/trace`` — ``(code, payload)``: the whole fleet's
+        buffered spans, the gateway's own beside the replicas', as one
+        Chrome-trace JSON; ``?id=<trace_id>`` narrows to one request's
+        timeline (404 when nothing buffered a span for that id)."""
+        raw = (query.get("id") or [None])[0]
+        tid = None
+        if raw is not None:
+            tid = clean_trace_id(raw)
+            if tid is None:
+                return 400, {"error": "invalid trace id"}
+        trace = merge_chrome_traces([self.replica_set.chrome_trace(tid),
+                                     self.tracer.chrome_trace(tid)])
+        if tid is not None and not any(
+                ev.get("ph") != "M" for ev in trace["traceEvents"]):
+            return 404, {"error": "trace not found", "trace_id": tid}
+        return 200, trace
+
     # -- metrics ----------------------------------------------------------
     def metrics_text(self) -> str:
         """The ``/metrics`` body: Prometheus text exposition (version
@@ -699,10 +768,21 @@ class ServingGateway:
             lines.append(f"{name} {int(v) if v == int(v) else v}")
 
         merged = self.replica_set.merged_stats()
+        by_phase = {"host_us": [], "host_us_max": []}
         for k, v in self.replica_set.fleet_metrics().items():
-            if k.startswith(("adapter/", "priority/")):
-                continue  # re-emitted below as properly labeled series
-            emit(f"accelerate_tpu_serving_{k}", v)
+            fam, slash, label = k.partition("/")
+            if slash and fam in by_phase:
+                by_phase[fam].append((label, v))
+            elif not k.startswith(("adapter/", "priority/")):
+                emit(f"accelerate_tpu_serving_{k}", v)
+            # (the slash-pathed keys are re-emitted as labeled series)
+        for fam, series in by_phase.items():
+            name = f"accelerate_tpu_serving_{fam}"
+            lines.append(f"# HELP {name} {_METRIC_HELP[name]}")
+            lines.append(f"# TYPE {name} gauge")
+            lines.extend(f'{name}{{phase="{phase}"}} '
+                         f'{int(v) if v == int(v) else v}'
+                         for phase, v in series)
         # Latency distributions: the *_ms summary gauges above keep their
         # names; the histogram twin gets a _hist-suffixed family so the
         # two never collide in one exposition.
@@ -861,22 +941,8 @@ class _Handler(BaseHTTPRequestHandler):
         """``GET /debug/trace`` — the whole fleet's buffered spans as one
         Chrome-trace JSON; ``?id=<trace_id>`` narrows to one request's
         timeline (404 when no replica buffered a span for that id)."""
-        route = "/debug/trace"
-        raw = (query.get("id") or [None])[0]
-        tid = None
-        if raw is not None:
-            tid = clean_trace_id(raw)
-            if tid is None:
-                self._send_json(400, {"error": "invalid trace id"}, route)
-                return
-        trace = self.gateway.replica_set.chrome_trace(tid)
-        if tid is not None and not any(
-                ev.get("ph") != "M" for ev in trace["traceEvents"]):
-            self._send_json(404, {"error": "trace not found",
-                                  "trace_id": tid}, route)
-            return
-        self._send_text(200, json.dumps(trace), route,
-                        content_type="application/json")
+        code, payload = self.gateway.debug_trace(query)
+        self._send_json(code, payload, "/debug/trace")
 
     # -- POST -------------------------------------------------------------
     def do_POST(self):  # noqa: N802
@@ -987,18 +1053,11 @@ class _Handler(BaseHTTPRequestHandler):
                         self.wfile.flush()
                         last_write = time.monotonic()
                     continue
-                self.wfile.write(
-                    f"data: {json.dumps({'token': int(tok)})}\n\n".encode())
+                self.gateway.write_sse_token(self.wfile.write, fleet, tok)
                 self.wfile.flush()
                 last_write = time.monotonic()
                 sent += 1
-            code, status = _STATUS_HTTP[fleet.status]
-            final = summary_payload(fleet, status)
-            final["done"] = True
-            if fleet.status is not RequestStatus.COMPLETED:
-                final["error"] = (str(fleet.error)
-                                  if fleet.error is not None else status)
-            self.wfile.write(f"data: {json.dumps(final)}\n\n".encode())
+            code = self.gateway.write_sse_done(self.wfile.write, fleet)
             self.wfile.flush()
         except (BrokenPipeError, ConnectionResetError):
             fleet.cancel()

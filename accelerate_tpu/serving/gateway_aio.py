@@ -47,9 +47,7 @@ from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
 from ..observability import clean_trace_id, new_trace_id
-from .gateway import (_STATUS_HTTP, _BadRequest, completion_result,
-                      parse_completion, summary_payload)
-from .request import RequestStatus
+from .gateway import _BadRequest, completion_result, parse_completion
 
 __all__ = ["AsyncioGatewayServer"]
 
@@ -272,23 +270,8 @@ class AsyncioGatewayServer:
         return True
 
     def _debug_trace(self, writer, query: dict):
-        route = "/debug/trace"
-        raw = (query.get("id") or [None])[0]
-        tid = None
-        if raw is not None:
-            tid = clean_trace_id(raw)
-            if tid is None:
-                self._send_json(writer, 400, {"error": "invalid trace id"},
-                                route)
-                return
-        trace = self.gateway.replica_set.chrome_trace(tid)
-        if tid is not None and not any(
-                ev.get("ph") != "M" for ev in trace["traceEvents"]):
-            self._send_json(writer, 404, {"error": "trace not found",
-                                          "trace_id": tid}, route)
-            return
-        self._send_text(writer, 200, json.dumps(trace), route,
-                        content_type="application/json")
+        code, payload = self.gateway.debug_trace(query)
+        self._send_json(writer, code, payload, "/debug/trace")
 
     # -- completions -------------------------------------------------------
     async def _completions(self, reader, writer, headers,
@@ -408,8 +391,7 @@ class AsyncioGatewayServer:
                     get_task = None
                     if item is _StreamBridge.DONE:
                         break
-                    writer.write(
-                        f"data: {json.dumps({'token': item})}\n\n".encode())
+                    gw.write_sse_token(writer.write, fleet, item)
                     await writer.drain()
                     sent += 1
                     continue
@@ -428,13 +410,7 @@ class AsyncioGatewayServer:
                 # Neither task fired within the heartbeat window.
                 writer.write(b": ping\n\n")
                 await writer.drain()
-            code, status = _STATUS_HTTP[fleet.status]
-            final = summary_payload(fleet, status)
-            final["done"] = True
-            if fleet.status is not RequestStatus.COMPLETED:
-                final["error"] = (str(fleet.error)
-                                  if fleet.error is not None else status)
-            writer.write(f"data: {json.dumps(final)}\n\n".encode())
+            code = gw.write_sse_done(writer.write, fleet)
             await writer.drain()
         except ConnectionError:
             fleet.cancel()

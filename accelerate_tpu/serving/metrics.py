@@ -40,6 +40,16 @@ LATENCY_BUCKETS_MS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 #: The per-request phase latencies exported as Prometheus histograms.
 HISTOGRAM_NAMES = ("ttft_ms", "itl_ms", "queue_wait_ms", "prefill_chunk_ms")
 
+#: The serving host path's phases, timed where the work happens (the
+#: engine thread's loop, and ``emit`` on the emitter thread). Each is a
+#: tracer span of the same name and an always-on accumulator; a chunk's
+#: phases are reported per prefill chunk, the others per decode tick.
+CHUNK_PHASES = ("prefill_launch", "prefill_wait", "prefill_commit")
+HOST_PHASES = ("sweep", "admit") + CHUNK_PHASES + (
+    "tick_launch", "tick_wait", "tick_commit", "idle", "emit")
+#: Intervals accumulated the same way but averaged over their own count.
+HOST_INTERVALS = ("chunk_to_dispatch", "emit_lag")
+
 
 class LatencyHistogram:
     """Fixed-bucket latency histogram (Prometheus-shaped).
@@ -154,7 +164,11 @@ class ServingStats:
             self._host_us_sum = 0.0
             self._host_us_max = 0.0
             self._host_us_ticks = 0
+            self._host_other_us_sum = 0.0
             self._emission_stalls = 0
+            # Host phases and intervals: name -> [sum_s, max_s, count].
+            self._host = {name: [0.0, 0.0, 0]
+                          for name in HOST_PHASES + HOST_INTERVALS}
             # Speculative decoding: draft proposals vs target acceptances.
             self._spec_ticks = 0
             self._spec_proposed = 0
@@ -205,9 +219,28 @@ class ServingStats:
             self._hists["queue_wait_ms"].observe(queue_wait_ms)
             self._hists["ttft_ms"].observe(ttft_ms)
 
+    def _fold_host(self, timings: Optional[dict]) -> None:
+        # call with self._lock held
+        for name, (sum_s, max_s, count) in (timings or {}).items():
+            entry = self._host[name]
+            entry[0] += sum_s
+            entry[1] = max(entry[1], max_s)
+            entry[2] += count
+
+    def record_host(self, timings: dict):
+        """Fold ``name -> (sum_s, max_s, count)`` host-phase timings in
+        on their own: the emitter thread's per-batch ``emit`` /
+        ``emit_lag``, and what the engine thread still holds when it goes
+        idle. On the hot path the same dict rides ``record_tick`` /
+        ``record_prefill_chunk`` (``host=``), which already take the
+        lock."""
+        with self._lock:
+            self._fold_host(timings)
+
     def record_tick(self, active_slots: int, committed_tokens: int,
                     max_slots: int, seconds: float,
-                    host_us: Optional[float] = None):
+                    host_us: Optional[float] = None,
+                    other_us: float = 0.0, host: Optional[dict] = None):
         """One ``decode_step_all_slots`` execution.
 
         ``seconds`` is the device-complete→device-complete interval for
@@ -216,8 +249,12 @@ class ServingStats:
         interval is the one a consumer actually experiences between
         tokens.) ``host_us`` is the tick's host scheduling + commit wall
         in microseconds — the part of the interval NOT spent waiting on
-        the device, i.e. the host overhead the async runtime hides."""
+        the device, i.e. the host overhead the async runtime hides;
+        ``other_us`` is the part of it no named phase covered, and
+        ``host`` the phase timings measured since the last record."""
         with self._lock:
+            self._fold_host(host)
+            self._host_other_us_sum += float(other_us)
             self._ticks += 1
             self._tick_s_sum += seconds
             self._active_slot_sum += int(active_slots)
@@ -236,11 +273,14 @@ class ServingStats:
         with self._lock:
             self._emission_stalls += 1
 
-    def record_prefill_chunk(self, ms: float, backlog: int = 0):
+    def record_prefill_chunk(self, ms: float, backlog: int = 0,
+                             host: Optional[dict] = None):
         """One ``prefill_chunk`` execution; ``backlog`` is the number of
         requests in ``PREFILLING`` at the time of the call (how much
-        admission work is still pending behind the per-tick budget)."""
+        admission work is still pending behind the per-tick budget);
+        ``host`` the phase timings measured since the last record."""
         with self._lock:
+            self._fold_host(host)
             self._prefill_chunks += 1
             self._prefill_ms_sum += ms
             self._hists["prefill_chunk_ms"].observe(ms)
@@ -402,7 +442,9 @@ class ServingStats:
             o_priority = {name: dict(e)
                           for name, e in other._priority.items()}
             o_hists = {name: h.copy() for name, h in other._hists.items()}
+            o_host = {name: tuple(e) for name, e in other._host.items()}
         with self._lock:
+            self._fold_host(o_host)
             for name, hist in o_hists.items():
                 mine = self._hists.get(name)
                 if mine is None:
@@ -432,7 +474,8 @@ class ServingStats:
                       "_preemptions", "_spec_ticks", "_spec_proposed",
                       "_spec_accepted", "_spec_lookup_slots",
                       "_spec_lookup_hits", "_host_us_sum",
-                      "_host_us_ticks", "_emission_stalls"):
+                      "_host_us_ticks", "_host_other_us_sum",
+                      "_emission_stalls"):
                 setattr(self, k, getattr(self, k) + o[k])
             for k in ("_queue_wait_ms_max", "_ttft_ms_max",
                       "_prefill_backlog_max", "_host_us_max",
@@ -544,6 +587,28 @@ class ServingStats:
                 # engine is bit-exact or never sampled).
                 "logprob_drift": round(self._logprob_drift, 6),
             }
+            # The host path by phase ("host_us/<phase>", slash-pathed like
+            # the adapter keys; the gateway re-emits them as one labeled
+            # family): mean per prefill chunk for a chunk's phases, per
+            # decode tick for the others, and the longest single span.
+            # "other" is the part of host_us_per_tick no phase covered.
+            for name in HOST_PHASES:
+                sum_s, max_s, _ = self._host[name]
+                per = max(1, self._prefill_chunks if name in CHUNK_PHASES
+                          else self._ticks)
+                out[f"host_us/{name}"] = round(sum_s * 1e6 / per, 3)
+                out[f"host_us_max/{name}"] = round(max_s * 1e6, 3)
+            out["host_us/other"] = round(
+                self._host_other_us_sum / max(1, self._host_us_ticks), 3)
+            # chunk_to_dispatch: a prefill chunk's result ready on the host
+            # -> the return of the engine thread's next device launch (the
+            # device has nothing queued meanwhile). emit_lag: a token's
+            # commit on the engine thread -> its on_token callback's return.
+            for name in HOST_INTERVALS:
+                sum_s, max_s, count = self._host[name]
+                out[f"{name}_ms"] = round(sum_s * 1e3 / max(1, count), 3)
+                out[f"{name}_ms_max"] = round(max_s * 1e3, 3)
+                out[f"{name}_count"] = count
             # Multi-tenant LoRA: flat aggregates plus per-name counters
             # ("adapter/<name>/<counter>" — slash-pathed like tracker keys;
             # the gateway re-emits these as labeled Prometheus series).

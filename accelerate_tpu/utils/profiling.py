@@ -309,7 +309,6 @@ class ProfileSession:
         self.pipeline_stats = pipeline_stats
         self.serving_stats = serving_stats
         self.gateway_stats = gateway_stats
-        self._step_breakdowns: list[dict] = []
         # Host-side span sink (observability.Tracer): each step() emits a
         # "train_step" span in the same Chrome-trace format the serving
         # engine uses, so a training timeline and a serving timeline can
@@ -350,19 +349,20 @@ class ProfileSession:
         return self
 
     def attach_pipeline_stats(self, stats: PipelineStats):
-        """Attach input-pipeline counters so ``step()`` snapshots them."""
+        """Attach input-pipeline counters (``data_breakdown()`` and the
+        ``train_step`` span's ``args`` read them)."""
         self.pipeline_stats = stats
         return self
 
     def attach_serving_stats(self, stats):
         """Attach serving-engine counters (``serving.metrics.ServingStats``)
-        so ``step()`` snapshots them under ``serving/`` keys."""
+        for ``serving_breakdown()``."""
         self.serving_stats = stats
         return self
 
     def attach_gateway_stats(self, stats):
         """Attach HTTP gateway counters (``serving.metrics.GatewayStats``)
-        so ``step()`` snapshots them under ``gateway/`` keys."""
+        for ``gateway_breakdown()``."""
         self.gateway_stats = stats
         return self
 
@@ -388,18 +388,6 @@ class ProfileSession:
                                  now - self._last_step_t, cat="training",
                                  args=args)
             self._last_step_t = now
-        if (self.pipeline_stats is not None or self.serving_stats is not None
-                or self.gateway_stats is not None):
-            snap = {"step": self._step}
-            if self.pipeline_stats is not None:
-                snap.update(self.pipeline_stats.summary())
-            if self.serving_stats is not None:
-                snap.update({f"serving/{k}": v
-                             for k, v in self.serving_stats.summary().items()})
-            if self.gateway_stats is not None:
-                snap.update({f"gateway/{k}": v
-                             for k, v in self.gateway_stats.summary().items()})
-            self._step_breakdowns.append(snap)
         self._step += 1
         should = self._should_trace()
         if should and not self._tracing:
@@ -430,26 +418,16 @@ class ProfileSession:
             return {}
         return self.gateway_stats.summary()
 
-    @property
-    def step_breakdowns(self) -> list[dict]:
-        """Per-``step()`` cumulative host-side snapshots (input pipeline +
-        ``serving/``-prefixed engine counters)."""
-        return list(self._step_breakdowns)
-
     def __exit__(self, *exc):
         self._stop()
         return False
 
 
 def annotate(name: str):
-    """Named trace span (maps to jax.profiler.TraceAnnotation)."""
+    """Named span on the jax profiler's host plane (a bare
+    ``jax.profiler.TraceAnnotation``; name it ``atpu:<what>`` like the
+    serving tracer's spans, so ``chipbench.host_spans`` finds it)."""
     import jax
 
     return jax.profiler.TraceAnnotation(name)
 
-
-def save_device_memory_profile(path: str):
-    """Dump a device memory profile (pprof format)."""
-    import jax
-
-    jax.profiler.save_device_memory_profile(path)

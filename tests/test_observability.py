@@ -23,6 +23,14 @@ What is pinned here:
 * ENGINE INTEGRATION — a tracing-enabled engine serves exactly, emits
   per-request span chains, dumps a valid merged trace, keeps the
   zero-recompile steady state, and freezes a postmortem on kill().
+* ONE TIMELINE — a ``tracer.span`` region is also an ``atpu:<name>``
+  annotation on the jax profiler's host plane (nesting, the ``trace_id``
+  stat, one line per thread; a disabled tracer leaves neither), and a
+  high-rate category never evicts another category's records.
+* LOOP PHASES — a chunked paged engine leaves every phase span of its
+  loop, disjoint on the engine thread, and the always-on counters behind
+  them (``host_us/*``, ``chunk_to_dispatch_ms``, ``emit_lag_ms``) reach
+  ``summary()``, ``merge()``, ``reset()`` and a lint-clean ``/metrics``.
 """
 
 import json
@@ -50,9 +58,16 @@ from accelerate_tpu.observability import (  # noqa: E402
     validate_chrome_trace,
 )
 from accelerate_tpu.observability.tracing import TRACE_ID_MAX_LEN  # noqa: E402
-from accelerate_tpu.serving import ServingEngine, ServingStats  # noqa: E402
+from accelerate_tpu.serving import (  # noqa: E402
+    GatewayConfig,
+    ServingEngine,
+    ServingGateway,
+    ServingStats,
+)
 from accelerate_tpu.serving.metrics import (  # noqa: E402
     HISTOGRAM_NAMES,
+    HOST_INTERVALS,
+    HOST_PHASES,
     LatencyHistogram,
 )
 from accelerate_tpu.utils.dataclasses import ProfileKwargs  # noqa: E402
@@ -93,15 +108,15 @@ class TestTracer:
     def test_emit_span_instant_ordering(self):
         tr = Tracer(capacity=64, name="t")
         tr.instant("first", trace_id="r1")
-        with tr.span("work", trace_id="r1", args={"k": 1}) as sp:
-            sp.note(hits=3)
+        with tr.span("work", trace_id="r1", args={"k": 1, "hits": 3}):
+            pass
         tr.emit("manual", time.monotonic(), 0.001, trace_id="r2")
         evs = tr.events()
         assert [e[3] for e in evs] == ["first", "work", "manual"]
         # record layout: (tid, t0, dur, name, cat, trace_id, args)
         work = evs[1]
         assert work[2] > 0 and work[5] == "r1"
-        assert work[6] == {"k": 1, "hits": 3}  # note() merged into args
+        assert work[6] == {"k": 1, "hits": 3}
         assert evs[0][2] is None  # instant has no duration
 
     def test_trace_id_filter(self):
@@ -486,6 +501,118 @@ class TestServingStatsMerge:
         assert summ["adapters_tracked"] == 2
 
 
+class TestCategoryRings:
+    def test_a_busy_category_never_evicts_another(self):
+        tr = Tracer(capacity=8)
+        tr.instant("retire", trace_id="req-1")
+        for i in range(100):
+            tr.emit("sweep", float(i), 0.001, cat="phase")
+        names = [e[3] for e in tr.events()]
+        assert names.count("sweep") == 8 and "retire" in names
+        assert [e[3] for e in tr.events("req-1")] == ["retire"]
+        assert len(tr) == 9
+        tr.clear()
+        assert tr.events() == []
+
+
+# ---------------------------------------------------------------------------
+# Tracer -> jax profiler bridge: one region, two clocks
+# ---------------------------------------------------------------------------
+def _host_plane_lines(trace_dir):
+    """``[[(name, start_ns, end_ns, stats)]]``: the ``atpu:*`` events of
+    the profiler's host plane, one list per thread line that has any."""
+    from jax.profiler import ProfileData
+
+    files = sorted(trace_dir.rglob("*.xplane.pb"))
+    assert files, "the profiler wrote no .xplane.pb"
+    lines = []
+    for plane in ProfileData.from_file(str(files[-1])).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith("atpu:")]
+            if evs:
+                lines.append(evs)
+    return lines
+
+
+@pytest.fixture(scope="module")
+def bridged(tmp_path_factory):
+    """One profiler session (``host_tracer_level = 1``, as the benchmark
+    sets it) around nested spans on two threads of an enabled tracer and
+    a span of a disabled one."""
+    trace_dir = tmp_path_factory.mktemp("bridge")
+    on, off = Tracer(capacity=64), Tracer(capacity=64, enabled=False)
+
+    def work(tag):
+        for i in range(3):
+            with on.span("outer", trace_id=f"{tag}-{i}"):
+                with on.span("inner"):
+                    time.sleep(0.001)
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        threads = [threading.Thread(target=work, args=(tag,))
+                   for tag in ("a", "b")]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        with off.span("ghost", trace_id="never"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    return on, off, _host_plane_lines(trace_dir)
+
+
+class TestProfilerBridge:
+    def test_spans_are_annotations_on_one_line_per_thread(self, bridged):
+        _, _, lines = bridged
+        assert len(lines) == 2
+        for evs in lines:
+            assert sorted(name for name, *_ in evs) == (
+                ["atpu:inner"] * 3 + ["atpu:outer"] * 3)
+
+    def test_nesting_and_trace_id_survive(self, bridged):
+        _, _, lines = bridged
+        tags = set()
+        for evs in lines:
+            outers = [e for e in evs if e[0] == "atpu:outer"]
+            inners = [e for e in evs if e[0] == "atpu:inner"]
+            for _, start, end, stats in inners:
+                assert stats == {}            # no trace_id, no stat
+                assert sum(o[1] <= start and end <= o[2]
+                           for o in outers) == 1
+            ids = [o[3]["trace_id"] for o in outers]
+            assert len({i[0] for i in ids}) == 1    # one thread, one tag
+            tags |= set(ids)
+        assert tags == {f"{t}-{i}" for t in "ab" for i in range(3)}
+
+    def test_the_same_regions_are_ring_records(self, bridged):
+        on, _, _ = bridged
+        evs = on.events()
+        assert sorted(e[3] for e in evs) == ["inner"] * 6 + ["outer"] * 6
+        assert len({e[0] for e in evs}) == 2          # two threads
+        for e in evs:
+            if e[3] == "inner":
+                assert sum(o[3] == "outer" and o[0] == e[0]
+                           and o[1] <= e[1] and e[1] + e[2] <= o[1] + o[2]
+                           for o in evs) == 1
+
+    def test_disabled_tracer_leaves_neither_record_nor_annotation(
+            self, bridged):
+        _, off, lines = bridged
+        assert off.events() == [] and len(off) == 0
+        assert not any(name == "atpu:ghost"
+                       for evs in lines for name, *_ in evs)
+        assert off.span("x") is off.span("y")     # one shared no-op span
+
+
 # ---------------------------------------------------------------------------
 # ProfileSession -> Tracer bridge (training-step spans)
 # ---------------------------------------------------------------------------
@@ -601,3 +728,124 @@ class TestEngineTracing:
         assert "kill" in kinds and "admission" in kinds
         with pytest.raises(RuntimeError):
             eng.shutdown(drain=False)  # dead engines re-raise on shutdown
+
+
+# ---------------------------------------------------------------------------
+# The engine loop as phases: spans on the engine thread, always-on counters
+# ---------------------------------------------------------------------------
+HOST_KEYS = ([f"host_us/{p}" for p in HOST_PHASES + ("other",)]
+             + [f"host_us_max/{p}" for p in HOST_PHASES]
+             + [f"{i}_ms{suffix}" for i in HOST_INTERVALS
+                for suffix in ("", "_max")])
+
+
+def _serve_chunked(m, params, tracing=True):
+    """A tiny paged engine (chunk 8) serving four prompts of one to three
+    chunks (no shared prefix: 3 + 2 + 1 + 3 chunk calls) to streaming
+    callers; returns it idle, not shut down."""
+    eng = ServingEngine(m, params, max_slots=2, max_len=64, prefill_chunk=8,
+                        eos_token_id=EOS, tracing=tracing)
+    eng.start()
+    got = []
+    reqs = [eng.submit(np.arange(k, k + n, dtype=np.int32)[None, :],
+                       max_new_tokens=5, ignore_eos=True,
+                       on_token=got.append)
+            for k, n in ((1, 20), (30, 9), (50, 3), (60, 17))]
+    for r in reqs:
+        r.result(timeout=120)
+    deadline = time.monotonic() + 30
+    while (eng.stats.summary()["emit_lag_count"] < len(got)
+           and time.monotonic() < deadline):
+        time.sleep(0.01)          # the emitter's last batch, the idle flush
+    time.sleep(0.05)
+    return eng, got
+
+
+@pytest.fixture(scope="class")
+def phased(tiny):
+    _, m, params = tiny
+    eng, got = _serve_chunked(m, params)
+    yield eng, got
+    eng.shutdown(drain=False)
+
+
+class TestEnginePhases:
+    def test_every_phase_leaves_a_span(self, phased):
+        eng, _ = phased
+        phase_names = {e[3] for e in eng.trace_events() if e[4] == "phase"}
+        assert phase_names == set(HOST_PHASES)
+
+    def test_engine_thread_spans_are_disjoint_or_nested(self, phased):
+        eng, _ = phased
+        evs = [e for e in eng.trace_events() if e[4] == "phase"]
+        engine_tid = next(e[0] for e in evs if e[3] == "tick_launch")
+        emitter_tid = next(e[0] for e in evs if e[3] == "emit")
+        assert engine_tid != emitter_tid
+        assert {e[3] for e in evs if e[0] == emitter_tid} == {"emit"}
+        spans = sorted((e[1], e[1] + e[2], e[3]) for e in evs
+                       if e[0] == engine_tid)
+        assert len(spans) > 20
+        for (s0, e0, n0), (s1, e1, n1) in zip(spans, spans[1:]):
+            assert e0 <= s1 or e1 <= e0, (n0, n1)   # never partly overlapping
+
+    def test_phase_spans_carry_no_args_and_no_trace_id(self, phased):
+        eng, _ = phased
+        assert all(e[5] is None and e[6] is None
+                   for e in eng.trace_events() if e[4] == "phase")
+
+    def test_summary_has_every_host_counter(self, phased):
+        eng, got = phased
+        s = eng.stats.summary()
+        assert set(HOST_KEYS) <= set(s)
+        assert s["prefill_chunks"] == 3 + 2 + 1 + 3
+        assert s["chunk_to_dispatch_count"] == s["prefill_chunks"]
+        assert s["emit_lag_count"] == len(got) == 20
+        for name in ("prefill_launch", "prefill_wait", "prefill_commit",
+                     "tick_launch", "tick_wait", "tick_commit", "emit"):
+            assert 0 < s[f"host_us/{name}"] <= s[f"host_us_max/{name}"] * max(
+                1, s["decode_ticks"]), name
+        assert 0 < s["chunk_to_dispatch_ms"] <= s["chunk_to_dispatch_ms_max"]
+        assert 0 < s["emit_lag_ms"] <= s["emit_lag_ms_max"]
+        assert s["host_us/other"] <= s["host_us_per_tick"]
+
+    def test_merge_and_reset_carry_them(self, phased):
+        eng, _ = phased
+        one = eng.stats.summary()
+        merged = ServingStats().merge(eng.stats).merge(eng.stats)
+        two = merged.summary()
+        for key in ("chunk_to_dispatch_count", "emit_lag_count"):
+            assert two[key] == 2 * one[key]
+        for key in HOST_KEYS:
+            if "_max" in key:
+                assert two[key] == one[key], key         # maxima max
+            else:                                        # sums and counts add
+                assert two[key] == pytest.approx(one[key], rel=1e-3, abs=1e-3)
+        merged.reset()
+        cleared = merged.summary()
+        assert all(cleared[key] == 0 for key in HOST_KEYS)
+        assert cleared["chunk_to_dispatch_count"] == 0
+
+    def test_metrics_exposes_the_phases_as_one_labelled_family(self, phased):
+        eng, _ = phased
+        gw = ServingGateway(eng, config=GatewayConfig(port=0))
+        text = gw.metrics_text()
+        assert lint_prometheus_text(text) == []
+        for phase in HOST_PHASES + ("other",):
+            assert (f'accelerate_tpu_serving_host_us{{phase="{phase}"}} '
+                    in text), phase
+        assert 'accelerate_tpu_serving_host_us_max{phase="prefill_wait"}' in text
+        assert "host_us/" not in text
+        for name in ("chunk_to_dispatch_ms", "chunk_to_dispatch_ms_max",
+                     "emit_lag_ms", "emit_lag_ms_max"):
+            assert f"\naccelerate_tpu_serving_{name} " in text
+
+    def test_tracing_off_keeps_the_counters_and_drops_the_spans(self, tiny):
+        _, m, params = tiny
+        eng, got = _serve_chunked(m, params, tracing=False)
+        try:
+            assert eng.trace_events() == []
+            s = eng.stats.summary()
+            assert s["chunk_to_dispatch_count"] == s["prefill_chunks"] == 9
+            assert s["host_us/tick_launch"] > 0 and s["emit_lag_ms"] > 0
+        finally:
+            eng.shutdown(drain=False)

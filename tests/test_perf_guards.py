@@ -457,36 +457,76 @@ class TestSLOControl:
 
 
 class TestObservabilityOverhead:
-    """CPU guard for always-on tracing (bench.tracing_overhead_bench): with
-    the span tracer enabled the engine must keep >=95% of its untraced
-    decode throughput on identical traffic — the acceptance budget that
-    lets tracing default ON in production. The tracer is host-side tuple
-    appends into per-thread rings; if this ratio regresses, someone put
-    work (or a lock) on the decode hot path. Timing-driven and retried
-    once, same as the other guards."""
+    """CPU guard for always-on tracing, on COUNTS (it used to time XLA:CPU
+    around sleeps — ``bench.tracing_overhead_bench``'s >= 0.95 ratio — and
+    failed on a busy host; what tracing costs on the chip is measured by
+    the serving cell, ``PERF.md``). What lets tracing default ON is that
+    its work per loop iteration is bounded and that off means off:
+
+    * the records one loop iteration leaves are bounded by a constant plus
+      a few per decode slot (``itl``, ``retire`` and the emitter's batch),
+      never one per page, token of context or queued request;
+    * the loop's phase spans carry no ``args`` dict and no trace id;
+    * a disabled tracer emits nothing and allocates no span object."""
+
+    #: sweep, admit, the three prefill phases + ``prefill_chunk`` and the
+    #: admission's ``queue_wait`` / ``first_token`` / ``prefix_hit`` /
+    #: flight mirror, the three tick phases + ``decode_tick``, ``idle``,
+    #: a ``tick_profile`` mirror: 16 covers one iteration's fixed part.
+    PER_ITERATION = 16
+    PER_SLOT = 3
 
     @staticmethod
-    def _retry_once(attempt):
-        try:
-            attempt()
-        except AssertionError:
-            attempt()
+    def _serve(tracing: bool):
+        engine, _, _, _ = bench._serving_test_engine(max_slots=4,
+                                                     tracing=tracing)
+        rng = np.random.default_rng(0)
+        prompts = rng.integers(1, 200, size=(10, 4)).astype(np.int32)
+        reqs = [engine.submit(prompts[i:i + 1], max_new_tokens=16, seed=i,
+                              on_token=lambda tok: None, block=True)
+                for i in range(len(prompts))]
+        for r in reqs:
+            assert r.wait(timeout=120)
+        return engine
 
     def test_tracing_keeps_95_percent_decode_throughput(self):
-        def attempt():
-            out = bench.tracing_overhead_bench()
-            assert out["overhead_ratio"] >= 0.95, (
-                f"tracing-on decode throughput is only "
-                f"{out['overhead_ratio']:.3f}x of tracing-off "
-                f"({out['tracing_on']['decode_tokens_per_sec']:.0f} vs "
-                f"{out['tracing_off']['decode_tokens_per_sec']:.0f} tok/s): "
-                "the span tracer is adding hot-path cost beyond ring appends")
-            # the traced arm must actually have traced something, and the
-            # untraced arm must be a true zero-overhead no-op
-            assert out["tracing_on"]["spans_buffered"] > 0
-            assert out["tracing_off"]["spans_buffered"] == 0
+        engine = self._serve(tracing=True)
+        try:
+            events = engine.trace_events()
+            ticks = engine.stats.summary()["decode_ticks"]
+        finally:
+            engine.shutdown()
+        phase = [e for e in events if e[4] == "phase"]
+        assert ticks > 10 and phase
+        assert all(e[5] is None and e[6] is None for e in phase), (
+            "a loop phase span carries a trace id or an args dict: that is "
+            "an allocation per phase on the decode hot path")
+        engine_tid = next(e[0] for e in phase if e[3] == "tick_launch")
+        sweeps = sorted(e[1] for e in phase if e[3] == "sweep")
+        stamps = sorted(e[1] for e in events
+                        if e[0] == engine_tid and e[1] >= sweeps[0])
+        bound = self.PER_ITERATION + self.PER_SLOT * engine.max_slots
+        lo = 0
+        for nxt in sweeps[1:] + [float("inf")]:
+            hi = lo
+            while hi < len(stamps) and stamps[hi] < nxt:
+                hi += 1
+            assert hi - lo <= bound, (
+                f"{hi - lo} trace records in one loop iteration (bound "
+                f"{bound}): something now records per token, page or "
+                "queued request")
+            lo = hi
+        emitted = sum(1 for e in phase if e[3] == "emit")
+        assert emitted <= engine.stats.summary()["tokens_emitted"]
 
-        self._retry_once(attempt)
+        off = self._serve(tracing=False)
+        try:
+            assert off.trace_events() == [] and len(off.tracer) == 0
+            assert off.tracer.span("tick_launch") is off.tracer.span("emit"), (
+                "a disabled tracer must hand back one shared no-op span")
+            assert off.stats.summary()["host_us/tick_launch"] > 0  # counters stay on
+        finally:
+            off.shutdown()
 
 
 class TestMultiTenantAdapters:

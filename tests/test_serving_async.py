@@ -26,12 +26,17 @@ The acceptance-critical properties pinned here:
 * HOST METRIC — ``host_us_per_tick`` (schedule+commit wall per tick,
   device waits excluded) flows through ServingStats into the summary
   and the flight recorder's periodic ``tick_profile`` events.
+* GATEWAY SPANS — both front ends leave ``gw.accept`` (``gw.route``
+  inside it), one ``gw.sse_write`` per token and ``gw.done`` in the
+  gateway's own tracer, merged into ``/debug/trace`` beside the engine's.
 """
 
+import json
 import os
 import sys
 import threading
 import time
+import urllib.request
 
 import jax
 import numpy as np
@@ -47,9 +52,12 @@ from accelerate_tpu.adapters.lora import (  # noqa: E402
     init_lora_params,
 )
 from accelerate_tpu.models.llama import LlamaConfig, LlamaForCausalLM  # noqa: E402
+from accelerate_tpu.observability import validate_chrome_trace  # noqa: E402
 from accelerate_tpu.serving import (  # noqa: E402
+    GatewayConfig,
     RequestStatus,
     ServingEngine,
+    ServingGateway,
 )
 from accelerate_tpu.utils.profiling import CompileWatcher  # noqa: E402
 
@@ -420,3 +428,67 @@ class TestHostTickMetric:
             assert eng.stats.summary()["host_us_per_tick"] > 0.0
         finally:
             eng.shutdown(drain=False)
+
+
+class TestGatewaySpans:
+    @pytest.mark.parametrize("server", ["asyncio", "threading"])
+    def test_gateway_spans_show_in_debug_trace(self, tiny, server):
+        _, m, params = tiny
+        eng = ServingEngine(m, params, max_slots=2, max_len=64,
+                            eos_token_id=EOS)
+        gw = ServingGateway(eng, config=GatewayConfig(port=0, server=server))
+        gw.start()
+        try:
+            tid = f"gw-spans-{server}"
+            req = urllib.request.Request(
+                gw.url + "/v1/completions",
+                data=json.dumps({"prompt": [3, 5, 7], "max_new_tokens": 6,
+                                 "ignore_eos": True, "stream": True}).encode(),
+                headers={"Content-Type": "application/json",
+                         "X-Request-Id": tid})
+            events = []
+            with urllib.request.urlopen(req, timeout=120) as resp:
+                for line in resp:
+                    if line.startswith(b"data: "):
+                        events.append(json.loads(line[6:]))
+            assert events[-1]["done"] and events[-1]["status"] == "completed"
+            assert [e["token"] for e in events[:-1]] == events[-1]["tokens"]
+
+            with urllib.request.urlopen(
+                    gw.url + f"/debug/trace?id={tid}", timeout=10) as resp:
+                trace = json.loads(resp.read())
+            assert validate_chrome_trace(trace) == []
+            lanes = {e["pid"]: e["args"]["name"]
+                     for e in trace["traceEvents"] if e["ph"] == "M"}
+            assert "gateway" in lanes.values()
+            spans = [e for e in trace["traceEvents"] if e["ph"] != "M"]
+            assert all(e["args"]["trace_id"] == tid for e in spans)
+            by_name = {}
+            for e in spans:
+                by_name.setdefault(e["name"], []).append(e)
+            # the gateway's spans beside the engine's request-scoped ones
+            assert {"gw.accept", "gw.route", "gw.sse_write", "gw.done",
+                    "submit", "first_token", "retire"} <= set(by_name)
+            assert all(lanes[e["pid"]] == "gateway"
+                       for n, evs in by_name.items() if n.startswith("gw.")
+                       for e in evs)
+            assert len(by_name["gw.sse_write"]) == 6
+            assert len(by_name["gw.done"]) == 1
+            (accept,), (route,) = by_name["gw.accept"], by_name["gw.route"]
+            assert accept["ts"] <= route["ts"]
+            assert (route["ts"] + route["dur"]
+                    <= accept["ts"] + accept["dur"] + 1e-3)
+            # a refused request (no replica holds the adapter) leaves its span too
+            bad = urllib.request.Request(
+                gw.url + "/v1/completions",
+                data=json.dumps({"prompt": [1], "max_new_tokens": 2,
+                                 "adapter": "nobody"}).encode(),
+                headers={"Content-Type": "application/json",
+                         "X-Request-Id": tid + "-refused"})
+            with pytest.raises(urllib.error.HTTPError) as err:
+                urllib.request.urlopen(bad, timeout=30)
+            assert err.value.code in (404, 503)
+            refused = {e[3] for e in gw.tracer.events(tid + "-refused")}
+            assert "gw.accept" in refused
+        finally:
+            gw.shutdown(drain=False)
