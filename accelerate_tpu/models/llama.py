@@ -79,10 +79,6 @@ class LlamaConfig:
     # 'auto' uses ring/Ulysses context parallelism when the ambient mesh has
     # cp > 1 (ops/ring_attention.py), flash/einsum otherwise.
     attention_backend: str = "auto"
-    # Pallas flash tile sizes — the single biggest MFU knob on real TPUs;
-    # tune per generation/sequence length without touching kernel code.
-    flash_block_q: int = 128
-    flash_block_k: int = 128
     # fp8 projections (ops/quant.py Fp8Dense, delayed scaling): the TE-swap
     # equivalent (reference: utils/transformer_engine.py:40-49). Pair with
     # Accelerator(mixed_precision="fp8") — the fp8 statistics params are
@@ -245,7 +241,6 @@ def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
 def multi_head_attention(
     q, k, v, causal: bool = True, use_flash: bool = True, segment_ids=None,
     backend: str = "auto", sliding_window: Optional[int] = None,
-    block_q: int = 128, block_k: int = 128,
     sm_scale: Optional[float] = None, logit_softcap: Optional[float] = None,
 ):
     """Dispatch between the attention implementations in ops/.
@@ -292,7 +287,7 @@ def multi_head_attention(
                 and flash_attention_available(q)):
             return flash_attention(
                 q, k, v, causal=True, sliding_window=window,
-                block_q=block_q, block_k=block_k, segment_ids=segment_ids,
+                segment_ids=segment_ids,
                 sm_scale=sm_scale, logit_softcap=logit_softcap)
         return _einsum_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                                  sliding_window=sliding_window, sm_scale=sm_scale,
@@ -317,7 +312,6 @@ def multi_head_attention(
             # training keeps the banded O(S*w) asymptotics).
             return flash_attention(q, k, v, causal=True,
                                    sliding_window=sliding_window,
-                                   block_q=block_q, block_k=block_k,
                                    segment_ids=segment_ids, sm_scale=sm_scale)
         return _einsum_attention(q, k, v, causal=causal,
                                  segment_ids=segment_ids,
@@ -353,7 +347,6 @@ def multi_head_attention(
         # segment_ids are masked inside the Pallas kernel, so packed-sequence
         # training keeps flash's memory asymptotics.
         return flash_attention(q, k, v, causal=causal,
-                               block_q=block_q, block_k=block_k,
                                segment_ids=segment_ids, sm_scale=sm_scale)
     return _einsum_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                              sm_scale=sm_scale)
@@ -627,7 +620,6 @@ class LlamaAttention(nn.Module):
             q, k, v, causal=causal, use_flash=cfg.use_flash_attention,
             segment_ids=segment_ids,
             backend=cfg.attention_backend, sliding_window=window,
-            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
             sm_scale=sm_scale, logit_softcap=softcap,
         )
         out = out.reshape(B, S, n_q * hd)

@@ -87,16 +87,18 @@ def _einsum_attention(q, k, v, causal: bool, segment_ids=None, sliding_window=No
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 128, block_k: int = 128,
-                    sliding_window=None, segment_ids=None, sm_scale=None,
-                    logit_softcap=None):
+def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
+                    block_k: int | None = None, sliding_window=None, segment_ids=None,
+                    sm_scale=None, logit_softcap=None):
     """Flash attention entry point.
 
     Args are [batch, seq, heads, head_dim]. Dispatches to the Pallas kernel
     on TPU; einsum fallback elsewhere. ``segment_ids`` (packed sequences)
     are masked inside the kernel and compose with ``sliding_window``'s
     banded grid. ``sm_scale`` overrides 1/sqrt(head_dim); ``logit_softcap``
-    (Gemma2) is applied inside the kernel pre-mask.
+    (Gemma2) is applied inside the kernel pre-mask. The kernels size their
+    tiles from the shape (``flash_pallas.tile_plan``); ``block_q`` /
+    ``block_k`` pin a size for kernel tests.
     """
     if sliding_window is not None and not causal:
         # Validated here (not just in the kernel) so CPU-fallback runs fail

@@ -1848,6 +1848,15 @@ def serving_extra(on_tpu: bool) -> dict:
     }
 
 
+def _flash_blocks(cfg, seq: int) -> dict:
+    """The tiles the flash kernels choose at this shape (bf16 compute)."""
+    from accelerate_tpu.ops.flash_pallas import KERNELS, tile_plan
+
+    head_dim = cfg.hidden_size // cfg.num_attention_heads
+    plans = {k: tile_plan(seq, seq, head_dim, "bfloat16", kernel=k) for k in KERNELS}
+    return {k: [p.block_q, p.block_k] for k, p in plans.items()}
+
+
 def run_bench(on_tpu: bool) -> dict:
     import jax
     import numpy as np
@@ -1929,7 +1938,7 @@ def run_bench(on_tpu: bool) -> dict:
                     "hidden": cfg.hidden_size, "layers": cfg.num_hidden_layers,
                     "batch": batch, "seq": seq, "backend": jax.default_backend(),
                     "flash_attention": cfg.use_flash_attention,
-                    "flash_blocks": [cfg.flash_block_q, cfg.flash_block_k],
+                    "flash_blocks": _flash_blocks(cfg, seq),
                     "remat_policy": remat_policy if cfg.remat else None,
                 },
                 "device_kind": _device_kind(),

@@ -302,7 +302,10 @@ def run_kernels(size: dict, seed: int) -> dict:
     do = jax.random.normal(kd, (1, S, H, D), jnp.bfloat16)
 
     def flash(q, k, v):
-        return pallas_flash_attention(q, k, v, causal=True)
+        return pallas_flash_attention(q, k, v, causal=True)  # tiles from the shape
+
+    plans = {kern: flash_pallas.tile_plan(S, S, D, "bfloat16", kernel=kern)
+             for kern in flash_pallas.KERNELS}
 
     def reference(q, k, v):
         with jax.default_matmul_precision("highest"):
@@ -336,6 +339,9 @@ def run_kernels(size: dict, seed: int) -> dict:
         "shape": {"batch": 1, "seq": S, "q_heads": H, "kv_heads": G, "head_dim": D,
                   "dtype": "bfloat16", "causal": True},
         "interpreted": flash_pallas._interpret(),
+        "tiles": {kern: {"block_q": p.block_q, "block_k": p.block_k, "steps_per_head": p.steps,
+                         "compute_steps": p.compute_steps, "masked_steps": p.masked_steps}
+                  for kern, p in plans.items()},
         "max_rel_err": {k: round(e, 6) for k, e in errs.items()},
         "tolerance": KERNEL_TOL,
         "flash_fwd_bwd_first_call_s": round(flash_s, 2),

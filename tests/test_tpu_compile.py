@@ -25,12 +25,18 @@ from accelerate_tpu.ops import attention, flash_pallas
 
 # name -> kernel shapes/options. H/G are query/KV heads. The first row is the
 # trainer's attention in chip_smoke.py (Mistral-7B widths, sequence 4096).
+# A case without block_q/block_k compiles at the tiles flash_pallas.tile_plan
+# chooses for it (1024-wide at these lengths), under the vmem_limit_bytes the
+# plan asks for: so the VMEM estimate of every shape family is checked by
+# Mosaic itself. ``blocks512_s4096`` stays as the pinned-size case.
 CASES = {
     "gqa32x8_d128_bf16_s4096": dict(S=4096, H=32, G=8, D=128, dtype=jnp.bfloat16),
     "gqa32x8_d128_fp32_s2048": dict(S=2048, H=32, G=8, D=128, dtype=jnp.float32),
     "window1024_s4096": dict(S=4096, H=32, G=8, D=128, dtype=jnp.bfloat16,
                              sliding_window=1024),
-    "segment_ids_s2048": dict(S=2048, H=32, G=8, D=128, dtype=jnp.bfloat16, segments=True),
+    # batch 2: a (1, block) tile of [B, S] segment ids lowers only at batch 1
+    "segment_ids_s2048": dict(S=2048, H=32, G=8, D=128, dtype=jnp.bfloat16, segments=True,
+                              batch=2),
     "d256_softcap_s2048": dict(S=2048, H=16, G=8, D=256, dtype=jnp.bfloat16,
                                logit_softcap=50.0),
     "blocks512_s4096": dict(S=4096, H=32, G=8, D=128, dtype=jnp.bfloat16,
@@ -77,7 +83,8 @@ def mosaic(monkeypatch):
                                      and q.shape[-1] <= 256))
 
 
-def _shapes(case, sharding, seg_sharding=None, batch=1):
+def _shapes(case, sharding, seg_sharding=None, batch=None):
+    batch = batch or case.get("batch", 1)
     S, H, G, D, dtype = case["S"], case["H"], case["G"], case["D"], case["dtype"]
     q = jax.ShapeDtypeStruct((batch, S, H, D), dtype, sharding=sharding)
     kv = jax.ShapeDtypeStruct((batch, S, G, D), dtype, sharding=sharding)
@@ -110,6 +117,16 @@ def _with_backward(fwd):
 def test_flash_kernel_compiles_for_v5e(name, direction, one_chip, mosaic):
     case = CASES[name]
     q, kv, seg = _shapes(case, one_chip)
+    for kernel in ("fwd",) if direction == "fwd" else ("dq", "dkdv"):
+        plan = flash_pallas.tile_plan(
+            case["S"], case["S"], case["D"], jnp.dtype(case["dtype"]).name,
+            case.get("sliding_window"), seg is not None, kernel,
+            softcap="logit_softcap" in case, block_q=case.get("block_q"),
+            block_k=case.get("block_k"))
+        if "block_q" in case:
+            assert (plan.block_q, plan.block_k) == (case["block_q"], case["block_k"])
+        else:   # the chooser's: far fewer steps than the 128 x 128 these cases used to pin
+            assert plan.steps * 16 <= (case["S"] // 128) ** 2, plan
     fn = _kernel(case) if direction == "fwd" else _with_backward(_kernel(case))
     args = (q, kv, kv) + ((seg,) if seg is not None else ())
     compiled = jax.jit(fn).lower(*args).compile()
