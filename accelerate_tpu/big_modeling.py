@@ -427,6 +427,7 @@ def cache_factory_for(module) -> Optional[Callable]:
     order, with the specs marked ``cache_slot=True`` (``kind == "layer"`` is
     honored as a legacy alias for externally-built spec lists)."""
     from .models.bloom import BloomForCausalLM
+    from .models.cohere2_moe import Cohere2MoeForCausalLM
     from .models.gpt2 import GPT2LMHeadModel
     from .models.gpt_neox import GPTNeoXForCausalLM
     from .models.gptj import GPTJForCausalLM
@@ -437,7 +438,7 @@ def cache_factory_for(module) -> Optional[Callable]:
 
     if isinstance(module, (LlamaForCausalLM, GPT2LMHeadModel, MixtralForCausalLM,
                            GPTJForCausalLM, GPTNeoXForCausalLM, OPTForCausalLM,
-                           PhiForCausalLM, BloomForCausalLM)):
+                           PhiForCausalLM, BloomForCausalLM, Cohere2MoeForCausalLM)):
         cfg = module.config  # non-Llama configs duck-type the kv-cache fields
 
         def factory(batch, max_len, dtype=jnp.bfloat16, ring_slack=0):

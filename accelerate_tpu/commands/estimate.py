@@ -31,6 +31,11 @@ def _model_registry():
 
         return MixtralForCausalLM(MixtralConfig.mixtral_8x7b())
 
+    def _command_a_plus():
+        from ..models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM
+
+        return Cohere2MoeForCausalLM(Cohere2MoeConfig())
+
     reg = {
         "llama3-8b": llama("llama3_8b"),
         "llama-tiny": llama("tiny"),
@@ -40,6 +45,7 @@ def _model_registry():
         # (reference: benchmarks/big_model_inference/README.md:31-37).
         "gptj-6b": lambda: GPTJForCausalLM(GPTJConfig.gptj_6b()),
         "mixtral-8x7b": _mixtral_8x7b,
+        "command-a-plus": _command_a_plus,
         "gpt-neox-20b": lambda: GPTNeoXForCausalLM(GPTNeoXConfig.neox_20b()),
         "opt-30b": lambda: OPTForCausalLM(OPTConfig.opt_30b()),
         "phi-2": lambda: PhiForCausalLM(PhiConfig.phi_2()),
@@ -219,6 +225,21 @@ def estimate_command(args) -> int:
     module = None
     if args.model_name in registry:
         module = registry[args.model_name]()
+        if args.held_experts is not None:
+            # One chip's share of an expert-parallel deployment: the routed
+            # experts it holds (the router keeps its full width).
+            import dataclasses
+
+            config = getattr(module, "config", None)
+            if not hasattr(config, "held_experts"):
+                print(f"--held-experts: {args.model_name} has no expert layer "
+                      "that can hold a share of its experts")
+                return 2
+            if not 1 <= args.held_experts <= config.num_experts:
+                print(f"--held-experts must be 1..{config.num_experts}")
+                return 2
+            module = type(module)(dataclasses.replace(
+                config, held_experts=(0, args.held_experts)))
         abstract = init_empty_weights(module)
     else:
         try:
@@ -256,6 +277,9 @@ def estimate_command(args) -> int:
         zero = int(env_dp) if env_dp and int(env_dp) > 0 else jax.device_count()
     zero_split = _zero_opt_split(abstract, zero) if zero and zero > 1 else None
     print(f"Model: {args.model_name}  ({n_params / 1e9:.2f} B params)")
+    if args.held_experts is not None and module is not None:
+        print(f"  holding {args.held_experts} of {module.config.num_experts} routed "
+              "experts a layer (one rank of an expert-parallel deployment)")
     header = f"{'dtype':>9} | {'largest layer':>14} | {'total size':>11} | {'training (Adam)':>16}"
     if args.fsdp > 1:
         header += f" | per-chip (fsdp={args.fsdp})"
@@ -480,6 +504,10 @@ def estimate_command_parser(subparsers=None):
                              "dp (ACCELERATE_TPU_MESH_DP) or the device "
                              "count. Leaves with no divisible dimension "
                              "replicate (reported).")
+    parser.add_argument("--held-experts", type=int, default=None,
+                        help="size one chip's share of an expert-parallel deployment: "
+                             "this many routed experts of each layer are held "
+                             "(built-in models whose expert layer can hold a share)")
     parser.add_argument("--lora-rank", type=int, default=None,
                         help="Also print the LoRA trainable-parameter count and "
                              "adapter checkpoint size at this rank")

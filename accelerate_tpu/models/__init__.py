@@ -1,4 +1,5 @@
 from .bloom import BloomConfig, BloomForCausalLM
+from .cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM
 from .bert import BertConfig, BertForSequenceClassification, classification_loss
 from .gpt2 import GPT2Config, GPT2LMHeadModel
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel, PipelinedLlamaForCausalLM, causal_lm_loss

@@ -201,3 +201,171 @@ def moe_mlp_apply(
     out = jnp.einsum("gnec,egcd->gnd", combine.astype(jnp.float32), out_e.astype(jnp.float32))
     out = _constrain(out, (("dp", "fsdp", "ep"), None, None), mesh)
     return out.reshape(B, S, D).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# An expert layer that is told which experts it holds
+# ---------------------------------------------------------------------------
+
+#: rows of one expert tile in :func:`moe_held_apply`'s sorted path. An expert
+#: with n routed rows computes ceil(n / tile) tiles, so the rows computed are
+#: at most the rows routed + (tile - 1) per touched expert. 32 is the one size
+#: run on the chip (a 256-token chunk over 16 held experts: 16.7 tiles a
+#: layer, each product reading its expert at 89 % of the HBM peak); no other
+#: size has a reading, so it is a constant and not an option.
+HELD_TILE_ROWS = 32
+
+
+def route_top_k(router_logits: jnp.ndarray, top_k: int, *, scores: str = "softmax",
+                normalize_gates: bool = True):
+    """``[T, E]`` router logits -> ``(gates [T, k] f32, experts [T, k] i32)``.
+
+    ``scores`` is ``"softmax"`` (Mixtral) or ``"sigmoid"`` (each expert scored
+    on its own); the k largest scores are kept and, with ``normalize_gates``,
+    divided by their sum."""
+    logits = router_logits.astype(jnp.float32)
+    if scores == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    elif scores == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scores must be 'softmax' or 'sigmoid' (got {scores!r})")
+    gates, experts = jax.lax.top_k(s, top_k)
+    if normalize_gates:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, experts.astype(jnp.int32)
+
+
+def _swiglu_experts(x_e, wg, wu, wd):
+    """x_e [E, R, D] through expert-major SwiGLU stacks -> [E, R, D]."""
+    h = jax.nn.silu(jnp.einsum("erd,edf->erf", x_e, wg)) * jnp.einsum("erd,edf->erf", x_e, wu)
+    return jnp.einsum("erf,efd->erd", h, wd)
+
+
+def averaged_experts_apply(expert_params: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """Always-on experts combined by their mean: ``x`` [B, S, D] through every
+    SwiGLU expert of the stacks ``[n, D, F]`` / ``[n, F, D]``, averaged."""
+    B, S, D = x.shape
+    wg, wu, wd = (expert_params[n].astype(x.dtype) for n in ("gate_proj", "up_proj", "down_proj"))
+    x_e = jnp.broadcast_to(x.reshape(1, B * S, D), (wg.shape[0], B * S, D))
+    out = _swiglu_experts(x_e, wg, wu, wd).astype(jnp.float32).mean(0)
+    return out.reshape(B, S, D).astype(x.dtype)
+
+
+def _held_dense(tokens, wg, wu, wd, gates, local, count):
+    """Every held expert computes every token (T <= one tile, so no more
+    rows than one tile an expert), weighted by its gate — zero where the
+    token did not pick it. One batched product per projection: each
+    expert's weights are read once, also when ``jax.vmap`` adds the serving
+    slots as a batch axis (the decode program)."""
+    T, D = tokens.shape
+    weight = (jax.nn.one_hot(local, count, dtype=jnp.float32)      # [T, k, count]; a pick
+              * gates[..., None]).sum(1)                            # outside [0, count) is 0
+    x_e = jnp.broadcast_to(tokens[None], (count, T, D))
+    out_e = _swiglu_experts(x_e, wg, wu, wd)                        # [count, T, D]
+    return jnp.einsum("te,etd->td", weight, out_e.astype(jnp.float32))
+
+
+def _held_sorted(tokens, wg, wu, wd, gates, local, is_held, counts, tile):
+    """Picks sorted by expert into tiles of ``tile`` rows that belong to one
+    expert each; a loop with a DYNAMIC trip count runs one SwiGLU per
+    occupied tile. Static buffers are sized for the worst case (every pick on
+    a held expert: no token is ever dropped), the work done is the occupied
+    tiles: rows routed + at most ``tile - 1`` of padding per touched expert.
+    Forward only (the loop's bound is data-dependent)."""
+    T, D = tokens.shape
+    k = gates.shape[1]
+    count = wg.shape[0]
+    P = T * k
+    max_tiles = -(-P // tile) + count
+    key = jnp.where(is_held, local, count).reshape(P)               # non-held picks sort last
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    tiles_e = -(-counts // tile)                                    # [count]
+    tile_end = jnp.cumsum(tiles_e)
+    n_tiles = tile_end[-1]
+    row0_e = (tile_end - tiles_e) * tile                            # first padded row of e
+    start_e = jnp.cumsum(counts) - counts                           # first sorted pick of e
+    safe = jnp.minimum(sorted_key, count - 1)
+    rank = jnp.arange(P, dtype=jnp.int32) - start_e[safe]
+    rows = max_tiles * tile
+    row_sorted = jnp.where(sorted_key < count, row0_e[safe] + rank, rows)   # rows = dropped
+    row_token = jnp.full((rows,), T, jnp.int32).at[row_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    x_pad = jnp.concatenate([tokens, jnp.zeros((1, D), tokens.dtype)])[row_token]
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
+        count - 1).astype(jnp.int32)
+
+    def one_tile(i, out_pad):
+        e = tile_expert[i]
+        x_t = jax.lax.dynamic_slice_in_dim(x_pad, i * tile, tile)
+        g = jax.lax.dynamic_index_in_dim(wg, e, keepdims=False)
+        u = jax.lax.dynamic_index_in_dim(wu, e, keepdims=False)
+        d = jax.lax.dynamic_index_in_dim(wd, e, keepdims=False)
+        y = (jax.nn.silu(x_t @ g) * (x_t @ u)) @ d
+        return jax.lax.dynamic_update_slice_in_dim(out_pad, y, i * tile, 0)
+
+    out_pad = jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((rows, D), tokens.dtype))
+    # Back to token order by a gather: pick (t, j) reads its padded row, the
+    # picks on absent experts read the trailing zero row.
+    row_of_pick = jnp.zeros((P,), jnp.int32).at[order].set(row_sorted).reshape(T, k)
+    out_rows = jnp.concatenate([out_pad, jnp.zeros((1, D), out_pad.dtype)])[row_of_pick]
+    return jnp.einsum("tk,tkd->td", gates, out_rows.astype(jnp.float32))
+
+
+def moe_held_apply(
+    expert_params: dict,
+    router_kernel: jnp.ndarray,
+    x: jnp.ndarray,
+    *,
+    top_k: int,
+    scores: str = "softmax",
+    normalize_gates: bool = True,
+    held: Optional[tuple] = None,
+):
+    """The part of a sparse expert MLP that the experts held here give.
+
+    ``router_kernel`` ``[D, E]`` has the model's full width: every token is
+    routed over all ``E`` experts (:func:`route_top_k`). ``expert_params``
+    holds SwiGLU stacks ``[count, D, F]`` / ``[count, F, D]`` of the experts
+    ``first .. first + count - 1`` (``held = (first, count)``; None = all
+    ``E``, starting at 0). The result is ``sum_{e in top-k, e held} g_e F_e(x)``:
+    what the absent experts would add is left out, and nothing stands in for
+    them. No token is dropped at any skew, and the expert products compute
+    routed rows, not a capacity: ``T <= HELD_TILE_ROWS`` tokens (a decode tick,
+    also under ``jax.vmap`` over serving slots) go through every held expert
+    in one batched product that reads each expert's weights once; more
+    tokens are sorted by expert into tiles and a loop runs the occupied
+    tiles only (see :func:`_held_sorted`; forward only).
+
+    Returns ``(out [B, S, D], stats)`` with ``stats["picks"]`` an int32
+    ``[count + 1]``: the picks that landed on each held expert, then all
+    picks of the call.
+    """
+    B, S, D = x.shape
+    wg, wu, wd = expert_params["gate_proj"], expert_params["up_proj"], expert_params["down_proj"]
+    E = router_kernel.shape[-1]
+    first, count = (0, E) if held is None else (int(held[0]), int(held[1]))
+    if wg.shape[0] != count or first < 0 or first + count > E:
+        raise ValueError(f"held={held} does not match {wg.shape[0]} expert stacks of a "
+                         f"router over {E}")
+    T = B * S
+    tokens = x.reshape(T, D)
+    with jax.named_scope("moe_router"):
+        logits = tokens.astype(jnp.float32) @ router_kernel.astype(jnp.float32)
+        gates, experts = route_top_k(logits, top_k, scores=scores,
+                                     normalize_gates=normalize_gates)
+        local = experts - first
+        is_held = (local >= 0) & (local < count)
+        counts = (jax.nn.one_hot(jnp.where(is_held, local, count), count + 1, dtype=jnp.int32)
+                  .sum((0, 1))[:count])
+    cdt = x.dtype
+    wg, wu, wd = wg.astype(cdt), wu.astype(cdt), wd.astype(cdt)
+    with jax.named_scope("moe_experts"):
+        if T <= HELD_TILE_ROWS:
+            out = _held_dense(tokens, wg, wu, wd, gates, jnp.where(is_held, local, -1), count)
+        else:
+            out = _held_sorted(tokens, wg, wu, wd, gates, local, is_held, counts, HELD_TILE_ROWS)
+    picks = jnp.concatenate([counts, jnp.full((1,), T * top_k, jnp.int32)])
+    return out.reshape(B, S, D).astype(x.dtype), {"picks": picks}

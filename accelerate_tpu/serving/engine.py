@@ -804,14 +804,36 @@ class ServingEngine:
             self._cache_axes = (self._cache_length_axes(2, 1) if self._paged
                                 else self._cache_length_axes())
         cfg = getattr(module, "config", None)
-        win = getattr(cfg, "sliding_window", None)
+        #: each layer's attention window where it is shorter than max_len
+        #: (None = the layer reads every row), from the same per-layer rule
+        #: the cache factory applies; empty for families without windows.
+        self._layer_windows: list = []
+        if has_ring:
+            from ..models.llama import _layer_window
+
+            self._layer_windows = [
+                w if w is not None and w < self.max_len else None
+                for w in (_layer_window(cfg, i)
+                          for i in range(cfg.num_hidden_layers))]
         #: window width when pages wholly out of the attention window may be
-        #: freed: paged + every layer uniformly windowed (mixed local/global
-        #: stacks keep all pages — correctness first, no freeing).
+        #: freed: paged + every layer windowed alike (mixed local/global
+        #: stacks keep all pages — a global layer reads them to the end).
+        kinds = set(self._layer_windows)
         self._page_window = (
-            int(win) if (self._paged and has_ring and isinstance(win, int)
-                         and getattr(cfg, "layer_types", None) is None)
+            int(next(iter(kinds))) if (self._paged and len(kinds) == 1
+                                       and None not in kinds)
             else None)
+        #: (window, layers) pairs for the kv_dead_rows_share counter.
+        self._dead_row_windows = [
+            (int(w), self._layer_windows.count(w)) for w in kinds
+            if w is not None]
+        #: the variable collection a module sows per-call counters into
+        #: (models/cohere2_moe.py: MoE pick counts); the paged programs ask
+        #: for it and return its sum packed behind the tokens. None on the
+        #: dense engine and for modules that sow nothing.
+        self._stats_collection = (
+            getattr(module, "serving_stats_collection", None)
+            if self._paged else None)
 
         if self._paged:
             probe = jax.eval_shape(lambda: self._factory(1, 2, self._dtype))
@@ -1511,6 +1533,20 @@ class ServingEngine:
                 jnp.where(touched, tid, 0), axes, scales)
         return pool_leaves, scales
 
+    def _apply_counted(self, params, ids, **kwargs):
+        """``module.apply`` on the cached path -> ``(logits, cache,
+        counts)``: where the module sows per-call counters
+        (``serving_stats_collection``), ``counts`` is their sum over the
+        layers, an int vector; else None and the call is the plain one."""
+        if self._stats_collection is None:
+            logits, cache = self.module.apply({"params": params}, ids,
+                                              **kwargs)
+            return logits, cache, None
+        (logits, cache), sown = self.module.apply(
+            {"params": params}, ids, mutable=[self._stats_collection],
+            **kwargs)
+        return logits, cache, sum(jax.tree.leaves(sown))
+
     def _paged_prefill_chunk_fn(self, params, state, ids_c, slot, pages,
                                 offset, true_len, rng, *extra):
         """Paged twin of :meth:`_prefill_chunk_fn`: gather the slot's pages
@@ -1539,8 +1575,8 @@ class ServingEngine:
         # vanishes, leaving the fp program byte-identical.
         scales = state.get("pscale")
         view = self._gather_view(state["pool"], pages, scales=scales)
-        logits, view = self.module.apply(
-            {"params": params}, ids_c, cache=view, cache_pos=offset,
+        logits, view, counts = self._apply_counted(
+            params, ids_c, cache=view, cache_pos=offset,
             **self._lora_kwargs(bank, aidx))
         tok, done, rng_carry = _chunk_prefill_token(
             logits, rng, self._select, self.eos_token_id, ids_c.dtype,
@@ -1584,6 +1620,10 @@ class ServingEngine:
                 self._draft_cache_struct, dpool_leaves)
             if dscales is not None:
                 new_state["dpscale"] = dscales
+        if counts is not None:
+            # the module's counters ride behind the token: one transfer
+            return new_state, jnp.concatenate(
+                [tok[:1].astype(jnp.int32), counts.astype(jnp.int32)]), block
         return new_state, tok[0], block
 
     def _draft_chunk_fn(self, dparams, state, ids_c, slot, dpages, offset):
@@ -1725,20 +1765,25 @@ class ServingEngine:
                                              scales=scales)
 
         def one_slot(cache, tok, pos, rng, done, aidx=None):
-            logits, cache = self.module.apply(
-                {"params": params}, tok[None, None], cache=cache,
+            logits, cache, counts = self._apply_counted(
+                params, tok[None, None], cache=cache,
                 cache_pos=pos, **self._lora_kwargs(bank, aidx))
             rng, sub = jax.random.split(rng)
             nxt, done = _next_token(logits[:, -1], sub, jnp.zeros((1, 1), bool),
                                     done[None], self._select, self.eos_token_id,
                                     tok.dtype)
-            return cache, nxt[0], rng, done[0]
+            return cache, nxt[0], rng, done[0], counts
 
         vmap_args = [views, state["tok"], state["pos"], state["rng"],
                      state["done"]]
         if bank is not None:
             vmap_args.append(state["adapter_idx"])
-        new_views, toks, rngs, dones = jax.vmap(one_slot)(*vmap_args)
+        new_views, toks, rngs, dones, counts = jax.vmap(one_slot)(*vmap_args)
+        toks_out = toks
+        if counts is not None:
+            # active slots' counters, summed, ride behind the tokens
+            counts = jnp.where(active[:, None], counts, 0).sum(0)
+            toks_out = jnp.concatenate([toks, counts.astype(toks.dtype)])
         nv_leaves = jax.tree.leaves(new_views)
         pool_leaves = jax.tree.leaves(state["pool"])
         for s in range(self.max_slots):
@@ -1772,7 +1817,7 @@ class ServingEngine:
         )
         if scales is not None:
             state["pscale"] = scales
-        return state, toks, dones
+        return state, toks_out, dones
 
     def _spec_accept(self, logits, drafts, done, rem, rng):
         """Per-slot accept epilogue shared by BOTH speculative programs
@@ -3156,8 +3201,13 @@ class ServingEngine:
         dt_ms = (time.monotonic() - t0) * 1e3
         backlog = sum(1 for r in self._prefilling
                       if r.status is RequestStatus.PREFILLING)
+        counts = None
+        if self._stats_collection is not None:
+            tok = np.asarray(tok)       # [token, the module's counters...]
+            tok, counts = tok[0], tok[1:]
         self._stats.record_prefill_chunk(dt_ms, backlog=backlog,
-                                         host=self._phases.drain())
+                                         host=self._phases.drain(),
+                                         moe_picks=counts)
         self._tracer.emit(
             "prefill_chunk", t0, dt_ms / 1e3, trace_id=req.trace_id,
             args={"chunk": i, "of": req._chunks_total, "offset": offset,
@@ -3328,13 +3378,17 @@ class ServingEngine:
             t1 = time.monotonic()
         self._blocked_s += phases.tick_wait.last_s
         with phases.tick_commit:
-            self._commit_tick(flight, t1, emit, ns, toks, dones)
+            counts = None
+            if toks is not None and self._stats_collection is not None:
+                toks, counts = toks[:self.max_slots], toks[self.max_slots:]
+            self._commit_tick(flight, t1, emit, ns, toks, dones, counts)
 
     def _commit_tick(self, flight: _TickFlight, t1: float, emit, ns, toks,
-                     dones):
+                     dones, counts=None):
         """The host side of a settled tick (phase ``tick_commit``): the
         timing split, the commit loop, retirements, emitter puts, stats
-        and page samples."""
+        and page samples. ``counts``: the module's own counters of this
+        tick (MoE picks), where it has any."""
         spec = emit is not None
         phases = self._phases
         if not self._heartbeat_frozen:
@@ -3350,6 +3404,8 @@ class ServingEngine:
         other_s = max(0.0, host_s - phases.covered_s)
         phases.covered_s = 0.0
         committed = accepted = n_valid = 0
+        dead_rows = held_rows = 0
+        n_layers = len(self._layer_windows)
         for slot, req, epoch in flight.entries:
             if (req.status is not RequestStatus.RUNNING
                     or req._preempted != epoch):
@@ -3381,8 +3437,18 @@ class ServingEngine:
                 if (len(req.tokens) >= req.max_new_tokens
                         or (not req.ignore_eos and bool(dones[slot]))):
                     self._retire(req, RequestStatus.COMPLETED)
-                elif self._page_window is not None:
+                    continue
+                if self._page_window is not None:
                     self._free_window_pages(req)
+                if self._dead_row_windows:
+                    # KV rows this stream holds on into the next tick, and
+                    # those of them no later query of a windowed layer can
+                    # read (row k is dead once k <= pos - window).
+                    pos = req._pos_base + len(req.tokens)
+                    held_rows += pos * n_layers
+                    for w, layers in self._dead_row_windows:
+                        if pos >= w:
+                            dead_rows += (pos - w + 1) * layers
         if spec:
             self._stats.record_spec(
                 proposed=self._spec_k * n_valid, accepted=accepted,
@@ -3396,7 +3462,8 @@ class ServingEngine:
                                 max_slots=self.max_slots, seconds=interval,
                                 host_us=host_s * 1e6,
                                 other_us=other_s * 1e6,
-                                host=phases.drain())
+                                host=phases.drain(), moe_picks=counts,
+                                kv_rows=(dead_rows, held_rows))
         tracer = self._tracer
         if tracer.enabled:
             targs = {"active": len(flight.entries), "committed": committed,

@@ -27,6 +27,8 @@ the engine thread.
 from __future__ import annotations
 
 import threading
+
+import numpy as np
 from bisect import bisect_left
 from typing import Optional
 
@@ -169,6 +171,12 @@ class ServingStats:
             # Host phases and intervals: name -> [sum_s, max_s, count].
             self._host = {name: [0.0, 0.0, 0]
                           for name in HOST_PHASES + HOST_INTERVALS}
+            # Expert layers that hold a share of the experts: picks landed
+            # on each held expert, then all picks (the programs return them
+            # with the tokens); KV rows held / dead behind a layer's window.
+            self._moe_picks = np.zeros((0,), np.int64)
+            self._kv_rows_held = 0
+            self._kv_rows_dead = 0
             # Speculative decoding: draft proposals vs target acceptances.
             self._spec_ticks = 0
             self._spec_proposed = 0
@@ -219,6 +227,15 @@ class ServingStats:
             self._hists["queue_wait_ms"].observe(queue_wait_ms)
             self._hists["ttft_ms"].observe(ttft_ms)
 
+    def _fold_moe(self, picks) -> None:
+        # call with self._lock held; ``picks``: per held expert, then all
+        if picks is None or not len(picks):
+            return
+        picks = np.asarray(picks, np.int64)
+        if len(self._moe_picks) != len(picks):
+            self._moe_picks = np.zeros_like(picks)
+        self._moe_picks += picks
+
     def _fold_host(self, timings: Optional[dict]) -> None:
         # call with self._lock held
         for name, (sum_s, max_s, count) in (timings or {}).items():
@@ -240,7 +257,8 @@ class ServingStats:
     def record_tick(self, active_slots: int, committed_tokens: int,
                     max_slots: int, seconds: float,
                     host_us: Optional[float] = None,
-                    other_us: float = 0.0, host: Optional[dict] = None):
+                    other_us: float = 0.0, host: Optional[dict] = None,
+                    moe_picks=None, kv_rows: Optional[tuple] = None):
         """One ``decode_step_all_slots`` execution.
 
         ``seconds`` is the device-complete→device-complete interval for
@@ -251,9 +269,17 @@ class ServingStats:
         in microseconds — the part of the interval NOT spent waiting on
         the device, i.e. the host overhead the async runtime hides;
         ``other_us`` is the part of it no named phase covered, and
-        ``host`` the phase timings measured since the last record."""
+        ``host`` the phase timings measured since the last record.
+        ``moe_picks`` are the tick's expert picks (per held expert, then
+        all), ``kv_rows`` the ``(dead, held)`` KV rows of the streams that
+        run on: held = rows written x layers, dead = those a windowed
+        layer can never read again."""
         with self._lock:
             self._fold_host(host)
+            self._fold_moe(moe_picks)
+            if kv_rows is not None:
+                self._kv_rows_dead += int(kv_rows[0])
+                self._kv_rows_held += int(kv_rows[1])
             self._host_other_us_sum += float(other_us)
             self._ticks += 1
             self._tick_s_sum += seconds
@@ -274,13 +300,15 @@ class ServingStats:
             self._emission_stalls += 1
 
     def record_prefill_chunk(self, ms: float, backlog: int = 0,
-                             host: Optional[dict] = None):
+                             host: Optional[dict] = None, moe_picks=None):
         """One ``prefill_chunk`` execution; ``backlog`` is the number of
         requests in ``PREFILLING`` at the time of the call (how much
         admission work is still pending behind the per-tick budget);
-        ``host`` the phase timings measured since the last record."""
+        ``host`` the phase timings measured since the last record,
+        ``moe_picks`` the chunk's expert picks (as in ``record_tick``)."""
         with self._lock:
             self._fold_host(host)
+            self._fold_moe(moe_picks)
             self._prefill_chunks += 1
             self._prefill_ms_sum += ms
             self._hists["prefill_chunk_ms"].observe(ms)
@@ -443,8 +471,10 @@ class ServingStats:
                           for name, e in other._priority.items()}
             o_hists = {name: h.copy() for name, h in other._hists.items()}
             o_host = {name: tuple(e) for name, e in other._host.items()}
+            o["_moe_picks"] = other._moe_picks.copy()
         with self._lock:
             self._fold_host(o_host)
+            self._fold_moe(o["_moe_picks"])
             for name, hist in o_hists.items():
                 mine = self._hists.get(name)
                 if mine is None:
@@ -475,7 +505,7 @@ class ServingStats:
                       "_spec_accepted", "_spec_lookup_slots",
                       "_spec_lookup_hits", "_host_us_sum",
                       "_host_us_ticks", "_host_other_us_sum",
-                      "_emission_stalls"):
+                      "_emission_stalls", "_kv_rows_held", "_kv_rows_dead"):
                 setattr(self, k, getattr(self, k) + o[k])
             for k in ("_queue_wait_ms_max", "_ttft_ms_max",
                       "_prefill_backlog_max", "_host_us_max",
@@ -586,6 +616,22 @@ class ServingStats:
                 # (running max |Δlogprob| vs fp reference; 0.0 when the
                 # engine is bit-exact or never sampled).
                 "logprob_drift": round(self._logprob_drift, 6),
+                # Held-expert layers (zero on a model without them): the
+                # share of all picks that landed on held experts, and the
+                # busiest held expert's picks over the held experts' mean.
+                "moe_held_pick_share": round(
+                    float(self._moe_picks[:-1].sum() / self._moe_picks[-1]), 6)
+                    if self._moe_picks[-1:].any() else 0.0,
+                "moe_load_max_over_mean": round(
+                    float(self._moe_picks[:-1].max()
+                          / self._moe_picks[:-1].mean()), 4)
+                    if self._moe_picks[:-1].any() else 0.0,
+                # KV rows of windowed layers no later query can read, over
+                # all KV rows held, summed over ticks: what an allocator
+                # with a class of pages per layer kind would free.
+                "kv_dead_rows_share": round(
+                    self._kv_rows_dead / self._kv_rows_held, 6)
+                    if self._kv_rows_held else 0.0,
             }
             # The host path by phase ("host_us/<phase>", slash-pathed like
             # the adapter keys; the gateway re-emits them as one labeled

@@ -9,7 +9,7 @@ name/layout translation between HF state dicts (torch conventions:
 ``Linear.weight`` is ``(out, in)``, dot-separated names) and our param
 pytrees (flax: ``kernel`` is ``(in, out)``, nested dicts).
 
-Supported families mirror ``accelerate_tpu.models``: llama, mixtral, bloom, gpt2,
+Supported families mirror ``accelerate_tpu.models``: llama, mixtral, cohere2_moe, bloom, gpt2,
 bert, t5. Each family is a table of bidirectional rules; conversion is pure
 numpy (no torch import needed when reading safetensors).
 
@@ -80,24 +80,46 @@ _MIXTRAL_EXPERT_RE = re.compile(
 # HF w1 = gate (F,D), w2 = down (D,F), w3 = up (F,D).
 _MIXTRAL_W_TO_NAME = {"1": "gate_proj", "2": "down_proj", "3": "up_proj"}
 
-_QWEN2_MOE_EXPERT_RE = re.compile(
-    r"model\.layers\.(\d+)\.mlp\.experts\.(\d+)\.(gate_proj|up_proj|down_proj)\.weight")
-
 # Per-expert HF Linears -> our stacked (E, in, out) tensors. Per family:
 # (regex with (layer, expert, proj-token) groups, token -> our proj name,
-# exporter (layer, expert, token) -> HF key).
+# exporter (layer, expert, token) -> HF key, name of the stack under
+# ``layers_{i}/mlp/``); a family lists one such tuple per stack.
+_SWIGLU_PROJ = {p: p for p in ("gate_proj", "up_proj", "down_proj")}
+
+
+def _swiglu_stack(stack: str):
+    return (
+        re.compile(rf"model\.layers\.(\d+)\.mlp\.{stack}\.(\d+)"
+                   r"\.(gate_proj|up_proj|down_proj)\.weight"),
+        _SWIGLU_PROJ,
+        lambda layer, e, tok: f"model.layers.{layer}.mlp.{stack}.{e}.{tok}.weight",
+        stack,
+    )
+
+
 _EXPERT_CONVENTIONS = {
-    "mixtral": (
+    "mixtral": [(
         _MIXTRAL_EXPERT_RE,
         _MIXTRAL_W_TO_NAME,
         lambda layer, e, tok: f"model.layers.{layer}.block_sparse_moe.experts.{e}.w{tok}.weight",
-    ),
-    "qwen2_moe": (
-        _QWEN2_MOE_EXPERT_RE,
-        {p: p for p in ("gate_proj", "up_proj", "down_proj")},
-        lambda layer, e, tok: f"model.layers.{layer}.mlp.experts.{e}.{tok}.weight",
-    ),
+        "experts",
+    )],
+    "qwen2_moe": [_swiglu_stack("experts")],
+    # cohere2_moe: the routed experts and the always-on shared experts are
+    # both stacks of per-expert SwiGLU Linears.
+    "cohere2_moe": [_swiglu_stack("experts"), _swiglu_stack("shared_experts")],
 }
+
+
+def _match_expert(key: str, family: str):
+    """``(stack path under the layer, expert index, proj name)`` of a
+    per-expert HF tensor name, or None."""
+    for expert_re, tok_to_name, _, stack in _EXPERT_CONVENTIONS.get(family, ()):
+        em = expert_re.match(key)
+        if em:
+            return (f"layers_{em.group(1)}/mlp/{stack}/{tok_to_name[em.group(3)]}",
+                    int(em.group(2)))
+    return None
 
 _GPT2_RULES = [
     ("wte.weight", "wte/embedding", "copy", None),
@@ -405,8 +427,22 @@ _GEMMA2_RULES = _LLAMA_RULES + [
      "model/layers_{i}/post_ffn_norm/scale", "copy", None),
 ]
 
+# Cohere2-MoE (models/cohere2_moe.py; flat scope, tied head). Attention and
+# norm names are Cohere2's; the MoE names (mlp.gate, mlp.experts.N,
+# mlp.shared_experts.N) follow the qwen2_moe / deepseek layout, which is an
+# assumption: no checkpoint of this family was at hand.
+_COHERE2_MOE_RULES = [
+    ("model.embed_tokens.weight", "embed_tokens/embedding", "copy", None),
+    ("model.layers.{i}.self_attn.{p}_proj.weight",
+     "layers_{i}/self_attn/{p}_proj/kernel", "t", ("q", "k", "v", "o")),
+    ("model.layers.{i}.input_layernorm.weight", "layers_{i}/input_norm/scale", "copy", None),
+    ("model.layers.{i}.mlp.gate.weight", "layers_{i}/mlp/router", "t", None),
+    ("model.norm.weight", "norm/scale", "copy", None),
+]
+
 _FAMILY_RULES = {
     "llama": _LLAMA_RULES,
+    "cohere2_moe": _COHERE2_MOE_RULES,
     "vit": _VIT_RULES,
     # Mistral checkpoints are llama-named tensor-for-tensor; the config adds
     # sliding_window (handled in config_from_hf).
@@ -440,6 +476,7 @@ _STRIP_PREFIXES = {
     "bert": ("bert.",),
     "vit": ("vit.",),
     "llama": (),
+    "cohere2_moe": (),
     "mixtral": (),
     "t5": (),
     "qwen2": (),
@@ -663,6 +700,39 @@ def config_from_hf(hf_config: dict, family: Optional[str] = None):
                              sliding_window=get("sliding_window"),
                              num_experts=get("num_local_experts", 8),
                              top_k=get("num_experts_per_tok", 2))
+    if family == "cohere2_moe":
+        from ..models.cohere2_moe import Cohere2MoeConfig
+
+        if get("use_qk_norm") or get("first_k_dense_replace") or not get("use_parallel_block", True):
+            raise NotImplementedError(
+                "cohere2_moe: q/k norm, leading dense layers and the sequential "
+                "block are not implemented (models/cohere2_moe.py)")
+        if get("position_embedding_type", "rope_gptj") != "rope_gptj" or get("rotary_pct", 1) != 1:
+            raise NotImplementedError("cohere2_moe: only rope_gptj over all of head_dim")
+        if get("shared_expert_combination_strategy", "average") != "average":
+            raise NotImplementedError("cohere2_moe: shared experts are averaged")
+        if not get("tie_word_embeddings", True):
+            raise NotImplementedError("cohere2_moe: the head is the tied embedding")
+        if not get("layer_types") and get("layer_switch", 4) != 4:
+            raise NotImplementedError("cohere2_moe: without layer_types the period is 4")
+        heads = get("num_attention_heads", 128)
+        return Cohere2MoeConfig(
+            vocab_size=get("vocab_size", 262144), hidden_size=get("hidden_size", 4096),
+            intermediate_size=get("intermediate_size", 4096),
+            num_hidden_layers=get("num_hidden_layers", 32), num_attention_heads=heads,
+            num_key_value_heads=get("num_key_value_heads", heads),
+            head_dim=get("head_dim") or get("hidden_size", 4096) // heads,
+            max_position_embeddings=get("max_position_embeddings", 200000),
+            layer_norm_eps=get("layer_norm_eps", 1e-5),
+            rope_theta=float(get("rope_theta", 50000.0)),
+            sliding_window=get("sliding_window", 4096),
+            layer_types=tuple(get("layer_types")) if get("layer_types") else None,
+            num_experts=get("num_experts", 128),
+            num_experts_per_tok=get("num_experts_per_tok", 8),
+            num_shared_experts=get("num_shared_experts", 4),
+            expert_selection_fn=get("expert_selection_fn", "sigmoid"),
+            norm_topk_prob=bool(get("norm_topk_prob", True)),
+            logit_scale=float(get("logit_scale", 1.0)))
     if family == "gpt2":
         from ..models.gpt2 import GPT2Config
 
@@ -873,6 +943,10 @@ def model_from_config(config, family: str):
         from ..models.mixtral import MixtralForCausalLM
 
         return MixtralForCausalLM(config)
+    if family == "cohere2_moe":
+        from ..models.cohere2_moe import Cohere2MoeForCausalLM
+
+        return Cohere2MoeForCausalLM(config)
     if family == "gpt2":
         from ..models.gpt2 import GPT2LMHeadModel
 
@@ -926,13 +1000,9 @@ def map_hf_key(key: str, family: str) -> Optional[tuple[str, str]]:
     if family not in _COMPILED:
         raise ValueError(f"unsupported family {family!r}; supported: {sorted(_COMPILED)}")
     key = _strip_prefix(key, family)
-    if family in _EXPERT_CONVENTIONS:
-        expert_re, tok_to_name, _ = _EXPERT_CONVENTIONS[family]
-        em = expert_re.match(key)
-        if em:
-            layer, expert, w = em.group(1), int(em.group(2)), em.group(3)
-            ours = f"layers_{layer}.mlp.experts.{tok_to_name[w]}"
-            return ours, f"stack:{expert}:t"
+    member = _match_expert(key, family)
+    if member:
+        return member[0].replace("/", "."), f"stack:{member[1]}:t"
     for hf_re, _, _, ours_t, op in _COMPILED[family]:
         match = hf_re.match(key)
         if match:
@@ -994,16 +1064,12 @@ def convert_hf_state_dict(
         if raw_key in drop_keys:
             continue
         key = _strip_prefix(raw_key, family)
-        if family in _EXPERT_CONVENTIONS:
-            expert_re, tok_to_name, _ = _EXPERT_CONVENTIONS[family]
-            em = expert_re.match(key)
-            if em:
-                layer, expert, w = em.group(1), int(em.group(2)), em.group(3)
-                ours = f"layers_{layer}/mlp/experts/{tok_to_name[w]}"
-                # HF per-expert Linear is (out, in); batched einsum wants
-                # (in, out) per expert -> transpose, then stack on E below.
-                expert_parts.setdefault(ours, {})[expert] = as_np(raw_value).T
-                continue
+        member = _match_expert(key, family)
+        if member:
+            # HF per-expert Linear is (out, in); batched einsum wants
+            # (in, out) per expert -> transpose, then stack on E below.
+            expert_parts.setdefault(member[0], {})[member[1]] = as_np(raw_value).T
+            continue
         for hf_re, _, _, ours_t, op in rules:
             match = hf_re.match(key)
             if match:
@@ -1046,13 +1112,15 @@ def export_hf_state_dict(params: dict, family: str, *, prefix: str = "",
     # projection as wi_0, not v1.0's wi — the first-match rule can't know.
     t5_gated = family == "t5" and any("intermediate_gate" in k for k in flat_params)
     for key, value in flat_params.items():
-        if family in _EXPERT_CONVENTIONS and re.match(r"^layers_\d+/mlp/experts/", key):
-            _, tok_to_name, hf_key_for = _EXPERT_CONVENTIONS[family]
-            layer = re.search(r"layers_(\d+)", key).group(1)
-            name = key.rsplit("/", 1)[1]
-            w = {v: k for k, v in tok_to_name.items()}[name]
+        stacked = re.match(r"^layers_(\d+)/mlp/(\w+)/(\w+)$", key)
+        convention = stacked and next(
+            (c for c in _EXPERT_CONVENTIONS.get(family, ()) if c[3] == stacked.group(2)), None)
+        if convention:
+            _, tok_to_name, hf_key_for, _ = convention
+            w = {v: k for k, v in tok_to_name.items()}[stacked.group(3)]
             for e in range(value.shape[0]):
-                out[prefix + hf_key_for(layer, e, w)] = np.ascontiguousarray(value[e].T)
+                out[prefix + hf_key_for(stacked.group(1), e, w)] = \
+                    np.ascontiguousarray(value[e].T)
             continue
         for _, ours_re, hf_t, _, op in rules:
             match = ours_re.match(key)

@@ -179,6 +179,20 @@ class TestCLISubprocess:
         assert out.returncode == 0, out.stderr
         assert "float32" in out.stdout and "bfloat16" in out.stdout
 
+    def test_estimate_memory_counts_held_experts(self):
+        """One chip's share: 16 of 128 routed experts a layer. A layer is
+        344.5 M outside the routed experts + 50.3 M an expert; 32 layers + the
+        1.07 B tied embedding: 218.3 B whole, 37.9 B with 16 held."""
+        whole = _run_cli("estimate-memory", "command-a-plus", "--dtypes", "bfloat16")
+        share = _run_cli("estimate-memory", "command-a-plus", "--dtypes", "bfloat16",
+                         "--held-experts", "16")
+        assert whole.returncode == 0 and share.returncode == 0, share.stderr
+        assert "(218.25 B params)" in whole.stdout
+        assert "(37.87 B params)" in share.stdout
+        assert "holding 16 of 128 routed experts" in share.stdout
+        refused = _run_cli("estimate-memory", "llama-tiny", "--held-experts", "2")
+        assert refused.returncode == 2 and "no expert layer" in refused.stdout
+
     def test_estimate_memory_lora_rank(self):
         out = _run_cli("estimate-memory", "llama-tiny",
                        "--dtypes", "float32", "--lora-rank", "8")

@@ -1817,3 +1817,53 @@ class TestQwen2Moe:
             use_sliding_window=True, sliding_window=8, max_window_layers=2))
         assert cfg.sliding_window is None
         assert cfg.layer_windows == (None, None, 8, 8)
+
+
+class TestCohere2Moe:
+    """No transformers class of this family is at hand: the round trip is
+    between our tree and the assumed HF names, and the config comes from the
+    published ``config.json`` keys."""
+
+    PUBLISHED = dict(
+        model_type="cohere2_moe", vocab_size=96, hidden_size=32, intermediate_size=16,
+        num_hidden_layers=4, num_attention_heads=8, num_key_value_heads=2, head_dim=8,
+        max_position_embeddings=256, layer_norm_eps=1e-5, rope_theta=50000, sliding_window=8,
+        layer_types=["sliding_attention"] * 3 + ["full_attention"], layer_switch=4,
+        num_experts=8, num_experts_per_tok=2, num_shared_experts=2,
+        expert_selection_fn="sigmoid", norm_topk_prob=True, logit_scale=1,
+        shared_expert_combination_strategy="average", position_embedding_type="rope_gptj",
+        rotary_pct=1, use_parallel_block=True, use_qk_norm=False, first_k_dense_replace=0,
+        tie_word_embeddings=True)
+
+    def test_config_and_round_trip(self):
+        import jax
+
+        from accelerate_tpu.models.cohere2_moe import Cohere2MoeConfig
+        from accelerate_tpu.utils.hf_interop import model_from_config
+
+        assert detect_family(self.PUBLISHED) == "cohere2_moe"
+        cfg = config_from_hf(self.PUBLISHED)
+        assert isinstance(cfg, Cohere2MoeConfig)
+        assert (cfg.num_experts, cfg.num_experts_per_tok, cfg.num_shared_experts) == (8, 2, 2)
+        assert cfg.held == (0, 8) and cfg.window_for(0) == 8 and cfg.window_for(3) is None
+        cfg.use_flash_attention = False
+        model = model_from_config(cfg, "cohere2_moe")
+        params = jax.tree.map(np.asarray, model.init_params(jax.random.PRNGKey(0)))
+        exported = export_hf_state_dict(params, "cohere2_moe")
+        assert exported["model.layers.2.mlp.experts.5.down_proj.weight"].shape == (32, 16)
+        assert exported["model.layers.0.mlp.shared_experts.1.gate_proj.weight"].shape == (16, 32)
+        assert exported["model.layers.3.mlp.gate.weight"].shape == (8, 32)
+        assert "lm_head.weight" not in exported          # tied
+        _roundtrip(params, "cohere2_moe", exported)
+        back = convert_hf_state_dict(exported, "cohere2_moe", strict=True)
+        ids = jnp.asarray((np.arange(20).reshape(1, 20) * 7) % 96, jnp.int32)
+        np.testing.assert_array_equal(model.apply({"params": params}, ids),
+                                      model.apply({"params": back}, ids))
+
+    def test_what_is_not_implemented_is_refused(self):
+        for key, value in (("use_qk_norm", True), ("first_k_dense_replace", 2),
+                           ("use_parallel_block", False),
+                           ("shared_expert_combination_strategy", "sum"),
+                           ("tie_word_embeddings", False)):
+            with pytest.raises(NotImplementedError):
+                config_from_hf(dict(self.PUBLISHED, **{key: value}))

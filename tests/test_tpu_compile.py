@@ -171,3 +171,70 @@ def test_unsharded_call_on_a_mesh_is_refused_by_mosaic(topo, mosaic):
     q, kv, _ = _shapes(case, NamedSharding(mesh, P("fsdp", None, "tp", None)), batch=2)
     with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
         jax.jit(_kernel(case)).lower(q, kv, kv).compile()
+
+
+# ---------------------------------------------------------------------------
+# The cohere2_moe family's serving programs at the benchmark cell's widths
+# ---------------------------------------------------------------------------
+
+def _cohere2_moe_programs(one_chip):
+    """The model's part of the paged engine's two programs at the widths of
+    ``command-a-plus-05-2026-l4e16`` (hidden 4096, 128/8 heads of 128, 16 of
+    128 experts held, 4 shared, 4 layers, vocab slice 32768), over the linear
+    full-length view the engine gathers (max_len 8192): a 256-token prefill
+    chunk, and the decode tick — ``jax.vmap`` over 16 slots of a batch-1
+    forward. Both ask for the module's pick counters, as the engine does."""
+    from accelerate_tpu.models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM
+
+    cfg = Cohere2MoeConfig(vocab_size=32768, num_hidden_layers=4, held_experts=(0, 16))
+    model = Cohere2MoeForCausalLM(cfg)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda a: struct(a.shape, jnp.bfloat16), shapes)
+    max_len, slots, chunk = 8192, 16, 256
+
+    def views(lead):
+        kv = struct(lead + (1, max_len, cfg.num_key_value_heads, cfg.head_dim), jnp.bfloat16)
+        return tuple({"k": kv, "v": kv} for _ in range(cfg.num_hidden_layers))
+
+    def apply(params, ids, cache, pos):
+        (logits, cache), sown = model.apply({"params": params}, ids, cache=cache,
+                                            cache_pos=pos, mutable=["moe_stats"])
+        return logits, cache, sum(jax.tree.leaves(sown))
+
+    def decode(params, toks, caches, positions):
+        def one_slot(tok, cache, pos):
+            logits, cache, picks = apply(params, tok[None, None], cache, pos)
+            return jnp.argmax(logits[0, -1]), cache, picks
+
+        return jax.vmap(one_slot)(toks, caches, positions)
+
+    return {
+        "prefill_chunk": (apply, (params, struct((1, chunk), jnp.int32), views(()),
+                                  struct((), jnp.int32))),
+        "decode_tick": (decode, (params, struct((slots,), jnp.int32), views((slots,)),
+                                 struct((slots,), jnp.int32))),
+    }
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
+def test_cohere2_moe_serving_program_compiles_for_v5e(program, one_chip):
+    fn, args = _cohere2_moe_programs(one_chip)[program]
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    weights = 9.6e9                       # 4 layers with 16 experts + the vocabulary slice, bf16
+    assert weights * 0.95 < memory.argument_size_in_bytes - (
+        0 if program == "prefill_chunk" else 2.15e9) < weights * 1.05
+    # what the program needs beside its arguments fits beside weights + page pool + views
+    assert memory.temp_size_in_bytes < 2.0e9, memory
+    text = compiled.as_text()
+    # no copy or transpose of a whole expert stack: each product reads the weights in place
+    import re
+
+    assert not re.search(r"= bf16\[(16|4),4096,4096\]\S* (copy|transpose)\(", text)
+    if program == "prefill_chunk":        # the loop over occupied expert tiles is there
+        assert " while(" in text
