@@ -68,28 +68,34 @@ class MixtralConfig(LlamaConfig):
         return dataclasses.replace(cfg, **overrides)
 
 
-class MixtralSparseMLP(nn.Module):
-    """Router + stacked SwiGLU experts; dispatch via ops.moe.
+def _zero_router_losses() -> dict:
+    return {"load_balance_loss": jnp.zeros((), jnp.float32),
+            "router_z_loss": jnp.zeros((), jnp.float32)}
 
-    ``no_drop=True`` sizes expert capacity so no token is ever dropped —
-    the decode-path setting: capacity dropping is a *training* throughput
-    trade (static shapes under load imbalance), and token counts differ
-    between prefill/decode and a full forward, so only the no-drop setting
-    makes cached generation faithful to the model."""
+
+class MixtralSparseMLP(nn.Module):
+    """Router + stacked SwiGLU experts, on one of ``ops.moe``'s two paths,
+    chosen by what the call observes: without a cache (the trainer)
+    ``moe_mlp_apply`` with ``capacity_factor`` slots an expert; under a cache
+    (serving, ``generate``) ``moe_held_apply`` with every expert held — no
+    token dropped, only routed rows computed.
+
+    Capacity dropping is a *training* throughput trade (static shapes under
+    load imbalance), and the path with a backward. Token counts differ
+    between prefill/decode and a full forward, so only a path that drops
+    nothing makes cached generation faithful to the model; the block passes
+    ``cached=True`` when it is given a cache (the serving engine's programs,
+    speculative verify, ``generate``). That path sows its pick counters
+    (``moe_held_apply``'s ``stats["picks"]``) into ``moe_stats`` and returns
+    zero router losses: nothing reads them under a cache."""
 
     config: MixtralConfig
-    no_drop: bool = False
 
     @nn.compact
-    def __call__(self, x):
-        from ..ops.moe import moe_mlp_apply
+    def __call__(self, x, cached: bool = False):
+        from ..ops.moe import moe_held_apply, moe_mlp_apply
 
         cfg = self.config
-        router_noise_rng = (
-            self.make_rng("router")
-            if cfg.router_noise_eps > 0.0 and self.has_rng("router")
-            else None
-        )
         D, F, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
         router = self.param("router", nn.initializers.lecun_normal(), (D, E), jnp.float32)
         init = nn.initializers.lecun_normal(batch_axis=(0,))
@@ -104,20 +110,30 @@ class MixtralSparseMLP(nn.Module):
                 }
 
         experts = Experts(name="experts")()
-        # capacity = ceil(top_k * T * factor / E): factor = E guarantees
-        # top_k * T slots, i.e. zero drops.
-        capacity_factor = float(cfg.num_experts) if self.no_drop else cfg.capacity_factor
-        out, aux = moe_mlp_apply(
-            experts,
-            router,
-            x,
-            top_k=cfg.top_k,
-            capacity_factor=capacity_factor,
-            num_groups=cfg.num_expert_groups,
-            router_noise_rng=router_noise_rng,
-            router_noise_eps=cfg.router_noise_eps,
-            normalize_gates=cfg.norm_topk_prob,
-        )
+        if cached:
+            normalize = cfg.top_k > 1 if cfg.norm_topk_prob is None else cfg.norm_topk_prob
+            out, stats = moe_held_apply(
+                experts, router, x, top_k=cfg.top_k, scores="softmax",
+                normalize_gates=normalize, held=None)
+            self.sow("moe_stats", "picks", stats["picks"])
+            aux = _zero_router_losses()
+        else:
+            router_noise_rng = (
+                self.make_rng("router")
+                if cfg.router_noise_eps > 0.0 and self.has_rng("router")
+                else None
+            )
+            out, aux = moe_mlp_apply(
+                experts,
+                router,
+                x,
+                top_k=cfg.top_k,
+                capacity_factor=cfg.capacity_factor,
+                num_groups=cfg.num_expert_groups,
+                router_noise_rng=router_noise_rng,
+                router_noise_eps=cfg.router_noise_eps,
+                normalize_gates=cfg.norm_topk_prob,
+            )
         if cfg.shared_expert_intermediate_size:
             # Qwen2-MoE shared expert: always-on SwiGLU, sigmoid-gated per
             # token — rides alongside the routed experts, no dispatch.
@@ -159,16 +175,20 @@ class MixtralBlock(nn.Module):
             dense_cfg = _dc.replace(
                 cfg, intermediate_size=cfg.dense_intermediate_size or cfg.intermediate_size)
             mlp_out = LlamaMLP(dense_cfg, name="mlp")(normed)
-            aux = {"load_balance_loss": jnp.zeros((), jnp.float32),
-                   "router_z_loss": jnp.zeros((), jnp.float32)}
+            aux = _zero_router_losses()
         else:
-            mlp_out, aux = MixtralSparseMLP(cfg, no_drop=cache is not None, name="mlp")(normed)
+            mlp_out, aux = MixtralSparseMLP(cfg, name="mlp")(normed, cached=cache is not None)
         out = h + mlp_out
         return (out, aux) if cache is None else (out, aux, new_cache)
 
 
 class MixtralForCausalLM(nn.Module):
     config: MixtralConfig
+
+    #: the variable collection the expert layers sow their pick counts into
+    #: under a cache; the serving engine asks for it and folds it into its
+    #: counters (``moe_tile_fill``, ``moe_load_max_over_mean``).
+    serving_stats_collection = "moe_stats"
 
     @nn.compact
     def __call__(self, input_ids, positions=None, cache=None, cache_pos=None):

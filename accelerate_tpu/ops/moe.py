@@ -24,6 +24,11 @@ Transformer formulation):
 
 Losses follow Switch Transformer: load-balance loss (experts × mean(fraction
 routed · mean router prob)) and router z-loss (mean logsumexp² of logits).
+
+That is the trainer's path (:func:`moe_mlp_apply`). Under a KV cache — the
+serving engine's programs, ``generate`` — the expert layers of every family
+go through :func:`moe_held_apply` instead: no capacity, no token dropped,
+the experts compute the rows that were routed (forward only).
 """
 
 from __future__ import annotations
@@ -207,13 +212,34 @@ def moe_mlp_apply(
 # An expert layer that is told which experts it holds
 # ---------------------------------------------------------------------------
 
-#: rows of one expert tile in :func:`moe_held_apply`'s sorted path. An expert
-#: with n routed rows computes ceil(n / tile) tiles, so the rows computed are
-#: at most the rows routed + (tile - 1) per touched expert. 32 is the one size
-#: run on the chip (a 256-token chunk over 16 held experts: 16.7 tiles a
-#: layer, each product reading its expert at 89 % of the HBM peak); no other
-#: size has a reading, so it is a constant and not an option.
-HELD_TILE_ROWS = 32
+#: a call of at most this many tokens goes through every held expert in one
+#: batched product (:func:`_held_dense`): a decode tick or a verify step,
+#: whose few rows cost nothing beside the read of each stack (755 GB/s in both
+#: serving cells' ticks). It is a threshold on the call, not a tile size.
+HELD_DENSE_TOKENS = 32
+
+
+def held_tile_rows(tokens: int, top_k: int, num_experts: int) -> int:
+    """Rows of one expert tile in :func:`moe_held_apply`'s sorted path, from
+    the call's shape: the power of two in [32, 256] next above twice the rows
+    an expert is expected to get (``tokens * top_k / num_experts``), so that
+    nearly every touched expert is ONE tile and its weights are read once.
+
+    A tile costs the read of its expert's three matrices, whatever its rows,
+    up to the chip's ridge (v5e: 197 TFLOP/s / 819 GB/s = 240 rows of bf16);
+    past it the padding costs compute, so the tile stops at 256. An expert
+    with n routed rows computes ceil(n / tile) tiles: rows computed are the
+    rows routed + at most ``tile - 1`` of padding per touched expert. How
+    many experts are held scales the number of tiles, not a tile's best
+    size, and neither do D and F (both sides of the ridge scale with D * F):
+    they are not arguments. v5e readings, ms a layer (PERF.md section 6, PR
+    30): Command A+'s chunk (256 tokens, top-8 of 128, 16 held: 16 rows
+    expected) -> 32; Mixtral's (256, top-2 of 8: 64 expected) -> 128."""
+    want = 2 * tokens * top_k / num_experts
+    tile = 32
+    while tile < want and tile < 256:
+        tile *= 2
+    return tile
 
 
 def route_top_k(router_logits: jnp.ndarray, top_k: int, *, scores: str = "softmax",
@@ -253,11 +279,11 @@ def averaged_experts_apply(expert_params: dict, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _held_dense(tokens, wg, wu, wd, gates, local, count):
-    """Every held expert computes every token (T <= one tile, so no more
-    rows than one tile an expert), weighted by its gate — zero where the
-    token did not pick it. One batched product per projection: each
-    expert's weights are read once, also when ``jax.vmap`` adds the serving
-    slots as a batch axis (the decode program)."""
+    """Every held expert computes every token (T <= ``HELD_DENSE_TOKENS``, so
+    no more rows than the least tile an expert), weighted by its gate — zero
+    where the token did not pick it. One batched product per projection:
+    each expert's weights are read once, also when ``jax.vmap`` adds the
+    serving slots as a batch axis (the decode program)."""
     T, D = tokens.shape
     weight = (jax.nn.one_hot(local, count, dtype=jnp.float32)      # [T, k, count]; a pick
               * gates[..., None]).sum(1)                            # outside [0, count) is 0
@@ -272,7 +298,8 @@ def _held_sorted(tokens, wg, wu, wd, gates, local, is_held, counts, tile):
     occupied tile. Static buffers are sized for the worst case (every pick on
     a held expert: no token is ever dropped), the work done is the occupied
     tiles: rows routed + at most ``tile - 1`` of padding per touched expert.
-    Forward only (the loop's bound is data-dependent)."""
+    Forward only (the loop's bound is data-dependent). Returns the output
+    and the number of tiles run."""
     T, D = tokens.shape
     k = gates.shape[1]
     count = wg.shape[0]
@@ -311,7 +338,7 @@ def _held_sorted(tokens, wg, wu, wd, gates, local, is_held, counts, tile):
     # picks on absent experts read the trailing zero row.
     row_of_pick = jnp.zeros((P,), jnp.int32).at[order].set(row_sorted).reshape(T, k)
     out_rows = jnp.concatenate([out_pad, jnp.zeros((1, D), out_pad.dtype)])[row_of_pick]
-    return jnp.einsum("tk,tkd->td", gates, out_rows.astype(jnp.float32))
+    return jnp.einsum("tk,tkd->td", gates, out_rows.astype(jnp.float32)), n_tiles
 
 
 def moe_held_apply(
@@ -324,24 +351,25 @@ def moe_held_apply(
     normalize_gates: bool = True,
     held: Optional[tuple] = None,
 ):
-    """The part of a sparse expert MLP that the experts held here give.
+    """The part of a sparse expert MLP that the experts held here give, with no
+    token dropped and only routed rows computed: ``(out [B, S, D], stats)``,
+    where ``stats["picks"]`` is an int32 ``[count + 3]`` — the picks that
+    landed on each held expert, then all picks of the call, the rows routed
+    through the sorted tiles and the rows computed there (tiles run x tile
+    rows; both 0 on the dense path).
 
     ``router_kernel`` ``[D, E]`` has the model's full width: every token is
     routed over all ``E`` experts (:func:`route_top_k`). ``expert_params``
     holds SwiGLU stacks ``[count, D, F]`` / ``[count, F, D]`` of the experts
     ``first .. first + count - 1`` (``held = (first, count)``; None = all
-    ``E``, starting at 0). The result is ``sum_{e in top-k, e held} g_e F_e(x)``:
-    what the absent experts would add is left out, and nothing stands in for
-    them. No token is dropped at any skew, and the expert products compute
-    routed rows, not a capacity: ``T <= HELD_TILE_ROWS`` tokens (a decode tick,
-    also under ``jax.vmap`` over serving slots) go through every held expert
-    in one batched product that reads each expert's weights once; more
-    tokens are sorted by expert into tiles and a loop runs the occupied
-    tiles only (see :func:`_held_sorted`; forward only).
-
-    Returns ``(out [B, S, D], stats)`` with ``stats["picks"]`` an int32
-    ``[count + 1]``: the picks that landed on each held expert, then all
-    picks of the call.
+    ``E``, starting at 0: the Mixtral family under a cache). The result is
+    ``sum_{e in top-k, e held} g_e F_e(x)``: what the absent experts would
+    add is left out, and nothing stands in for them. ``T <=
+    HELD_DENSE_TOKENS`` tokens (a decode tick, also under ``jax.vmap`` over
+    serving slots) go through every held expert in one batched product that
+    reads each expert's weights once; more tokens are sorted by expert into
+    tiles of :func:`held_tile_rows` rows and a loop runs the occupied tiles
+    only (see :func:`_held_sorted`; forward only).
     """
     B, S, D = x.shape
     wg, wu, wd = expert_params["gate_proj"], expert_params["up_proj"], expert_params["down_proj"]
@@ -363,9 +391,12 @@ def moe_held_apply(
     cdt = x.dtype
     wg, wu, wd = wg.astype(cdt), wu.astype(cdt), wd.astype(cdt)
     with jax.named_scope("moe_experts"):
-        if T <= HELD_TILE_ROWS:
+        if T <= HELD_DENSE_TOKENS:
             out = _held_dense(tokens, wg, wu, wd, gates, jnp.where(is_held, local, -1), count)
+            routed = computed = jnp.zeros((), jnp.int32)
         else:
-            out = _held_sorted(tokens, wg, wu, wd, gates, local, is_held, counts, HELD_TILE_ROWS)
-    picks = jnp.concatenate([counts, jnp.full((1,), T * top_k, jnp.int32)])
+            tile = held_tile_rows(T, top_k, E)
+            out, n_tiles = _held_sorted(tokens, wg, wu, wd, gates, local, is_held, counts, tile)
+            routed, computed = counts.sum(), n_tiles * tile
+    picks = jnp.concatenate([counts, jnp.stack([jnp.int32(T * top_k), routed, computed])])
     return out.reshape(B, S, D).astype(x.dtype), {"picks": picks}

@@ -828,12 +828,16 @@ class ServingEngine:
             (int(w), self._layer_windows.count(w)) for w in kinds
             if w is not None]
         #: the variable collection a module sows per-call counters into
-        #: (models/cohere2_moe.py: MoE pick counts); the paged programs ask
-        #: for it and return its sum packed behind the tokens. None on the
-        #: dense engine and for modules that sow nothing.
+        #: (models/cohere2_moe.py, models/mixtral.py: MoE pick counts); the
+        #: paged programs ask for it and return its sum packed behind the
+        #: tokens. None on the dense engine and for modules that sow nothing.
         self._stats_collection = (
             getattr(module, "serving_stats_collection", None)
             if self._paged else None)
+        #: results of prefill chunks whose counters are not folded yet: a
+        #: chunk that is not its prompt's last is read on the host only at
+        #: a later last chunk, when its copy has long arrived.
+        self._late_counts: list = []
 
         if self._paged:
             probe = jax.eval_shape(lambda: self._factory(1, 2, self._dtype))
@@ -3181,6 +3185,11 @@ class ServingEngine:
                     self.params, self._state, ids_c, np.int32(req.slot),
                     np.int32(offset), np.int32(S), req._rng_key,
                     *self._adapter_args(req))
+            if final or self._stats_collection is not None:
+                # ``tok`` is read on the host (the first token, the module's
+                # counters behind it): the copy starts when the chunk ends,
+                # not when a commit asks for it
+                tok.copy_to_host_async()
             phases.launched()
         with phases.prefill_wait:
             tok.block_until_ready()  # honest chunk timing, paced dispatch
@@ -3203,8 +3212,14 @@ class ServingEngine:
                       if r.status is RequestStatus.PREFILLING)
         counts = None
         if self._stats_collection is not None:
-            tok = np.asarray(tok)       # [token, the module's counters...]
-            tok, counts = tok[0], tok[1:]
+            # [token, the module's counters...]: only a prompt's last chunk
+            # waits for its copy (it needs the token) and folds the earlier
+            # chunks' counters with its own
+            self._late_counts.append(tok)
+            if final:
+                rows = [np.asarray(t) for t in self._late_counts]
+                self._late_counts.clear()
+                tok, counts = rows[-1][0], sum(r[1:] for r in rows)
         self._stats.record_prefill_chunk(dt_ms, backlog=backlog,
                                          host=self._phases.drain(),
                                          moe_picks=counts)

@@ -171,9 +171,11 @@ class ServingStats:
             # Host phases and intervals: name -> [sum_s, max_s, count].
             self._host = {name: [0.0, 0.0, 0]
                           for name in HOST_PHASES + HOST_INTERVALS}
-            # Expert layers that hold a share of the experts: picks landed
-            # on each held expert, then all picks (the programs return them
-            # with the tokens); KV rows held / dead behind a layer's window.
+            # Expert layers (ops.moe.moe_held_apply's ``picks``; the programs
+            # return them with the tokens): picks landed on each held
+            # expert, then three totals: all picks, rows routed through the
+            # sorted tiles, rows computed there. KV rows held / dead behind
+            # a layer's window.
             self._moe_picks = np.zeros((0,), np.int64)
             self._kv_rows_held = 0
             self._kv_rows_dead = 0
@@ -229,6 +231,7 @@ class ServingStats:
 
     def _fold_moe(self, picks) -> None:
         # call with self._lock held; ``picks``: per held expert, then all
+        # picks, rows routed and rows computed in the sorted tiles
         if picks is None or not len(picks):
             return
         picks = np.asarray(picks, np.int64)
@@ -271,9 +274,9 @@ class ServingStats:
         ``other_us`` is the part of it no named phase covered, and
         ``host`` the phase timings measured since the last record.
         ``moe_picks`` are the tick's expert picks (per held expert, then
-        all), ``kv_rows`` the ``(dead, held)`` KV rows of the streams that
-        run on: held = rows written x layers, dead = those a windowed
-        layer can never read again."""
+        ``moe_held_apply``'s three totals), ``kv_rows`` the ``(dead,
+        held)`` KV rows of the streams that run on: held = rows written x
+        layers, dead = those a windowed layer can never read again."""
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
@@ -542,6 +545,11 @@ class ServingStats:
             admits = max(1, self._admitted)
             caps = max(1, self._slot_capacity_sum)
             samples = list(self._ttft_samples)
+            # picks per held expert, then all picks and the sorted tiles'
+            # rows routed / rows computed (empty before the first record)
+            moe_held = self._moe_picks[:-3]
+            moe_all, moe_routed, moe_rows = (self._moe_picks[-3:].tolist()
+                                             or (0, 0, 0))
             out = {
                 "requests_submitted": self._submitted,
                 "requests_admitted": self._admitted,
@@ -616,16 +624,18 @@ class ServingStats:
                 # (running max |Δlogprob| vs fp reference; 0.0 when the
                 # engine is bit-exact or never sampled).
                 "logprob_drift": round(self._logprob_drift, 6),
-                # Held-expert layers (zero on a model without them): the
-                # share of all picks that landed on held experts, and the
-                # busiest held expert's picks over the held experts' mean.
+                # Expert layers on the held path (zero on a model without
+                # them): the share of all picks that landed on held
+                # experts, the busiest held expert's picks over the held
+                # experts' mean, and in the sorted tiles (a prefill chunk's
+                # path) the rows routed over the rows computed.
                 "moe_held_pick_share": round(
-                    float(self._moe_picks[:-1].sum() / self._moe_picks[-1]), 6)
-                    if self._moe_picks[-1:].any() else 0.0,
+                    float(moe_held.sum() / moe_all), 6) if moe_all else 0.0,
                 "moe_load_max_over_mean": round(
-                    float(self._moe_picks[:-1].max()
-                          / self._moe_picks[:-1].mean()), 4)
-                    if self._moe_picks[:-1].any() else 0.0,
+                    float(moe_held.max() / moe_held.mean()), 4)
+                    if moe_held.any() else 0.0,
+                "moe_tile_fill": round(float(moe_routed / moe_rows), 6)
+                    if moe_rows else 0.0,
                 # KV rows of windowed layers no later query can read, over
                 # all KV rows held, summed over ticks: what an allocator
                 # with a class of pages per layer kind would free.

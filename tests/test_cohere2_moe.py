@@ -92,7 +92,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, tokens):
         part, stats = moe_held_apply(
             held, layer["mlp"]["router"], normed[None], top_k=cfg.num_experts_per_tok,
             scores="sigmoid", held=(first, 2))
-        assert int(stats["picks"][-1]) == tokens * cfg.num_experts_per_tok
+        assert int(stats["picks"][-3]) == tokens * cfg.num_experts_per_tok
         # the reference given the same share agrees part by part
         want = ref.routed_part(normed, dict(layer["mlp"], experts=held), cfg, (first, 2))
         assert float(jnp.abs(part[0] - want).max()) < TOL
@@ -127,7 +127,10 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_expert(tokens):
     x = jnp.abs(jax.random.normal(keys[3], (1, tokens, d))) + 0.1      # all positive
     router = jnp.zeros((d, e)).at[:, 5].set(1.0).at[:, 2].set(0.5)     # all pick 5, then 2
     out, stats = moe_held_apply(experts, router, x, top_k=2, scores="sigmoid")
-    assert stats["picks"].tolist() == [0, 0, tokens, 0, 0, tokens, 0, 0, 2 * tokens]
+    # per expert, all picks, then the sorted path's rows routed and computed:
+    # 70 tokens, top-2 of 8 -> 64-row tiles, and 70 rows of an expert are two
+    sorted_rows = [0, 0] if tokens <= 32 else [2 * tokens, 2 * 2 * 64]
+    assert stats["picks"].tolist() == [0, 0, tokens, 0, 0, tokens, 0, 0, 2 * tokens] + sorted_rows
     gates, _ = route_top_k(x[0] @ router, 2, scores="sigmoid")
     want = sum(gates[:, j, None] * ref.swiglu(x[0], experts["gate_proj"][i],
                                               experts["up_proj"][i], experts["down_proj"][i])
@@ -233,7 +236,7 @@ def test_moving_all_positions_changes_a_sliding_layer_only(tiny, layer_idx, move
 # -- (f) held = all, softmax: the Mixtral layer -------------------------------
 
 @pytest.mark.parametrize("tokens", [7, 90], ids=["dense_path", "sorted_path"])
-def test_held_all_with_softmax_is_moe_mlp_apply_no_drop(tokens):
+def test_held_all_with_softmax_is_moe_mlp_apply_at_full_capacity(tokens):
     d, f, e, k = 32, 48, 8, 2
     keys = jax.random.split(jax.random.PRNGKey(8), 5)
     experts = {"gate_proj": jax.random.normal(keys[0], (e, d, f)) * d ** -0.5,
@@ -245,7 +248,7 @@ def test_held_all_with_softmax_is_moe_mlp_apply_no_drop(tokens):
     got, stats = jax.jit(lambda p, r, x: moe_held_apply(p, r, x, top_k=k))(
         experts, router, x)
     assert float(jnp.abs(got - want).max()) < TOL
-    assert int(stats["picks"][:-1].sum()) == int(stats["picks"][-1]) == tokens * k
+    assert int(stats["picks"][:-3].sum()) == int(stats["picks"][-3]) == tokens * k
 
 
 def test_the_decode_vmap_of_a_one_token_call_is_the_calls_one_by_one():
@@ -276,21 +279,27 @@ def test_held_must_match_the_stacks():
 
 def test_the_counters_appear_merge_and_reset():
     a, b = ServingStats(), ServingStats()
-    for key in ("moe_held_pick_share", "moe_load_max_over_mean", "kv_dead_rows_share"):
+    keys = ("moe_held_pick_share", "moe_load_max_over_mean", "moe_tile_fill", "kv_dead_rows_share")
+    for key in keys:
         assert a.summary()[key] == 0.0
-    a.record_tick(2, 2, 4, 0.01, moe_picks=np.array([3, 1, 32]), kv_rows=(10, 100))
-    a.record_prefill_chunk(1.0, moe_picks=np.array([1, 3, 32]))
-    b.record_tick(2, 2, 4, 0.01, moe_picks=[4, 0, 32], kv_rows=(30, 100))
+    # per held expert, all picks, rows routed and rows computed in sorted tiles
+    a.record_tick(2, 2, 4, 0.01, moe_picks=np.array([3, 1, 32, 0, 0]), kv_rows=(10, 100))
+    assert a.summary()["moe_tile_fill"] == 0.0      # ticks alone: nothing went through tiles
+    a.record_prefill_chunk(1.0, moe_picks=np.array([1, 3, 32, 4, 64]))
+    b.record_tick(2, 2, 4, 0.01, moe_picks=[4, 0, 32, 0, 0], kv_rows=(30, 100))
+    b.record_prefill_chunk(1.0, moe_picks=[0, 0, 0, 20, 32])
     s = a.summary()
     assert s["moe_held_pick_share"] == pytest.approx(8 / 64)
     assert s["moe_load_max_over_mean"] == pytest.approx(1.0)
+    assert s["moe_tile_fill"] == pytest.approx(4 / 64)
     assert s["kv_dead_rows_share"] == pytest.approx(0.1)
     m = ServingStats().merge(a).merge(b).summary()
     assert m["moe_held_pick_share"] == pytest.approx(12 / 96)
     assert m["moe_load_max_over_mean"] == pytest.approx(8 / 6, abs=1e-4)
+    assert m["moe_tile_fill"] == pytest.approx(24 / 96)
     assert m["kv_dead_rows_share"] == pytest.approx(0.2)
     a.reset()
-    assert a.summary()["moe_held_pick_share"] == 0.0 and a.summary()["kv_dead_rows_share"] == 0.0
+    assert all(a.summary()[key] == 0.0 for key in keys)
 
 
 def test_a_held_share_behind_the_engine_counts_its_picks_and_no_dead_rows_below_the_window(tiny):
@@ -307,7 +316,8 @@ def test_a_held_share_behind_the_engine_counts_its_picks_and_no_dead_rows_below_
         eng.shutdown(drain=False)
     assert s["kv_dead_rows_share"] == 0.0
     assert 0.0 <= s["moe_held_pick_share"] < 1.0
-    picks = eng.stats._moe_picks                # [on expert 0, on expert 1, all picks]
+    picks = eng.stats._moe_picks[:3]            # [on expert 0, on expert 1, all picks]
+    assert len(eng.stats._moe_picks) == 5 and not eng.stats._moe_picks[3:].any()   # no tiles ran
     # one chunk of 4 positions + at least 3 decode ticks (one more if a tick
     # was dispatched ahead of the retirement), each through 4 layers, top-2
     assert len(picks) == 3 and picks[-1] % (4 * 2) == 0 and picks[-1] >= (4 + 3) * 4 * 2

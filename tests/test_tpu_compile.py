@@ -177,29 +177,21 @@ def test_unsharded_call_on_a_mesh_is_refused_by_mosaic(topo, mosaic):
 # The cohere2_moe family's serving programs at the benchmark cell's widths
 # ---------------------------------------------------------------------------
 
-def _cohere2_moe_programs(one_chip):
-    """The model's part of the paged engine's two programs at the widths of
-    ``command-a-plus-05-2026-l4e16`` (hidden 4096, 128/8 heads of 128, 16 of
-    128 experts held, 4 shared, 4 layers, vocab slice 32768), over the linear
-    full-length view the engine gathers (max_len 8192): a 256-token prefill
-    chunk, and the decode tick — ``jax.vmap`` over 16 slots of a batch-1
-    forward. Both ask for the module's pick counters, as the engine does."""
-    from accelerate_tpu.models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM
-
-    cfg = Cohere2MoeConfig(vocab_size=32768, num_hidden_layers=4, held_experts=(0, 16))
-    model = Cohere2MoeForCausalLM(cfg)
-
+def _serving_programs(model, num_layers, kv_heads, head_dim, one_chip, *, max_len, slots, chunk):
+    """The model's part of the paged engine's two programs over the linear
+    full-length view the engine gathers: a ``chunk``-token prefill chunk, and
+    the decode tick — ``jax.vmap`` over ``slots`` of a batch-1 forward. Both
+    ask for the module's pick counters, as the engine does."""
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     params = jax.tree.map(lambda a: struct(a.shape, jnp.bfloat16), shapes)
-    max_len, slots, chunk = 8192, 16, 256
 
     def views(lead):
-        kv = struct(lead + (1, max_len, cfg.num_key_value_heads, cfg.head_dim), jnp.bfloat16)
-        return tuple({"k": kv, "v": kv} for _ in range(cfg.num_hidden_layers))
+        kv = struct(lead + (1, max_len, kv_heads, head_dim), jnp.bfloat16)
+        return tuple({"k": kv, "v": kv} for _ in range(num_layers))
 
     def apply(params, ids, cache, pos):
         (logits, cache), sown = model.apply({"params": params}, ids, cache=cache,
@@ -221,6 +213,17 @@ def _cohere2_moe_programs(one_chip):
     }
 
 
+def _cohere2_moe_programs(one_chip):
+    """At the widths of ``command-a-plus-05-2026-l4e16`` (hidden 4096, 128/8
+    heads of 128, 16 of 128 experts held, 4 shared, 4 layers, vocab slice
+    32768): max_len 8192, 16 slots, 256-token chunks."""
+    from accelerate_tpu.models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM
+
+    cfg = Cohere2MoeConfig(vocab_size=32768, num_hidden_layers=4, held_experts=(0, 16))
+    return _serving_programs(Cohere2MoeForCausalLM(cfg), 4, cfg.num_key_value_heads,
+                             cfg.head_dim, one_chip, max_len=8192, slots=16, chunk=256)
+
+
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
 def test_cohere2_moe_serving_program_compiles_for_v5e(program, one_chip):
     fn, args = _cohere2_moe_programs(one_chip)[program]
@@ -238,3 +241,39 @@ def test_cohere2_moe_serving_program_compiles_for_v5e(program, one_chip):
     assert not re.search(r"= bf16\[(16|4),4096,4096\]\S* (copy|transpose)\(", text)
     if program == "prefill_chunk":        # the loop over occupied expert tiles is there
         assert " while(" in text
+
+
+# ---------------------------------------------------------------------------
+# Mixtral's serving programs at the benchmark cell's widths
+# ---------------------------------------------------------------------------
+
+def _mixtral_programs(one_chip):
+    """At the widths of ``mixtral-8x7b-v0.1-d3`` (hidden 4096, 32/8 heads of
+    128, 8 experts top-2 of width 14336, vocab 32000; depth 1 is enough for
+    the lowering): max_len 1024, 8 slots, 256-token chunks."""
+    from accelerate_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+
+    cfg = MixtralConfig.mixtral_8x7b(num_hidden_layers=1, use_flash_attention=False)
+    return _serving_programs(MixtralForCausalLM(cfg), 1, cfg.num_key_value_heads, 128,
+                             one_chip, max_len=1024, slots=8, chunk=256)
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
+def test_mixtral_serving_program_compiles_for_v5e(program, one_chip):
+    import re
+
+    fn, args = _mixtral_programs(one_chip)[program]
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    weights = 3.43e9        # one layer (8 experts 2.82 GB) + embedding + head, bf16; views <= 34 MB
+    assert weights * 0.98 < memory.argument_size_in_bytes < weights * 1.02
+    assert memory.temp_size_in_bytes < 1.0e9, memory
+    text = compiled.as_text()
+    # no copy or transpose of an expert stack, nor of one expert's matrix
+    assert not re.search(r"= bf16\[(8,)?(4096,14336|14336,4096)\]\S* (copy|transpose)\(", text)
+    # the experts compute routed rows: no [.., 8 experts, 512 slots] dispatch or
+    # combine one-hot, no 512-row capacity an expert
+    assert not re.search(r"\[(\d+,)*8,512(,\d+)*\]", text)
+    if program == "prefill_chunk":        # the loop over occupied 128-row tiles is there
+        assert " while(" in text
+        assert re.search(r"bf16\[128,14336\]", text)
