@@ -341,8 +341,8 @@ def estimate_command(args) -> int:
         # the base stays frozen, so Adam costs 2 fp32 moments on n_lora.
         print(f"  Adam moments (fp32)      : {_fmt(ckpt_bytes * 2)}")
     if args.spec_tokens is not None and args.page_size is None:
-        print("--spec-tokens needs --page-size (speculative decoding "
-              "requires the paged engine)")
+        print("--spec-tokens needs --page-size (draft KV is sized in "
+              "pages)")
         return 2
     if args.page_size is not None:
         geom = _kv_geometry(module)

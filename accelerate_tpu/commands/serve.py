@@ -110,8 +110,7 @@ def build_fleet(args, model, params):
         return AdapterBank(params, config=LoRAConfig(rank=args.lora_rank),
                            max_adapters=max_adapters)
 
-    paging = dict(paged=(False if args.no_paged else None),
-                  page_size=args.page_size, max_pages=args.max_pages,
+    paging = dict(page_size=args.page_size, max_pages=args.max_pages,
                   kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype)
     spec = {}
     if args.draft_model:
@@ -305,19 +304,14 @@ def serve_command_parser(subparsers=None):
                              "divide the chunk)")
     parser.add_argument("--max-pages", type=int, default=None,
                         help="KV pool pages per replica (default: enough for "
-                             "every slot at max_len — same HBM as dense; "
-                             "lower it to oversubscribe capacity and rely on "
+                             "every slot at max_len; lower it to "
+                             "oversubscribe capacity and rely on "
                              "preemption under pressure)")
-    parser.add_argument("--no-paged", action="store_true",
-                        help="Use the dense per-slot KV layout instead of "
-                             "the paged pool (the pre-paging engine; also "
-                             "the A/B baseline)")
     parser.add_argument("--kv-dtype", default=None, choices=["int8"],
                         help="Store KV pages quantized (per-page scales): "
                              "~2x concurrent streams from the same pool "
                              "bytes at bounded logprob divergence; omit for "
-                             "the bit-exact full-precision pool (paged "
-                             "engines only)")
+                             "the bit-exact full-precision pool")
     parser.add_argument("--weights-dtype", default=None, choices=["int8"],
                         help="Store base weights per-channel int8, "
                              "dequantized on the fly (LoRA adapters stay "
@@ -392,9 +386,9 @@ def serve_command_parser(subparsers=None):
                         help="Speculative decoding draft: 'tiny' or "
                              "'pkg.mod:factory' returning (model, params) "
                              "with the SAME vocab as --model; every replica "
-                             "then decodes speculatively (paged engines "
-                             "only; composes with sampling, adapters, tp "
-                             "slices, and the prefix cache)")
+                             "then decodes speculatively (composes with "
+                             "sampling, adapters, tp slices, and the "
+                             "prefix cache)")
     parser.add_argument("--spec-tokens", type=int, default=4,
                         help="Proposed tokens per speculative verify step "
                              "(K); used with --draft-model or --spec-lookup")

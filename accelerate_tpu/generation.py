@@ -280,8 +280,7 @@ def _next_token(last, rng, seen, done, select, eos_token_id, dtype):
 
 def _chunk_prefill_token(logits, rng, select, eos_token_id, dtype, true_len,
                          offset=0, seen=None):
-    """THE prefill epilogue, shared by the serving engine's monolithic and
-    chunked prefill programs: split ``rng`` exactly like offline
+    """THE prefill epilogue of the serving engine's chunk programs: split ``rng`` exactly like offline
     :func:`generate` (decode carry first, prefill half second), read the
     logits row of the last REAL prompt position — ``true_len - 1`` in
     absolute positions, mapped into this chunk's ``[offset, offset + W)``

@@ -4,8 +4,9 @@ Public surface:
 
 * :class:`ServingEngine` — slot-based decode service running a FIXED set
   of compiled programs after warmup (one ``prefill_chunk`` executable of
-  shape ``[1, prefill_chunk]`` for every prompt length, one
-  ``decode_step_all_slots`` tick, one ``restore_prefix`` copy); requests
+  shape ``[1, prefill_chunk]`` for every prompt length and one decode
+  tick over a pool of KV pages; one ``restore_prefix`` copy only with an
+  external prefix cache); requests
   join and leave the batch mid-flight with zero recompiles, and admission
   is interleaved — at most ``prefill_chunks_per_tick`` chunk calls
   between decode ticks, so long prompts never stall active streams.
@@ -38,7 +39,7 @@ Public surface:
 * :class:`SlicePlan` / :class:`SliceExec` — mesh-sliced tensor
   parallelism: carve ``jax.devices()`` into disjoint ``tp``-wide slices,
   each one replica of a ``ReplicaSet.from_mesh`` fleet serving sharded
-  params / KV / adapter bank through the same three warm executables
+  params / KV / adapter bank through the same warm executables
   (``ServingEngine(tp=...)`` for a single slice).
 * :class:`ServingGateway` / :class:`GatewayConfig` /
   :class:`GatewayStats` — stdlib-only HTTP front end: ``POST

@@ -2,121 +2,110 @@
 
 The TPU constraint (GSPMD: peak performance comes from a small number of
 fixed-shape compiled programs) shapes the whole design. The engine owns a
-fixed ``[max_slots, max_len]`` decode state — per-slot KV cache, write
-position, carry rng, and eos latch — and after warmup runs a FIXED set of
+fixed decode state — a global pool of KV pages and, per slot, a write
+position, carry rng and eos latch — and after warmup runs a FIXED set of
 compiled programs, no matter how requests arrive or leave:
 
-* ``prefill_chunk`` — ONE compiled executable of fixed shape
-  ``[1, prefill_chunk]`` serves every prompt length: a prompt is a
-  sequence of identical-shape chunk calls at traced ``cache_pos =
-  offset`` (slot index, chunk offset, and true length are all traced
-  arguments, never shapes). The tail chunk is EDGE-padded on the host
-  (numpy, so no per-length jnp pad programs); the executable reads the
-  logits row of ``true_len - 1`` mapped into the chunk window, and also
-  returns the chunk's own KV block so the prefix cache never needs a
-  separate extract program. Warmup therefore leaves ZERO lazy compiles
-  for any prompt length — there is no per-bucket prefill family anymore.
-* ``decode_step_all_slots`` — one token for every slot per tick, a
+* the prefill chunk (``_paged_prefill_chunk_fn``) — ONE executable of
+  fixed shape ``[1, prefill_chunk]`` serves every prompt length: a prompt
+  is a sequence of identical-shape chunk calls at traced ``cache_pos =
+  offset`` (slot index, page-table row, chunk offset and true length are
+  all traced arguments, never shapes). The tail chunk is EDGE-padded on
+  the host (numpy, so no per-length jnp pad programs); the executable
+  reads the logits row of ``true_len - 1`` mapped into the chunk window,
+  and also returns the chunk's own KV block, which feeds an external
+  prefix cache without a separate extract program. Warmup therefore
+  leaves ZERO lazy compiles for any prompt length.
+* the decode tick (``_paged_decode_fn``) — one token for every slot, a
   ``jax.vmap`` of the batch-1 single-token forward over the slot axis,
   sharing :func:`generation._next_token` with the offline scan so engine
   streams are bit-identical to offline :func:`generation.generate` for the
   same (prompt, rng, sampling). Slot membership is a host-provided boolean
   mask ARGUMENT, never a shape: admitting or retiring a request changes
   the mask bits, not the program.
-* ``restore_prefix`` — one compiled copy of a cached ``[1, prefill_chunk]``
-  KV block into a slot's cache at a traced offset, so a prompt whose
-  chunk-aligned prefix is in the :class:`scheduler.PrefixCache` (shared
-  system prompts, few-shot headers) skips those chunks' prefill FLOPs
-  entirely and resumes chunking at the boundary.
+* the copy-restore (``_paged_restore_prefix_fn``) — only with an EXTERNAL
+  ``prefix_cache=``: one compiled copy of a cached ``[1, prefill_chunk]``
+  KV block into freshly allocated pages. The engine's private cache needs
+  no program at all (see below).
 
-Mesh-sliced mode (``tp=`` / ``mesh=``): the same three programs compile
-with ``in_shardings``/``out_shardings`` from
-:class:`~.mesh_exec.SliceExec`, so one engine spans a tensor-parallel
-slice of devices — params in the Megatron column/row layout the training
-side uses, the KV cache sharded on its heads axis, the adapter bank
-matching its base kernels — while slot membership, pos/tok/rng/done
-rows, prompt chunks, and masks stay replicated *data*. Nothing about the
-zero-recompile discipline changes: membership is still a traced
-argument, the warm-executable count is still three, and streams are
-token-identical to the single-chip engine. Prefix-cache blocks are
-fetched to host numpy in this mode, so one :class:`PrefixCache` can be
-shared by every slice of a ``ReplicaSet.from_mesh`` fleet (a block saved
-by one slice restores into any other's shardings — cross-slice hits
-survive failover).
+KV memory is PAGED: a global pool of fixed-size pages (``page_size``
+tokens, default one prefill chunk) plus a host-side ``[max_slots,
+max_pages_per_slot]`` page table. The table rides into the warm
+executables as traced integer data — each program gathers a slot's pages
+into a dense view, runs the unchanged forward, and scatters only the
+written pages back — so page allocation, free, preemption, and
+prefix-block ALIASING (a private-cache hit is a host table write +
+refcount, zero device copies) all compile nothing. Page 0 is a reserved
+scratch page: unallocated table entries point at it, so clamped
+gathers/scatters for inactive slots land there harmlessly. Short requests
+hold pages, not worst-case rows, and a pool-exhausted engine preempts a
+stream at a chunk boundary and resumes it token-exactly later (the
+router-failover resume-as-longer-prompt trick). Sliding-window models
+serve through the same view: the gathered view is a full-length linear
+cache, and pages that fall wholly out of every layer's attention window
+are freed (ring semantics as a page-lifetime policy, no new kernel).
 
-Admission is interleaved, not monolithic: an admitted request sits in
-``PREFILLING`` holding its slot, and each scheduler iteration spends at
-most ``prefill_chunks_per_tick`` chunk calls (round-robin across the
-prefill backlog) before the next decode tick — so decode lanes advance
-every tick and a 4k-token arrival can no longer stall every active
-stream for its whole prefill. Outputs stay token-identical to the
-monolithic path and to offline ``generate``: chunking changes WHEN KV is
-written, not what is written, and the first-token rng split
-(:func:`generation._chunk_prefill_token`) is the same.
-
-Pad/garbage-KV safety, chunked edition: chunk calls write KV in place
-into the slot's region of the shared cache, which may hold a previous
-occupant's entries (and the tail chunk writes edge-pad KV past
+Pad/garbage-KV safety: chunk calls write KV into pages that may hold a
+previous occupant's entries (and the tail chunk writes edge-pad KV past
 ``true_len``). Both are safe for the same reason the offline bucketing
 is: the attention mask attends ``k_pos <= q_pos`` only, and masking is
 REPLACEMENT (``jnp.where(mask, logits, -1e30)``), so a masked garbage
 key contributes exactly 0 probability — finite garbage KV never changes
 a real row's output. Positions at/past ``true_len`` are overwritten by
 the first decode write at-or-before the first query that could attend
-them. One extra invariant protects ``PREFILLING`` slots from the decode
-tick (whose cache commit is unconditional): every ``prefill_chunk`` and
-``restore_prefix`` call writes ``pos[slot] = true_len``, so any garbage
-a tick writes for a mid-prefill slot lands at ``true_len`` — a position
-no prompt chunk reads and the first real decode write overwrites.
+them. A tick scatters an inactive or ``PREFILLING`` slot's write to the
+scratch page, and every chunk and restore call writes ``pos[slot] =
+true_len``, so a mid-prefill slot's rows stay frozen and in bounds.
 
-Paged KV memory (``paged=True``, the default with chunked prefill): the
-dense per-slot rows are replaced by a global pool of fixed-size KV PAGES
-(``page_size`` tokens, default one prefill chunk) plus a host-side
-``[max_slots, max_pages_per_slot]`` page table. The table rides into the
-SAME warm executables as traced integer data — the prefill chunk, decode
-tick, and restore programs gather each slot's pages into a dense view,
-run the unchanged forward, and scatter only the written pages back — so
-page allocation, free, preemption, and prefix-block ALIASING (a cache
-hit becomes a host table write + refcount, zero device copies) all
-compile nothing. Page 0 is a reserved scratch page: unallocated table
-entries point at it, so clamped gathers/scatters for inactive slots land
-there harmlessly (garbage KV is masked or overwritten, the same
-invariant as the dense path). Short requests now hold pages, not
-worst-case rows — severalfold more concurrent slots at equal HBM — and
-a pool-exhausted engine preempts the newest stream at a chunk boundary
-and resumes it token-exactly later (the router-failover
-resume-as-longer-prompt trick). Sliding-window models serve under
-paging too: pages that fall wholly out of the attention window are freed
-(ring semantics as a page-lifetime policy, no new kernel).
+Admission is interleaved: an admitted request sits in ``PREFILLING``
+holding its slot, and each scheduler iteration spends at most
+``prefill_chunks_per_tick`` chunk calls (round-robin across the prefill
+backlog) before the next decode tick — so decode lanes advance every
+tick and a 4k-token arrival cannot stall every active stream for its
+whole prefill. Outputs stay token-identical to offline ``generate``:
+chunking changes WHEN KV is written, not what is written, and the
+first-token rng split (:func:`generation._chunk_prefill_token`) is the
+same.
 
-Speculative decoding (``draft_model=`` or ``spec_lookup=``, paged
-engines) is universal, not a special case: each tick runs ONE warm
-executable that obtains ``spec_tokens`` proposals — a compiled draft
-scan over draft KV paged from the SAME pool (separate table columns),
-or a host-side prompt-lookup n-gram match with no draft model at all —
-then verifies them with one fixed-width ``[1, K+1]`` target forward
-against the paged view. Greedy engines accept the longest matching
-prefix (streams bit-identical to non-speculative greedy: the verify
-logits ARE the dense tick's logits); sampled engines apply the exact
-rejection-sampling rule (:func:`generation.speculative_accept`) on the
-per-slot rng rows, so the emitted distribution is the dense sampled
+Mesh-sliced mode (``tp=`` / ``mesh=``): the same programs compile with
+``in_shardings``/``out_shardings`` from :class:`~.mesh_exec.SliceExec`,
+so one engine spans a tensor-parallel slice of devices — params in the
+Megatron column/row layout the training side uses, the page pool sharded
+on its heads axis, the adapter bank matching its base kernels — while
+slot membership, pos/tok/rng/done rows, prompt chunks, page tables and
+masks stay replicated *data*. Streams are token-identical to the
+single-chip engine. External prefix-cache blocks are fetched to host
+numpy in this mode, so one :class:`PrefixCache` can be shared by every
+slice of a ``ReplicaSet.from_mesh`` fleet (a block saved by one slice
+restores into any other's shardings — cross-slice hits survive
+failover).
+
+Speculative decoding (``draft_model=`` or ``spec_lookup=``): each tick
+runs ONE warm executable that obtains ``spec_tokens`` proposals — a
+compiled draft scan over draft KV paged from the SAME pool (separate
+table columns), or a host-side prompt-lookup n-gram match with no draft
+model at all — then verifies them with one fixed-width ``[1, K+1]``
+target forward against the paged view. Greedy engines accept the longest
+matching prefix (streams bit-identical to non-speculative greedy: the
+verify logits ARE the plain tick's logits); sampled engines apply the
+exact rejection-sampling rule (:func:`generation.speculative_accept`) on
+the per-slot rng rows, so the emitted distribution is the plain sampled
 law. Adapter rows gather inside the same program (the draft stays
 base-weight), mesh slices compile the verify tp-sharded with the draft
 replicated, and prefix-cache hits rebuild draft KV via a draft-only
 chunk program — all under the same zero-recompile pin.
 
-The ASYNC HOST RUNTIME (``async_ticks=True``, the default) takes the
-Python host off the device's critical path. JAX dispatch is
-asynchronous: a compiled call returns futures immediately, and chaining
-``self._state`` through successive calls fixes device execution order
-without the host ever waiting. The run loop exploits this by dispatching
-tick N+1 — page coverage, membership mask, admission work and all —
-against tick N's still-in-flight state futures, then reconciling N
-(materialize tokens, commit, retire) while N+1 runs. The dispatch uses a
-SPECULATIVE view of the batch: host state is stale by exactly the one
-in-flight tick, so a stream that retires at N wastes one masked lane at
-N+1 (its stray token is discarded by an epoch/validity check at
-reconcile — emission stays exactly once), streams within one token of
+The run loop keeps the Python host off the device's critical path. JAX
+dispatch is asynchronous: a compiled call returns futures immediately,
+and chaining ``self._state`` through successive calls fixes device
+execution order without the host ever waiting. The loop dispatches tick
+N+1 — page coverage, membership mask, admission work and all — against
+tick N's still-in-flight state futures, then reconciles N (materialize
+tokens, commit, retire) while N+1 runs. The dispatch uses a SPECULATIVE
+view of the batch: host state is stale by exactly the one in-flight
+tick, so a stream that retires at N wastes one masked lane at N+1 (its
+stray token is discarded by an epoch/validity check at reconcile —
+emission stays exactly once), streams within one token of
 ``max_new_tokens`` are conservatively excluded (their stray write would
 exceed the position bound), and pages are pre-allocated one position
 ahead. Page-table snapshots (``.copy()`` per dispatch) double-buffer the
@@ -124,20 +113,17 @@ host tables: reconcile-time frees/preemptions mutate the live table
 while the in-flight program reads its own generation, and device program
 order guarantees any write a stale snapshot routes into a
 since-recycled page happens BEFORE the page's new owner prefills it
-(overwrite-before-attend, again). Streaming callbacks move to a bounded
+(overwrite-before-attend, again). Streaming callbacks go to a bounded
 per-request queue drained by an emitter thread, so a slow consumer
 flow-controls its own stream (skipped lanes, ``emission_stalls``) and
 never stalls the batch; a retiring stream's completion is deferred
-behind its buffered callbacks (drain-on-retire barrier). Token streams
-are identical to ``async_ticks=False`` across every path — dense,
-paged, adapters, mesh slices, speculative — with the same warm
-executables; what changes is that ``host_us_per_tick`` (scheduling +
-commit wall) hides under device time instead of adding to ITL. One
-carve-out: prompt-lookup engines reconcile before dispatching (no
-ahead tick) — their proposals anchor on the newest committed token,
-and a proposal drafted one variable-length tick behind verifies to
-zero accepts, which would trade all of lookup's acceptance for the
-overlap.
+behind its buffered callbacks (drain-on-retire barrier).
+``host_us_per_tick`` (scheduling + commit wall) thus hides under device
+time instead of adding to ITL. One carve-out: prompt-lookup engines
+reconcile before dispatching (no ahead tick) — their proposals anchor on
+the newest committed token, and a proposal drafted one variable-length
+tick behind verifies to zero accepts, which would trade all of lookup's
+acceptance for the overlap.
 
 Around the compiled programs: a bounded FCFS admission queue with
 backpressure, per-request ``max_new_tokens``/timeout/cancellation,
@@ -165,7 +151,6 @@ import numpy as np
 
 from ..adapters.registry import AdapterBank
 from ..generation import (
-    _bucket128,
     _check_position_bound,
     _chunk_prefill_token,
     _make_selector,
@@ -209,7 +194,7 @@ class _TickFlight:
                  emit=None, ns=None, lookup_hits=0):
         self.entries = entries          # [(slot, req, req._preempted)]
         self.t_dispatch = t_dispatch
-        self.toks = toks                # dense/paged tick outputs
+        self.toks = toks                # plain tick outputs
         self.dones = dones
         self.emit = emit                # speculative tick outputs
         self.ns = ns
@@ -404,26 +389,25 @@ class ServingEngine:
         cache-threading flax module (see ``generation.supports_kv_cache``).
       params: parameter pytree (defaults to the prepared model's).
       max_slots: decode lanes — the fixed batch dimension of the tick.
-      max_len: per-slot KV capacity; every request must satisfy
+      max_len: longest stream a slot can hold; every request must satisfy
         ``prompt_len + max_new_tokens <= max_len``.
       eos_token_id / do_sample / temperature / top_k / top_p: ENGINE-level
         sampling config — baked into the compiled executables (a
         per-request change would be a recompile). Greedy when
         ``do_sample=False``.
       cache_dtype: KV buffer dtype (default bfloat16, like offline).
-      kv_dtype: ``"int8"`` stores the paged KV pool quantized — each page
+      kv_dtype: ``"int8"`` stores the KV page pool quantized — each page
         row is symmetric int8 with one per-page f32 scale held in a
         ``pscale`` state array indexed by page id, written by the same
         executables that write the page (quantize at the page scatter,
         dequantize at the gather into the dense view). Pages cost half
         the bytes, so the same HBM pool admits ~2x the concurrent
         streams; alloc/free/alias/preempt stay pure host work because
-        scales live device-side keyed by page id. Requires the paged
-        engine. ``None`` (default) keeps the full-precision pool and
-        traces byte-identical programs to before this knob existed —
-        the bit-exact mode. Exactness under ``"int8"`` is
-        bounded-divergence instead: see ``logprob_drift`` in bench and
-        docs/usage_guides/serving.md.
+        scales live device-side keyed by page id. ``None`` (default)
+        keeps the full-precision pool — the bit-exact mode, whose
+        programs carry no quantization op at all. Exactness under
+        ``"int8"`` is bounded-divergence instead: see ``logprob_drift``
+        in bench and docs/usage_guides/serving.md.
       weights_dtype: ``"int8"`` quantizes eligible BASE weight kernels
         per-output-channel (:func:`~accelerate_tpu.adapters.
         quantize_base_weights`); each program dequantizes at its top and
@@ -433,12 +417,13 @@ class ServingEngine:
         adapters apply exactly on the quantized base. ``None`` (default)
         serves full-precision weights.
       max_queued: admission-queue bound (backpressure past it).
+      priority_policy: a :class:`~.control.PriorityPolicy` ranking the
+        admission queue's classes and choosing preemption victims
+        (``"default"`` builds one; ``None`` is plain FCFS).
       prefill_chunk: width of the single fixed-shape prefill executable
         (clamped to ``max_len`` and the model's position table); a prompt
         of any length runs as identical ``[1, prefill_chunk]`` chunk
-        calls. ``None`` selects the legacy monolithic path (one compiled
-        prefill per 128-bucketed prompt length, admission runs the whole
-        prompt inline) — kept for A/B measurement.
+        calls. Chunked prefill is the only prefill: ``None`` is refused.
       prefill_chunks_per_tick: admission budget — at most this many chunk
         calls run between consecutive decode ticks, alternating
         continuations of the ``PREFILLING`` backlog (round-robin) with
@@ -446,9 +431,10 @@ class ServingEngine:
         streams' next token. At the default 1 a new arrival waits for the
         backlog to drain; 2+ lets its first chunk ride alongside an
         in-flight long prefill.
-      prefix_cache_mb: LRU budget for chunk-aligned prefix KV blocks
-        (0 disables). On admit, the longest cached chunk-aligned prefix
-        is restored by ``restore_prefix`` instead of recomputed; the
+      prefix_cache_mb: LRU budget of the engine's private prefix cache
+        (0 disables). It holds page ids, not KV copies: on admit, the
+        longest cached chunk-aligned prefix is restored by ALIASING its
+        pages into the slot's table instead of recomputing it; the
         final chunk always re-runs so the first token's logits exist.
         Cache keys include the request's adapter identity — two tenants
         with identical prompts never share KV blocks.
@@ -474,29 +460,27 @@ class ServingEngine:
       devices: with ``tp=``, the device pool to carve the slice from
         (default ``jax.devices()``).
       prefix_cache: a pre-built (possibly fleet-shared)
-        :class:`~.scheduler.PrefixCache` to use instead of constructing
-        one from ``prefix_cache_mb`` — how ``ReplicaSet.from_mesh``
-        gives every slice one cache for cross-slice prefix hits.
+        :class:`~.scheduler.PrefixCache` of host-portable KV blocks to
+        use instead of the private cache — how ``ReplicaSet.from_mesh``
+        gives every slice one cache for cross-slice prefix hits; a hit
+        is a compiled copy into fresh pages (``restore_prefix``).
       accelerator: optional — wires preemption-drain cooperation and, when
         the accelerator carries a ``serving_stats``, shares it so
         ``Accelerator.log(include_serving=True)`` sees this engine.
-      paged: use the paged KV pool instead of dense per-slot rows.
-        ``None`` (default) auto-selects paging whenever chunked prefill
-        is on; ``False`` keeps the dense layout (the A/B baseline);
-        ``True`` with ``prefill_chunk=None`` is an error (pages are
-        chunk-granular).
+      stats: a :class:`~.metrics.ServingStats` to record into (default:
+        the accelerator's, else a fresh one).
       page_size: tokens per KV page (default = ``prefill_chunk`` so
         PrefixCache blocks map onto whole pages and cache hits restore
         by table ALIASING); must divide ``prefill_chunk``.
       max_pages: usable pool pages (page 0 scratch is extra). Default
-        ``max_slots * ceil(max_len / page_size)`` — enough that paging
-        can never serve FEWER requests than dense; pass less to
-        overcommit memory and lean on preemption.
+        ``max_slots * ceil(max_len / page_size)`` — every slot can reach
+        ``max_len`` at once; pass less to overcommit memory and lean on
+        preemption.
       draft_model / draft_params: enable speculative decoding — a small
         cache-threading draft module proposing ``spec_tokens`` tokens per
-        tick, verified by one fixed-width target forward. Requires
-        ``paged=True`` (draft KV pages come from the same pool, so a
-        speculative slot costs roughly twice the pages); composes with
+        tick, verified by one fixed-width target forward. Draft KV pages
+        come from the same pool, so a speculative slot costs roughly
+        twice the pages; composes with
         sampling (exact rejection-rule acceptance), adapter banks (the
         target verify gathers the slot's row; the draft runs base
         weights), mesh slices (draft replicated, verify tp-sharded), and
@@ -524,28 +508,17 @@ class ServingEngine:
         slow ticks, a wedge inside a dispatched call) applied from the
         run loop — the deterministic fault-injection harness behind the
         self-healing tests.
-      async_ticks: run the ASYNC host runtime (default): after
-        dispatching tick N the loop immediately schedules pages,
-        admission, and tick N+1 against the still-in-flight state
-        futures (JAX async dispatch), reconciling N's tokens when they
-        materialize — host scheduling/commit work overlaps device
-        compute, and per-token streaming callbacks move to a dedicated
-        emitter thread so a slow consumer can never stall the tick
-        loop. Token streams are identical to sync mode (a stream that
-        retires at tick N wastes one masked lane at N+1; the lane's
-        extra token is discarded host-side) and the compiled programs
-        are byte-identical — ``async_ticks=False`` is the strictly
-        tick-synchronous A/B fallback (dispatch, block, commit, inline
-        callbacks), the pre-async behavior.
       emission_queue: per-request bound on emitter-queued ``on_token``
-        callbacks (async mode only). A stream whose consumer falls this
-        far behind is flow-controlled — skipped from decode ticks
-        (``emission_stalls`` counts them) until its queue drains —
-        instead of growing host memory or stalling the batch.
+        callbacks. A stream whose consumer falls this far behind is
+        flow-controlled — skipped from decode ticks (``emission_stalls``
+        counts them) until its queue drains — instead of growing host
+        memory or stalling the batch.
       autostart: spawn the engine thread (and warm up) in the constructor.
       warmup: run dummy requests through every program at start so the
         first real request never pays a compile; stats, spans, and
         flight events reset afterwards.
+      idle_poll_s: how long the idle loop blocks on the admission queue
+        before it republishes its heartbeat.
     """
 
     def __init__(self, model, params=None, *, max_slots: int = 4,
@@ -555,11 +528,10 @@ class ServingEngine:
                  cache_dtype=None, kv_dtype: Optional[str] = None,
                  weights_dtype: Optional[str] = None, max_queued: int = 64,
                  priority_policy: Optional[PriorityPolicy] = "default",
-                 prefill_chunk: Optional[int] = 256,
+                 prefill_chunk: int = 256,
                  prefill_chunks_per_tick: int = 1,
                  prefix_cache_mb: float = 64.0,
                  adapters: Optional[AdapterBank] = None,
-                 paged: Optional[bool] = None,
                  page_size: Optional[int] = None,
                  max_pages: Optional[int] = None,
                  draft_model=None, draft_params=None, spec_tokens: int = 4,
@@ -571,7 +543,6 @@ class ServingEngine:
                  flight_capacity: int = 256,
                  trace_dir: Optional[str] = None,
                  chaos=None,
-                 async_ticks: Optional[bool] = None,
                  emission_queue: int = 256,
                  autostart: bool = True, warmup: bool = True,
                  idle_poll_s: float = 0.005):
@@ -595,9 +566,13 @@ class ServingEngine:
         if max_slots < 1 or max_len < 2:
             raise ValueError(f"need max_slots >= 1 and max_len >= 2 "
                              f"(got {max_slots}, {max_len})")
-        if prefill_chunk is not None and int(prefill_chunk) < 1:
+        if prefill_chunk is None:
             raise ValueError(
-                f"prefill_chunk must be >= 1 or None (got {prefill_chunk})")
+                "prefill_chunk=None: chunked prefill is the only prefill; "
+                "pass a chunk width >= 1")
+        if int(prefill_chunk) < 1:
+            raise ValueError(
+                f"prefill_chunk must be >= 1 (got {prefill_chunk})")
         if prefill_chunks_per_tick < 1:
             raise ValueError("prefill_chunks_per_tick must be >= 1 "
                              f"(got {prefill_chunks_per_tick})")
@@ -616,11 +591,6 @@ class ServingEngine:
             from .mesh_exec import SliceExec
 
             self._exec: Optional["SliceExec"] = SliceExec(serving_mesh)
-            if prefill_chunk is None:
-                raise NotImplementedError(
-                    "the monolithic prefill path (prefill_chunk=None) is "
-                    "single-chip only; mesh-sliced engines require chunked "
-                    "prefill (pass a prefill_chunk width)")
         else:
             self._exec = None
         #: tensor-parallel width of this engine's slice (1 = single-chip).
@@ -642,57 +612,31 @@ class ServingEngine:
                         "max_position_embeddings", None)
         self._chunk_limit = (self.max_len if bound is None
                              else min(self.max_len, int(bound)))
-        if prefill_chunk is None:
-            self._chunk: Optional[int] = None
-            self._chunk_cap = 0
-        else:
-            self._chunk = min(int(prefill_chunk), self._chunk_limit)
-            # The final chunk may start below its natural i*C offset so its
-            # fixed width never writes past max_len / the position table
-            # (re-running already-prefilled positions rewrites identical KV).
-            self._chunk_cap = self._chunk_limit - self._chunk
+        self._chunk = min(int(prefill_chunk), self._chunk_limit)
+        # The final chunk may start below its natural i*C offset so its
+        # fixed width never writes past max_len / the position table
+        # (re-running already-prefilled positions rewrites identical KV).
+        self._chunk_cap = self._chunk_limit - self._chunk
         self._chunks_per_tick = int(prefill_chunks_per_tick)
 
-        # -- paged-pool resolution (before the prefix cache: an alias-mode
-        # cache wires its eviction hook to the page pool) ----------------
-        if paged is None:
-            paged = self._chunk is not None
-        if paged and self._chunk is None:
+        self._page = int(page_size) if page_size is not None else self._chunk
+        if self._page < 1 or self._chunk % self._page != 0:
             raise ValueError(
-                "paged=True requires chunked prefill (pages are allocated at "
-                "chunk granularity); pass a prefill_chunk width")
-        self._paged = bool(paged)
-        if self._paged:
-            P = int(page_size) if page_size is not None else self._chunk
-            if P < 1 or self._chunk % P != 0:
-                raise ValueError(
-                    f"page_size ({page_size}) must be >= 1 and divide the "
-                    f"prefill chunk ({self._chunk}) so chunk writes and "
-                    "cached blocks cover whole pages")
-            self._page: Optional[int] = P
-        else:
-            if page_size is not None or max_pages is not None:
-                raise ValueError(
-                    "page_size=/max_pages= only apply to the paged engine "
-                    "(paged=False keeps dense per-slot rows)")
-            self._page = None
+                f"page_size ({page_size}) must be >= 1 and divide the "
+                f"prefill chunk ({self._chunk}) so chunk writes and "
+                "cached blocks cover whole pages")
 
         # -- quantized serving resolution --------------------------------
-        # kv int8 lives at PAGE granularity (one scale per page row), so it
-        # needs the paged pool; kv_dtype=None must trace byte-identical
-        # programs to the pre-quantization engine — every quant/dequant
-        # site below is gated on the scale arrays being present at all.
+        # kv int8 lives at PAGE granularity (one scale per page row);
+        # kv_dtype=None programs carry no quantization op — every
+        # quant/dequant site below is gated on the scale arrays being
+        # present at all.
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None or 'int8' (got {kv_dtype!r})")
         if weights_dtype not in (None, "int8"):
             raise ValueError(
                 f"weights_dtype must be None or 'int8' (got {weights_dtype!r})")
-        if kv_dtype is not None and not self._paged:
-            raise ValueError(
-                "kv_dtype='int8' requires the paged engine (per-page scales "
-                "live in page-id-indexed state); pass paged=True or drop "
-                "kv_dtype")
         self._kv_dtype = kv_dtype
         self._weights_dtype = weights_dtype
 
@@ -700,17 +644,12 @@ class ServingEngine:
         # Two drafting modes share one verify program shape: a DRAFT MODEL
         # (paged draft KV alongside the target's) or host-side
         # PROMPT-LOOKUP n-gram proposals (no draft state at all). Either
-        # composes with sampling, adapters, mesh slices, and prefix caches
-        # — speculation is no longer a special case.
+        # composes with sampling, adapters, mesh slices, and prefix caches.
         if draft_model is not None and spec_lookup is not None:
             raise ValueError(
                 "draft_model= and spec_lookup= are mutually exclusive — one "
                 "engine drafts either with a model or by prompt lookup")
         if draft_model is not None or spec_lookup is not None:
-            if not self._paged:
-                raise NotImplementedError(
-                    "speculative decoding requires the paged engine "
-                    "(paged=True)")
             if int(spec_tokens) < 1:
                 raise ValueError(
                     f"spec_tokens must be >= 1 (got {spec_tokens})")
@@ -760,49 +699,31 @@ class ServingEngine:
         self._draft_page_bytes = 0
 
         if prefix_cache is not None:
-            if self._chunk is None:
-                raise ValueError(
-                    "prefix_cache= requires chunked prefill "
-                    "(prefill_chunk=None has no chunk-aligned blocks)")
             self._prefix_cache: Optional[PrefixCache] = prefix_cache
             self._alias_cache = False   # external/shared cache: COPY restores
-        elif self._chunk is not None and prefix_cache_mb > 0:
-            # A PRIVATE cache on a paged engine stores page-id tuples, not
-            # KV blocks: a hit is a host table write + refcount (aliasing),
-            # and eviction gives the pages back through the hook.
-            self._alias_cache = self._paged
+        elif prefix_cache_mb > 0:
+            # The PRIVATE cache stores page-id tuples, not KV blocks: a hit
+            # is a host table write + refcount (aliasing), and eviction
+            # gives the pages back through the hook.
+            self._alias_cache = True
             self._prefix_cache = PrefixCache(
                 int(prefix_cache_mb * 2 ** 20),
-                on_evict=self._on_prefix_evict if self._alias_cache else None)
+                on_evict=self._on_prefix_evict)
         else:
             self._prefix_cache = None
             self._alias_cache = False
         self._prefilling: collections.deque[Request] = collections.deque()
 
-        # One slot's cache is the state template. Ring (sliding-window)
-        # caches rotate by stored position — the dense slot-stacked layout
-        # cannot model that, but the PAGED layout serves them: the gathered
-        # view is always a full-length LINEAR cache (the model's linear
-        # branch applies the window mask), and ring semantics become a
-        # page-lifetime policy (out-of-window pages are freed). Only the
-        # dense path refuses.
+        # Ring (sliding-window) caches rotate by stored position; the page
+        # pool serves them as what the gathered view always is, a
+        # full-length LINEAR cache (the model's linear branch applies the
+        # window mask), and ring semantics become a page-lifetime policy
+        # (out-of-window pages are freed).
         slot_shape = jax.eval_shape(
             lambda: self._factory(1, self.max_len, self._dtype))
         has_ring = any(isinstance(layer, dict) and "pos" in layer
                        for layer in slot_shape)
-        if has_ring and not self._paged:
-            raise NotImplementedError(
-                "sliding-window (ring) KV caches need the paged engine "
-                "(paged=True frees out-of-window pages); the dense slot "
-                "layout cannot rotate them — or set the config's window "
-                ">= max_len")
-        if self._chunk is not None:
-            # The paged template probes at tiny lengths where every layer is
-            # linear (a window >= 2 never rings at length 2) because the
-            # gathered page view is a full-length linear cache; the dense
-            # chunked path keeps the max_len probes.
-            self._cache_axes = (self._cache_length_axes(2, 1) if self._paged
-                                else self._cache_length_axes())
+        self._cache_axes = self._cache_length_axes(self._factory)
         cfg = getattr(module, "config", None)
         #: each layer's attention window where it is shorter than max_len
         #: (None = the layer reads every row), from the same per-layer rule
@@ -816,12 +737,11 @@ class ServingEngine:
                 for w in (_layer_window(cfg, i)
                           for i in range(cfg.num_hidden_layers))]
         #: window width when pages wholly out of the attention window may be
-        #: freed: paged + every layer windowed alike (mixed local/global
-        #: stacks keep all pages — a global layer reads them to the end).
+        #: freed: every layer windowed alike (mixed local/global stacks keep
+        #: all pages — a global layer reads them to the end).
         kinds = set(self._layer_windows)
         self._page_window = (
-            int(next(iter(kinds))) if (self._paged and len(kinds) == 1
-                                       and None not in kinds)
+            int(next(iter(kinds))) if len(kinds) == 1 and None not in kinds
             else None)
         #: (window, layers) pairs for the kv_dead_rows_share counter.
         self._dead_row_windows = [
@@ -829,114 +749,99 @@ class ServingEngine:
             if w is not None]
         #: the variable collection a module sows per-call counters into
         #: (models/cohere2_moe.py, models/mixtral.py: MoE pick counts); the
-        #: paged programs ask for it and return its sum packed behind the
-        #: tokens. None on the dense engine and for modules that sow nothing.
-        self._stats_collection = (
-            getattr(module, "serving_stats_collection", None)
-            if self._paged else None)
+        #: programs ask for it and return its sum packed behind the tokens.
+        #: None for modules that sow nothing.
+        self._stats_collection = getattr(module, "serving_stats_collection",
+                                         None)
         #: results of prefill chunks whose counters are not folded yet: a
         #: chunk that is not its prompt's last is read on the host only at
         #: a later last chunk, when its copy has long arrived.
         self._late_counts: list = []
 
-        if self._paged:
-            probe = jax.eval_shape(lambda: self._factory(1, 2, self._dtype))
-            self._cache_struct = jax.tree.structure(probe)
-            K = self._spec_k or 0
-            # The view must hold max_len + K positions: a verify near the
-            # end of a stream writes up to pos + K, and the model's internal
-            # dynamic_update_slice would CLAMP (corrupting earlier
-            # positions) if the view were shorter.
-            self._pages_per_slot = -(-(self.max_len + K) // self._page)
-            usable = (int(max_pages) if max_pages is not None
-                      else self.max_slots * (-(-self.max_len // self._page)))
-            if usable < 1:
-                raise ValueError(f"max_pages must be >= 1 (got {max_pages})")
-            self._pool = PagePool(usable)
-            self._table = np.zeros((self.max_slots, self._pages_per_slot),
-                                   np.int32)
-            quant = self._kv_dtype is not None
-            pool_leaves, self._page_bytes = [], 0
-            for sh, ax in zip(jax.tree.leaves(probe), self._cache_axes):
+        probe = jax.eval_shape(lambda: self._factory(1, 2, self._dtype))
+        self._cache_struct = jax.tree.structure(probe)
+        K = self._spec_k or 0
+        # The view must hold max_len + K positions: a verify near the
+        # end of a stream writes up to pos + K, and the model's internal
+        # dynamic_update_slice would CLAMP (corrupting earlier
+        # positions) if the view were shorter.
+        self._pages_per_slot = -(-(self.max_len + K) // self._page)
+        usable = (int(max_pages) if max_pages is not None
+                  else self.max_slots * (-(-self.max_len // self._page)))
+        if usable < 1:
+            raise ValueError(f"max_pages must be >= 1 (got {max_pages})")
+        self._pool = PagePool(usable)
+        self._table = np.zeros((self.max_slots, self._pages_per_slot),
+                               np.int32)
+        quant = self._kv_dtype is not None
+        pool_leaves, self._page_bytes = [], 0
+        for sh, ax in zip(jax.tree.leaves(probe), self._cache_axes):
+            shape = list(sh.shape)
+            shape[ax] = self._page
+            # +1: page 0 is the reserved scratch page every clamped or
+            # inactive write routes to.
+            pool_leaves.append(jnp.zeros(
+                (usable + 1,) + tuple(shape),
+                jnp.int8 if quant else sh.dtype))
+            # Quantized pages charge 1 byte/element + 4 bytes for the
+            # per-page scale — _page_bytes feeds every byte-accounting
+            # path (pool metrics, alias-put nbytes, per-chip HBM), so
+            # all of them report quantized bytes automatically.
+            self._page_bytes += (
+                int(np.prod(shape))
+                * (1 if quant else np.dtype(sh.dtype).itemsize)
+                + (4 if quant else 0))
+        self._state = {
+            "pool": jax.tree.unflatten(self._cache_struct, pool_leaves),
+            "pos": jnp.zeros((self.max_slots,), jnp.int32),
+            "tok": jnp.zeros((self.max_slots,), jnp.int32),
+            "rng": jnp.zeros((self.max_slots, 2), jnp.uint32),
+            "done": jnp.zeros((self.max_slots,), bool),
+        }
+        if quant:
+            # Per-page dequant scales, one row per pool leaf, indexed
+            # by page id like the pool itself — device-resident, so a
+            # host page-table alias restore (table write + incref)
+            # reuses the page's scale with zero device work. Ones keep
+            # scratch-page gathers finite before any real write.
+            self._state["pscale"] = jnp.ones(
+                (len(pool_leaves), usable + 1), jnp.float32)
+        if self._spec_mode == "draft":
+            dshape = jax.eval_shape(lambda: self._draft_factory(
+                1, self.max_len + self._spec_k, self._dtype))
+            if any(isinstance(layer, dict) and "pos" in layer
+                   for layer in dshape):
+                raise NotImplementedError(
+                    "the draft model's KV cache must be linear at "
+                    "max_len + spec_tokens (raise its sliding window)")
+            # Draft KV pages come from the SAME pool as the target's —
+            # one id space, one refcount, honest page accounting — but
+            # live in their own ``dpool`` leaves (draft layer geometry)
+            # behind their own table columns.
+            dprobe = jax.eval_shape(
+                lambda: self._draft_factory(1, 2, self._dtype))
+            self._draft_cache_struct = jax.tree.structure(dprobe)
+            self._draft_cache_axes = self._cache_length_axes(
+                self._draft_factory)
+            dpool_leaves, self._draft_page_bytes = [], 0
+            for sh, ax in zip(jax.tree.leaves(dprobe),
+                              self._draft_cache_axes):
                 shape = list(sh.shape)
                 shape[ax] = self._page
-                # +1: page 0 is the reserved scratch page every clamped or
-                # inactive write routes to.
-                pool_leaves.append(jnp.zeros(
+                dpool_leaves.append(jnp.zeros(
                     (usable + 1,) + tuple(shape),
                     jnp.int8 if quant else sh.dtype))
-                # Quantized pages charge 1 byte/element + 4 bytes for the
-                # per-page scale — _page_bytes feeds every byte-accounting
-                # path (pool metrics, alias-put nbytes, per-chip HBM), so
-                # all of them report quantized bytes automatically.
-                self._page_bytes += (
+                self._draft_page_bytes += (
                     int(np.prod(shape))
                     * (1 if quant else np.dtype(sh.dtype).itemsize)
                     + (4 if quant else 0))
-            self._state = {
-                "pool": jax.tree.unflatten(self._cache_struct, pool_leaves),
-                "pos": jnp.zeros((self.max_slots,), jnp.int32),
-                "tok": jnp.zeros((self.max_slots,), jnp.int32),
-                "rng": jnp.zeros((self.max_slots, 2), jnp.uint32),
-                "done": jnp.zeros((self.max_slots,), bool),
-            }
+            self._state["dpool"] = jax.tree.unflatten(
+                self._draft_cache_struct, dpool_leaves)
             if quant:
-                # Per-page dequant scales, one row per pool leaf, indexed
-                # by page id like the pool itself — device-resident, so a
-                # host page-table alias restore (table write + incref)
-                # reuses the page's scale with zero device work. Ones keep
-                # scratch-page gathers finite before any real write.
-                self._state["pscale"] = jnp.ones(
-                    (len(pool_leaves), usable + 1), jnp.float32)
-            if self._spec_mode == "draft":
-                dshape = jax.eval_shape(lambda: self._draft_factory(
-                    1, self.max_len + self._spec_k, self._dtype))
-                if any(isinstance(layer, dict) and "pos" in layer
-                       for layer in dshape):
-                    raise NotImplementedError(
-                        "the draft model's KV cache must be linear at "
-                        "max_len + spec_tokens (raise its sliding window)")
-                # Draft KV pages come from the SAME pool as the target's —
-                # one id space, one refcount, honest page accounting — but
-                # live in their own ``dpool`` leaves (draft layer geometry)
-                # behind their own table columns.
-                dprobe = jax.eval_shape(
-                    lambda: self._draft_factory(1, 2, self._dtype))
-                self._draft_cache_struct = jax.tree.structure(dprobe)
-                self._draft_cache_axes = self._cache_length_axes(
-                    2, 1, factory=self._draft_factory)
-                dpool_leaves, self._draft_page_bytes = [], 0
-                for sh, ax in zip(jax.tree.leaves(dprobe),
-                                  self._draft_cache_axes):
-                    shape = list(sh.shape)
-                    shape[ax] = self._page
-                    dpool_leaves.append(jnp.zeros(
-                        (usable + 1,) + tuple(shape),
-                        jnp.int8 if quant else sh.dtype))
-                    self._draft_page_bytes += (
-                        int(np.prod(shape))
-                        * (1 if quant else np.dtype(sh.dtype).itemsize)
-                        + (4 if quant else 0))
-                self._state["dpool"] = jax.tree.unflatten(
-                    self._draft_cache_struct, dpool_leaves)
-                if quant:
-                    self._state["dpscale"] = jnp.ones(
-                        (len(dpool_leaves), usable + 1), jnp.float32)
-                self._dtable = np.zeros(
-                    (self.max_slots, self._pages_per_slot), np.int32)
-        else:
-            self._pool = None
-            self._table = None
-            slot_cache = self._factory(1, self.max_len, self._dtype)
-            self._state = {
-                "cache": jax.tree.map(
-                    lambda l: jnp.zeros((self.max_slots,) + l.shape, l.dtype),
-                    slot_cache),
-                "pos": jnp.zeros((self.max_slots,), jnp.int32),
-                "tok": jnp.zeros((self.max_slots,), jnp.int32),
-                "rng": jnp.zeros((self.max_slots, 2), jnp.uint32),
-                "done": jnp.zeros((self.max_slots,), bool),
-            }
+                self._state["dpscale"] = jnp.ones(
+                    (len(dpool_leaves), usable + 1), jnp.float32)
+            self._dtable = np.zeros(
+                (self.max_slots, self._pages_per_slot), np.int32)
         # Adapter bank: the per-slot adapter row index joins the decode
         # state ONLY when a bank is attached — a bank-less engine traces
         # byte-identical programs to the pre-adapter engine.
@@ -958,9 +863,9 @@ class ServingEngine:
 
         # CPU jit warns (and ignores) donation; donate only where it works.
         donate = () if jax.default_backend() == "cpu" else (1,)
-        # A paged engine with its private alias cache restores prefixes by
-        # host page-table writes — there is no compiled restore program at
-        # all (steady state is TWO warm executables, not three).
+        # With its private alias cache the engine restores prefixes by host
+        # page-table writes — there is no compiled restore program at all
+        # (steady state is TWO warm executables, not three).
         self._restore_prefix = None
         self._spec = None
         self._draft_chunk = None
@@ -978,55 +883,43 @@ class ServingEngine:
                 self._draft_params = jax.device_put(self._draft_params, device)
             if adapters is not None:
                 adapters.commit(device)
-            if self._paged:
-                self._decode = jax.jit(self._paged_decode_fn,
-                                       donate_argnums=donate)
-                self._prefill_chunk = jax.jit(self._paged_prefill_chunk_fn,
-                                              donate_argnums=donate)
-                if self._prefix_cache is not None and not self._alias_cache:
-                    # Only a shared EXTERNAL cache needs the copy-restore
-                    # program — the private cache restores by table aliasing
-                    # (pure host work, nothing to compile).
-                    self._restore_prefix = jax.jit(
-                        self._paged_restore_prefix_fn,
-                        donate_argnums=(0,) if donate else ())
-                if self._spec_mode == "draft":
-                    # state is positional arg 2 of the spec program.
-                    self._spec = jax.jit(self._spec_fn,
-                                         donate_argnums=(2,) if donate else ())
-                    if self._prefix_cache is not None:
-                        # Prefix restores rebuild draft KV lazily: a
-                        # draft-only chunk forward over the restored tokens
-                        # (state is its positional arg 1).
-                        self._draft_chunk = jax.jit(
-                            self._draft_chunk_fn,
-                            donate_argnums=(1,) if donate else ())
-                elif self._spec_mode == "lookup":
-                    # state is positional arg 1 (no draft params argument).
-                    self._spec = jax.jit(self._spec_lookup_fn,
-                                         donate_argnums=(1,) if donate else ())
-            else:
-                self._decode = jax.jit(self._decode_fn, donate_argnums=donate)
-                if self._chunk is None:
-                    self._prefill = jax.jit(self._prefill_fn,
-                                            donate_argnums=donate)
-                else:
-                    self._prefill_chunk = jax.jit(self._prefill_chunk_fn,
-                                                  donate_argnums=donate)
-                    # restore donates the STATE only (its arg 0) — the block
-                    # is a live prefix-cache entry that must survive the copy.
-                    self._restore_prefix = jax.jit(
-                        self._restore_prefix_fn,
-                        donate_argnums=(0,) if donate else ())
+            self._decode = jax.jit(self._paged_decode_fn,
+                                   donate_argnums=donate)
+            self._prefill_chunk = jax.jit(self._paged_prefill_chunk_fn,
+                                          donate_argnums=donate)
+            if self._prefix_cache is not None and not self._alias_cache:
+                # Only a shared EXTERNAL cache needs the copy-restore
+                # program — the private cache restores by table aliasing
+                # (pure host work, nothing to compile). It donates the
+                # STATE only (its arg 0): the block is a live prefix-cache
+                # entry that must survive the copy.
+                self._restore_prefix = jax.jit(
+                    self._paged_restore_prefix_fn,
+                    donate_argnums=(0,) if donate else ())
+            if self._spec_mode == "draft":
+                # state is positional arg 2 of the spec program.
+                self._spec = jax.jit(self._spec_fn,
+                                     donate_argnums=(2,) if donate else ())
+                if self._prefix_cache is not None:
+                    # Prefix restores rebuild draft KV lazily: a
+                    # draft-only chunk forward over the restored tokens
+                    # (state is its positional arg 1).
+                    self._draft_chunk = jax.jit(
+                        self._draft_chunk_fn,
+                        donate_argnums=(1,) if donate else ())
+            elif self._spec_mode == "lookup":
+                # state is positional arg 1 (no draft params argument).
+                self._spec = jax.jit(self._spec_lookup_fn,
+                                     donate_argnums=(1,) if donate else ())
         else:
             # Mesh-sliced compilation: derive every placement once, put
             # params/state/bank exactly onto it (jit with explicit
             # in_shardings rejects committed arrays laid out differently),
             # and compile the SAME program functions with those shardings —
             # the engine's call sites don't change at all. The page pool
-            # shards exactly like the dense cache (kv-heads axis split, page
-            # axis replicated-in-index like the slot axis); the page table,
-            # masks, and per-call scalars stay replicated data.
+            # shards on its kv-heads axis (the page axis is replicated in
+            # index); the page table, masks, and per-call scalars stay
+            # replicated data.
             exec_ = self._exec
             if self._weights_dtype is not None:
                 # Quantized leaves shard by their LOGICAL kernel shape: q
@@ -1038,33 +931,16 @@ class ServingEngine:
             else:
                 self._param_sh = exec_.param_shardings(params)
             self.params = params = exec_.place(params, self._param_sh)
-            if self._paged:
-                tmpl = [jax.ShapeDtypeStruct(l.shape[1:], l.dtype)
-                        for l in jax.tree.leaves(self._state["pool"])]
-                struct = self._cache_struct
-            else:
-                tmpl = jax.tree.leaves(slot_cache)
-                struct = jax.tree.structure(slot_cache)
+            tmpl = [jax.ShapeDtypeStruct(l.shape[1:], l.dtype)
+                    for l in jax.tree.leaves(self._state["pool"])]
             self._state_sh = exec_.state_shardings(self._state, tmpl,
                                                    self._cache_axes)
-            self._block_sh = exec_.block_shardings(struct, tmpl,
+            self._block_sh = exec_.block_shardings(self._cache_struct, tmpl,
                                                    self._cache_axes)
             self._state = exec_.place(self._state, self._state_sh)
             rep = exec_.replicated
-            if self._paged:
-                decode_in = [self._param_sh, self._state_sh, rep, rep]
-                chunk_in = [self._param_sh, self._state_sh] + [rep] * 6
-                restore_in = (self._state_sh, self._block_sh, rep, rep, rep)
-                decode_fn = self._paged_decode_fn
-                chunk_fn = self._paged_prefill_chunk_fn
-                restore_fn = self._paged_restore_prefix_fn
-            else:
-                decode_in = [self._param_sh, self._state_sh, rep]
-                chunk_in = [self._param_sh, self._state_sh] + [rep] * 5
-                restore_in = (self._state_sh, self._block_sh, rep, rep, rep)
-                decode_fn = self._decode_fn
-                chunk_fn = self._prefill_chunk_fn
-                restore_fn = self._restore_prefix_fn
+            decode_in = [self._param_sh, self._state_sh, rep, rep]
+            chunk_in = [self._param_sh, self._state_sh] + [rep] * 6
             if adapters is not None:
                 self._bank_sh = exec_.bank_shardings(adapters)
                 adapters.place(self._bank_sh)
@@ -1077,14 +953,15 @@ class ServingEngine:
                 self._draft_params = jax.device_put(self._draft_params, rep)
                 chunk_in += [rep, rep]      # dparams subtree, dpages row
             self._decode = exec_.jit(
-                decode_fn, tuple(decode_in),
+                self._paged_decode_fn, tuple(decode_in),
                 (self._state_sh, rep, rep), donate_argnums=donate)
             self._prefill_chunk = exec_.jit(
-                chunk_fn, tuple(chunk_in),
+                self._paged_prefill_chunk_fn, tuple(chunk_in),
                 (self._state_sh, rep, self._block_sh), donate_argnums=donate)
-            if not (self._paged and self._alias_cache):
+            if not self._alias_cache:
                 self._restore_prefix = exec_.jit(
-                    restore_fn, restore_in,
+                    self._paged_restore_prefix_fn,
+                    (self._state_sh, self._block_sh, rep, rep, rep),
                     self._state_sh, donate_argnums=(0,) if donate else ())
             if self._spec_mode == "draft":
                 spec_in = [self._param_sh, rep, self._state_sh,
@@ -1159,14 +1036,11 @@ class ServingEngine:
         self._decode_ticks = 0
         self._heartbeat = (0, time.monotonic())
         self._heartbeat_frozen = False
-        # Async host runtime: one-tick-ahead dispatch + off-thread token
-        # emission (see class docstring). ``_wedge_s`` is the chaos
-        # harness's dispatched-call wedge: the next reconcile sleeps it
-        # off INSIDE the barrier, so the stall is indistinguishable from
-        # a compiled call that never returns.
-        if async_ticks is None:
-            async_ticks = True
-        self._async = bool(async_ticks)
+        # One-tick-ahead dispatch + off-thread token emission (see the
+        # module docstring). ``_wedge_s`` is the chaos harness's
+        # dispatched-call wedge: the next reconcile sleeps it off INSIDE
+        # the barrier, so the stall is indistinguishable from a compiled
+        # call that never returns.
         if int(emission_queue) < 1:
             raise ValueError(
                 f"emission_queue must be >= 1 (got {emission_queue})")
@@ -1246,40 +1120,32 @@ class ServingEngine:
                 "gather params to host before serving.")
         return None
 
-    def _cache_length_axes(self, la: Optional[int] = None,
-                           lb: Optional[int] = None,
-                           factory=None) -> list[int]:
-        """Per-leaf sequence-length axis of the slot cache, detected by
-        comparing ``eval_shape`` of the factory at two lengths (layouts are
+    def _cache_length_axes(self, factory) -> list[int]:
+        """Per-leaf sequence-length axis of ``factory``'s cache, detected by
+        comparing its ``eval_shape`` at two lengths (layouts are
         family-specific; llama is ``[1, L, n_kv, head]`` but nothing
-        guarantees that elsewhere). Default probes are ``max_len`` vs
-        ``max_len - 1``, never ``+ 1`` — growing past ``max_len`` could
-        flip a sliding-window layer into its ring layout and change the
-        tree structure itself; the PAGED engine probes at (2, 1) instead,
-        where a windowed layer is still linear, because its page template
-        must be the linear layout regardless of the window. Flattened-leaf
-        order, the same order every tree op in the programs uses."""
-        la = self.max_len if la is None else la
-        lb = self.max_len - 1 if lb is None else lb
-        factory = self._factory if factory is None else factory
+        guarantees that elsewhere). The probes are lengths 2 and 1, where
+        a windowed layer is still linear (a window >= 2 never rings at
+        length 2): the page template must be the linear layout whatever
+        the window. Flattened-leaf order, the same order every tree op in
+        the programs uses."""
         a = jax.tree.leaves(jax.eval_shape(
-            lambda: factory(1, la, self._dtype)))
+            lambda: factory(1, 2, self._dtype)))
         b = jax.tree.leaves(jax.eval_shape(
-            lambda: factory(1, lb, self._dtype)))
+            lambda: factory(1, 1, self._dtype)))
         if len(a) != len(b):
             raise NotImplementedError(
-                "the KV cache changes structure between probe lengths "
-                f"({la} vs {lb}); this layout cannot be paged/chunked")
+                "the KV cache changes structure between lengths 2 and 1; "
+                "this layout cannot be paged")
         axes = []
         for x, y in zip(a, b):
             diff = [i for i, (m, n) in enumerate(zip(x.shape, y.shape))
                     if m != n]
             if len(diff) != 1:
                 raise NotImplementedError(
-                    "chunked prefill needs every KV leaf to carry exactly "
+                    "the page pool needs every KV leaf to carry exactly "
                     f"one length axis (leaf {x.shape} vs {y.shape} at "
-                    f"probe lengths {la}/{lb}); pass prefill_chunk=None "
-                    "for the monolithic path")
+                    "lengths 2 / 1)")
             axes.append(diff[0])
         return axes
 
@@ -1297,154 +1163,6 @@ class ServingEngine:
             return {}
         return {"lora": jax.tree.map(lambda s: s[aidx], bank)}
 
-    def _prefill_fn(self, params, state, ids_p, slot, rng, true_len,
-                    aidx=None, bank=None):
-        """Monolithic prefill (``prefill_chunk=None`` only). ids_p [1, P]
-        edge-padded prompt; slot/true_len traced i32 scalars. Builds a
-        fresh batch-1 cache, runs the whole prompt, selects the first
-        token exactly like offline generate (the shared
-        :func:`generation._chunk_prefill_token` epilogue at offset 0), and
-        writes the slot's whole decode state at the traced slot index.
-        Returns (state, first_token). One executable per 128-bucketed
-        prompt length — the compile-family the chunked path replaces.
-        """
-        params = self._dq(params)
-        cache = self._factory(1, self.max_len, self._dtype)
-        logits, cache = self.module.apply(
-            {"params": params}, ids_p, cache=cache, cache_pos=0,
-            **self._lora_kwargs(bank, aidx))
-        tok, done, rng_carry = _chunk_prefill_token(
-            logits, rng, self._select, self.eos_token_id, ids_p.dtype,
-            true_len)
-        new_cache = jax.tree.map(
-            lambda full, one: jax.lax.dynamic_update_slice(
-                full, one[None].astype(full.dtype), (slot,) + (0,) * one.ndim),
-            state["cache"], cache)
-        new_state = dict(
-            state,
-            cache=new_cache,
-            pos=state["pos"].at[slot].set(true_len),
-            tok=state["tok"].at[slot].set(tok[0].astype(jnp.int32)),
-            rng=state["rng"].at[slot].set(rng_carry),
-            done=state["done"].at[slot].set(done[0]),
-        )
-        if bank is not None:
-            new_state["adapter_idx"] = state["adapter_idx"].at[slot].set(aidx)
-        return new_state, tok[0]
-
-    def _prefill_chunk_fn(self, params, state, ids_c, slot, offset, true_len,
-                          rng, aidx=None, bank=None):
-        """ONE chunk of prefill: ids_c ``[1, C]`` (tail chunks edge-padded
-        on the host); slot/offset/true_len traced i32 scalars. Runs the
-        chunk at ``cache_pos=offset`` directly against the slot's region
-        of the shared cache (in-place: garbage left by a previous occupant
-        is masked-out by construction, see the module docstring), selects
-        a candidate first token via the shared epilogue (real only in the
-        chunk containing ``true_len - 1``), and writes the slot rows —
-        ``pos[slot] = true_len`` on EVERY call, the invariant that keeps
-        interleaved decode ticks from corrupting a mid-prefill slot.
-
-        Also returns the chunk's own KV block (each leaf sliced to width C
-        on its length axis) so the prefix cache is fed by THIS executable
-        — no separate extract program, keeping the steady state at exactly
-        one chunk-prefill executable. Returns (state, first_token, block).
-        """
-        params = self._dq(params)
-        C = ids_c.shape[1]
-        cache = jax.tree.map(
-            lambda full: jax.lax.dynamic_slice(
-                full, (slot,) + (0,) * (full.ndim - 1),
-                (1,) + full.shape[1:])[0],
-            state["cache"])
-        logits, cache = self.module.apply(
-            {"params": params}, ids_c, cache=cache, cache_pos=offset,
-            **self._lora_kwargs(bank, aidx))
-        tok, done, rng_carry = _chunk_prefill_token(
-            logits, rng, self._select, self.eos_token_id, ids_c.dtype,
-            true_len, offset)
-        leaves = jax.tree.leaves(cache)
-        block = jax.tree.unflatten(
-            jax.tree.structure(cache),
-            [jax.lax.dynamic_slice_in_dim(l, offset, C, axis=ax)
-             for l, ax in zip(leaves, self._cache_axes)])
-        new_cache = jax.tree.map(
-            lambda full, one: jax.lax.dynamic_update_slice(
-                full, one[None].astype(full.dtype), (slot,) + (0,) * one.ndim),
-            state["cache"], cache)
-        new_state = dict(
-            state,
-            cache=new_cache,
-            pos=state["pos"].at[slot].set(true_len),
-            tok=state["tok"].at[slot].set(tok[0].astype(jnp.int32)),
-            rng=state["rng"].at[slot].set(rng_carry),
-            done=state["done"].at[slot].set(done[0]),
-        )
-        if bank is not None:
-            new_state["adapter_idx"] = state["adapter_idx"].at[slot].set(aidx)
-        return new_state, tok[0], block
-
-    def _restore_prefix_fn(self, state, block, slot, offset, true_len):
-        """Copy one cached ``[1, C]`` KV block into the slot's cache at the
-        traced chunk offset and stamp ``pos[slot] = true_len`` (the same
-        decode-tick-safety invariant as the chunk program). The block is
-        NOT donated — it stays live in the prefix cache."""
-        full_leaves = jax.tree.leaves(state["cache"])
-        blk_leaves = jax.tree.leaves(block)
-        out = []
-        for full, blk, ax in zip(full_leaves, blk_leaves, self._cache_axes):
-            start = [0] * full.ndim
-            start[0] = slot
-            start[ax + 1] = offset
-            out.append(jax.lax.dynamic_update_slice(
-                full, blk[None].astype(full.dtype), tuple(start)))
-        return dict(
-            state,
-            cache=jax.tree.unflatten(jax.tree.structure(state["cache"]), out),
-            pos=state["pos"].at[slot].set(true_len),
-        )
-
-    def _decode_fn(self, params, state, active, bank=None):
-        """One tick: a batch-1 single-token forward vmapped over the slot
-        axis (per-slot scalar cache_pos, per-slot rng chain — bitwise the
-        same selection as offline's scan body). The cache commits
-        unconditionally — an inactive or PREFILLING slot rewrites its
-        ``pos`` with garbage — which is safe because prefill/restore pin
-        every mid-prefill slot's pos to ``true_len``, a position no prompt
-        chunk reads and the first real decode write overwrites (a retired
-        slot's next use starts with a fresh prefill of its region). But
-        pos/tok/rng/done advance only where ``active`` is set, so
-        non-running slots stay frozen and in-bounds. Returns
-        (state, tokens [S], done [S])."""
-        params = self._dq(params)
-
-        def one_slot(cache, tok, pos, rng, done, aidx=None):
-            logits, cache = self.module.apply(
-                {"params": params}, tok[None, None], cache=cache, cache_pos=pos,
-                **self._lora_kwargs(bank, aidx))
-            rng, sub = jax.random.split(rng)
-            nxt, done = _next_token(logits[:, -1], sub, jnp.zeros((1, 1), bool),
-                                    done[None], self._select, self.eos_token_id,
-                                    tok.dtype)
-            return cache, nxt[0], rng, done[0]
-
-        # The bank is closed over (broadcast): each slot gathers its own
-        # adapter row at its vmapped adapter_idx.
-        vmap_args = [state["cache"], state["tok"], state["pos"], state["rng"],
-                     state["done"]]
-        if bank is not None:
-            vmap_args.append(state["adapter_idx"])
-        new_cache, toks, rngs, dones = jax.vmap(one_slot)(*vmap_args)
-        state = dict(
-            state,
-            cache=new_cache,
-            pos=jnp.where(active, state["pos"] + 1, state["pos"]),
-            tok=jnp.where(active, toks, state["tok"]),
-            rng=jnp.where(active[:, None], rngs, state["rng"]),
-            done=jnp.where(active, dones, state["done"]),
-        )
-        return state, toks, dones
-
-    # -- paged programs -------------------------------------------------
     def _dq(self, params):
         """Dequantize int8 base weights at the top of a compiled program.
 
@@ -1553,11 +1271,18 @@ class ServingEngine:
 
     def _paged_prefill_chunk_fn(self, params, state, ids_c, slot, pages,
                                 offset, true_len, rng, *extra):
-        """Paged twin of :meth:`_prefill_chunk_fn`: gather the slot's pages
-        into a dense view, run the chunk at ``cache_pos=offset`` exactly as
-        the dense program does, then scatter back only the pages the chunk
-        wrote. The returned block is sliced from the view — same bytes as
-        the dense block, so external prefix caches stay layout-compatible.
+        """ONE chunk of prefill: ids_c ``[1, C]`` (tail chunks edge-padded
+        on the host); slot/offset/true_len traced i32 scalars, ``pages``
+        the slot's table row. Gathers the slot's pages into a dense view,
+        runs the chunk at ``cache_pos=offset`` (garbage left in a page by a
+        previous occupant is masked out by construction, see the module
+        docstring), selects a candidate first token via the shared
+        epilogue (real only in the chunk containing ``true_len - 1``),
+        scatters back only the pages the chunk wrote, and writes the slot
+        rows — ``pos[slot] = true_len`` on EVERY call. Returns ``(state,
+        first_token, block)``: the block is the chunk's own KV (each leaf
+        sliced to width C on its length axis), so an external prefix cache
+        is fed by THIS executable, no separate extract program.
 
         ``extra`` is positional (mesh in_shardings forbid kwargs) and holds
         whatever this engine's config adds, in order: ``aidx, bank`` when
@@ -1655,9 +1380,8 @@ class ServingEngine:
         return out
 
     def _paged_restore_prefix_fn(self, state, block, pages_c, slot, true_len):
-        """Copy-restore for paged engines with an EXTERNAL (fleet-shared)
-        prefix cache: split one cached ``[1, C]`` block into ``C/P`` pages
-        and write each into the pool page named by ``pages_c`` (traced
+        """Copy-restore from an EXTERNAL (fleet-shared) prefix cache: split
+        one cached ``[1, C]`` block into ``C/P`` pages and write each into the pool page named by ``pages_c`` (traced
         [C/P] i32 — the slot's freshly-allocated table entries). Pins
         ``pos[slot] = true_len`` like every restore. The engine's PRIVATE
         cache never calls this — it restores by host table aliasing."""
@@ -1753,15 +1477,16 @@ class ServingEngine:
         return pool_leaves, scales
 
     def _paged_decode_fn(self, params, state, active, table, bank=None):
-        """Paged twin of :meth:`_decode_fn`: gather every slot's view, run
-        the identical vmapped batch-1 forward (same logits, same
-        :func:`generation._next_token` — paged streams are bit-identical
-        to dense), then scatter back ONE page per slot: the page holding
-        ``pos[slot]``, the only position a tick writes. Inactive slots
-        scatter to scratch, so their stale ``pos`` can't corrupt the pool
-        — the paged analogue of the dense path's unconditional-commit
-        safety. The host guarantees an active slot's ``pos`` page is
-        allocated before every tick."""
+        """One tick: gather every slot's view, run a batch-1 single-token
+        forward vmapped over the slot axis (per-slot scalar cache_pos,
+        per-slot rng chain, :func:`generation._next_token` — bitwise the
+        same selection as offline's scan body), then scatter back ONE
+        page per slot: the page holding ``pos[slot]``, the only position
+        a tick writes. Inactive (and ``PREFILLING``) slots scatter to
+        scratch and their pos/tok/rng/done stay frozen, so a stale ``pos``
+        can't corrupt the pool. The host guarantees an active slot's
+        ``pos`` page is allocated before every tick. Returns ``(state,
+        tokens [S], done [S])``."""
         P = self._page
         params = self._dq(params)
         scales = state.get("pscale")
@@ -1831,9 +1556,9 @@ class ServingEngine:
         samples) and derive the slot's committed count, carry token, and
         eos latch. Greedy engines pass the rng through UNTOUCHED (greedy
         selection never consumes it — spec streams stay bit-comparable to
-        dense greedy ones); sampled engines split it once per tick, so a
+        plain greedy ones); sampled engines split it once per tick, so a
         slot's rng trajectory is one split per verify, mirroring one split
-        per dense tick."""
+        per plain tick."""
         K = drafts.shape[0]
         if self._sampling is not None:
             rng, step_rng = jax.random.split(rng)
@@ -1858,11 +1583,11 @@ class ServingEngine:
         of the warped draft logits — a delta proposal, so the sampled
         accept rule stays exact), verify draft + carry token in ONE fixed
         ``[1, K+1]`` target forward against the paged target view (the
-        slot's adapter row gathered inside, like the dense tick), and
+        slot's adapter row gathered inside, like the plain tick), and
         accept via :meth:`_spec_accept`. Committing the emitted chain's
         first ``n = min(accepted + 1, remaining)`` tokens is
         token-identical (greedy) / distribution-exact (sampled) to ``n``
-        dense ticks.
+        plain ticks.
 
         Rejected-draft KV (positions past ``pos + n - 1``) is garbage in
         BOTH pools, but the next verify rewrites target positions
@@ -2004,7 +1729,7 @@ class ServingEngine:
         self._accepting = True
         self._heartbeat = (self._loop_iters, time.monotonic())
         self._heartbeat_frozen = False
-        if self._async and (self._emitter is None or not self._emitter.alive):
+        if self._emitter is None or not self._emitter.alive:
             self._emitter = _TokenEmitter(self._emission_queue,
                                           self._stats, self._tracer)
         self._thread = threading.Thread(target=self._run,
@@ -2018,8 +1743,8 @@ class ServingEngine:
         through the normal path: one chunk call + one decode tick, and —
         when a multi-chunk prompt fits the engine at all — two identical
         two-chunk prompts so the second one's prefix hit compiles
-        ``restore_prefix`` too. ``ignore_eos`` keeps the dummies decoding
-        even if the model emits eos immediately. Counters reset and the
+        ``restore_prefix`` (and the draft-only chunk) where they exist.
+        ``ignore_eos`` keeps the dummies decoding even if the model emits eos immediately. Counters reset and the
         prefix cache is cleared afterwards so warmup traffic never
         pollutes serving metrics (or lingers as phantom cached prefixes)."""
         req = self.submit(np.zeros((1, 1), np.int32), max_new_tokens=2,
@@ -2028,7 +1753,7 @@ class ServingEngine:
             raise TimeoutError("engine warmup did not finish "
                                f"within {timeout}s")
         self._raise_if_failed(req)
-        if (self._chunk is not None and self._prefix_cache is not None
+        if (self._prefix_cache is not None
                 and self._chunk + 2 <= self._chunk_limit):
             ids = np.zeros((1, self._chunk + 1), np.int32)
             for _ in range(2):
@@ -2128,25 +1853,19 @@ class ServingEngine:
         return len(self._queue)
 
     @property
-    def paged(self) -> bool:
-        """Whether this engine uses the paged KV pool."""
-        return self._paged
-
-    @property
-    def page_size(self) -> Optional[int]:
-        """Tokens per KV page (None for dense engines)."""
+    def page_size(self) -> int:
+        """Tokens per KV page."""
         return self._page
 
     @property
     def total_pages(self) -> int:
-        """Usable pool pages (0 for dense engines)."""
-        return self._pool.num_pages if self._paged else 0
+        """Usable pool pages."""
+        return self._pool.num_pages
 
     @property
     def free_pages(self) -> int:
-        """Unallocated pool pages right now (0 for dense engines — their
-        capacity is slots, which ``free_slots`` already reports)."""
-        return self._pool.free_pages if self._paged else 0
+        """Unallocated pool pages right now."""
+        return self._pool.free_pages
 
     @property
     def _spec_page_factor(self) -> int:
@@ -2160,14 +1879,13 @@ class ServingEngine:
     def page_deficit(self, total_tokens: int) -> int:
         """How many pages this engine is SHORT for a request of
         ``total_tokens`` (prompt + max_new): 0 means the pool can hold it
-        right now, >0 means admitting it would lean on preemption. Dense
-        engines reserve a full max_len row per slot, so they are never
-        page-starved (0). The router folds this into its least-loaded
+        right now, >0 means admitting it would lean on preemption. The
+        router folds this into its least-loaded
         score so long prompts route to replicas with free pages — and a
         draft-speculating replica reports its doubled footprint
         (:attr:`_spec_page_factor`), so the router never over-admits it
         relative to its real pool pressure."""
-        if not self._paged or total_tokens <= 0:
+        if total_tokens <= 0:
             return 0
         needed = (-(-int(total_tokens) // self._page)
                   * self._spec_page_factor)
@@ -2196,12 +1914,10 @@ class ServingEngine:
 
     def page_drain_rate(self, window_s: float = 15.0) -> float:
         """Observed pool page-free rate (pages/second) over the last
-        ``window_s`` of decode ticks, 0.0 when dense or not yet observed.
+        ``window_s`` of decode ticks, 0.0 when not yet observed.
         The gateway divides a projected page deficit by this to derive
         Retry-After for a pressure shed — "the pool frees ~N pages/s, so
         your M-page deficit clears in about M/N seconds"."""
-        if not self._paged:
-            return 0.0
         samples = list(self._drain_samples)
         if len(samples) < 2:
             return 0.0
@@ -2217,12 +1933,11 @@ class ServingEngine:
     def projected_page_deficit(self, total_tokens: int) -> int:
         """Pages the pool is short if this request is admitted BEHIND the
         work already queued: ``ceil(total_tokens / page) + ceil(queued
-        footprint / page) - free_pages``, floored at 0 (dense engines are
-        never short). Unlike :meth:`page_deficit` this counts the
-        admission queue's projected demand too — the signal behind the
+        footprint / page) - free_pages``, floored at 0. Unlike
+        :meth:`page_deficit` this counts the admission queue's projected demand too — the signal behind the
         gateway's projected-pressure 429 (ROADMAP's "429 on projected
         pool pressure rather than queue depth")."""
-        if not self._paged or total_tokens <= 0:
+        if total_tokens <= 0:
             return 0
         factor = self._spec_page_factor
         needed = -(-int(total_tokens) // self._page) * factor
@@ -2233,15 +1948,13 @@ class ServingEngine:
     def load(self) -> float:
         """Occupancy fraction over the engine's whole admission capacity:
         ``(active slots + queued) / (max_slots + max_queued)`` — the
-        router's least-loaded score; 1.0 means a submit would bounce. A
-        paged engine also folds in POOL pressure (used/total pages), so
-        the router steers traffic away from a replica whose memory, not
-        slots, is the bottleneck."""
+        router's least-loaded score; 1.0 means a submit would bounce. POOL
+        pressure (used/total pages) is folded in, so the router steers
+        traffic away from a replica whose memory, not slots, is the
+        bottleneck."""
         base = ((self._slots.active_slots + len(self._queue))
                 / (self.max_slots + self._queue.max_queued))
-        if self._paged:
-            return max(base, self._pool.used_pages / self._pool.num_pages)
-        return base
+        return max(base, self._pool.used_pages / self._pool.num_pages)
 
     def kill(self, error: Optional[BaseException] = None):
         """Fault injection / fencing: make the run loop raise ``error`` at
@@ -2305,22 +2018,21 @@ class ServingEngine:
                 f"prompt ({S}) + max_new_tokens ({request.max_new_tokens}) "
                 f"exceeds the engine's max_len ({self.max_len}); resize the "
                 "engine or shorten the request")
-        if self._paged:
-            # A lone request must always be satisfiable: with everyone else
-            # preempted and the alias cache drained, its worst-case footprint
-            # has to fit the pool, or admission could wedge forever.
-            need = (-(-(S + request.max_new_tokens) // self._page)
-                    * self._spec_page_factor)
-            if need > self._pool.num_pages:
-                raise ValueError(
-                    f"request needs up to {need} KV pages (prompt {S} + "
-                    f"max_new_tokens {request.max_new_tokens} at page_size "
-                    f"{self._page}"
-                    + (", doubled for draft KV pages"
-                       if self._spec_page_factor > 1 else "")
-                    + f") but the pool only has "
-                    f"{self._pool.num_pages}; raise max_pages or shorten "
-                    "the request")
+        # A lone request must always be satisfiable: with everyone else
+        # preempted and the alias cache drained, its worst-case footprint
+        # has to fit the pool, or admission could wedge forever.
+        need = (-(-(S + request.max_new_tokens) // self._page)
+                * self._spec_page_factor)
+        if need > self._pool.num_pages:
+            raise ValueError(
+                f"request needs up to {need} KV pages (prompt {S} + "
+                f"max_new_tokens {request.max_new_tokens} at page_size "
+                f"{self._page}"
+                + (", doubled for draft KV pages"
+                   if self._spec_page_factor > 1 else "")
+                + f") but the pool only has "
+                f"{self._pool.num_pages}; raise max_pages or shorten "
+                "the request")
         if self._spec_k is not None:
             # A verify near the end of the stream writes positions up to
             # (S + max_new - 1) + K; the draft scan stops one short.
@@ -2456,13 +2168,12 @@ class ServingEngine:
     def kv_cache_per_chip_bytes(self) -> int:
         """Per-device byte footprint of the decode KV state (max shard per
         leaf): the HBM-planning number, ≈ ``1/tp`` of the single-chip
-        figure for heads-sharded leaves (docs/performance.md). For a
-        paged engine this is the page POOL — the number ``max_pages``
-        controls directly, independent of ``max_slots`` — plus the
-        per-page scale arrays on a quantized engine (they're replicated,
-        so they count at full size per chip)."""
-        tree = (self._state["pool"] if self._paged
-                else self._state["cache"])
+        figure for heads-sharded leaves (docs/performance.md). This is
+        the page POOL — the number ``max_pages`` controls directly,
+        independent of ``max_slots`` — plus the per-page scale arrays on a
+        quantized engine (they're replicated, so they count at full size
+        per chip)."""
+        tree = self._state["pool"]
         extra = sum(self._state[k].nbytes for k in ("pscale", "dpscale")
                     if k in self._state)
         if self._exec is not None:
@@ -2470,12 +2181,10 @@ class ServingEngine:
         return sum(l.nbytes for l in jax.tree.leaves(tree)) + extra
 
     def page_pool_metrics(self) -> dict:
-        """Host-side pool snapshot (empty for dense engines): page size,
-        totals, occupancy, allocation and preemption counters. On a
-        quantized engine ``page_bytes`` is already the int8 figure
-        (1 byte/element + 4-byte scale per leaf)."""
-        if not self._paged:
-            return {}
+        """Host-side pool snapshot: page size, totals, occupancy,
+        allocation and preemption counters. On a quantized engine
+        ``page_bytes`` is already the int8 figure (1 byte/element +
+        4-byte scale per leaf)."""
         out = {
             "page_size": self._page,
             "kv_dtype": self._kv_dtype,
@@ -2499,22 +2208,17 @@ class ServingEngine:
         itself would add a cache entry and break the warm-executable
         accounting the zero-recompile tests pin."""
         args = [self.params, self._state,
-                np.zeros((self.max_slots,), bool)]
-        if self._paged:
-            args.append(self._table.copy())
+                np.zeros((self.max_slots,), bool), self._table.copy()]
         if self._adapters is not None:
             args.append(self._adapters.stacks)
-        decode_fn = self._paged_decode_fn if self._paged else self._decode_fn
         if self._exec is None:
-            fn = jax.jit(decode_fn)
+            fn = jax.jit(self._paged_decode_fn)
         else:
             rep = self._exec.replicated
-            ins = [self._param_sh, self._state_sh, rep]
-            if self._paged:
-                ins.append(rep)
+            ins = [self._param_sh, self._state_sh, rep, rep]
             if self._adapters is not None:
                 ins.append(self._bank_sh)
-            fn = self._exec.jit(decode_fn, tuple(ins),
+            fn = self._exec.jit(self._paged_decode_fn, tuple(ins),
                                 (self._state_sh, rep, rep))
         return fn.lower(*args).compile().memory_analysis()
 
@@ -2522,10 +2226,10 @@ class ServingEngine:
     # engine thread
     # ------------------------------------------------------------------
     def _run(self):
-        # The one in-flight dispatched tick (async mode; always None in
-        # sync mode). Loop shape per iteration: sweeps → admission →
-        # DISPATCH tick N+1 → RECONCILE tick N — so every piece of host
-        # work between the two barriers overlaps tick N+1's device time.
+        # The one in-flight dispatched tick. Loop shape per iteration:
+        # sweeps → admission → DISPATCH tick N+1 → RECONCILE tick N — so
+        # every piece of host work between the two barriers overlaps tick
+        # N+1's device time.
         flight: Optional[_TickFlight] = None
         phases = self._phases
         try:
@@ -2575,85 +2279,63 @@ class ServingEngine:
                 # backlog (round-robin) with one new admission — so with a
                 # budget of 2+, a fresh arrival's first chunk rides
                 # alongside an in-flight long prefill instead of queueing
-                # behind all of it. Monolithic mode (prefill_chunk=None)
-                # has no budget — admission runs the whole prompt inline,
-                # the behavior this PR A/Bs against.
-                if self._chunk is None:
-                    while self._slots.has_free():
+                # behind all of it.
+                budget = self._chunks_per_tick
+                while budget > 0:
+                    progressed = False
+                    if self._advance_one_prefill():
+                        budget -= 1
+                        progressed = True
+                    if budget > 0 and self._slots.has_free():
                         with phases.admit:
                             req = self._queue.get_nowait()
-                            ok = req is not None and self._screen(req, now)
-                        if req is None:
-                            break
-                        if ok:
-                            self._admit(req)
-                else:
-                    budget = self._chunks_per_tick
-                    while budget > 0:
-                        progressed = False
-                        if self._advance_one_prefill():
-                            budget -= 1
+                            placed = (req is not None
+                                      and self._screen(req, now)
+                                      and self._begin_prefill(req))
+                        if req is not None:
                             progressed = True
-                        if budget > 0 and self._slots.has_free():
-                            with phases.admit:
-                                req = self._queue.get_nowait()
-                                placed = (req is not None
-                                          and self._screen(req, now)
-                                          and self._begin_prefill(req))
-                            if req is not None:
-                                progressed = True
-                                if placed is None:
-                                    # Paged admission gate: the request
-                                    # went back to the queue front; stop
-                                    # admitting until decode frees pages.
-                                    break
-                                if placed:
-                                    self._run_chunk(req)
-                                    budget -= 1
-                        if not progressed:
-                            break
+                            if placed is None:
+                                # Admission gate: the request went back
+                                # to the queue front; stop admitting
+                                # until decode frees pages.
+                                break
+                            if placed:
+                                self._run_chunk(req)
+                                budget -= 1
+                    if not progressed:
+                        break
                 running = [(slot, req) for slot, req in self._slots.active()
                            if req.status is RequestStatus.RUNNING]
                 if running:
-                    if self._async:
-                        if self._spec_mode == "lookup" and flight is not None:
-                            # Prompt-lookup proposals must anchor on the
-                            # NEWEST committed token: a proposal drafted
-                            # ahead is misaligned by the in-flight tick's
-                            # variable-length commit (1..K+1 tokens) and
-                            # verifies to zero accepts, collapsing lookup
-                            # speculation to dense decode. So lookup
-                            # engines settle tick N before drafting N+1 —
-                            # off-thread emission and the commit barrier
-                            # are unchanged; only dispatch/device overlap
-                            # is given up.
-                            self._reconcile(flight)
-                            flight = None
-                            continue
-                        # One tick ahead: dispatch N+1 against the
-                        # in-flight state futures (host view stale by
-                        # exactly the one unreconciled tick when
-                        # ``flight`` exists), THEN settle tick N.
-                        nxt = self._dispatch(running,
-                                             ahead=flight is not None)
-                        if flight is not None:
-                            self._reconcile(flight)
-                        flight = nxt
-                        if flight is None:
-                            # Nothing dispatched (every stream flow-
-                            # controlled or preempted) and nothing in
-                            # flight: yield so consumers can drain
-                            # instead of hot-spinning the loop.
-                            self._go_idle()
-                            with phases.idle:
-                                time.sleep(min(self._idle_poll_s, 0.001))
-                    else:
-                        # Sync A/B fallback: dispatch and immediately
-                        # reconcile — the strictly tick-synchronous
-                        # pre-async behavior, same commit path.
-                        f = self._dispatch(running, ahead=False)
-                        if f is not None:
-                            self._reconcile(f)
+                    if self._spec_mode == "lookup" and flight is not None:
+                        # Prompt-lookup proposals must anchor on the
+                        # NEWEST committed token: a proposal drafted ahead
+                        # is misaligned by the in-flight tick's
+                        # variable-length commit (1..K+1 tokens) and
+                        # verifies to zero accepts, collapsing lookup
+                        # speculation to plain decode. So lookup engines
+                        # settle tick N before drafting N+1 — off-thread
+                        # emission and the commit barrier are unchanged;
+                        # only dispatch/device overlap is given up.
+                        self._reconcile(flight)
+                        flight = None
+                        continue
+                    # One tick ahead: dispatch N+1 against the in-flight
+                    # state futures (host view stale by exactly the one
+                    # unreconciled tick when ``flight`` exists), THEN
+                    # settle tick N.
+                    nxt = self._dispatch(running, flight)
+                    if flight is not None:
+                        self._reconcile(flight)
+                    flight = nxt
+                    if flight is None:
+                        # Nothing dispatched (every stream flow-controlled
+                        # or preempted) and nothing in flight: yield so
+                        # consumers can drain instead of hot-spinning the
+                        # loop.
+                        self._go_idle()
+                        with phases.idle:
+                            time.sleep(min(self._idle_poll_s, 0.001))
                     continue
                 if flight is not None:
                     # The last running streams retired/preempted out from
@@ -2683,13 +2365,9 @@ class ServingEngine:
                         continue
                     with phases.admit:
                         placed = (self._screen(req, time.monotonic())
-                                  and (self._chunk is None
-                                       or self._begin_prefill(req)))
+                                  and self._begin_prefill(req))
                     if placed:
-                        if self._chunk is None:
-                            self._admit(req)
-                        else:
-                            self._run_chunk(req)
+                        self._run_chunk(req)
         except BaseException as e:  # engine-fatal: fail everything loudly
             self._error = e
             # Black-box capture at the moment of death: the fatal event
@@ -2715,12 +2393,11 @@ class ServingEngine:
             for req in self._queue.drain():
                 self._finish_req(req, terminal, self._error)
                 self._stats.record_finish(req.status)
-            if self._emitter is not None:
-                # AFTER the retire sweep queued its deferred completions:
-                # drain every buffered token and completion, then join —
-                # failover handlers (``_on_finish``) all fire before the
-                # engine thread exits.
-                self._emitter.close()
+            # AFTER the retire sweep queued its deferred completions: drain
+            # every buffered token and completion, then join — failover
+            # handlers (``_on_finish``) all fire before the engine thread
+            # exits.
+            self._emitter.close()
 
     def _go_idle(self):
         """Before the loop waits with nothing launched: an open
@@ -2926,35 +2603,7 @@ class ServingEngine:
                 row[j] = 0
             req._page_floor = j + 1
 
-    def _admit(self, req: Request):
-        """Monolithic admission (``prefill_chunk=None``): host edge-pad to
-        the 128 bucket (numpy — a jnp pad would compile per prompt
-        length), run the whole prompt inline, and commit the first token.
-        TTFT is stamped here because prefill itself emits token #1."""
-        if not self._acquire_adapter(req):
-            return
-        req.admitted_at = time.monotonic()
-        slot = self._slots.assign(req)
-        self._flight.record("admission", trace_id=req.trace_id, slot=slot,
-                            prompt_len=req.prompt_ids.shape[1],
-                            adapter=req.adapter)
-        req._serve_ids = req.prompt_ids
-        S = req.prompt_ids.shape[1]
-        P = self._bucket(S)
-        ids_p = req.prompt_ids
-        if P > S:
-            ids_p = np.pad(ids_p, ((0, 0), (0, P - S)), mode="edge")
-        rng = req.rng if req.rng is not None else jax.random.PRNGKey(
-            req.seed if req.seed is not None else 0)
-        self._state, tok = self._prefill(
-            self.params, self._state, ids_p, np.int32(slot), rng, np.int32(S),
-            *self._adapter_args(req))
-        self._finish_prefill(req, int(tok))
-
-    def _bucket(self, S: int) -> int:
-        return max(min(_bucket128(S), self._chunk_limit), S)
-
-    # -- chunked prefill ------------------------------------------------
+    # -- prefill --------------------------------------------------------
     def _begin_prefill(self, req: Request) -> Optional[bool]:
         """Assign a slot and restore the longest cached chunk-aligned
         prefix (restores are not billed against the chunk budget — they
@@ -2962,31 +2611,30 @@ class ServingEngine:
         live chunk (``_run_chunk``) outside its ``admit`` phase. Returns
         True when the request is placed, False when it was retired
         instead (its adapter could not be acquired) — or ``None`` when the
-        paged admission gate refuses: the prompt needs more pages than
+        admission gate refuses: the prompt needs more pages than
         are free or reclaimable, so the request goes back to the queue
         FRONT and the caller stops admitting until decode progress frees
         pages (admitting anyway would just trigger preemption thrash).
 
-        A paged engine prefills ``req._serve_ids`` — the original prompt,
-        or prompt + committed tokens after a preemption — so the same code
+        What is prefilled is ``req._serve_ids`` — the original prompt, or
+        prompt + committed tokens after a preemption — so the same code
         path is both first admission and token-exact resume."""
         if req._serve_ids is None:
             req._serve_ids = req.prompt_ids
         req._page_floor = 0  # every (re)admission prefills from page 0
         S = req._serve_ids.shape[1]
         C = self._chunk
-        if self._paged:
-            need = -(-S // self._page) * self._spec_page_factor
-            if need > self._pool.free_pages + self._reclaimable_pages():
-                self._flight.record(
-                    "pool_exhausted", trace_id=req.trace_id,
-                    need_pages=need, free_pages=self._pool.free_pages)
-                try:
-                    self._queue.putleft(req)
-                except QueueClosed:
-                    req._finish(RequestStatus.CANCELLED)
-                    self._stats.record_finish(req.status)
-                return None
+        need = -(-S // self._page) * self._spec_page_factor
+        if need > self._pool.free_pages + self._reclaimable_pages():
+            self._flight.record(
+                "pool_exhausted", trace_id=req.trace_id,
+                need_pages=need, free_pages=self._pool.free_pages)
+            try:
+                self._queue.putleft(req)
+            except QueueClosed:
+                req._finish(RequestStatus.CANCELLED)
+                self._stats.record_finish(req.status)
+            return None
         if not self._acquire_adapter(req):
             return False
         req.admitted_at = time.monotonic()
@@ -3011,7 +2659,7 @@ class ServingEngine:
             if restorable:
                 blocks = self._prefix_cache.match(req._chunk_keys[:restorable])
                 restored_bytes = aliased = 0
-                Cp = C // self._page if self._paged else 0
+                Cp = C // self._page
                 for i, blk in enumerate(blocks):
                     if self._alias_cache:
                         # blk is a tuple of page ids: restoring is a host
@@ -3024,22 +2672,17 @@ class ServingEngine:
                         restored_bytes += len(blk) * self._page_bytes
                         aliased += 1
                         continue
-                    if self._paged:
-                        ok = all(self._alloc_page_into(req, i * Cp + j)
-                                 for j in range(Cp))
-                        if not ok:
-                            raise RuntimeError(
-                                "page pool exhausted during prefix restore "
-                                "with no preemptable stream — the submit "
-                                "page bound should make this impossible")
-                        pages_c = self._table[slot, i * Cp:(i + 1) * Cp]
-                        self._state = self._restore_prefix(
-                            self._state, blk, pages_c.astype(np.int32),
-                            np.int32(slot), np.int32(S))
-                    else:
-                        self._state = self._restore_prefix(
-                            self._state, blk, np.int32(slot), np.int32(i * C),
-                            np.int32(S))
+                    ok = all(self._alloc_page_into(req, i * Cp + j)
+                             for j in range(Cp))
+                    if not ok:
+                        raise RuntimeError(
+                            "page pool exhausted during prefix restore "
+                            "with no preemptable stream — the submit "
+                            "page bound should make this impossible")
+                    pages_c = self._table[slot, i * Cp:(i + 1) * Cp]
+                    self._state = self._restore_prefix(
+                        self._state, blk, pages_c.astype(np.int32),
+                        np.int32(slot), np.int32(S))
                     restored_bytes += sum(
                         l.nbytes for l in jax.tree.leaves(blk))
                 if blocks and self._spec_mode == "draft":
@@ -3109,7 +2752,7 @@ class ServingEngine:
         and cannot perturb cache eviction order. Mirrors the restore
         bound in ``_begin_prefill``: the final chunk always re-runs, so
         at most ``ceil(S/C) - 1`` full chunks count."""
-        if self._prefix_cache is None or self._chunk is None:
+        if self._prefix_cache is None:
             return 0
         ids = np.asarray(prompt_ids, np.int32)
         if ids.ndim == 1:
@@ -3158,33 +2801,27 @@ class ServingEngine:
                 ids_c = np.pad(ids_c, ((0, 0), (0, C - ids_c.shape[1])),
                                mode="edge")
             t0 = time.monotonic()
-            if self._paged:
-                # Cover the chunk's whole write span (including the
-                # edge-pad tail — decode writes land there next) before the
-                # call; the program scatters only into these table entries.
-                if not self._ensure_pages(req, offset + C - 1):
+            # Cover the chunk's whole write span (including the edge-pad
+            # tail — decode writes land there next) before the call; the
+            # program scatters only into these table entries.
+            if not self._ensure_pages(req, offset + C - 1):
+                raise RuntimeError(
+                    "page pool exhausted mid-prefill with no preemptable "
+                    "stream — the submit page bound should make this "
+                    "impossible")
+            extra = self._adapter_args(req)
+            if self._spec_mode == "draft":
+                if not self._ensure_draft_pages(req, offset + C - 1):
                     raise RuntimeError(
-                        "page pool exhausted mid-prefill with no "
-                        "preemptable stream — the submit page bound should "
-                        "make this impossible")
-                extra = self._adapter_args(req)
-                if self._spec_mode == "draft":
-                    if not self._ensure_draft_pages(req, offset + C - 1):
-                        raise RuntimeError(
-                            "page pool exhausted mid-prefill for draft KV — "
-                            "the admission gate's draft factor should make "
-                            "this impossible")
-                    extra += (self._draft_params,
-                              self._dtable[req.slot].copy())
-                self._state, tok, block = self._prefill_chunk(
-                    self.params, self._state, ids_c, np.int32(req.slot),
-                    self._table[req.slot].copy(), np.int32(offset),
-                    np.int32(S), req._rng_key, *extra)
-            else:
-                self._state, tok, block = self._prefill_chunk(
-                    self.params, self._state, ids_c, np.int32(req.slot),
-                    np.int32(offset), np.int32(S), req._rng_key,
-                    *self._adapter_args(req))
+                        "page pool exhausted mid-prefill for draft KV — "
+                        "the admission gate's draft factor should make "
+                        "this impossible")
+                extra += (self._draft_params,
+                          self._dtable[req.slot].copy())
+            self._state, tok, block = self._prefill_chunk(
+                self.params, self._state, ids_c, np.int32(req.slot),
+                self._table[req.slot].copy(), np.int32(offset),
+                np.int32(S), req._rng_key, *extra)
             if final or self._stats_collection is not None:
                 # ``tok`` is read on the host (the first token, the module's
                 # counters behind it): the copy starts when the chunk ends,
@@ -3290,41 +2927,49 @@ class ServingEngine:
                         and token == self.eos_token_id)):
                 self._retire(req, RequestStatus.COMPLETED)
 
-    def _dispatch(self, running, ahead: bool) -> Optional[_TickFlight]:
+    def _dispatch(self, running,
+                  flight: Optional[_TickFlight]) -> Optional[_TickFlight]:
         """Dispatch one decode tick and return its flight WITHOUT waiting
         for the device. ``running`` is the (slot, request) list in RUNNING
         — PREFILLING slots ride along in the vmapped forward (fixed
         shape) but are masked out of every state advance and commit no
-        tokens. Paged engines first guarantee every dispatched slot's
-        write position has a page (allocating — and preempting on
-        exhaustion — at this dispatch boundary), then pass a page-table
+        tokens. Every dispatched slot's write position is first given a
+        page (allocating — and preempting on exhaustion — at this
+        dispatch boundary); the program then gets a page-table
         SNAPSHOT as traced data (the double buffer: reconcile-time frees
         mutate the live table, never the in-flight copy).
 
-        ``ahead=True`` means one unreconciled tick is in flight, so host
-        state (``len(req.tokens)``, page frontier) is stale by exactly
-        one committed token per stream. The speculative view is made safe
-        by two conservative rules: a stream within one token of its
-        budget is EXCLUDED (it deterministically retires at the in-flight
-        tick; dispatching it would write at a position past its bound),
-        and page coverage extends one position past the stale frontier
-        (the in-flight commit's write). A stream that instead retires on
+        ``flight`` is the one unreconciled tick in flight, if any: host
+        state (``len(req.tokens)``, page frontier) of the streams IN it
+        is stale by exactly one committed token. The speculative view is
+        made safe by two conservative rules: a stream of that tick within
+        one token of its budget is EXCLUDED (it deterministically retires
+        at the in-flight tick; dispatching it would write at a position
+        past its bound) — a stream that is not in it (fresh from prefill,
+        or held back by flow control) has exact host state and is
+        dispatched whatever its budget — and page coverage extends one
+        position past the stale frontier (the in-flight commit's write).
+        A stream that instead retires on
         EOS at the in-flight tick stays masked in — its lane advances
         once more and the stray token is discarded by the reconcile
         validity check (exactly-once emission). The whole of it is the
         host phase ``tick_launch``."""
         with self._phases.tick_launch:
+            ahead = flight is not None
+            stale = ({(id(req), epoch) for _, req, epoch in flight.entries}
+                     if ahead else ())
             if self._spec_k is not None:
-                return self._dispatch_spec(running, ahead)
-            return self._dispatch_dense(running, ahead)
+                return self._dispatch_spec(running, ahead, stale)
+            return self._dispatch_plain(running, ahead, stale)
 
-    def _dispatch_dense(self, running, ahead: bool) -> Optional[_TickFlight]:
+    def _dispatch_plain(self, running, ahead: bool,
+                        stale) -> Optional[_TickFlight]:
         live = []
         for slot, req in running:
-            if ahead and req.max_new_tokens - len(req.tokens) <= 1:
+            if (req.max_new_tokens - len(req.tokens) <= 1
+                    and (id(req), req._preempted) in stale):
                 continue  # retires at the in-flight tick (position bound)
-            if (self._emitter is not None and req.on_token is not None
-                    and self._emitter.backlogged(req)):
+            if req.on_token is not None and self._emitter.backlogged(req):
                 # Flow control: the consumer is emission_queue callbacks
                 # behind — hold this stream back (its device state stays
                 # put; the stream resumes bit-exactly) rather than buffer
@@ -3332,28 +2977,24 @@ class ServingEngine:
                 self._stats.record_emission_stall()
                 continue
             live.append((slot, req))
-        if self._paged:
-            for slot, req in live:
-                if req.status is not RequestStatus.RUNNING:
-                    continue  # preempted by an earlier slot's allocation
-                upto = (req._pos_base + len(req.tokens)
-                        + (1 if ahead else 0))
-                if not self._ensure_pages(req, upto):
-                    raise RuntimeError(
-                        "page pool exhausted at a tick with no preemptable "
-                        "stream — the submit page bound should make this "
-                        "impossible")
-            live = [(s, r) for s, r in live
-                    if r.status is RequestStatus.RUNNING]
+        for slot, req in live:
+            if req.status is not RequestStatus.RUNNING:
+                continue  # preempted by an earlier slot's allocation
+            upto = req._pos_base + len(req.tokens) + (1 if ahead else 0)
+            if not self._ensure_pages(req, upto):
+                raise RuntimeError(
+                    "page pool exhausted at a tick with no preemptable "
+                    "stream — the submit page bound should make this "
+                    "impossible")
+        live = [(s, r) for s, r in live if r.status is RequestStatus.RUNNING]
         if not live:
             return None
         mask = np.zeros((self.max_slots,), bool)
         for slot, _ in live:
             mask[slot] = True
         t0 = time.monotonic()
-        args = [self.params, self._state, jnp.asarray(mask)]
-        if self._paged:
-            args.append(self._table.copy())
+        args = [self.params, self._state, jnp.asarray(mask),
+                self._table.copy()]
         if self._adapters is not None:
             args.append(self._adapters.stacks)
         self._state, toks, dones = self._decode(*args)
@@ -3373,7 +3014,7 @@ class ServingEngine:
         (what a consumer experiences between tokens), and
         ``host_us_per_tick`` is that interval minus every blocked device
         wait since the previous reconcile — the host scheduling + commit
-        wall the async runtime hides under device time."""
+        wall that one-tick-ahead dispatch hides under device time."""
         if self._wedge_s:
             # Chaos: wedge INSIDE the reconcile barrier of a dispatched
             # call — the loop stops publishing heartbeats mid-"device
@@ -3502,26 +3143,25 @@ class ServingEngine:
                                 itl_ms=round(interval * 1e3, 3),
                                 host_us=round(host_s * 1e6, 1),
                                 active=len(flight.entries))
-        if self._paged:
-            self._drain_samples.append((time.monotonic(), self._pool.frees))
-            self._stats.record_pages(self._pool.free_pages,
-                                     self._pool.used_pages,
-                                     self._pool.num_pages,
-                                     freed_total=self._pool.frees)
+        self._drain_samples.append((time.monotonic(), self._pool.frees))
+        self._stats.record_pages(self._pool.free_pages, self._pool.used_pages,
+                                 self._pool.num_pages,
+                                 freed_total=self._pool.frees)
 
-    def _dispatch_spec(self, running, ahead: bool) -> Optional[_TickFlight]:
+    def _dispatch_spec(self, running, ahead: bool,
+                       stale) -> Optional[_TickFlight]:
         """Speculative twin of :meth:`_dispatch`: dispatch one draft-scan
         + verify tick (up to ``spec_tokens + 1`` tokens per slot) without
         waiting. Page coverage is guaranteed only up to the furthest
         position a slot can COMMIT — overshoot writes route to scratch
         inside the program. Reconcile commits the emitted chain exactly
-        like ``n`` dense ticks would: stop at ``max_new_tokens`` or the
+        like ``n`` plain ticks would: stop at ``max_new_tokens`` or the
         first eos.
 
-        The ``ahead`` staleness rules: a stream with fewer than 2 budget
-        tokens is excluded (it deterministically retires at the in-flight
-        tick); page coverage extends to two chains' worth of commits
-        (``min(2*(K+1), remaining)``) because the in-flight tick may
+        The ``ahead`` staleness rules: a stream of the in-flight tick
+        (``stale``) with fewer than 2 budget tokens is excluded (it
+        deterministically retires at that tick); page coverage extends
+        to two chains' worth of commits (``min(2*(K+1), remaining)``) because the in-flight tick may
         advance the write frontier by a full chain before this one runs;
         and ``remaining`` is passed STALE — safe because it is always >=
         the true budget, and the device clamp only matters when it binds
@@ -3532,14 +3172,14 @@ class ServingEngine:
         first): a proposal drafted one tick behind is misaligned by the
         in-flight tick's variable-length commit and verifies to zero
         accepts, so ahead lookup would be exact but never faster than
-        dense decode."""
+        plain decode."""
         K = self._spec_k
         live = []
         for slot, req in running:
-            if ahead and req.max_new_tokens - len(req.tokens) < 2:
+            if (req.max_new_tokens - len(req.tokens) < 2
+                    and (id(req), req._preempted) in stale):
                 continue  # retires at the in-flight tick (position bound)
-            if (self._emitter is not None and req.on_token is not None
-                    and self._emitter.backlogged(req)):
+            if req.on_token is not None and self._emitter.backlogged(req):
                 self._stats.record_emission_stall()
                 continue
             live.append((slot, req))
@@ -3625,38 +3265,30 @@ class ServingEngine:
         return np.full((K,), seq[-1], np.int32), False
 
     def _commit_token(self, req: Request, token: int) -> bool:
-        """Append + stream one token. With an emitter (async mode) the
-        callback is QUEUED, not run — the tick loop never waits on a
-        consumer — and a callback that already raised off-thread fails
-        the request here, before committing more. Inline mode (sync A/B)
-        keeps the original semantics: a raising ``on_token`` fails ONLY
-        its own request (slot freed, batch untouched). Returns False when
-        the request was retired instead of committed to."""
+        """Append + stream one token. The callback is QUEUED to the
+        emitter, not run — the tick loop never waits on a consumer — and
+        a callback that already raised off-thread fails ONLY its own
+        request here (slot freed, batch untouched), before committing
+        more. Returns False when the request was retired instead of
+        committed to."""
         if req._emit_error is not None:
             self._retire(req, RequestStatus.FAILED, req._emit_error)
             return False
         req.tokens.append(token)
         if req.on_token is not None:
-            if self._emitter is not None:
-                self._emitter.put(req, token)
-            else:
-                try:
-                    req.on_token(token)
-                except Exception as e:
-                    self._retire(req, RequestStatus.FAILED, e)
-                    return False
+            self._emitter.put(req, token)
         return True
 
     def _finish_req(self, req: Request, status: RequestStatus,
                     error: Optional[BaseException] = None):
         """Terminal transition, emitter-aware: status/error land NOW (the
         engine thread's scheduling view stays consistent), while for a
-        streaming request in async mode the observable completion
-        (``_done``, ``_on_finish``) is queued BEHIND its buffered tokens
+        streaming request the observable completion (``_done``,
+        ``_on_finish``) is queued BEHIND its buffered tokens
         — the drain-on-retire barrier that keeps ``result()`` ordered
         after the last ``on_token`` call and lets shutdown/failover drain
         instead of drop."""
-        if self._emitter is not None and req.on_token is not None:
+        if req.on_token is not None:
             if req._finish(status, error, defer=True):
                 self._emitter.finish(req)
         else:
@@ -3665,8 +3297,7 @@ class ServingEngine:
     def _retire(self, req: Request, status: RequestStatus,
                 error: Optional[BaseException] = None):
         if req.slot is not None:
-            if self._paged:
-                self._release_slot_pages(req.slot)
+            self._release_slot_pages(req.slot)
             self._slots.release(req.slot)
         if req._adapter_pinned:
             req._adapter_pinned = False

@@ -192,10 +192,10 @@ class SliceExec:
     def cache_leaf_shardings(self, template_leaves, length_axes,
                              with_slot_axis: bool):
         """Flat list of NamedShardings, one per KV leaf. ``template_leaves``
-        are the per-slot cache leaves (``eval_shape`` structs are fine);
-        ``with_slot_axis`` prepends the engine's ``[max_slots]`` dimension
-        (replicated — slots are data-parallel rows of one slice's batch,
-        never split across its chips)."""
+        are one page's (or one chunk block's) cache leaves (``eval_shape``
+        structs are fine); ``with_slot_axis`` prepends the pool's leading
+        page dimension (replicated — pages are data-parallel rows, never
+        split across a slice's chips)."""
         out = []
         for leaf, lax in zip(template_leaves, length_axes):
             ax = self.heads_axis(tuple(leaf.shape), lax)
@@ -210,15 +210,11 @@ class SliceExec:
 
     def state_shardings(self, state, template_leaves, length_axes):
         """Shardings pytree matching the engine state dict exactly: the
-        KV subtree (dense ``cache`` or paged ``pool``) per-leaf
-        heads-sharded, every other row (pos/tok/rng/done/adapter_idx — the
-        membership-as-data arrays) replicated so host writes and mask
-        flips stay collective-free. The paged pool reuses the slot-axis
-        path unchanged: a pool leaf is ``[num_pages+1, P, heads, hd]``
-        where a slot cache leaf is ``[max_slots, L, heads, hd]`` — the
-        leading axis is just pages instead of slots (replicated either
-        way; pages are data-parallel rows), and the heads axis sits at the
-        same template-relative offset.
+        KV subtree (the page ``pool``) per-leaf heads-sharded, every other
+        row (pos/tok/rng/done/adapter_idx — the membership-as-data
+        arrays) replicated so host writes and mask flips stay
+        collective-free. A pool leaf is ``[num_pages+1, P, heads, hd]``:
+        the leading page axis is replicated, the heads axis split.
 
         A speculative engine's DRAFT page pool (``dpool``) deliberately
         lands in the replicated bucket with the scalar rows: the draft is
@@ -236,15 +232,14 @@ class SliceExec:
         no code here needs to know the pool is quantized at all."""
         import jax
 
-        kv_key = "pool" if "pool" in state else "cache"
         kv_sh = jax.tree.unflatten(
-            jax.tree.structure(state[kv_key]),
+            jax.tree.structure(state["pool"]),
             self.cache_leaf_shardings(template_leaves, length_axes,
                                       with_slot_axis=True))
         # Non-KV entries expand to a full subtree of replicated shardings
         # (not a prefix leaf): ``place`` tree-maps state against this
         # strictly, and the draft pool is a pytree, not a row.
-        return {key: (kv_sh if key == kv_key
+        return {key: (kv_sh if key == "pool"
                       else jax.tree.map(lambda _: self.replicated,
                                         state[key]))
                 for key in state}

@@ -322,10 +322,10 @@ class ServingStats:
     def record_prefix(self, looked_up: int, hit: int, bytes_restored: int,
                       aliased: int = 0):
         """One admission's prefix-cache lookup: ``looked_up`` restorable
-        chunks were probed, the first ``hit`` of them were restored by
-        ``restore_prefix`` instead of recomputed. On the paged engine,
-        ``aliased`` of those hits were satisfied by page-table aliasing
-        (a host page-id write, zero device copies)."""
+        chunks were probed, the first ``hit`` of them were restored
+        instead of recomputed: ``aliased`` of those hits by page-table
+        aliasing (a host page-id write, zero device copies), the rest by
+        ``restore_prefix`` copies from an external cache."""
         with self._lock:
             self._prefix_lookup_chunks += int(looked_up)
             self._prefix_hit_chunks += int(hit)
@@ -590,7 +590,7 @@ class ServingStats:
                 "prefix_cache_restored_bytes": self._prefix_restored_bytes,
                 "prefix_cache_bytes": self._prefix_cache_bytes,
                 "prefix_cache_entries": self._prefix_cache_entries,
-                # Paged-KV pool pressure (all zero on a dense engine).
+                # KV page-pool pressure.
                 "pages_total": self._pages_total,
                 "pages_free": self._pages_free,
                 "pages_used": self._pages_used,

@@ -105,9 +105,9 @@ class Request:
 
         self._cancel_requested = False
         self._done = threading.Event()
-        # True once the terminal transition ran (engine thread). Under the
-        # async host runtime the OBSERVABLE completion (``_done`` /
-        # ``_on_finish``) may lag this flag: the engine sets status/error
+        # True once the terminal transition ran (engine thread). The
+        # OBSERVABLE completion (``_done`` / ``_on_finish``) may lag this
+        # flag: the engine sets status/error
         # synchronously via ``_finish(..., defer=True)`` and the emitter
         # thread calls ``_complete()`` only after every buffered ``on_token``
         # callback for this request has drained — the drain-on-retire
@@ -206,8 +206,8 @@ class Request:
 
     def _finish(self, status: RequestStatus, error: Optional[BaseException] = None,
                 defer: bool = False):
-        """Terminal transition. ``defer=True`` (async engines, streaming
-        requests) records status/error immediately — so the engine thread
+        """Terminal transition. ``defer=True`` (streaming requests)
+        records status/error immediately — so the engine thread
         sees a consistent terminal state for scheduling — but leaves the
         observable completion (:meth:`_complete`) to the emitter thread,
         AFTER this request's buffered callbacks drain. Returns True when
@@ -224,8 +224,8 @@ class Request:
 
     def _complete(self):
         """Second half of the terminal transition: stamp, wake waiters,
-        fire the router hook. Runs on the engine thread (sync path) or the
-        emitter thread (deferred path) — exactly once either way."""
+        fire the router hook. Runs on the engine thread or, deferred, on
+        the emitter thread — exactly once either way."""
         self.finished_at = time.monotonic()
         self._done.set()
         if self._on_finish is not None:

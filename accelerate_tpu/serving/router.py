@@ -711,8 +711,8 @@ class ReplicaSet:
         :meth:`~.engine.ServingEngine.projected_page_deficit` — one
         replica with headroom means the request has a home, so only when
         EVERY healthy replica is short does the gateway shed. 0 when any
-        replica is dense or has room (and when none is healthy — the
-        no-replica path 503s instead)."""
+        replica has room (and when none is healthy — the no-replica path
+        503s instead)."""
         deficits = [r.engine.projected_page_deficit(total_tokens)
                     for r in self._replicas
                     if r.state is ReplicaState.HEALTHY and r.engine.healthy]
@@ -1050,9 +1050,9 @@ class ReplicaSet:
                 "fleet_free_slots": sum(
                     r.engine.free_slots for r in self._replicas
                     if r.state is ReplicaState.HEALTHY and r.engine.healthy),
-                # Paged-KV headroom across the healthy fleet (0 when every
-                # replica is dense). Page pressure already steers routing
-                # through ``engine.load``; this is the operator's view.
+                # KV-page headroom across the healthy fleet. Page pressure
+                # already steers routing through ``engine.load``; this is
+                # the operator's view.
                 "fleet_free_pages": sum(
                     r.engine.free_pages for r in self._replicas
                     if r.state is ReplicaState.HEALTHY and r.engine.healthy),

@@ -303,11 +303,9 @@ def _sleepy_llama_cls(step_ms: float, per_token: bool = False):
 
     ``per_token=True`` scales the sleep by the call's STATIC sequence
     width (``step_ms`` per input position), modeling the real cost shape
-    of prefill: a monolithic width-P prefill burns ``P * step_ms`` in one
-    uninterruptible block while a width-C chunk burns only ``C * step_ms``
-    — the asymmetry the chunked-prefill interference A/B measures. The
-    per-forward default would bill a whole 128-token prefill the same one
-    sleep as a single decode tick and invert that comparison."""
+    of prefill: a width-C chunk burns ``C * step_ms`` where a decode tick
+    burns ``step_ms`` — what the admission tests need for a long
+    prompt's prefill window to be wide on any host."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -477,120 +475,6 @@ def continuous_vs_static(n_short: int = 3, short_new_tokens: int = 8,
         "continuous_short_latency_s": round(cont_short, 4),
         "speedup": round(static_short / cont_short, 3) if cont_short else None,
         "continuous_stats": stats,
-    }
-
-
-def chunked_prefill_interference(n_streams: int = 3, stream_new_tokens: int = 40,
-                                 long_prompt_len: int = 96,
-                                 long_new_tokens: int = 4, n_late: int = 3,
-                                 late_new_tokens: int = 4,
-                                 prefill_chunk: int = 8,
-                                 prefill_chunks_per_tick: int = 2,
-                                 step_ms: float = 1.0, max_slots: int = 8,
-                                 max_len: int = 128) -> dict:
-    """Admission-interference A/B: the traffic chunked prefill exists for.
-
-    ``n_streams`` short requests are mid-decode when one LONG prompt
-    arrives, tailed by ``n_late`` short arrivals. Monolithic admission
-    (``prefill_chunk=None``) runs the whole long prefill — and then every
-    late prefill, each padded to its 128 bucket — inline between decode
-    ticks, so the active streams stall for the full block and the late
-    arrivals queue behind it. Chunked admission spends at most
-    ``prefill_chunks_per_tick`` fixed-width chunk calls between ticks
-    (the default 2 alternates one long-prefill continuation with one new
-    admission), so the worst-case tick-to-tick gap is a couple of chunks,
-    whatever arrives — and a late short starts prefilling while the long
-    prompt is still streaming into KV.
-
-    Both engines run the same per-token sleepy model (``step_ms`` of
-    deterministic host sleep per input position, see
-    :func:`_sleepy_llama_cls`), fully warmed before timing, so the gap is
-    scheduling. Reported per engine: the decoding streams' inter-token-gap
-    p95/max inside the interference window and the late arrivals' TTFT
-    p95 — plus the chunk/tick split from ``serving_metrics()``."""
-    import jax
-    import numpy as np
-
-    from accelerate_tpu.models.llama import LlamaConfig
-    from accelerate_tpu.serving import ServingEngine
-
-    model = _sleepy_llama_cls(step_ms, per_token=True)(LlamaConfig.tiny())
-    params = model.init_params(jax.random.PRNGKey(0))
-
-    def percentile(xs, q):
-        if not xs:
-            return 0.0
-        s = sorted(xs)
-        return s[min(len(s) - 1, max(0, int(round(q * (len(s) - 1)))))]
-
-    def run(chunked: bool) -> dict:
-        engine = ServingEngine(
-            model, params, max_slots=max_slots, max_len=max_len,
-            prefill_chunk=prefill_chunk if chunked else None,
-            prefill_chunks_per_tick=prefill_chunks_per_tick,
-            prefix_cache_mb=0.0)
-        rng = np.random.default_rng(0)
-        try:
-            stamps = [[] for _ in range(n_streams)]
-            streams = []
-            for i in range(n_streams):
-                p = rng.integers(1, 200, size=(1, 4)).astype(np.int32)
-                streams.append(engine.submit(
-                    p, max_new_tokens=stream_new_tokens, ignore_eos=True,
-                    on_token=(lambda tok, s=stamps[i]:
-                              s.append(time.perf_counter()))))
-            t0 = time.perf_counter()
-            while any(len(s) < 4 for s in stamps):  # all streams decoding
-                if time.perf_counter() - t0 > 120:
-                    raise RuntimeError("short streams never started decoding")
-                time.sleep(0.001)
-            engine.stats.reset()  # count only the interference window
-            t_long = time.perf_counter()
-            long_req = engine.submit(
-                rng.integers(1, 200, size=(1, long_prompt_len)).astype(np.int32),
-                max_new_tokens=long_new_tokens, ignore_eos=True)
-            late = []
-            for _ in range(n_late):
-                time.sleep(0.002)
-                late.append(engine.submit(
-                    rng.integers(1, 200, size=(1, 4)).astype(np.int32),
-                    max_new_tokens=late_new_tokens, ignore_eos=True))
-            for r in [long_req] + late + streams:
-                r.wait(timeout=120)
-            s = engine.serving_metrics()
-        finally:
-            engine.shutdown()
-        gaps_ms = [(b - a) * 1e3 for st in stamps
-                   for a, b in zip(st, st[1:]) if b >= t_long]
-        ttfts_ms = [(r.first_token_at - r.submitted_at) * 1e3 for r in late]
-        return {
-            "late_ttft_ms_p95": round(percentile(ttfts_ms, 0.95), 3),
-            "late_ttft_ms_mean": round(sum(ttfts_ms) / len(ttfts_ms), 3),
-            "stream_itl_ms_p95": round(percentile(gaps_ms, 0.95), 3),
-            "stream_itl_ms_max": round(max(gaps_ms), 3) if gaps_ms else 0.0,
-            "prefill_chunks": s["prefill_chunks"],
-            "prefill_ms": s["prefill_ms"],
-            "decode_ms": s["decode_ms"],
-            "prefill_backlog_max": s["prefill_backlog_max"],
-        }
-
-    chunked = run(chunked=True)
-    mono = run(chunked=False)
-    return {
-        "n_streams": n_streams,
-        "long_prompt_len": long_prompt_len,
-        "n_late": n_late,
-        "prefill_chunk": prefill_chunk,
-        "prefill_chunks_per_tick": prefill_chunks_per_tick,
-        "step_ms": step_ms,
-        "chunked": chunked,
-        "monolithic": mono,
-        "ttft_speedup": round(
-            mono["late_ttft_ms_p95"] / chunked["late_ttft_ms_p95"], 3)
-            if chunked["late_ttft_ms_p95"] else None,
-        "itl_stall_speedup": round(
-            mono["stream_itl_ms_max"] / chunked["stream_itl_ms_max"], 3)
-            if chunked["stream_itl_ms_max"] else None,
     }
 
 
@@ -1229,79 +1113,75 @@ def serving_tp_bench(n_requests: int = 3, prompt_len: int = 6,
     }
 
 
-def paged_capacity_bench(dense_slots: int = 2, max_len: int = 64,
+def paged_capacity_bench(worst_case_slots: int = 2, max_len: int = 64,
                          page_size: int = 8, prompt_len: int = 4,
                          new_tokens: int = 12, step_ms: float = 2.0) -> dict:
-    """Slots-at-equal-KV-HBM A/B — the paged tentpole's capacity claim.
+    """Slots a KV pool sustains on short traffic — the page pool's
+    capacity claim, as counts.
 
-    The dense engine reserves ``max_len`` tokens of KV per slot, so
-    ``dense_slots`` slots cost ``dense_slots * max_len`` tokens of HBM and
-    cap concurrency at ``dense_slots`` no matter how short the traffic is.
-    The paged engine gets a pool of the SAME total tokens
-    (``dense_slots * max_len / page_size`` pages) and as many slots as
-    that pool can cover at the benchmark's actual sequence length
-    (``prompt + new`` tokens = a couple of pages). Both engines then serve
-    one burst of that many requests on the same deterministic-sleep model;
-    ``peak_concurrency`` is the maximum number of overlapping
-    admitted->finished intervals — what each layout actually sustained.
-    Greedy tokens must be identical (paging is a memory layout, not a
-    semantic change) and the paged run must not preempt (the pool really
-    fits the advertised concurrency)."""
+    A pool of ``worst_case_slots * max_len`` tokens is what
+    ``worst_case_slots`` streams need if each may grow to ``max_len``.
+    The engine gets exactly that pool (``worst_case_slots * max_len /
+    page_size`` pages) and as many slots as it can cover at the
+    benchmark's actual sequence length (``prompt + new`` tokens = a
+    couple of pages), then serves one burst of that many requests on the
+    deterministic-sleep model. ``peak_concurrency`` is the maximum
+    number of overlapping admitted->finished intervals;
+    ``slots_ratio`` divides it by ``worst_case_slots``. Greedy tokens
+    must equal offline ``generate`` (paging is a memory layout, not a
+    semantic change) and the run must not preempt (the pool really fits
+    the advertised concurrency)."""
     import jax
     import numpy as np
 
+    from accelerate_tpu import generation
     from accelerate_tpu.models.llama import LlamaConfig
     from accelerate_tpu.serving import ServingEngine
 
-    pool_pages = dense_slots * max_len // page_size
+    pool_pages = worst_case_slots * max_len // page_size
     pages_per_req = -(-(prompt_len + new_tokens) // page_size)
-    paged_slots = pool_pages // pages_per_req
+    slots = pool_pages // pages_per_req
 
     model = _sleepy_llama_cls(step_ms)(LlamaConfig.tiny())
     params = model.init_params(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
-    prompts = rng.integers(1, 200,
-                           size=(paged_slots, prompt_len)).astype(np.int32)
+    prompts = rng.integers(1, 200, size=(slots, prompt_len)).astype(np.int32)
 
-    def serve(**kw):
-        engine = ServingEngine(model, params, max_len=max_len,
-                               prefill_chunk=page_size, eos_token_id=None,
-                               **kw)
-        try:
-            kv_bytes = engine.kv_cache_per_chip_bytes()
-            reqs = [engine.submit(prompts[i:i + 1], max_new_tokens=new_tokens,
-                                  ignore_eos=True, block=True)
-                    for i in range(paged_slots)]
-            toks = [np.asarray(r.result(timeout=300)) for r in reqs]
-            # Peak concurrency = max overlap of slot-residency intervals.
-            events = sorted([(r.admitted_at, 1) for r in reqs]
-                            + [(r.finished_at, -1) for r in reqs])
-            peak = cur = 0
-            for _, d in events:
-                cur += d
-                peak = max(peak, cur)
-            stats = engine.serving_metrics()
-        finally:
-            engine.shutdown()
-        return toks, peak, kv_bytes, stats
-
-    d_toks, d_peak, d_kv, _ = serve(max_slots=dense_slots, paged=False)
-    p_toks, p_peak, p_kv, p_stats = serve(max_slots=paged_slots,
-                                          max_pages=pool_pages)
-    tokens_equal = all(np.array_equal(a, b) for a, b in zip(d_toks, p_toks))
+    engine = ServingEngine(model, params, max_len=max_len, max_slots=slots,
+                           max_pages=pool_pages, prefill_chunk=page_size,
+                           eos_token_id=None)
+    try:
+        kv_bytes = engine.kv_cache_per_chip_bytes()
+        reqs = [engine.submit(prompts[i:i + 1], max_new_tokens=new_tokens,
+                              ignore_eos=True, block=True)
+                for i in range(slots)]
+        toks = [np.asarray(r.result(timeout=300)) for r in reqs]
+        # Peak concurrency = max overlap of slot-residency intervals.
+        events = sorted([(r.admitted_at, 1) for r in reqs]
+                        + [(r.finished_at, -1) for r in reqs])
+        peak = cur = 0
+        for _, d in events:
+            cur += d
+            peak = max(peak, cur)
+        stats = engine.serving_metrics()
+    finally:
+        engine.shutdown()
+    offline = np.asarray(generation.generate(
+        model, params, prompts, max_new_tokens=new_tokens))[:, prompt_len:]
     return {
-        "dense_slots": dense_slots,
-        "paged_slots": paged_slots,
+        "worst_case_slots": worst_case_slots,
+        "slots": slots,
         "max_len": max_len,
         "page_size": page_size,
         "pool_pages": pool_pages,
         "request_tokens": prompt_len + new_tokens,
-        "kv_bytes": {"dense": d_kv, "paged": p_kv},
-        "peak_concurrency": {"dense": d_peak, "paged": p_peak},
-        "slots_ratio": round(p_peak / max(d_peak, 1), 3),
-        "tokens_equal": bool(tokens_equal),
-        "preemptions": p_stats["preemptions"],
-        "page_utilization": p_stats["page_utilization"],
+        "kv_bytes": kv_bytes,
+        "peak_concurrency": peak,
+        "slots_ratio": round(peak / worst_case_slots, 3),
+        "tokens_equal": all(np.array_equal(a, b)
+                            for a, b in zip(toks, offline)),
+        "preemptions": stats["preemptions"],
+        "page_utilization": stats["page_utilization"],
     }
 
 
@@ -1393,14 +1273,14 @@ def speculative_bench(prompt_len: int = 5, new_tokens: int = 24,
     return out
 
 
-def quantized_serving_bench(dense_slots: int = 2, max_len: int = 64,
+def quantized_serving_bench(worst_case_slots: int = 2, max_len: int = 64,
                             page_size: int = 8, prompt_len: int = 4,
                             new_tokens: int = 16, step_ms: float = 2.0,
                             spec_tokens: int = 4) -> dict:
     """Equal-HBM quantized-KV A/B — the int8 serving tentpole's claim.
 
     Capacity: the fp paged engine gets the 16-page template pool
-    (``dense_slots * max_len / page_size`` pages, same as
+    (``worst_case_slots * max_len / page_size`` pages, same as
     :func:`paged_capacity_bench`); the ``kv_dtype="int8"`` engine gets
     the SAME pool BYTES, which buy it ``itemsize``-ish times more pages
     (per-page f32 scales included — the engine's own ``_page_bytes``
@@ -1429,7 +1309,7 @@ def quantized_serving_bench(dense_slots: int = 2, max_len: int = 64,
     from accelerate_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from accelerate_tpu.serving import ServingEngine
 
-    pool_pages = dense_slots * max_len // page_size
+    pool_pages = worst_case_slots * max_len // page_size
     pages_per_req = -(-(prompt_len + new_tokens) // page_size)
     fp_slots = pool_pages // pages_per_req
 
@@ -1600,67 +1480,6 @@ def quantized_serving_bench(dense_slots: int = 2, max_len: int = 64,
     }
 
 
-def host_overlap_bench(n_streams: int = 2, new_tokens: int = 24,
-                       step_ms: float = 12.0, consume_ms: float = 4.0,
-                       prompt_len: int = 5, max_len: int = 64) -> dict:
-    """Async-host-runtime A/B: the same sleepy-model traffic (every
-    forward burns a deterministic ``step_ms``) with a ``consume_ms``
-    ``on_token`` consumer per stream, served once with
-    ``async_ticks=False`` and once with the async runtime.
-
-    The sync engine's ITL is additive — device step + host
-    schedule/commit + every consumer callback runs inline between ticks
-    — while the async engine dispatches tick N+1 before reconciling N
-    and drains callbacks on the emitter thread, so its ITL approaches
-    the device leg alone. ``itl_ratio`` (sync/async mean ITL) is the
-    overlap win the perf guard pins; ``host_us_per_tick`` from each mode
-    shows where the hidden time went."""
-    import jax
-    import numpy as np
-
-    from accelerate_tpu.models.llama import LlamaConfig
-    from accelerate_tpu.serving import ServingEngine
-
-    model = _sleepy_llama_cls(step_ms)(LlamaConfig.tiny())
-    params = model.init_params(jax.random.PRNGKey(0))
-    rng = np.random.default_rng(3)
-    prompts = rng.integers(1, 200, size=(n_streams, prompt_len)).astype(np.int32)
-
-    def run(async_ticks: bool) -> dict:
-        engine = ServingEngine(model, params, max_slots=n_streams,
-                               max_len=max_len, async_ticks=async_ticks)
-        try:
-            engine.stats.reset()
-            reqs = [engine.submit(prompts[i:i + 1], max_new_tokens=new_tokens,
-                                  ignore_eos=True,
-                                  on_token=lambda t: time.sleep(consume_ms / 1e3))
-                    for i in range(n_streams)]
-            for r in reqs:
-                r.wait(timeout=300)
-            s = engine.stats.summary()
-            hist = engine.stats.histograms()["itl_ms"]
-        finally:
-            engine.shutdown(drain=False)
-        return {
-            "itl_mean_ms": round(hist["sum"] / max(hist["count"], 1), 3),
-            "decode_ticks": s["decode_ticks"],
-            "host_us_per_tick": s["host_us_per_tick"],
-            "emission_stalls": s["emission_stalls"],
-        }
-
-    sync, asyn = run(False), run(True)
-    return {
-        "n_streams": n_streams,
-        "new_tokens": new_tokens,
-        "step_ms": step_ms,
-        "consume_ms": consume_ms,
-        "sync": sync,
-        "async": asyn,
-        "itl_ratio": round(sync["itl_mean_ms"] / asyn["itl_mean_ms"], 3)
-        if asyn["itl_mean_ms"] else None,
-    }
-
-
 def tracing_overhead_bench(n_requests: int = 10, prompt_len: int = 4,
                            max_new_tokens: int = 16, repeats: int = 3) -> dict:
     """Tracing on/off A/B: identical traffic through two warmed tiny-model
@@ -1816,11 +1635,10 @@ def zero_sharding_bench(steps: int = 30, warmup: int = 5, dp: int = 2,
 
 def serving_extra(on_tpu: bool) -> dict:
     """The ``extra.serving`` payload: on CPU the offered-load sweep, the
-    continuous-vs-static staggered-arrival comparison, the
-    chunked-prefill pair — admission-interference A/B plus the
-    prefix-cache hit check — the gateway pair — HTTP-overhead-vs-
-    direct-submit plus the replica-kill failover drill — and the paged
-    pair — slots-at-equal-HBM capacity A/B plus the speculative-decoding
+    continuous-vs-static staggered-arrival comparison, the prefix-cache
+    hit check, the gateway pair — HTTP-overhead-vs-direct-submit plus
+    the replica-kill failover drill — and the paged pair — slots a pool
+    sustains on short traffic plus the speculative-decoding
     accepted-tokens/step A/B (cheap, tiny model); on TPU skipped —
     serving the tier-1 model is its own benchmark, not a rider on the
     training run."""
@@ -1830,7 +1648,6 @@ def serving_extra(on_tpu: bool) -> dict:
         "sweep": serving_sweep(),
         "continuous_vs_static": continuous_vs_static(),
         "chunked_prefill": {
-            "interference": chunked_prefill_interference(),
             "prefix_cache": prefix_cache_hit_bench(),
         },
         "gateway": {
@@ -1844,7 +1661,6 @@ def serving_extra(on_tpu: bool) -> dict:
         "paged": paged_capacity_bench(),
         "quantized": quantized_serving_bench(),
         "speculative": speculative_bench(),
-        "host_overlap": host_overlap_bench(),
     }
 
 

@@ -437,11 +437,12 @@ class TestCLISubprocess:
         out = _run_cli("serve", "--help")
         assert out.returncode == 0, out.stderr
         for flag in ["--model", "--replicas", "--port", "--max-slots", "--tp",
-                     "--page-size", "--max-pages", "--no-paged",
+                     "--page-size", "--max-pages",
                      "--priority-preemption", "--no-priority-preemption",
                      "--rate-limit", "--fair-share",
                      "--autoscale-min", "--autoscale-max"]:
             assert flag in out.stdout
+        assert "--no-paged" not in out.stdout
 
     def test_serve_tenant_float_specs(self):
         """--rate-limit/--fair-share NAME=FLOAT parsing: valid pairs (incl.

@@ -161,7 +161,7 @@ def test_chunked_prefill_then_decode_through_the_cache_gives_the_reference_logit
 
 
 def test_the_paged_engine_serves_a_stream_of_four_windows(tiny):
-    """Prefill in chunks, then decode, through ``ServingEngine(paged=True)``
+    """Prefill in chunks, then decode, through ``ServingEngine``
     with both layer kinds past the window. Compared in logits, not tokens:
     every served token's reference logit lies within TOL of the reference's
     best at its position (a served token that differs only by a near-tie
@@ -169,7 +169,7 @@ def test_the_paged_engine_serves_a_stream_of_four_windows(tiny):
     cfg, model, params = tiny
     prompt, new = 3 * WINDOW + 3, WINDOW + 5                      # ends past 4 windows
     ids = np.asarray(ids_of(prompt, seed=6))[None]
-    eng = ServingEngine(model, params, max_slots=2, max_len=64, paged=True, prefill_chunk=8,
+    eng = ServingEngine(model, params, max_slots=2, max_len=64, prefill_chunk=8,
                         page_size=8)
     try:
         assert eng._page_window is None            # a mixed stack keeps every page
@@ -306,7 +306,7 @@ def test_a_held_share_behind_the_engine_counts_its_picks_and_no_dead_rows_below_
     cfg, _, params = tiny
     cfg_h, params_h = share_of(params, cfg, 0, 2)                 # 2 of 8 experts
     eng = ServingEngine(Cohere2MoeForCausalLM(cfg_h), params_h, max_slots=2, max_len=32,
-                        paged=True, prefill_chunk=4, page_size=4)
+                        prefill_chunk=4, page_size=4)
     try:
         req = eng.submit(np.asarray(ids_of(3, seed=10))[None], max_new_tokens=4,
                          ignore_eos=True, block=True)             # ends at 7 < window

@@ -180,7 +180,7 @@ def tiny_mixtral():
 
 
 def _serve(model, params, prompt, new, **kwargs):
-    eng = ServingEngine(model, params, max_slots=2, max_len=128, paged=True, prefill_chunk=64,
+    eng = ServingEngine(model, params, max_slots=2, max_len=128, prefill_chunk=64,
                         page_size=16, **kwargs)
     try:
         req = eng.submit(prompt, max_new_tokens=new, ignore_eos=True, block=True)
@@ -215,7 +215,7 @@ def test_the_paged_engine_serves_mixtral_through_the_tiles_and_counts_them(tiny_
 def test_moe_tile_fill_is_zero_where_no_tile_ran(tiny_mixtral):
     """A prompt of one 8-token chunk never leaves the batched product."""
     cfg, model, params = tiny_mixtral
-    eng = ServingEngine(model, params, max_slots=2, max_len=32, paged=True, prefill_chunk=8,
+    eng = ServingEngine(model, params, max_slots=2, max_len=32, prefill_chunk=8,
                         page_size=8)
     try:
         req = eng.submit(np.arange(1, 6, dtype=np.int32)[None], max_new_tokens=3,

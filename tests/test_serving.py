@@ -9,11 +9,11 @@ The acceptance-critical properties pinned here:
 * ZERO RECOMPILES — after warmup, admitting and retiring requests of
   varying prompt lengths triggers no new XLA compilation (probed via
   jax.monitoring's event-duration listener, which fires per compile);
-  with chunked prefill the steady state is exactly ONE executable each
-  for prefill_chunk, restore_prefix, and decode, whatever prompt-length
-  mix arrives.
+  the steady state is exactly ONE executable each for prefill_chunk and
+  decode (and restore_prefix, with an external cache), whatever
+  prompt-length mix arrives.
 * CHUNKED PREFILL — chunk-size x prompt-length x sampling exactness
-  against both the monolithic engine and offline generate, decode ticks
+  against offline generate, decode ticks
   interleaving with a long prompt's chunk calls, and the prefix cache
   (unit LRU semantics + a repeat prompt admitting in one chunk).
 * SCHEDULING SEMANTICS — bounded-queue backpressure, cancel (queued and
@@ -275,7 +275,7 @@ class TestZeroRecompile:
 class TestChunkedExactness:
     """Chunked prefill changes WHEN prompt KV is written, never what is
     written: every (chunk size, prompt length, sampling) cell must be
-    token-identical to the monolithic engine AND offline generate —
+    token-identical to offline generate (so to every other chunk size) —
     including non-multiple tails, single-chunk prompts, and S=1."""
 
     CHUNKS = (4, 16)
@@ -284,9 +284,7 @@ class TestChunkedExactness:
     @pytest.fixture(scope="class")
     def engines(self, tiny):
         _, m, params = tiny
-        engs = {"mono": ServingEngine(m, params, max_slots=2, max_len=64,
-                                      eos_token_id=EOS, prefill_chunk=None,
-                                      warmup=False)}
+        engs = {}
         for C in self.CHUNKS:
             engs[C] = ServingEngine(m, params, max_slots=2, max_len=64,
                                     eos_token_id=EOS, prefill_chunk=C,
@@ -307,9 +305,7 @@ class TestChunkedExactness:
                 got_c = engines[C].submit(p, max_new_tokens=n).result(timeout=120)
                 chunks = engines[C].serving_metrics()["prefill_chunks"] - before
                 assert chunks == -(-S // C), (S, C, chunks)  # really chunked
-                got_m = engines["mono"].submit(p, max_new_tokens=n).result(timeout=120)
                 _assert_matches_offline(got_c, _offline(m, params, p, n), n)
-                assert np.array_equal(got_c, got_m), (S, C, got_c, got_m)
 
     def test_sampled_chunk_matrix(self, tiny):
         """Sampled decoding pins the rng protocol: every chunk call splits
@@ -319,36 +315,36 @@ class TestChunkedExactness:
         _, m, params = tiny
         kw = dict(max_slots=2, max_len=64, eos_token_id=EOS, do_sample=True,
                   temperature=0.9, top_k=50, warmup=False)
-        eng_c = ServingEngine(m, params, prefill_chunk=4,
-                              prefix_cache_mb=0.0, **kw)
-        eng_m = ServingEngine(m, params, prefill_chunk=None, **kw)
+        engs = {C: ServingEngine(m, params, prefill_chunk=C,
+                                 prefix_cache_mb=0.0, **kw)
+                for C in self.CHUNKS}
         try:
             n = 10
             rng = np.random.default_rng(12)
             for S in (5, 13, 21):
                 p = rng.integers(0, 256, size=(1, S)).astype(np.int32)
                 seed = 200 + S
-                got_c = eng_c.submit(p, max_new_tokens=n,
-                                     seed=seed).result(timeout=120)
-                got_m = eng_m.submit(p, max_new_tokens=n,
-                                     seed=seed).result(timeout=120)
                 ref = _offline(m, params, p, n, seed=seed, do_sample=True,
                                temperature=0.9, top_k=50)
-                _assert_matches_offline(got_c, ref, n)
-                assert np.array_equal(got_c, got_m), (S, got_c, got_m)
+                for C, eng in engs.items():
+                    before = eng.serving_metrics()["prefill_chunks"]
+                    got = eng.submit(p, max_new_tokens=n,
+                                     seed=seed).result(timeout=120)
+                    chunks = eng.serving_metrics()["prefill_chunks"] - before
+                    assert chunks == -(-S // C), (S, C, chunks)
+                    _assert_matches_offline(got, ref, n)
         finally:
-            for e in (eng_c, eng_m):
+            for e in engs.values():
                 if e.running:
                     e.shutdown(drain=False)
 
 
 class TestZeroRecompileChunked:
     def test_one_chunk_executable_for_any_length_mix(self):
-        """The tentpole's acceptance bar: prompt lengths spanning what used
-        to be THREE 128-bucket prefill executables (3..300, both sides of
-        the chunk width) run after warmup with zero compile/trace events
-        and exactly ONE cached executable each for prefill_chunk,
-        restore_prefix, and decode."""
+        """Prompt lengths on both sides of the chunk width (3..300) run
+        after warmup with zero compile/trace events and exactly ONE cached
+        executable each for prefill_chunk and decode (and restore_prefix,
+        where there is one)."""
         cfg = LlamaConfig.tiny(use_flash_attention=False,
                                max_position_embeddings=512)
         m = LlamaForCausalLM(cfg)
@@ -373,12 +369,10 @@ class TestZeroRecompileChunked:
             "prefill must serve every prompt length with the one "
             "fixed-shape executable")
         assert eng._prefill_chunk._cache_size() == 1
-        # The paged engine's private prefix cache restores by page-table
-        # aliasing on the host — it compiles NO restore program (steady
-        # state is two warm executables). The dense engine (and a paged
-        # engine sharing an external cache) still pins the third.
-        if eng._restore_prefix is not None:
-            assert eng._restore_prefix._cache_size() == 1
+        # The private prefix cache restores by page-table aliasing on the
+        # host — it compiles NO restore program (steady state is two warm
+        # executables); only an external cache pins a third.
+        assert eng._restore_prefix is None
         assert eng._decode._cache_size() == 1
 
 
@@ -905,3 +899,28 @@ class TestConcurrentSubmit:
                 assert list(full[0, S:]) == [int(t) for t in r.tokens]
         finally:
             eng.shutdown(drain=False)
+
+
+@pytest.mark.parametrize("option", ["paged=False", "async_ticks=False",
+                                    "prefill_chunk=None", "serve --no-paged"])
+def test_removed_engine_options_are_refused(option, tiny):
+    """The engine has one KV layout (the page pool), one prefill (chunked)
+    and one tick loop (one tick ahead): the options that used to select
+    the others are gone, not ignored."""
+    _, m, params = tiny
+    kw = dict(max_slots=1, max_len=16, autostart=False)
+    if option == "serve --no-paged":
+        from accelerate_tpu.commands.serve import serve_command_parser
+
+        parser = serve_command_parser()
+        parser.parse_args(["--model", "tiny"])  # the flag alone is refused
+        with pytest.raises(SystemExit) as exc:
+            parser.parse_args(["--model", "tiny", "--no-paged"])
+        assert exc.value.code == 2
+    elif option == "prefill_chunk=None":
+        with pytest.raises(ValueError, match="only prefill"):
+            ServingEngine(m, params, prefill_chunk=None, **kw)
+    else:
+        name = option.split("=")[0]
+        with pytest.raises(TypeError, match=name):
+            ServingEngine(m, params, **{name: False}, **kw)

@@ -2,14 +2,13 @@
 
 The acceptance-critical properties pinned here:
 
-* ASYNC == SYNC — with ``async_ticks=True`` (the default) the engine
-  dispatches tick N+1 before reconciling tick N against a speculative
-  membership snapshot; the streams must stay BIT-IDENTICAL to the
-  ``async_ticks=False`` engine (and to offline ``generation.generate``)
-  across the whole serving matrix: greedy, sampled, eos-latched,
-  multi-tenant adapters, dense, paged, draft speculation, and draft-free
-  prompt lookup. A stream that retires at tick N may waste one masked
-  lane at N+1 — never emit a wrong or duplicate token.
+* AHEAD == OFFLINE — the engine dispatches tick N+1 before reconciling
+  tick N against a speculative membership snapshot; the streams must
+  stay BIT-IDENTICAL to offline ``generation.generate`` across the whole
+  serving matrix: greedy, sampled, eos-latched, multi-tenant adapters,
+  draft speculation, and draft-free prompt lookup. A stream that retires
+  at tick N may waste one masked lane at N+1 — never emit a wrong or
+  duplicate token.
 * ZERO RECOMPILES — ahead dispatch reuses the same pinned executables:
   the warm chunk/decode programs serve a staggered prompt-length mix
   with the compile listener silent and the executable counts unchanged.
@@ -45,7 +44,11 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from accelerate_tpu import generation  # noqa: E402
-from accelerate_tpu.adapters import AdapterBank, LoRAConfig  # noqa: E402
+from accelerate_tpu.adapters import (  # noqa: E402
+    AdapterBank,
+    LoRAConfig,
+    merge_adapter,
+)
 from accelerate_tpu.adapters.lora import (  # noqa: E402
     _get_path,
     adapter_module_paths,
@@ -113,41 +116,36 @@ def _run(eng, prompts=PROMPTS, n=24, **kw):
 
 
 class TestAsyncVsSyncExactness:
-    """Every cell: async engine streams == sync-twin streams, token for
-    token. The sync twin (``async_ticks=False``) is the A/B fallback the
-    issue requires — constructing both here keeps it load-bearing."""
+    """Every cell: the streams of the one-tick-ahead loop == offline
+    ``generate`` (the reference that stays), token for token, under
+    staggered arrivals."""
 
     N = 24
     BASE = dict(max_slots=3, max_len=64, eos_token_id=EOS)
 
     def _pair(self, m, params, engine_kw=None, submit_kw=None,
               prompts=PROMPTS, n=N):
-        engine_kw = dict(self.BASE, **(engine_kw or {}))
-        submit_kw = submit_kw or {}
-        ea = ServingEngine(m, params, **engine_kw)  # async_ticks default
-        es = ServingEngine(m, params, async_ticks=False, **engine_kw)
-        assert ea._async and not es._async
+        eng = ServingEngine(m, params, **dict(self.BASE, **(engine_kw or {})))
         try:
-            a = _run(ea, prompts=prompts, n=n, **submit_kw)
-            b = _run(es, prompts=prompts, n=n, **submit_kw)
+            out = _run(eng, prompts=prompts, n=n, **(submit_kw or {}))
+            stats = eng.stats.summary()
         finally:
-            ea.shutdown(drain=False)
-            es.shutdown(drain=False)
-        for x, y in zip(a, b):
-            assert np.array_equal(x, y), (x, y)
-        return a
+            eng.shutdown(drain=False)
+        return out, stats
 
     def test_greedy_dense(self, tiny):
+        """The default constructor: 256-token chunks clamped to
+        ``max_len``, the private prefix cache on."""
         _, m, params = tiny
-        a = self._pair(m, params, engine_kw=dict(paged=False))
+        a, _ = self._pair(m, params)
         refs = [_offline(m, params, p, self.N) for p in PROMPTS]
         for got, ref in zip(a, refs):
             _assert_matches_offline(got, ref, self.N)
 
     def test_greedy_paged_chunked(self, tiny):
         _, m, params = tiny
-        a = self._pair(m, params,
-                       engine_kw=dict(prefill_chunk=8, prefix_cache_mb=0.0))
+        a, _ = self._pair(m, params,
+                          engine_kw=dict(prefill_chunk=8, prefix_cache_mb=0.0))
         refs = [_offline(m, params, p, self.N) for p in PROMPTS]
         for got, ref in zip(a, refs):
             _assert_matches_offline(got, ref, self.N)
@@ -155,12 +153,12 @@ class TestAsyncVsSyncExactness:
     def test_sampled_seeded(self, tiny):
         """Sampled streams consume one rng split per slot per tick; the
         ahead tick replays the same splits, so a fixed seed must stay
-        bit-identical to the sync twin AND offline."""
+        bit-identical to offline."""
         _, m, params = tiny
-        a = self._pair(m, params,
-                       engine_kw=dict(do_sample=True, temperature=0.9,
-                                      top_k=50, paged=False),
-                       submit_kw=dict(seed=3))
+        a, _ = self._pair(m, params,
+                          engine_kw=dict(do_sample=True, temperature=0.9,
+                                         top_k=50),
+                          submit_kw=dict(seed=3))
         refs = [_offline(m, params, p, self.N, seed=3, do_sample=True,
                          temperature=0.9, top_k=50) for p in PROMPTS]
         for got, ref in zip(a, refs):
@@ -171,77 +169,59 @@ class TestAsyncVsSyncExactness:
         discarded host-side: no token may follow the eos latch."""
         _, m, params = tiny
         n = 48  # long enough for the tiny model to hit eos organically
-        a = self._pair(m, params, engine_kw=dict(paged=False), n=n)
+        a, _ = self._pair(m, params, n=n)
         refs = [_offline(m, params, p, n) for p in PROMPTS]
         for got, ref in zip(a, refs):
             _assert_matches_offline(got, ref, n)
 
     def test_adapters(self, tiny):
+        """Tenant and base traffic in one batch: each stream matches
+        offline generate under its tenant's MERGED weights."""
         _, m, params = tiny
         ad = _nonzero_adapter(params, rank=4, seed=5)
-        banks = []
-        for _ in range(2):
-            bank = AdapterBank(params, config=LoRAConfig(rank=4),
-                               max_adapters=3)
-            bank.register("a", ad)
-            banks.append(bank)
-        kw = dict(self.BASE, prefill_chunk=8)
-        ea = ServingEngine(m, params, adapters=banks[0], **kw)
-        es = ServingEngine(m, params, adapters=banks[1], async_ticks=False,
-                           **kw)
+        bank = AdapterBank(params, config=LoRAConfig(rank=4), max_adapters=3)
+        bank.register("a", ad)
+        eng = ServingEngine(m, params, adapters=bank,
+                            **dict(self.BASE, prefill_chunk=8))
         try:
-            a = _run(ea, adapter="a") + _run(ea)  # tenant + base traffic
-            b = _run(es, adapter="a") + _run(es)
+            a = _run(eng, adapter="a") + _run(eng)  # tenant + base traffic
         finally:
-            ea.shutdown(drain=False)
-            es.shutdown(drain=False)
-        for x, y in zip(a, b):
-            assert np.array_equal(x, y), (x, y)
+            eng.shutdown(drain=False)
+        merged = merge_adapter(params, ad)
+        refs = ([_offline(m, merged, p, self.N) for p in PROMPTS]
+                + [_offline(m, params, p, self.N) for p in PROMPTS])
+        for got, ref in zip(a, refs):
+            _assert_matches_offline(got, ref, self.N)
 
     def test_spec_draft(self, tiny):
         """One-tick-ahead speculative dispatch passes a STALE per-slot
         ``remaining`` budget (safe: stale >= true, and the host commit
         loop enforces the true budget); streams must not notice."""
         _, m, params = tiny
-        ea = None
-        kw = dict(self.BASE, prefill_chunk=8, prefix_cache_mb=0.0,
-                  draft_model=m, draft_params=params, spec_tokens=4)
-        ea = ServingEngine(m, params, **kw)
-        es = ServingEngine(m, params, async_ticks=False, **kw)
-        try:
-            a = _run(ea)
-            b = _run(es)
-            assert ea.stats.summary()["spec_ticks"] > 0
-        finally:
-            ea.shutdown(drain=False)
-            es.shutdown(drain=False)
-        for x, y in zip(a, b):
-            assert np.array_equal(x, y), (x, y)
+        a, stats = self._pair(
+            m, params,
+            engine_kw=dict(prefill_chunk=8, prefix_cache_mb=0.0,
+                           draft_model=m, draft_params=params, spec_tokens=4))
+        assert stats["spec_ticks"] > 0
         refs = [_offline(m, params, p, self.N) for p in PROMPTS]
         for got, ref in zip(a, refs):
             _assert_matches_offline(got, ref, self.N)
 
     def test_spec_lookup(self, tiny):
         """Draft-free prompt-lookup proposals are built from the HOST
-        token state, which is one tick stale under ahead dispatch —
-        proposals steer acceptance, never the emitted law, so streams
-        stay exact."""
+        token state — proposals steer acceptance, never the emitted law,
+        so greedy streams stay exact."""
         _, m, params = tiny
         # Repetitive prompts so lookup actually proposes.
         prompts = [np.tile(p, (1, 3)) for p in PROMPTS[:3]]
-        kw = dict(self.BASE, prefill_chunk=8, prefix_cache_mb=0.0,
-                  spec_lookup=3)
-        ea = ServingEngine(m, params, **kw)
-        es = ServingEngine(m, params, async_ticks=False, **kw)
-        try:
-            a = _run(ea, prompts=prompts)
-            b = _run(es, prompts=prompts)
-            assert ea.stats.summary()["spec_ticks"] > 0
-        finally:
-            ea.shutdown(drain=False)
-            es.shutdown(drain=False)
-        for x, y in zip(a, b):
-            assert np.array_equal(x, y), (x, y)
+        a, stats = self._pair(
+            m, params, prompts=prompts,
+            engine_kw=dict(prefill_chunk=8, prefix_cache_mb=0.0,
+                           spec_lookup=3))
+        assert stats["spec_ticks"] > 0
+        refs = [_offline(m, params, p, self.N) for p in prompts]
+        for got, ref in zip(a, refs):
+            _assert_matches_offline(got, ref, self.N)
 
 
 class TestAsyncZeroRecompile:
@@ -249,12 +229,11 @@ class TestAsyncZeroRecompile:
         """The speculative membership mask and pre-covered page table of
         the ahead tick are DATA — after warmup a staggered prompt-length
         mix must run through the same warm executables with the compile
-        listener silent, exactly like the sync engine."""
+        listener silent."""
         _, m, params = tiny
         eng = ServingEngine(m, params, max_slots=3, max_len=64,
                             eos_token_id=EOS, prefill_chunk=8,
                             prefix_cache_mb=0.0)
-        assert eng._async
         rng = np.random.default_rng(11)
         long = rng.integers(0, 256, size=(1, 29)).astype(np.int32)
         try:
@@ -274,6 +253,71 @@ class TestAsyncZeroRecompile:
         assert eng._decode._cache_size() == 1
 
 
+    def test_next_tick_launches_before_the_last_commits(self, tiny):
+        """One tick ahead, as an order of the loop's own phase records:
+        in steady decode ``tick_launch`` of tick N+1 begins before
+        ``tick_commit`` of tick N begins, so a tick's commit runs under
+        the next tick's device time (the i-th commit has at least i + 1
+        launches before it, where a dispatch-wait-commit loop has i)."""
+        _, m, params = tiny
+        eng = ServingEngine(m, params, max_slots=2, max_len=64,
+                            eos_token_id=EOS)
+        n = 16
+        try:
+            got = eng.submit(PROMPTS[0], max_new_tokens=n,
+                             ignore_eos=True).result(timeout=120)
+        finally:
+            eng.shutdown(drain=True)  # the last commit's record is closed
+        assert len(got) == n
+        phase = {"tick_launch": [], "tick_commit": []}
+        for _, t0, _, name, cat, _, _ in eng.trace_events():
+            if cat == "phase" and name in phase:
+                phase[name].append(t0)
+        launches, commits = sorted(phase["tick_launch"]), sorted(
+            phase["tick_commit"])
+        assert len(commits) == n - 1, commits  # the prefill emits token 1
+        for i, c in enumerate(commits, start=1):
+            ahead = sum(t < c for t in launches)
+            assert ahead >= i + 1, (
+                f"commit {i} began after {ahead} launches: tick {i + 1} "
+                "was not dispatched before tick {i} was settled")
+
+
+    @pytest.mark.parametrize("mode", ["plain", "draft"])
+    def test_a_two_token_request_is_not_held_back_by_the_tick_in_flight(
+            self, tiny, mode):
+        """The ahead dispatch leaves out a stream one token short of its
+        budget only if that stream is IN the in-flight tick (it retires
+        there). A request fresh from prefill with one token left is not:
+        it must get its tick while the other streams keep decoding, not
+        when the batch next drains."""
+        _, m, params = tiny
+        spec = (dict(draft_model=m, draft_params=params, spec_tokens=4)
+                if mode == "draft" else {})
+        eng = ServingEngine(m, params, max_slots=2, max_len=128,
+                            eos_token_id=EOS, prefill_chunk=8,
+                            prefix_cache_mb=0.0, **spec)
+        stamps = []
+        try:
+            stream = eng.submit(
+                PROMPTS[0], max_new_tokens=100, ignore_eos=True,
+                on_token=lambda t: stamps.append(time.monotonic()))
+            deadline = time.monotonic() + 60
+            while len(stamps) < 3:
+                assert time.monotonic() < deadline, "stream never decoded"
+                time.sleep(0.001)
+            short = eng.submit(PROMPTS[1], max_new_tokens=2, ignore_eos=True)
+            assert short.wait(120) and stream.wait(120)
+        finally:
+            eng.shutdown(drain=False)
+        assert len(short.tokens) == 2
+        after = sum(t > short.finished_at for t in stamps)
+        assert after >= 10, (
+            f"the stream emitted only {after} of its 100 tokens after the "
+            "two-token request finished: that request waited for the "
+            "in-flight pipeline to drain")
+
+
 class TestAsyncPreemption:
     def test_pool_exhaustion_under_flight_is_token_exact(self, tiny):
         """Preemption fires while a speculatively-dispatched tick is in
@@ -284,7 +328,6 @@ class TestAsyncPreemption:
         eng = ServingEngine(m, params, max_slots=2, max_len=64,
                             eos_token_id=EOS, prefill_chunk=8,
                             prefix_cache_mb=0.0, max_pages=10)
-        assert eng._async
         n = 40
         try:
             refs = [_offline(m, params, p, n, eos=None)
@@ -414,18 +457,6 @@ class TestHostTickMetric:
                         if e["kind"] == "tick_profile"]
             assert profiles, "no tick_profile event in the flight recorder"
             assert all("host_us" in e and "itl_ms" in e for e in profiles)
-        finally:
-            eng.shutdown(drain=False)
-
-    def test_sync_fallback_reports_metric_too(self, tiny):
-        """The A/B story needs the same metric from ``async_ticks=False``
-        so the two modes are comparable on one dashboard."""
-        _, m, params = tiny
-        eng = ServingEngine(m, params, max_slots=2, max_len=64,
-                            eos_token_id=EOS, async_ticks=False)
-        try:
-            _run(eng, prompts=PROMPTS[:2], n=12)
-            assert eng.stats.summary()["host_us_per_tick"] > 0.0
         finally:
             eng.shutdown(drain=False)
 

@@ -210,26 +210,16 @@ class TestTokenParity:
             e2.shutdown(drain=False)
 
     def test_async_matches_sync_at_tp2(self, tiny, tp2_engine):
-        """The async host runtime's one-tick-ahead dispatch must stay
-        bit-exact when the tick is a GSPMD-sliced executable: the shared
-        tp=2 engine (async by default) against an ``async_ticks=False``
-        twin over staggered mixed-length traffic."""
+        """One-tick-ahead dispatch must stay bit-exact when the tick is
+        a GSPMD-sliced executable: the shared tp=2 engine against
+        offline generate over mixed-length traffic submitted at once."""
         _, m, params = tiny
-        assert tp2_engine._async
-        es = ServingEngine(m, params, tp=2, max_slots=3, max_len=64,
-                           eos_token_id=EOS, prefill_chunk=8,
-                           async_ticks=False)
         n = 16
-        try:
-            prompts = PROMPTS + [LONG_PROMPT]
-            ra = [tp2_engine.submit(p, max_new_tokens=n) for p in prompts]
-            rb = [es.submit(p, max_new_tokens=n) for p in prompts]
-            for a, b in zip(ra, rb):
-                ga = np.asarray(a.result(120))
-                gb = np.asarray(b.result(120))
-                assert np.array_equal(ga, gb), (ga, gb)
-        finally:
-            es.shutdown(drain=False)
+        prompts = PROMPTS + [LONG_PROMPT]
+        reqs = [tp2_engine.submit(p, max_new_tokens=n) for p in prompts]
+        for r, p in zip(reqs, prompts):
+            _assert_matches_offline(r.result(120), _offline(m, params, p, n),
+                                    n)
 
     def test_multi_tenant_adapters_match(self, tiny):
         """Adapter and base streams through bank-equipped engines: tp=2
@@ -551,7 +541,7 @@ class TestMeshPreparedModels:
 
     def test_monolithic_prefill_rejected_under_tp(self, tiny):
         _, m, params = tiny
-        with pytest.raises(NotImplementedError, match="single-chip"):
+        with pytest.raises(ValueError, match="only prefill"):
             ServingEngine(m, params, tp=2, max_slots=1, max_len=32,
                           prefill_chunk=None, autostart=False)
 

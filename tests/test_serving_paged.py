@@ -2,11 +2,10 @@
 
 The acceptance-critical properties pinned here:
 
-* PAGED == DENSE — the paged engine changes WHERE KV rows live (a global
-  page pool indexed through a per-slot page table), never what is read
-  or written: every cell of the greedy/sampled/eos/adapter/failover
-  matrix must be token-identical to the dense engine and to offline
-  ``generation.generate``.
+* PAGED == OFFLINE — paging decides WHERE KV rows live (a global page
+  pool indexed through a per-slot page table), never what is read or
+  written: every cell of the greedy/sampled/eos/adapter/failover matrix
+  must be token-identical to offline ``generation.generate``.
 * ZERO RECOMPILES — page allocation, frees, preemption and prefix
   aliasing are HOST work (the table is traced integer data), so a
   warmed paged engine serves a staggered prompt-length mix with the
@@ -100,8 +99,8 @@ def _nonzero_adapter(params, rank, seed):
 
 
 class TestPagedVsDenseExactness:
-    """Greedy and sampled streams from the paged engine must be
-    bit-identical to the dense (``paged=False``) engine and offline."""
+    """Greedy and sampled streams served out of the page pool must be
+    bit-identical to offline generate."""
 
     N = 24
 
@@ -110,9 +109,7 @@ class TestPagedVsDenseExactness:
         _, m, params = tiny
         kw = dict(max_slots=3, max_len=64, eos_token_id=EOS,
                   prefill_chunk=8, prefix_cache_mb=0.0)
-        engs = {"paged": ServingEngine(m, params, **kw),  # paged=None -> True
-                "dense": ServingEngine(m, params, paged=False, **kw)}
-        assert engs["paged"].paged and not engs["dense"].paged
+        engs = {"paged": ServingEngine(m, params, **kw)}
         yield engs
         for e in engs.values():
             if e.running:
@@ -122,16 +119,13 @@ class TestPagedVsDenseExactness:
     def test_matrix_matches_dense_and_offline(self, tiny, engines, seed):
         _, m, params = tiny
         refs = [_offline(m, params, p, self.N, seed=seed) for p in PROMPTS]
-        outs = {}
-        for name, eng in engines.items():
-            reqs = []
-            for p in PROMPTS:  # staggered: joins exercise the page table
-                reqs.append(eng.submit(p, max_new_tokens=self.N, seed=seed))
-                time.sleep(0.01)
-            outs[name] = [np.asarray(r.result(timeout=120)) for r in reqs]
-        for got_p, got_d, ref in zip(outs["paged"], outs["dense"], refs):
-            assert np.array_equal(got_p, got_d), (got_p, got_d)
-            _assert_matches_offline(got_p, ref, self.N)
+        reqs = []
+        for p in PROMPTS:  # staggered: joins exercise the page table
+            reqs.append(engines["paged"].submit(p, max_new_tokens=self.N,
+                                                seed=seed))
+            time.sleep(0.01)
+        for r, ref in zip(reqs, refs):
+            _assert_matches_offline(r.result(timeout=120), ref, self.N)
 
     def test_eos_latch_paged(self, tiny, engines):
         """A stream that hits EOS mid-flight stops exactly where offline
@@ -158,7 +152,6 @@ class TestPagedVsDenseExactness:
         bank.register("a", ad)
         eng = ServingEngine(m, params, max_slots=2, max_len=64,
                             eos_token_id=EOS, prefill_chunk=8, adapters=bank)
-        assert eng.paged
         try:
             n = 16
             refs = {"a": merge_adapter(params, ad), None: params}
@@ -181,7 +174,6 @@ class TestPagedVsDenseExactness:
         rs = ReplicaSet.from_factory(
             lambda: ServingEngine(sleepy, params, max_slots=4, max_len=64,
                                   eos_token_id=EOS, prefill_chunk=16), 2)
-        assert all(r.engine.paged for r in rs._replicas)
         n = 24
         refs = [_offline(sleepy, params, p, n) for p in PROMPTS]
         try:
@@ -402,9 +394,6 @@ class TestSpeculativeDecoding:
         is this PR's point) and must NOT raise."""
         _, m, params = tiny
         spec = dict(draft_model=m, draft_params=params)
-        with pytest.raises(NotImplementedError, match="paged"):
-            ServingEngine(m, params, paged=False, prefill_chunk=8,
-                          autostart=False, warmup=False, **spec)
         with pytest.raises(ValueError, match="spec_tokens"):
             ServingEngine(m, params, prefill_chunk=8, spec_tokens=0,
                           autostart=False, warmup=False, **spec)
@@ -591,14 +580,17 @@ class TestPagedValidation:
     def test_constructor_combos(self, tiny):
         _, m, params = tiny
         with pytest.raises(ValueError, match="chunked prefill"):
-            ServingEngine(m, params, paged=True, prefill_chunk=None,
+            ServingEngine(m, params, prefill_chunk=None,
+                          autostart=False, warmup=False)
+        with pytest.raises(ValueError, match="prefill_chunk"):
+            ServingEngine(m, params, prefill_chunk=0,
                           autostart=False, warmup=False)
         with pytest.raises(ValueError, match="divide"):
             ServingEngine(m, params, prefill_chunk=8, page_size=3,
                           autostart=False, warmup=False)
-        with pytest.raises(ValueError, match="paged"):
-            ServingEngine(m, params, paged=False, prefill_chunk=8,
-                          page_size=8, autostart=False, warmup=False)
+        with pytest.raises(ValueError, match="divide"):
+            ServingEngine(m, params, prefill_chunk=8, page_size=0,
+                          autostart=False, warmup=False)
         with pytest.raises(ValueError, match="max_pages"):
             ServingEngine(m, params, prefill_chunk=8, max_pages=0,
                           autostart=False, warmup=False)

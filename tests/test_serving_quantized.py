@@ -82,7 +82,7 @@ class TestOffMeansOff:
     def test_fp_paged_engine_bit_exact_vs_offline(self, tiny):
         _, m, params = tiny
         eng = ServingEngine(m, params, **BASE)
-        assert eng.paged and eng.kv_dtype is None and eng.weights_dtype is None
+        assert eng.kv_dtype is None and eng.weights_dtype is None
         try:
             for toks, p in zip(_run(eng), PROMPTS):
                 assert np.array_equal(toks, _offline(m, params, p, 12)), (
@@ -232,8 +232,3 @@ class TestValidation:
             ServingEngine(m, params, kv_dtype="int4", **BASE)
         with pytest.raises(ValueError, match="weights_dtype"):
             ServingEngine(m, params, weights_dtype="fp8", **BASE)
-
-    def test_kv_dtype_requires_paged(self, tiny):
-        _, m, params = tiny
-        with pytest.raises(ValueError, match="paged"):
-            ServingEngine(m, params, kv_dtype="int8", paged=False, **BASE)

@@ -431,13 +431,6 @@ class TestFenceAndRestart:
             assert eng.page_drain_rate() == 0.0  # nothing observed yet
         finally:
             eng.shutdown(drain=False)
-        dense = ServingEngine(m, params, max_slots=1, max_len=16,
-                              eos_token_id=EOS, paged=False)
-        try:
-            assert dense.projected_page_deficit(10_000) == 0
-            assert dense.page_drain_rate() == 0.0
-        finally:
-            dense.shutdown(drain=False)
 
 
 # ---------------------------------------------------------------------
