@@ -26,6 +26,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+import numpy as np
 
 
 @dataclasses.dataclass
@@ -394,21 +395,86 @@ def init_kv_cache(config: "LlamaConfig", batch_size: int, max_len: int, dtype=jn
     return tuple(caches)
 
 
+#: What one key block's float32 scores may take: half of a v5e core's 128 MiB
+#: of VMEM, the largest size at which the compiler was seen to keep them there.
+_SCORE_BLOCK_BYTES = 64 * 2**20
+
+
+def cached_key_block(score_rows: int, L: int) -> int:
+    """Key rows :func:`_cached_attention` scores at a time, from the call's
+    static shape alone: ``score_rows = B * H * S`` float32 scores a key row,
+    against a cache of ``L`` rows. ``L`` means one block — the whole view in
+    one pass, which is what every single-token decode, every speculative
+    verify and every chunk against a short cache gets.
+
+    The rule: the widest power of two (from 128, the lane width) whose
+    block of scores fits ``_SCORE_BLOCK_BYTES``. Scores that fit stay in VMEM
+    between the product that writes them and the two passes that read them;
+    wider, they go through HBM three times, which costs four times as much a
+    key row. v5e readings for 128 heads x 256 queries, ms a block (PERF.md
+    section 6, PR 32): 256 rows 0.049, **512 rows (64 MiB) 0.077**, 1024 rows
+    0.57, 2048 rows 1.27; a block also costs ~0.02 ms whatever its width
+    (rescaling the accumulator), so the widest that fits wins."""
+    block = 128
+    while 2 * block * score_rows * 4 <= _SCORE_BLOCK_BYTES:
+        block *= 2
+    return min(block, L)
+
+
+def cached_key_extent(cache_pos, S: int, L: int, block: int, sliding_window=None,
+                      lib=jnp):
+    """Key blocks ``[first, last)`` of width ``block`` that a query block of
+    ``S`` positions at ``cache_pos`` can see in a cache of ``L`` rows: keys
+    up to ``cache_pos + S - 1``, from ``cache_pos - sliding_window + 1`` on a
+    windowed layer. ``cache_pos`` may be traced (``lib=jnp``); the serving
+    engine counts the same blocks on the host (``lib=np``)."""
+    last = lib.minimum((cache_pos + S + block - 1) // block, -(-L // block))
+    if sliding_window is None:
+        return 0, last
+    return lib.maximum(cache_pos - sliding_window + 1, 0) // block, last
+
+
+def cached_attention_rows(cache_pos: int, S: int, L: int, score_rows: int,
+                          sliding_window=None):
+    """Host arithmetic for the serving counters: ``(scored, visible)`` key
+    rows of one layer's :func:`_cached_attention` call — the rows the program
+    scores under :func:`cached_key_block`'s rule, and the rows some query of
+    the block can see."""
+    block = cached_key_block(score_rows, L)
+    first, last = cached_key_extent(cache_pos, S, L, block, sliding_window, lib=np)
+    lo = 0 if sliding_window is None else max(cache_pos - sliding_window + 1, 0)
+    return int(last - first) * block, min(cache_pos + S, L) - lo
+
+
 def _cached_attention(q, k_all, v_all, cache_pos, n_rep: int, sliding_window=None,
                       sm_scale=None, logit_softcap=None, alibi_slopes=None):
-    """Attention of q [B, S, H, hd] against the full cache [B, L, n_kv, hd].
+    """Attention of q [B, S, H, hd] against the linear cache [B, L, n_kv, hd].
 
     Valid keys are those at global index <= cache_pos + (local query index):
     one mask expression covers both prefill (S = prompt, cache_pos = 0, the
     ordinary causal triangle) and decode (S = 1, cache_pos = t, attend to
-    everything written so far). Future cache slots hold zeros and are masked.
+    everything written so far).
+
+    Where :func:`cached_key_block` gives one block (decode, speculative
+    verify, short caches) every row of the cache is scored and the mask
+    discards what no query sees (future slots hold zeros or a previous
+    occupant's rows). Else — a prefill chunk against a long view — only the
+    key blocks :func:`cached_key_extent` names are read at all
+    (:func:`_bounded_cached_attention`): the cost follows the rows the
+    queries can see, not L. Same mathematics: a row outside the extent is one
+    whose softmax weight the mask makes exactly 0.
 
     GQA is a *grouped* einsum — queries reshape to [B, S, n_kv, rep, hd] and
     contract directly against the unrepeated cache, so per-token HBM traffic
     scales with n_kv, never with a materialized n_q-wide K/V copy.
     """
-    B, S, _, _ = q.shape
+    B, S, H, _ = q.shape
     L = k_all.shape[1]
+    block = cached_key_block(B * H * S, L)
+    if block < L:
+        return _bounded_cached_attention(
+            q, k_all, v_all, cache_pos, n_rep, block, sliding_window=sliding_window,
+            sm_scale=sm_scale, logit_softcap=logit_softcap, alibi_slopes=alibi_slopes)
     q_pos = cache_pos + jnp.arange(S, dtype=jnp.int32)
     k_pos = jnp.arange(L, dtype=jnp.int32)[None, :]
     mask = k_pos <= q_pos[:, None]
@@ -417,6 +483,59 @@ def _cached_attention(q, k_all, v_all, cache_pos, n_rep: int, sliding_window=Non
     return _grouped_cached_attention(q, k_all, v_all, mask[None], n_rep,
                                      sm_scale=sm_scale, logit_softcap=logit_softcap,
                                      alibi_slopes=alibi_slopes, k_positions=k_pos[0])
+
+
+def _bounded_cached_attention(q, k_all, v_all, cache_pos, n_rep: int, block: int,
+                              sliding_window=None, sm_scale=None, logit_softcap=None,
+                              alibi_slopes=None):
+    """:func:`_cached_attention` over the key blocks the queries can see: a
+    loop whose trip count follows ``cache_pos`` (traced: one program for every
+    offset; forward only), each step scoring ``block`` key rows in float32 as
+    :func:`_grouped_cached_attention` scores all of them, under a running
+    maximum and a running sum. A block that overhangs ``L`` is pulled back
+    inside and the rows the step before it scored are masked."""
+    from ..ops.attention import softcap_logits
+
+    B, S, H, hd = q.shape
+    L = k_all.shape[1]
+    G = H // n_rep
+    scale = hd**-0.5 if sm_scale is None else sm_scale
+    qg = (q * scale).astype(jnp.float32).reshape(B, S, G, n_rep, hd)
+    q_pos = (cache_pos + jnp.arange(S, dtype=jnp.int32))[:, None]
+    first, last = cached_key_extent(cache_pos, S, L, block, sliding_window)
+    if alibi_slopes is not None:
+        slopes = alibi_slopes.astype(jnp.float32).reshape(G, n_rep)[None, :, :, None, None]
+
+    def one_block(j, carry):
+        m, l, acc = carry
+        start = jnp.minimum(j * block, L - block)
+        k_blk = jax.lax.dynamic_slice_in_dim(k_all, start, block, axis=1)
+        v_blk = jax.lax.dynamic_slice_in_dim(v_all, start, block, axis=1)
+        k_pos = (start + jnp.arange(block, dtype=jnp.int32))[None, :]
+        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_blk.astype(jnp.float32))
+        logits = softcap_logits(logits, logit_softcap)
+        if alibi_slopes is not None:
+            logits = logits + slopes * k_pos.astype(jnp.float32)
+        mask = (k_pos >= j * block) & (k_pos <= q_pos)
+        if sliding_window is not None:
+            mask &= k_pos > q_pos - sliding_window
+        logits = jnp.where(mask, logits, -1e30)
+        # A row wholly masked so far carries weight exp(0) per masked key;
+        # the first visible key (its own, at the latest) rescales that by
+        # exp(-1e30 - m) = 0 exactly.
+        m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(logits - m_new)
+        l = l * alpha + p.sum(-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum("bgrqk,bkgd->bgrqd", p, v_blk.astype(jnp.float32))
+        return m_new, l, acc
+
+    stat = jnp.zeros((B, G, n_rep, S, 1), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        first, last, one_block,
+        (stat - 1e30, stat, jnp.zeros((B, G, n_rep, S, hd), jnp.float32)))
+    out = jnp.moveaxis(acc / l, 3, 1)                            # [B, S, G, rep, hd]
+    return out.reshape(B, S, H, hd).astype(q.dtype)
 
 
 def _ring_cached_attention(q, cache, cache_pos, n_rep: int, window: int,
@@ -472,7 +591,11 @@ def _grouped_cached_attention(q, k_all, v_all, mask, n_rep: int,
 def update_kv_cache_and_attend(cache, q, k, v, cache_pos, n_rep: int, sliding_window=None,
                                sm_scale=None, logit_softcap=None, alibi_slopes=None):
     """Write this call's K/V into the cache at ``cache_pos`` and attend q
-    against the whole buffer. Shared by every cached attention (Llama, GPT-2).
+    against what it can see of the buffer: on a linear cache the rows up to
+    the last query's own, from the window's start on a windowed layer
+    (:func:`_cached_attention` — every row under the mask where the call is
+    one key block, only the visible key blocks where it is several). Shared
+    by every cached attention (Llama, GPT-2, ...).
     Returns (out [B,S,H,hd], new_cache).
 
     Ring caches (``"pos"`` present — sliding-window layers) write slot

@@ -51,7 +51,11 @@ previous occupant's entries (and the tail chunk writes edge-pad KV past
 is: the attention mask attends ``k_pos <= q_pos`` only, and masking is
 REPLACEMENT (``jnp.where(mask, logits, -1e30)``), so a masked garbage
 key contributes exactly 0 probability — finite garbage KV never changes
-a real row's output. Positions at/past ``true_len`` are overwritten by
+a real row's output. A chunk against a long view does not even read it:
+``models.llama._cached_attention`` scores only the key blocks up to the
+chunk's last query (``hi = offset + C``, rounded out to a block), so the
+mask meets garbage only inside the last block and a tick's one-block
+pass. Positions at/past ``true_len`` are overwritten by
 the first decode write at-or-before the first query that could attend
 them. A tick scatters an inactive or ``PREFILLING`` slot's write to the
 scratch page, and every chunk and restore call writes ``pos[slot] =
@@ -159,6 +163,7 @@ from ..generation import (
     speculative_emit,
 )
 from ..inference import resolve_model_source
+from ..models.llama import cached_attention_rows
 from ..observability import FlightRecorder, Tracer, new_trace_id
 from .metrics import ServingStats
 from .request import Request, RequestStatus
@@ -743,10 +748,15 @@ class ServingEngine:
         self._page_window = (
             int(next(iter(kinds))) if len(kinds) == 1 and None not in kinds
             else None)
+        #: (window or None, layers) per layer kind, and the query heads (one
+        #: float32 score a head, a query and a key row): what the
+        #: prefill_attn_rows_* counters need of the chunk's shape.
+        windows = self._layer_windows or [None] * len(slot_shape)
+        self._attn_layer_kinds = [(w, windows.count(w)) for w in set(windows)]
+        self._attn_score_heads = getattr(cfg, "num_attention_heads", None)
         #: (window, layers) pairs for the kv_dead_rows_share counter.
         self._dead_row_windows = [
-            (int(w), self._layer_windows.count(w)) for w in kinds
-            if w is not None]
+            (int(w), n) for w, n in self._attn_layer_kinds if w is not None]
         #: the variable collection a module sows per-call counters into
         #: (models/cohere2_moe.py, models/mixtral.py: MoE pick counts); the
         #: programs ask for it and return its sum packed behind the tokens.
@@ -1274,9 +1284,12 @@ class ServingEngine:
         """ONE chunk of prefill: ids_c ``[1, C]`` (tail chunks edge-padded
         on the host); slot/offset/true_len traced i32 scalars, ``pages``
         the slot's table row. Gathers the slot's pages into a dense view,
-        runs the chunk at ``cache_pos=offset`` (garbage left in a page by a
-        previous occupant is masked out by construction, see the module
-        docstring), selects a candidate first token via the shared
+        runs the chunk at ``cache_pos=offset`` (attention never reads the
+        view past ``hi = offset + C`` rounded out to a key block, nor
+        before a windowed layer's ``offset - window + 1``: the trip count
+        of one loop, so every offset is this one executable; what a
+        previous occupant left inside the last block is masked, see the
+        module docstring), selects a candidate first token via the shared
         epilogue (real only in the chunk containing ``true_len - 1``),
         scatters back only the pages the chunk wrote, and writes the slot
         rows — ``pos[slot] = true_len`` on EVERY call. Returns ``(state,
@@ -2827,6 +2840,7 @@ class ServingEngine:
                 # counters behind it): the copy starts when the chunk ends,
                 # not when a commit asks for it
                 tok.copy_to_host_async()
+            attn_rows = self._chunk_attn_rows(offset)
             phases.launched()
         with phases.prefill_wait:
             tok.block_until_ready()  # honest chunk timing, paced dispatch
@@ -2835,10 +2849,27 @@ class ServingEngine:
         # queued behind) — excluded from host_us_per_tick.
         self._blocked_s += phases.prefill_wait.last_s
         with phases.prefill_commit:
-            self._commit_chunk(req, i, offset, final, tok, block, t0)
+            self._commit_chunk(req, i, offset, final, tok, block, t0,
+                               attn_rows)
+
+    def _chunk_attn_rows(self, offset: int) -> Optional[tuple]:
+        """``(scored, visible, view)`` key rows of the target model's
+        attention in one chunk at ``offset``, summed over its layers: the
+        program's own rule (``models.llama.cached_key_block`` and
+        ``cached_key_extent``) applied to the chunk's static shape on the
+        host — no device read."""
+        if self._attn_score_heads is None:
+            return None
+        C, L = self._chunk, self._pages_per_slot * self._page
+        scored = visible = layers = 0
+        for window, n in self._attn_layer_kinds:
+            s, v = cached_attention_rows(
+                offset, C, L, self._attn_score_heads * C, window)
+            scored, visible, layers = scored + n * s, visible + n * v, layers + n
+        return scored, visible, layers * L
 
     def _commit_chunk(self, req: Request, i: int, offset: int, final: bool,
-                      tok, block, t0: float):
+                      tok, block, t0: float, attn_rows=None):
         """The host side of a finished chunk (phase ``prefill_commit``):
         stats and the request-scoped span, the prefix-cache put, and on
         the final chunk the first-token commit."""
@@ -2859,7 +2890,8 @@ class ServingEngine:
                 tok, counts = rows[-1][0], sum(r[1:] for r in rows)
         self._stats.record_prefill_chunk(dt_ms, backlog=backlog,
                                          host=self._phases.drain(),
-                                         moe_picks=counts)
+                                         moe_picks=counts,
+                                         attn_rows=attn_rows)
         self._tracer.emit(
             "prefill_chunk", t0, dt_ms / 1e3, trace_id=req.trace_id,
             args={"chunk": i, "of": req._chunks_total, "offset": offset,

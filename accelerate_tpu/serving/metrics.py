@@ -179,6 +179,11 @@ class ServingStats:
             self._moe_picks = np.zeros((0,), np.int64)
             self._kv_rows_held = 0
             self._kv_rows_dead = 0
+            # Key rows a prefill chunk's attention scored / rows some query
+            # of the chunk could see / rows of the view, summed over layers.
+            self._attn_rows_scored = 0
+            self._attn_rows_visible = 0
+            self._attn_rows_view = 0
             # Speculative decoding: draft proposals vs target acceptances.
             self._spec_ticks = 0
             self._spec_proposed = 0
@@ -303,15 +308,22 @@ class ServingStats:
             self._emission_stalls += 1
 
     def record_prefill_chunk(self, ms: float, backlog: int = 0,
-                             host: Optional[dict] = None, moe_picks=None):
+                             host: Optional[dict] = None, moe_picks=None,
+                             attn_rows: Optional[tuple] = None):
         """One ``prefill_chunk`` execution; ``backlog`` is the number of
         requests in ``PREFILLING`` at the time of the call (how much
         admission work is still pending behind the per-tick budget);
         ``host`` the phase timings measured since the last record,
-        ``moe_picks`` the chunk's expert picks (as in ``record_tick``)."""
+        ``moe_picks`` the chunk's expert picks (as in ``record_tick``),
+        ``attn_rows`` the ``(scored, visible, view)`` key rows of its
+        attention, summed over the layers."""
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
+            if attn_rows is not None:
+                self._attn_rows_scored += int(attn_rows[0])
+                self._attn_rows_visible += int(attn_rows[1])
+                self._attn_rows_view += int(attn_rows[2])
             self._prefill_chunks += 1
             self._prefill_ms_sum += ms
             self._hists["prefill_chunk_ms"].observe(ms)
@@ -508,7 +520,9 @@ class ServingStats:
                       "_spec_accepted", "_spec_lookup_slots",
                       "_spec_lookup_hits", "_host_us_sum",
                       "_host_us_ticks", "_host_other_us_sum",
-                      "_emission_stalls", "_kv_rows_held", "_kv_rows_dead"):
+                      "_emission_stalls", "_kv_rows_held", "_kv_rows_dead",
+                      "_attn_rows_scored", "_attn_rows_visible",
+                      "_attn_rows_view"):
                 setattr(self, k, getattr(self, k) + o[k])
             for k in ("_queue_wait_ms_max", "_ttft_ms_max",
                       "_prefill_backlog_max", "_host_us_max",
@@ -642,6 +656,17 @@ class ServingStats:
                 "kv_dead_rows_share": round(
                     self._kv_rows_dead / self._kv_rows_held, 6)
                     if self._kv_rows_held else 0.0,
+                # Key rows the prefill chunks' attention scored over the
+                # rows of the views they ran against (1.0: every chunk
+                # scored its whole view, whatever its queries could see),
+                # and the rows some query could see over the rows scored
+                # (what rounding out to key blocks costs).
+                "prefill_attn_rows_share": round(
+                    self._attn_rows_scored / self._attn_rows_view, 6)
+                    if self._attn_rows_view else 0.0,
+                "prefill_attn_rows_fill": round(
+                    self._attn_rows_visible / self._attn_rows_scored, 6)
+                    if self._attn_rows_scored else 0.0,
             }
             # The host path by phase ("host_us/<phase>", slash-pathed like
             # the adapter keys; the gateway re-emits them as one labeled

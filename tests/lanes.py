@@ -52,6 +52,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
     "models": (12, [
         "test_adapters.py",
         "test_big_modeling.py",
+        "test_cached_attention_bounded.py",
         "test_cohere2_moe.py",
         "test_fp8.py",
         "test_generation.py",

@@ -279,25 +279,28 @@ def test_held_must_match_the_stacks():
 
 def test_the_counters_appear_merge_and_reset():
     a, b = ServingStats(), ServingStats()
-    keys = ("moe_held_pick_share", "moe_load_max_over_mean", "moe_tile_fill", "kv_dead_rows_share")
+    keys = ("moe_held_pick_share", "moe_load_max_over_mean", "moe_tile_fill", "kv_dead_rows_share",
+            "prefill_attn_rows_share", "prefill_attn_rows_fill")
     for key in keys:
         assert a.summary()[key] == 0.0
     # per held expert, all picks, rows routed and rows computed in sorted tiles
     a.record_tick(2, 2, 4, 0.01, moe_picks=np.array([3, 1, 32, 0, 0]), kv_rows=(10, 100))
     assert a.summary()["moe_tile_fill"] == 0.0      # ticks alone: nothing went through tiles
-    a.record_prefill_chunk(1.0, moe_picks=np.array([1, 3, 32, 4, 64]))
+    a.record_prefill_chunk(1.0, moe_picks=np.array([1, 3, 32, 4, 64]), attn_rows=(16, 12, 64))
     b.record_tick(2, 2, 4, 0.01, moe_picks=[4, 0, 32, 0, 0], kv_rows=(30, 100))
-    b.record_prefill_chunk(1.0, moe_picks=[0, 0, 0, 20, 32])
+    b.record_prefill_chunk(1.0, moe_picks=[0, 0, 0, 20, 32], attn_rows=(48, 40, 64))
     s = a.summary()
     assert s["moe_held_pick_share"] == pytest.approx(8 / 64)
     assert s["moe_load_max_over_mean"] == pytest.approx(1.0)
     assert s["moe_tile_fill"] == pytest.approx(4 / 64)
     assert s["kv_dead_rows_share"] == pytest.approx(0.1)
+    assert (s["prefill_attn_rows_share"], s["prefill_attn_rows_fill"]) == (0.25, 0.75)
     m = ServingStats().merge(a).merge(b).summary()
     assert m["moe_held_pick_share"] == pytest.approx(12 / 96)
     assert m["moe_load_max_over_mean"] == pytest.approx(8 / 6, abs=1e-4)
     assert m["moe_tile_fill"] == pytest.approx(24 / 96)
     assert m["kv_dead_rows_share"] == pytest.approx(0.2)
+    assert m["prefill_attn_rows_share"] == 0.5 and m["prefill_attn_rows_fill"] == pytest.approx(52 / 64)
     a.reset()
     assert all(a.summary()[key] == 0.0 for key in keys)
 
@@ -322,3 +325,94 @@ def test_a_held_share_behind_the_engine_counts_its_picks_and_no_dead_rows_below_
     # was dispatched ahead of the retirement), each through 4 layers, top-2
     assert len(picks) == 3 and picks[-1] % (4 * 2) == 0 and picks[-1] >= (4 + 3) * 4 * 2
     assert sum(picks[:-1]) <= picks[-1]
+
+
+# -- (h) a chunk's attention over the key blocks its queries can see ----------
+
+BLOCK = 8
+
+
+@pytest.fixture(scope="module")
+def served_in_blocks(tiny):
+    """One engine whose chunk program scores BLOCK key rows at a time: the
+    shape rule (``models.llama.cached_key_block``: 64 MiB of scores a block)
+    gives one block at any toy size, so the test stands in for it — several
+    blocks for a multi-token call, one for a tick's single token, as on the
+    chip. Offline ``generate`` runs before, under the real rule."""
+    from accelerate_tpu import generation
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.utils.profiling import CompileWatcher
+
+    cfg, model, params = tiny
+    prompts = {"below_the_window": 5, "three_chunks": 2 * WINDOW + 3, "past_the_window": 3 * WINDOW + 3}
+    new = WINDOW + 5
+    ids = {name: np.asarray(ids_of(n, seed=20 + n))[None] for name, n in prompts.items()}
+    offline = {name: np.asarray(generation.generate(model, params, jnp.asarray(p),
+                                                    max_new_tokens=new))[0, p.shape[1]:]
+               for name, p in ids.items()}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(llama, "cached_key_block",
+                      lambda rows, view: min(BLOCK, view) if rows > cfg.num_attention_heads else view)
+        eng = ServingEngine(model, params, max_slots=2, max_len=64, prefill_chunk=8, page_size=8)
+        try:
+            served, summaries = {}, {}
+            with CompileWatcher() as watcher:
+                for name, p in ids.items():
+                    eng.stats.reset()
+                    req = eng.submit(p, max_new_tokens=new, ignore_eos=True, block=True)
+                    assert req.wait(180)
+                    served[name], summaries[name] = np.asarray(req.tokens), eng.stats.summary()
+            chunk_text = eng._prefill_chunk.lower(*_chunk_args(eng, ids["below_the_window"])).as_text()
+            return {"served": served, "offline": offline, "summaries": summaries,
+                    "compiles": list(watcher.events), "chunk_text": chunk_text,
+                    "chunk_executables": eng._prefill_chunk._cache_size(),
+                    "decode_executables": eng._decode._cache_size()}
+        finally:
+            eng.shutdown(drain=False)
+
+
+def _chunk_args(eng, ids):
+    return (eng.params, eng._state, np.resize(ids, (1, eng._chunk)), np.int32(0),
+            eng._table[0].copy(), np.int32(0), np.int32(ids.shape[1]), jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("prompt", ["below_the_window", "three_chunks", "past_the_window"])
+def test_chunks_scored_in_blocks_serve_offline_generates_tokens(served_in_blocks, prompt):
+    assert np.array_equal(served_in_blocks["served"][prompt], served_in_blocks["offline"][prompt])
+
+
+def test_the_chunk_program_loops_over_blocks_and_compiles_once_across_offsets(served_in_blocks):
+    assert "while" in served_in_blocks["chunk_text"]
+    assert served_in_blocks["compiles"] == []          # offsets 0, 8, 16, 24: one warm program
+    assert served_in_blocks["chunk_executables"] == 1 and served_in_blocks["decode_executables"] == 1
+
+
+def test_prefill_attn_rows_share_and_fill_equal_a_hand_count(served_in_blocks):
+    """The 19-token prompt: chunks at offsets 0, 8, 16 against views of 64
+    rows; one full layer scores blocks [0, hi), three windowed layers (window
+    8) blocks from (offset - 7) // 8. Rows scored 8+3x8, 16+3x16, 24+3x16;
+    rows visible 8+3x8, 16+3x15, 24+3x15."""
+    s = served_in_blocks["summaries"]["three_chunks"]
+    scored, visible = 32 + 64 + 72, 32 + 61 + 69
+    assert s["prefill_attn_rows_share"] == pytest.approx(scored / (3 * 4 * 64), abs=1e-6)
+    assert s["prefill_attn_rows_fill"] == pytest.approx(visible / scored, abs=1e-6)
+    one = served_in_blocks["summaries"]["below_the_window"]
+    assert one["prefill_attn_rows_share"] == pytest.approx(8 / 64, abs=1e-6)
+    assert one["prefill_attn_rows_fill"] == 1.0
+
+
+def test_a_whole_view_chunk_reads_a_share_of_one(tiny):
+    """Under the real rule a toy chunk is one block: share 1.0 by construction."""
+    cfg, model, params = tiny
+    eng = ServingEngine(model, params, max_slots=2, max_len=32, prefill_chunk=8, page_size=8)
+    try:
+        eng.stats.reset()
+        req = eng.submit(np.asarray(ids_of(11, seed=31))[None], max_new_tokens=2,
+                         ignore_eos=True, block=True)
+        assert req.wait(120)
+        s = eng.stats.summary()
+    finally:
+        eng.shutdown(drain=False)
+    assert s["prefill_attn_rows_share"] == 1.0
+    # offsets 0 and 8 of a 32-row view: a full layer sees 8 and 16 rows, a windowed one 8 and 15
+    assert s["prefill_attn_rows_fill"] == pytest.approx((4 * 8 + 16 + 3 * 15) / (2 * 4 * 32), abs=1e-6)

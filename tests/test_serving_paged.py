@@ -685,3 +685,65 @@ class TestPageAwareRouting:
         finally:
             for e in (plain, spec, lookup):
                 e.shutdown(drain=False)
+
+
+class TestChunkAttentionInBlocks:
+    """A prefill chunk's attention reads the key blocks its queries can
+    see (``models.llama._cached_attention``).  The shape rule gives one
+    block at any toy size, so the test stands in for it: a multi-token
+    call scores 16 key rows at a time against a 128-row view, a tick's
+    single token the whole view, as on the chip.  Tokens must match
+    offline ``generate`` (run under the real rule) on prompts shorter and
+    longer than the window, through one warm chunk program."""
+
+    BLOCK = 16
+
+    @pytest.mark.parametrize("window", [None, 40], ids=["full", "windowed"])
+    def test_mixtral_engine_matches_offline_across_blocks(self, window):
+        from accelerate_tpu.models import llama
+        from accelerate_tpu.models.mixtral import (MixtralConfig,
+                                                   MixtralForCausalLM)
+
+        cfg = MixtralConfig.tiny_moe(use_flash_attention=False,
+                                     capacity_factor=4.0,
+                                     sliding_window=window)
+        m = MixtralForCausalLM(cfg)
+        params = m.init_params(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(1, 256, size=(1, n)).astype(np.int32)
+                   for n in (20, 100, 57)]
+        n = 12
+        refs = [_offline(m, params, p, n, eos=None) for p in prompts]
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(
+                llama, "cached_key_block",
+                lambda rows, view: (min(self.BLOCK, view)
+                                    if rows > cfg.num_attention_heads
+                                    else view))
+            eng = ServingEngine(m, params, max_slots=2, max_len=128,
+                                prefill_chunk=16, prefix_cache_mb=0.0)
+            try:
+                with CompileWatcher() as watcher:
+                    reqs = [eng.submit(p, max_new_tokens=n, ignore_eos=True)
+                            for p in prompts]
+                    got = [np.asarray(r.result(timeout=180)) for r in reqs]
+                summary = eng.stats.summary()
+                assert eng._prefill_chunk._cache_size() == 1
+                assert eng._decode._cache_size() == 1
+            finally:
+                eng.shutdown(drain=False)
+        for g, ref in zip(got, refs):
+            assert np.array_equal(g, ref), (g, ref)
+        assert not watcher.events, watcher.events
+        # 2 + 7 + 4 chunks of 16 against 128-row views: a full layer scores
+        # offset + 16 rows, all of them visible; a windowed one starts at
+        # the block that holds offset - 39
+        share, fill = (summary["prefill_attn_rows_share"],
+                       summary["prefill_attn_rows_fill"])
+        if window is None:
+            scored = 16 * (sum(range(1, 3)) + sum(range(1, 8))
+                           + sum(range(1, 5)))
+            assert share == pytest.approx(scored / (13 * 128), abs=1e-6)
+            assert fill == 1.0
+        else:
+            assert 0.0 < share < 656 / (13 * 128) and 0.5 < fill < 1.0

@@ -241,6 +241,13 @@ def test_cohere2_moe_serving_program_compiles_for_v5e(program, one_chip):
     assert not re.search(r"= bf16\[(16|4),4096,4096\]\S* (copy|transpose)\(", text)
     if program == "prefill_chunk":        # the loop over occupied expert tiles is there
         assert " while(" in text
+        # attention scores 512 key rows at a time (models.llama.cached_key_block: 64 MiB of
+        # float32 scores a block) and never the 8192-row view's 1.07 GB a layer; the parent's
+        # program needed 1.09 GB beside its arguments for them
+        assert "f32[1,8,16,256,512]" in text and "f32[1,8,16,256,8192]" not in text
+        assert memory.temp_size_in_bytes < 0.5e9, memory
+    else:                                 # a tick's one token scores its whole view in one pass
+        assert "f32[16,1,8,16,1,8192]" in text
 
 
 # ---------------------------------------------------------------------------
@@ -277,3 +284,5 @@ def test_mixtral_serving_program_compiles_for_v5e(program, one_chip):
     if program == "prefill_chunk":        # the loop over occupied 128-row tiles is there
         assert " while(" in text
         assert re.search(r"bf16\[128,14336\]", text)
+        # a 1024-row view's scores are 32 MiB: one block, the whole view in one pass
+        assert "f32[1,8,4,256,1024]" in text
