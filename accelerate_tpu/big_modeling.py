@@ -425,7 +425,15 @@ def cache_factory_for(module) -> Optional[Callable]:
     """``(batch, max_len, dtype=bf16) -> per-layer KV cache tuple`` for model
     families with cache threading; None otherwise. Layer caches pair, in
     order, with the specs marked ``cache_slot=True`` (``kind == "layer"`` is
-    honored as a legacy alias for externally-built spec lists)."""
+    honored as a legacy alias for externally-built spec lists).
+
+    A module that declares its own cache (``init_cache(batch, max_len,
+    dtype)``: a latent cache has no K and V to size from head counts) is
+    asked first, duck-typed as ``window_for`` is; the ladder below serves the
+    families whose cache is per-head K and V."""
+    if hasattr(module, "init_cache"):
+        return module.init_cache
+
     from .models.bloom import BloomForCausalLM
     from .models.cohere2_moe import Cohere2MoeForCausalLM
     from .models.gpt2 import GPT2LMHeadModel

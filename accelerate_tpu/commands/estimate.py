@@ -36,6 +36,11 @@ def _model_registry():
 
         return Cohere2MoeForCausalLM(Cohere2MoeConfig())
 
+    def _openpangu_ultra_moe():
+        from ..models.pangu_ultra_moe import PanguUltraMoeConfig, PanguUltraMoeForCausalLM
+
+        return PanguUltraMoeForCausalLM(PanguUltraMoeConfig())
+
     reg = {
         "llama3-8b": llama("llama3_8b"),
         "llama-tiny": llama("tiny"),
@@ -46,6 +51,7 @@ def _model_registry():
         "gptj-6b": lambda: GPTJForCausalLM(GPTJConfig.gptj_6b()),
         "mixtral-8x7b": _mixtral_8x7b,
         "command-a-plus": _command_a_plus,
+        "openpangu-ultra-moe": _openpangu_ultra_moe,
         "gpt-neox-20b": lambda: GPTNeoXForCausalLM(GPTNeoXConfig.neox_20b()),
         "opt-30b": lambda: OPTForCausalLM(OPTConfig.opt_30b()),
         "phi-2": lambda: PhiForCausalLM(PhiConfig.phi_2()),
@@ -199,6 +205,26 @@ def _kv_geometry(module):
     return int(layers), int(kv), int(head_dim)
 
 
+def _declared_kv(module):
+    """``(bytes a token over all layers in bf16, leaves, label)`` of the cache
+    a module declares itself (``init_cache``: latent rows, no head axis), read
+    from its leaves at lengths 2 and 1; None for the per-head K/V families."""
+    if not hasattr(module, "init_cache"):
+        return None
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    two, one = (jax.tree.leaves(jax.eval_shape(lambda n=n: module.init_cache(1, n, jnp.bfloat16)))
+                for n in (2, 1))
+    per_tok = sum((int(np.prod(a.shape)) - int(np.prod(b.shape))) * a.dtype.itemsize
+                  for a, b in zip(two, one))
+    widths = sorted({int(np.prod(a.shape)) - int(np.prod(b.shape)) for a, b in zip(two, one)})
+    label = (f"declared by the model: {len(two)} leaves of "
+             f"{'/'.join(map(str, widths))} values a token, no head axis")
+    return per_tok, len(two), label
+
+
 def _fmt(nbytes: float) -> str:
     for unit in ("B", "KiB", "MiB", "GiB", "TiB"):
         if nbytes < 1024 or unit == "TiB":
@@ -345,21 +371,36 @@ def estimate_command(args) -> int:
               "pages)")
         return 2
     if args.page_size is not None:
+        declared = _declared_kv(module) if module is not None else None
         geom = _kv_geometry(module)
-        if geom is None:
+        if declared is None and geom is None:
             print("\nPaged KV pool: n/a (no model config — pass a built-in "
                   "name or config.json)")
             return 2
-        layers, kv_heads, head_dim = geom
         kv_int8 = args.kv_dtype == "int8"
         itemsize = 1 if kv_int8 else 2
-        per_tok = 2 * layers * kv_heads * head_dim * itemsize  # k+v
-        # Quantized pages carry one f32 scale per pool leaf (k and v per
-        # layer = 2*layers leaves) per page — mirrors the engine's
-        # _page_bytes accounting exactly.
-        scale_bytes = 2 * layers * 4 if kv_int8 else 0
+        if declared is not None:
+            # The engine refuses what assumes per-head K and V for such a
+            # cache (serving/engine.py): say so here instead of printing a
+            # number it would not serve.
+            if kv_int8 or args.tp > 1:
+                print(f"\nPaged KV pool: {type(module).__name__} declares its own KV cache "
+                      "(rows without a head axis); the engine refuses --kv-dtype int8 and "
+                      "--tp > 1 for it")
+                return 2
+            per_tok, n_leaves, geometry = declared
+            scale_bytes, fp_page_bytes = 0, per_tok * args.page_size
+            layers = geom[0] if geom is not None else n_leaves
+        else:
+            layers, kv_heads, head_dim = geom
+            per_tok = 2 * layers * kv_heads * head_dim * itemsize  # k+v
+            # Quantized pages carry one f32 scale per pool leaf (k and v per
+            # layer = 2*layers leaves) per page — mirrors the engine's
+            # _page_bytes accounting exactly.
+            scale_bytes = 2 * layers * 4 if kv_int8 else 0
+            fp_page_bytes = 2 * layers * kv_heads * head_dim * 2 * args.page_size
+            geometry = f"2 x {layers} layers x {kv_heads} kv-heads x {head_dim} head-dim"
         page_bytes = per_tok * args.page_size + scale_bytes
-        fp_page_bytes = 2 * layers * kv_heads * head_dim * 2 * args.page_size
         # Per-chip share under --tp: pool leaves shard on kv-heads (or
         # head_dim) exactly like the dense cache, so the divisor matches
         # the KV-cache-per-chip line above.
@@ -369,8 +410,7 @@ def estimate_command(args) -> int:
                    else args.tp if head_dim % args.tp == 0 else 1)
         kv_label = ("int8 + per-page scales" if kv_int8 else "bf16")
         print(f"\nPaged KV pool (page_size={args.page_size} tokens, "
-              f"{kv_label}, 2 x {layers} layers x {kv_heads} kv-heads x "
-              f"{head_dim} head-dim):")
+              f"{kv_label}, {geometry}):")
         print(f"  bytes per token : {_fmt(per_tok)}")
         print(f"  bytes per page  : {_fmt(page_bytes)}"
               + (f"  ({_fmt(page_bytes / div)}/chip at tp={args.tp})"
@@ -444,7 +484,10 @@ def estimate_command(args) -> int:
         # table's formula applied to the per-chip element count.
         print(f"  training (Adam) per chip  : {_fmt(per_chip * 2 * 2 + per_chip * 4 * 3)}")
         geom = _kv_geometry(module)
-        if geom is not None:
+        if module is not None and hasattr(module, "init_cache"):
+            print(f"  KV cache per chip         : n/a ({type(module).__name__} declares its "
+                  "own KV cache, rows without a head axis: the engine refuses tp > 1 for it)")
+        elif geom is not None:
             layers, kv_heads, head_dim = geom
             # The engine shards the KV heads axis when divisible, else the
             # head_dim axis, else the cache replicates (SliceExec.heads_axis).

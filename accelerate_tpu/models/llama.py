@@ -675,6 +675,137 @@ def update_kv_cache_and_attend(cache, q, k, v, cache_pos, n_rep: int, sliding_wi
     return out, new_cache
 
 
+def init_latent_cache(num_layers: int, batch_size: int, max_len: int, rank: int, rope_dim: int,
+                      dtype=jnp.bfloat16):
+    """Per-layer LATENT cache: a tuple of ``{"latent": [B, max_len, rank],
+    "rope": [B, max_len, rope_dim]}`` — a token's normed joint latent of keys
+    and values and its rotated decoupled key, shared by every head: no head
+    axis, always linear. Two leaves, not one row of ``rank + rope_dim``: a
+    product contracts over ``rank`` (a whole number of 128-lane tiles) and
+    the values ARE the latent leaf, where one 576-wide row cost a decode
+    tick a transposed copy and a sliced copy of every lane's view a layer
+    (2.3 ms of 7 on a v5e at 32 x 8192 rows; PERF.md section 6, PR 33)."""
+    return tuple({"latent": jnp.zeros((batch_size, max_len, rank), dtype),
+                  "rope": jnp.zeros((batch_size, max_len, rope_dim), dtype)}
+                 for _ in range(num_layers))
+
+
+def latent_attention_form(S: int, rank: int, nope_dim: int, v_dim: int) -> str:
+    """``"absorbed"`` or ``"expanded"``: the cheaper way to attend ``S``
+    queries a head against a latent cache, from the call's static shape alone
+    (as :func:`cached_key_block` is: no option, no flag).
+
+    A key row costs, for each head, ``S * (2 (rank + rope) + 2 rank)``
+    multiply-adds absorbed (scores and the weighted sum live in the latent
+    space) and ``2 rank (nope + v) + S * (2 (nope + rope) + 2 v)`` expanded
+    (the row's per-head key and value are made first, once for all ``S``
+    queries). Expanding wins once the queries that share a row outnumber
+    ``2 rank (nope + v) / (4 rank - 2 nope - 2 v)``: 171 at rank 512 and
+    heads of 128 + 128, so one token a slot (a tick) and a speculative
+    verify are absorbed and a 256-token chunk is expanded. The v5e sweep
+    that checked the rule is in PERF.md section 6 (PR 33)."""
+    saved = 4 * rank - 2 * nope_dim - 2 * v_dim        # a query's saving on an expanded row
+    if saved <= 0:
+        return "absorbed"
+    return "expanded" if S * saved > 2 * rank * (nope_dim + v_dim) else "absorbed"
+
+
+def _latent_cached_attention(q_nope, q_rope, latent, k_rope, w_uk, w_uv, cache_pos, sm_scale):
+    """Attention of ``S`` queries a head against the latent cache (``latent
+    [B, L, rank]``, ``k_rope [B, L, rope]``): ``q_nope [B, S, H, nope]``,
+    ``q_rope [B, S, H, rope]`` (rotated), ``w_uk [rank, H, nope]`` / ``w_uv
+    [rank, H, v]`` the key and value halves of the up-projection. Returns
+    ``[B, S, H, v]``.
+
+    ``score(t, s) = (q_nope . (c_s W_uk) + q_rope . k_r(s)) * sm_scale`` and
+    ``o = sum_s p(t, s) (c_s W_uv)`` in either form; absorbed folds ``W_uk``
+    into the query and applies ``W_uv`` after the weighted sum of latents
+    (:func:`latent_attention_form`). Key rows are read as
+    :func:`_cached_attention` reads them: one block under the mask where
+    :func:`cached_key_block` gives one, else only the blocks
+    :func:`cached_key_extent` names, under a running maximum and sum.
+    Products take their operands in the wider of the queries' and the
+    cache's types and accumulate in float32."""
+    B, S, H, _ = q_nope.shape
+    L, rank = latent.shape[1], w_uk.shape[0]
+    out_dtype = q_nope.dtype
+    cdt = jnp.promote_types(out_dtype, latent.dtype)
+    w_uk, w_uv = w_uk.astype(cdt), w_uv.astype(cdt)
+    f32 = dict(preferred_element_type=jnp.float32)
+    absorbed = latent_attention_form(S, rank, w_uk.shape[-1], w_uv.shape[-1]) == "absorbed"
+    q_rope = (q_rope * sm_scale).astype(cdt)
+    if absorbed:
+        q_nope = jnp.einsum("bshd,rhd->bshr", q_nope.astype(cdt), w_uk, **f32) * sm_scale
+    else:
+        q_nope = q_nope * sm_scale
+    q_nope = q_nope.astype(cdt)                        # absorbed: a query in the latent space
+
+    def scores_and_values(c, k_r):
+        """c [B, K, rank], k_r [B, K, rope] -> (logits [B, H, S, K] f32, values)."""
+        c, k_r = c.astype(cdt), k_r.astype(cdt)
+        rope_part = jnp.einsum("bshd,bkd->bhsk", q_rope, k_r, **f32)
+        if absorbed:
+            return jnp.einsum("bshr,bkr->bhsk", q_nope, c, **f32) + rope_part, c
+        k_nope = jnp.einsum("bkr,rhd->bkhd", c, w_uk, **f32).astype(cdt)
+        logits = jnp.einsum("bshd,bkhd->bhsk", q_nope, k_nope, **f32) + rope_part
+        return logits, jnp.einsum("bkr,rhd->bkhd", c, w_uv, **f32).astype(cdt)
+
+    def weigh(p, values):
+        """p [B, H, S, K] f32 -> [B, H, S, rank (absorbed) or v]."""
+        spec = "bhsk,bkr->bhsr" if absorbed else "bhsk,bkhd->bhsd"
+        return jnp.einsum(spec, p.astype(cdt), values, **f32)
+
+    q_pos = (cache_pos + jnp.arange(S, dtype=jnp.int32))[:, None]
+    block = cached_key_block(B * H * S, L)
+    if block >= L:
+        logits, values = scores_and_values(latent, k_rope)
+        mask = jnp.arange(L, dtype=jnp.int32)[None, :] <= q_pos
+        out = weigh(jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1), values)
+    else:
+        first, last = cached_key_extent(cache_pos, S, L, block)
+
+        def one_block(j, carry):
+            m, l, acc = carry
+            start = jnp.minimum(j * block, L - block)
+            logits, values = scores_and_values(
+                jax.lax.dynamic_slice_in_dim(latent, start, block, axis=1),
+                jax.lax.dynamic_slice_in_dim(k_rope, start, block, axis=1))
+            k_pos = (start + jnp.arange(block, dtype=jnp.int32))[None, :]
+            logits = jnp.where((k_pos >= j * block) & (k_pos <= q_pos), logits, -1e30)
+            m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(logits - m_new)
+            return m_new, l * alpha + p.sum(-1, keepdims=True), acc * alpha + weigh(p, values)
+
+        stat = jnp.zeros((B, H, S, 1), jnp.float32)
+        width = rank if absorbed else w_uv.shape[-1]
+        _, l, acc = jax.lax.fori_loop(
+            first, last, one_block,
+            (stat - 1e30, stat, jnp.zeros((B, H, S, width), jnp.float32)))
+        out = acc / l
+    if absorbed:
+        out = jnp.einsum("bhsr,rhd->bhsd", out.astype(cdt), w_uv, **f32)
+    return jnp.moveaxis(out, 1, 2).astype(out_dtype)                      # [B, S, H, v]
+
+
+def update_latent_cache_and_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, cache_pos,
+                                   sm_scale: float):
+    """Write this call's latents ``[B, S, rank]`` and rotated keys ``[B, S,
+    rope]`` into the cache at ``cache_pos`` and attend the queries against
+    what they can see of it (:func:`_latent_cached_attention`). Returns
+    ``(out [B, S, H, v], new_cache)``: the latent twin of
+    :func:`update_kv_cache_and_attend`."""
+    new_cache = {
+        "latent": jax.lax.dynamic_update_slice(
+            cache["latent"], c_kv.astype(cache["latent"].dtype), (0, cache_pos, 0)),
+        "rope": jax.lax.dynamic_update_slice(
+            cache["rope"], k_rope.astype(cache["rope"].dtype), (0, cache_pos, 0)),
+    }
+    out = _latent_cached_attention(q_nope, q_rope, new_cache["latent"], new_cache["rope"],
+                                   w_uk, w_uv, cache_pos, sm_scale)
+    return out, new_cache
+
+
 def _lora_delta(y, x, lora, name):
     """Add a gathered low-rank LoRA delta to a projection output.
 

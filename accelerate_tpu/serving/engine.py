@@ -142,6 +142,7 @@ exit inside the notice window.
 from __future__ import annotations
 
 import collections
+import gc
 import hashlib
 import itertools
 import os
@@ -163,7 +164,8 @@ from ..generation import (
     speculative_emit,
 )
 from ..inference import resolve_model_source
-from ..models.llama import cached_attention_rows
+from ..models.llama import (cached_attention_rows, cached_key_block,
+                            cached_key_extent)
 from ..observability import FlightRecorder, Tracer, new_trace_id
 from .metrics import ServingStats
 from .request import Request, RequestStatus
@@ -643,6 +645,23 @@ class ServingEngine:
             raise ValueError(
                 f"weights_dtype must be None or 'int8' (got {weights_dtype!r})")
         self._kv_dtype = kv_dtype
+        if hasattr(module, "init_cache"):
+            # A cache the module declares (latent rows: no head axis). Its
+            # leaves are paged as any other's; what assumes per-head K and V
+            # is refused here, by name, rather than run unproven.
+            family = type(module).__name__
+            if self.tp > 1:
+                raise NotImplementedError(
+                    f"{family} declares its own KV cache (init_cache: rows "
+                    "without a head axis); tp > 1 shards cache leaves on a "
+                    "heads axis (mesh_exec.heads_axis), which such rows do "
+                    "not have — serve this family with tp=1")
+            if kv_dtype is not None:
+                raise NotImplementedError(
+                    f"{family} declares its own KV cache (init_cache); "
+                    f"kv_dtype={kv_dtype!r} keeps one scale a page, which "
+                    "would span a latent row's normed part and its rotary "
+                    "key — serve this family with kv_dtype=None")
         self._weights_dtype = weights_dtype
 
         # -- speculative-decoding resolution ------------------------------
@@ -1759,7 +1778,8 @@ class ServingEngine:
         ``restore_prefix`` (and the draft-only chunk) where they exist.
         ``ignore_eos`` keeps the dummies decoding even if the model emits eos immediately. Counters reset and the
         prefix cache is cleared afterwards so warmup traffic never
-        pollutes serving metrics (or lingers as phantom cached prefixes)."""
+        pollutes serving metrics (or lingers as phantom cached prefixes).
+        Ends with the collector's full pass, which set-up leaves due."""
         req = self.submit(np.zeros((1, 1), np.int32), max_new_tokens=2,
                           seed=0, ignore_eos=True, block=True)
         if not req.wait(timeout):
@@ -1793,6 +1813,13 @@ class ServingEngine:
         self._next_profile_tick = self._decode_ticks + 1
         if self._compile_watcher is not None:
             self._compile_watcher.reset()
+        # Tracing and compiling allocate millions of containers, so the
+        # collector's next full pass falls due about here: left to the
+        # allocation count it lands in the first seconds of serving or just
+        # before them (a 55-60 ms stop of every thread at 10 GB of weights),
+        # by how many objects set-up happened to make. Take it now, once:
+        # the counters restart from a heap that is all long-lived.
+        gc.collect()
 
     @staticmethod
     def _raise_if_failed(req):
@@ -2177,6 +2204,12 @@ class ServingEngine:
         """``"int8"`` when base weights are stored quantized (LoRA path
         full precision); None = full-precision weights."""
         return self._weights_dtype
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Bytes one cached token takes over all layers, from the leaves of
+        the cache the model declares (a page's bytes over its rows)."""
+        return self._page_bytes // self._page
 
     def kv_cache_per_chip_bytes(self) -> int:
         """Per-device byte footprint of the decode KV state (max shard per
@@ -2868,6 +2901,31 @@ class ServingEngine:
             scored, visible, layers = scored + n * s, visible + n * v, layers + n
         return scored, visible, layers * L
 
+    def _tick_attn_rows(self, positions: list) -> Optional[tuple]:
+        """``(scored, visible, view)`` key rows of one plain tick's
+        attention, summed over its lanes and layers (host arithmetic, the
+        chunk's rule at one query a lane, all lanes in one numpy pass).
+        Every lane of the program scores, whether a stream runs in it or
+        not; a lane without one is counted at position 0 (its rows are not
+        visible to anything)."""
+        heads = self._attn_score_heads
+        if heads is None:
+            return None
+        L = self._pages_per_slot * self._page
+        block = cached_key_block(heads, L)
+        # the running lanes' positions, then one idle lane's
+        pos = np.asarray(list(positions) + [0], np.int64)
+        idle = self.max_slots - len(positions)
+        scored = visible = layers = 0
+        for window, n in self._attn_layer_kinds:
+            first, last = cached_key_extent(pos, 1, L, block, window, lib=np)
+            rows = (last - first) * block
+            low = 0 if window is None else np.maximum(pos - window + 1, 0)
+            scored += n * int(rows[:-1].sum() + idle * rows[-1])
+            visible += n * int((np.minimum(pos + 1, L) - low)[:-1].sum())
+            layers += n
+        return scored, visible, layers * L * self.max_slots
+
     def _commit_chunk(self, req: Request, i: int, offset: int, final: bool,
                       tok, block, t0: float, attn_rows=None):
         """The host side of a finished chunk (phase ``prefill_commit``):
@@ -3094,6 +3152,7 @@ class ServingEngine:
         committed = accepted = n_valid = 0
         dead_rows = held_rows = 0
         n_layers = len(self._layer_windows)
+        positions = []          # of the streams this tick ran (its query's position)
         for slot, req, epoch in flight.entries:
             if (req.status is not RequestStatus.RUNNING
                     or req._preempted != epoch):
@@ -3119,6 +3178,7 @@ class ServingEngine:
                 if not retired and self._page_window is not None:
                     self._free_window_pages(req)
             else:
+                positions.append(req._pos_base + len(req.tokens))
                 if not self._commit_token(req, int(toks[slot])):
                     continue  # callback failed; slot already freed
                 committed += 1
@@ -3151,7 +3211,9 @@ class ServingEngine:
                                 host_us=host_s * 1e6,
                                 other_us=other_s * 1e6,
                                 host=phases.drain(), moe_picks=counts,
-                                kv_rows=(dead_rows, held_rows))
+                                kv_rows=(dead_rows, held_rows),
+                                attn_rows=None if spec else
+                                self._tick_attn_rows(positions))
         tracer = self._tracer
         if tracer.enabled:
             targs = {"active": len(flight.entries), "committed": committed,
@@ -3178,7 +3240,8 @@ class ServingEngine:
         self._drain_samples.append((time.monotonic(), self._pool.frees))
         self._stats.record_pages(self._pool.free_pages, self._pool.used_pages,
                                  self._pool.num_pages,
-                                 freed_total=self._pool.frees)
+                                 freed_total=self._pool.frees,
+                                 kv_bytes_per_token=self.kv_bytes_per_token)
 
     def _dispatch_spec(self, running, ahead: bool,
                        stale) -> Optional[_TickFlight]:

@@ -184,6 +184,14 @@ class ServingStats:
             self._attn_rows_scored = 0
             self._attn_rows_visible = 0
             self._attn_rows_view = 0
+            # The same three of the decode ticks' attention (every lane of
+            # the tick's program scores; only running streams make rows
+            # visible), and the bytes a cached token takes over all layers
+            # (gauge; from the cache the model declares).
+            self._tick_rows_scored = 0
+            self._tick_rows_visible = 0
+            self._tick_rows_view = 0
+            self._kv_bytes_per_token = 0
             # Speculative decoding: draft proposals vs target acceptances.
             self._spec_ticks = 0
             self._spec_proposed = 0
@@ -266,7 +274,8 @@ class ServingStats:
                     max_slots: int, seconds: float,
                     host_us: Optional[float] = None,
                     other_us: float = 0.0, host: Optional[dict] = None,
-                    moe_picks=None, kv_rows: Optional[tuple] = None):
+                    moe_picks=None, kv_rows: Optional[tuple] = None,
+                    attn_rows: Optional[tuple] = None):
         """One ``decode_step_all_slots`` execution.
 
         ``seconds`` is the device-complete→device-complete interval for
@@ -281,10 +290,16 @@ class ServingStats:
         ``moe_picks`` are the tick's expert picks (per held expert, then
         ``moe_held_apply``'s three totals), ``kv_rows`` the ``(dead,
         held)`` KV rows of the streams that run on: held = rows written x
-        layers, dead = those a windowed layer can never read again."""
+        layers, dead = those a windowed layer can never read again;
+        ``attn_rows`` the ``(scored, visible, view)`` key rows of the tick's
+        attention, summed over its lanes and layers."""
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
+            if attn_rows is not None:
+                self._tick_rows_scored += int(attn_rows[0])
+                self._tick_rows_visible += int(attn_rows[1])
+                self._tick_rows_view += int(attn_rows[2])
             if kv_rows is not None:
                 self._kv_rows_dead += int(kv_rows[0])
                 self._kv_rows_held += int(kv_rows[1])
@@ -345,11 +360,13 @@ class ServingStats:
             self._prefix_restored_bytes += int(bytes_restored)
 
     def record_pages(self, free: int, used: int, total: int,
-                     freed_total: int = 0):
+                     freed_total: int = 0, kv_bytes_per_token: int = 0):
         """Gauge: paged-KV pool occupancy after a tick (page counts).
         ``freed_total`` mirrors the pool's cumulative free count — the
-        page-drain observable behind the gateway's pressure Retry-After."""
+        page-drain observable behind the gateway's pressure Retry-After;
+        ``kv_bytes_per_token`` is a page's bytes over its rows."""
         with self._lock:
+            self._kv_bytes_per_token = int(kv_bytes_per_token)
             self._pages_free = int(free)
             self._pages_used = int(used)
             self._pages_total = int(total)
@@ -522,11 +539,12 @@ class ServingStats:
                       "_host_us_ticks", "_host_other_us_sum",
                       "_emission_stalls", "_kv_rows_held", "_kv_rows_dead",
                       "_attn_rows_scored", "_attn_rows_visible",
-                      "_attn_rows_view"):
+                      "_attn_rows_view", "_tick_rows_scored",
+                      "_tick_rows_visible", "_tick_rows_view"):
                 setattr(self, k, getattr(self, k) + o[k])
             for k in ("_queue_wait_ms_max", "_ttft_ms_max",
                       "_prefill_backlog_max", "_host_us_max",
-                      "_logprob_drift"):
+                      "_logprob_drift", "_kv_bytes_per_token"):
                 setattr(self, k, max(getattr(self, k), o[k]))
             self._ttft_samples.extend(o_samples)
             if len(self._ttft_samples) > self.MAX_TTFT_SAMPLES:
@@ -667,6 +685,19 @@ class ServingStats:
                 "prefill_attn_rows_fill": round(
                     self._attn_rows_visible / self._attn_rows_scored, 6)
                     if self._attn_rows_scored else 0.0,
+                # The decode ticks' twin of the pair: key rows the ticks'
+                # attention scored over slots x view rows x layers (1.0:
+                # every tick scored every lane's whole view), and the rows
+                # the running streams' positions make visible over the
+                # rows scored. Then what one cached token takes, all
+                # layers, from the cache the model declares.
+                "decode_attn_rows_share": round(
+                    self._tick_rows_scored / self._tick_rows_view, 6)
+                    if self._tick_rows_view else 0.0,
+                "decode_attn_rows_fill": round(
+                    self._tick_rows_visible / self._tick_rows_scored, 6)
+                    if self._tick_rows_scored else 0.0,
+                "kv_bytes_per_token": self._kv_bytes_per_token,
             }
             # The host path by phase ("host_us/<phase>", slash-pathed like
             # the adapter keys; the gateway re-emits them as one labeled

@@ -9,7 +9,7 @@ name/layout translation between HF state dicts (torch conventions:
 ``Linear.weight`` is ``(out, in)``, dot-separated names) and our param
 pytrees (flax: ``kernel`` is ``(in, out)``, nested dicts).
 
-Supported families mirror ``accelerate_tpu.models``: llama, mixtral, cohere2_moe, bloom, gpt2,
+Supported families mirror ``accelerate_tpu.models``: llama, mixtral, cohere2_moe, pangu_ultra_moe, bloom, gpt2,
 bert, t5. Each family is a table of bidirectional rules; conversion is pure
 numpy (no torch import needed when reading safetensors).
 
@@ -108,6 +108,9 @@ _EXPERT_CONVENTIONS = {
     # cohere2_moe: the routed experts and the always-on shared experts are
     # both stacks of per-expert SwiGLU Linears.
     "cohere2_moe": [_swiglu_stack("experts"), _swiglu_stack("shared_experts")],
+    # pangu_ultra_moe: routed experts are per-expert Linears; the shared
+    # experts are ONE module of their joint width (plain rules below).
+    "pangu_ultra_moe": [_swiglu_stack("experts")],
 }
 
 
@@ -440,9 +443,39 @@ _COHERE2_MOE_RULES = [
     ("model.norm.weight", "norm/scale", "copy", None),
 ]
 
+# openPangu-Ultra-MoE (models/pangu_ultra_moe.py; flat scope, untied head):
+# latent attention under the published names (q_a_proj, q_a_layernorm,
+# q_b_proj, kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj), the four
+# sandwich norms, mlp.gate.weight for the router, mlp.shared_experts.* for the
+# shared expert and llama's mlp.* for the leading dense layers.
+_PANGU_ULTRA_MOE_RULES = [
+    ("model.embed_tokens.weight", "embed_tokens/embedding", "copy", None),
+    ("model.layers.{i}.self_attn.{p}_proj.weight",
+     "layers_{i}/self_attn/{p}_proj/kernel", "t", ("q_a", "q_b", "kv_b", "o")),
+    ("model.layers.{i}.self_attn.kv_a_proj_with_mqa.weight",
+     "layers_{i}/self_attn/kv_a_proj/kernel", "t", None),
+    ("model.layers.{i}.self_attn.q_a_layernorm.weight",
+     "layers_{i}/self_attn/q_a_norm/scale", "copy", None),
+    ("model.layers.{i}.self_attn.kv_a_layernorm.weight",
+     "layers_{i}/self_attn/kv_a_norm/scale", "copy", None),
+    ("model.layers.{i}.input_layernorm.weight", "layers_{i}/input_norm/scale", "copy", None),
+    ("model.layers.{i}.post_attention_layernorm.weight",
+     "layers_{i}/post_attn_norm/scale", "copy", None),
+    ("model.layers.{i}.pre_mlp_layernorm.weight", "layers_{i}/pre_mlp_norm/scale", "copy", None),
+    ("model.layers.{i}.post_mlp_layernorm.weight", "layers_{i}/post_mlp_norm/scale", "copy", None),
+    ("model.layers.{i}.mlp.gate.weight", "layers_{i}/mlp/router", "t", None),
+    ("model.layers.{i}.mlp.shared_experts.{p}_proj.weight",
+     "layers_{i}/mlp/shared_experts/{p}_proj/kernel", "t", ("gate", "up", "down")),
+    ("model.layers.{i}.mlp.{p}_proj.weight",
+     "layers_{i}/mlp/{p}_proj/kernel", "t", ("gate", "up", "down")),
+    ("model.norm.weight", "norm/scale", "copy", None),
+    ("lm_head.weight", "lm_head/kernel", "t", None),
+]
+
 _FAMILY_RULES = {
     "llama": _LLAMA_RULES,
     "cohere2_moe": _COHERE2_MOE_RULES,
+    "pangu_ultra_moe": _PANGU_ULTRA_MOE_RULES,
     "vit": _VIT_RULES,
     # Mistral checkpoints are llama-named tensor-for-tensor; the config adds
     # sliding_window (handled in config_from_hf).
@@ -477,6 +510,7 @@ _STRIP_PREFIXES = {
     "vit": ("vit.",),
     "llama": (),
     "cohere2_moe": (),
+    "pangu_ultra_moe": (),
     "mixtral": (),
     "t5": (),
     "qwen2": (),
@@ -733,6 +767,35 @@ def config_from_hf(hf_config: dict, family: Optional[str] = None):
             expert_selection_fn=get("expert_selection_fn", "sigmoid"),
             norm_topk_prob=bool(get("norm_topk_prob", True)),
             logit_scale=float(get("logit_scale", 1.0)))
+    if family == "pangu_ultra_moe":
+        from ..models.pangu_ultra_moe import PanguUltraMoeConfig
+
+        if not get("sandwich_norm", True):
+            raise NotImplementedError("pangu_ultra_moe: only the sandwich-norm block")
+        if get("tie_word_embeddings", False):
+            raise NotImplementedError("pangu_ultra_moe: the head has its own matrix")
+        if get("rope_scaling") or get("attention_bias", False):
+            raise NotImplementedError("pangu_ultra_moe: no rope scaling, no attention bias")
+        if get("scoring_func", "sigmoid") != "sigmoid" or get("n_group", 1) not in (None, 1):
+            raise NotImplementedError(
+                "pangu_ultra_moe: the router is a sigmoid with a plain top-k (no groups)")
+        return PanguUltraMoeConfig(
+            vocab_size=get("vocab_size", 153600), hidden_size=get("hidden_size", 7680),
+            intermediate_size=get("intermediate_size", 18432),
+            moe_intermediate_size=get("moe_intermediate_size", 2048),
+            num_hidden_layers=get("num_hidden_layers", 61),
+            first_k_dense_replace=get("first_k_dense_replace", 3),
+            num_attention_heads=get("num_attention_heads", 128),
+            q_lora_rank=get("q_lora_rank", 1536), kv_lora_rank=get("kv_lora_rank", 512),
+            qk_nope_head_dim=get("qk_nope_head_dim", 128),
+            qk_rope_head_dim=get("qk_rope_head_dim", 64), v_head_dim=get("v_head_dim", 128),
+            max_position_embeddings=get("max_position_embeddings", 131072),
+            rms_norm_eps=get("rms_norm_eps", 1e-5), rope_theta=float(get("rope_theta", 25.6e6)),
+            num_experts=get("n_routed_experts", 256),
+            num_experts_per_tok=get("num_experts_per_tok", 8),
+            n_shared_experts=get("n_shared_experts", 1),
+            routed_scaling_factor=float(get("routed_scaling_factor", 2.5)),
+            norm_topk_prob=bool(get("norm_topk_prob", True)))
     if family == "gpt2":
         from ..models.gpt2 import GPT2Config
 
@@ -947,6 +1010,10 @@ def model_from_config(config, family: str):
         from ..models.cohere2_moe import Cohere2MoeForCausalLM
 
         return Cohere2MoeForCausalLM(config)
+    if family == "pangu_ultra_moe":
+        from ..models.pangu_ultra_moe import PanguUltraMoeForCausalLM
+
+        return PanguUltraMoeForCausalLM(config)
     if family == "gpt2":
         from ..models.gpt2 import GPT2LMHeadModel
 

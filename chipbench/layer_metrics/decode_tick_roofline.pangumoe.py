@@ -1,0 +1,22 @@
+"""A decode tick's share of its bandwidth roofline, for the pangu_ultra_moe
+family: the bytes one tick has to read (the weights every token passes, the
+held experts the active slots are expected to touch, the latent rows of their
+contexts; flops_pangu_ultra_moe.py) / HBM rate, over the mean device time of
+one execution of the decode program."""
+
+from chipbench import flops_pangu_ultra_moe as flops
+from chipbench import trace_reduce
+
+DECODE = r"^jit__paged_decode_fn"
+
+
+def compute(ctx):
+    if ctx.trace is None or "kv_lora_rank" not in ctx.config:
+        return None
+    contexts = [c for _, _, later in ctx.counts.get("_work") or [] for c in later]
+    tick_ms = trace_reduce.mean_module_ms(ctx.trace, DECODE)
+    occupancy = ctx.stats.get("slot_occupancy")
+    if tick_ms is None or not occupancy or not contexts:
+        return None
+    nbytes = flops.decode_tick_bytes(ctx.config, occupancy * ctx.counts["slots"], contexts)
+    return 100.0 * nbytes / ctx.peaks["hbm_bytes_per_s"] / (tick_ms * 1e-3)

@@ -623,6 +623,35 @@ class TestLifecycle:
         with pytest.raises(RuntimeError, match="not accepting"):
             eng.submit([[1]])
 
+    def test_warmup_takes_the_collectors_full_pass(self, tiny):
+        """Set-up leaves the collector's full pass due; warm-up takes it at
+        its end, so it does not stop the threads in the first seconds of
+        serving: a generation-2 collection runs inside ``start()`` after
+        the last warm-up request has finished, and the counters of the
+        older generations start serving at zero."""
+        import gc
+
+        _, m, params = tiny
+        eng = ServingEngine(m, params, max_slots=1, max_len=32,
+                            eos_token_id=EOS, autostart=False)
+        seen = []            # (generation, requests completed so far)
+
+        def watch(phase, info):
+            if phase == "stop":
+                seen.append((info["generation"],
+                             eng.stats.summary()["requests_completed"]))
+
+        gc.callbacks.append(watch)
+        try:
+            eng.start()
+            older = gc.get_count()[1:]
+        finally:
+            gc.callbacks.remove(watch)
+            eng.shutdown(drain=False)
+        # warm-up resets its stats before the pass: it counts none finished
+        assert (2, 0) in seen[-3:], seen[-5:]
+        assert older[1] == 0 and older[0] <= 1, older
+
     def test_rejects_model_without_kv_cache(self):
         import flax.linen as nn
 

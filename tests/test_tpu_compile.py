@@ -177,11 +177,13 @@ def test_unsharded_call_on_a_mesh_is_refused_by_mosaic(topo, mosaic):
 # The cohere2_moe family's serving programs at the benchmark cell's widths
 # ---------------------------------------------------------------------------
 
-def _serving_programs(model, num_layers, kv_heads, head_dim, one_chip, *, max_len, slots, chunk):
+def _serving_programs(model, num_layers, kv_heads, head_dim, one_chip, *, max_len, slots, chunk,
+                      latent_row=None):
     """The model's part of the paged engine's two programs over the linear
     full-length view the engine gathers: a ``chunk``-token prefill chunk, and
     the decode tick — ``jax.vmap`` over ``slots`` of a batch-1 forward. Both
-    ask for the module's pick counters, as the engine does."""
+    ask for the module's pick counters, as the engine does. ``latent_row``
+    ``(rank, rope)``: the view is a latent cache, not per-head K and V."""
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -190,6 +192,11 @@ def _serving_programs(model, num_layers, kv_heads, head_dim, one_chip, *, max_le
     params = jax.tree.map(lambda a: struct(a.shape, jnp.bfloat16), shapes)
 
     def views(lead):
+        if latent_row is not None:
+            rank, rope = latent_row
+            return tuple({"latent": struct(lead + (1, max_len, rank), jnp.bfloat16),
+                          "rope": struct(lead + (1, max_len, rope), jnp.bfloat16)}
+                         for _ in range(num_layers))
         kv = struct(lead + (1, max_len, kv_heads, head_dim), jnp.bfloat16)
         return tuple({"k": kv, "v": kv} for _ in range(num_layers))
 
@@ -286,3 +293,49 @@ def test_mixtral_serving_program_compiles_for_v5e(program, one_chip):
         assert re.search(r"bf16\[128,14336\]", text)
         # a 1024-row view's scores are 32 MiB: one block, the whole view in one pass
         assert "f32[1,8,4,256,1024]" in text
+
+
+# ---------------------------------------------------------------------------
+# The pangu_ultra_moe family's serving programs at the benchmark cell's widths
+# ---------------------------------------------------------------------------
+
+def _pangu_programs(one_chip):
+    """At the widths of ``openpangu-ultra-moe-718b-l5e16`` (hidden 7680, 128
+    heads over a 512 + 64 wide latent row, 16 of 256 experts held, 1 dense + 4
+    expert layers, vocab slice 19200): max_len 8192, 32 slots, 256-token chunks."""
+    from accelerate_tpu.models.pangu_ultra_moe import (PanguUltraMoeConfig,
+                                                        PanguUltraMoeForCausalLM)
+
+    cfg = PanguUltraMoeConfig(vocab_size=19200, num_hidden_layers=5, first_k_dense_replace=1,
+                              held_experts=(0, 16))
+    return _serving_programs(PanguUltraMoeForCausalLM(cfg), 5, None, None, one_chip,
+                             max_len=8192, slots=32, chunk=256,
+                             latent_row=(cfg.kv_lora_rank, cfg.qk_rope_head_dim))
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
+def test_pangu_ultra_moe_serving_program_compiles_for_v5e(program, one_chip):
+    fn, args = _pangu_programs(one_chip)[program]
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    weights = 9.84e9                      # 1 dense + 4 expert layers of 16 experts + the slices, bf16
+    views = 0 if program == "prefill_chunk" else 32 * 8192 * 576 * 2 * 5     # 1.51 GB of latent rows
+    assert weights * 0.98 < memory.argument_size_in_bytes - views < weights * 1.02
+    # beside weights (9.84 GB), the page pool and the tick's views (1.51 GB each) there is ~3 GB
+    assert memory.temp_size_in_bytes < 1.0e9, memory
+    text = compiled.as_text()
+    import re
+
+    if program == "prefill_chunk":
+        # 256 queries share their rows: the expanded form, 512 key rows at a time (64 MiB of
+        # float32 scores a block), in a loop over the visible blocks; never the whole view's scores
+        assert " while(" in text
+        assert "f32[1,128,256,512]" in text and "f32[1,128,256,8192]" not in text
+        assert memory.temp_size_in_bytes < 0.5e9, memory
+    else:
+        # one query a lane: the absorbed form, every lane's whole view in one pass; no
+        # per-head keys or values of a view's rows ever exist (they would be 17 GB)
+        assert "f32[32,1,128,1,8192]" in text
+        assert "[32,1,8192,128,128]" not in text and "[32,8192,128,128]" not in text
+        # no lane's view is copied or laid out anew: the products read the gathered leaves
+        assert not re.search(r"= bf16\[32,(1,)?8192,512\]\S* (copy|transpose)\(", text)
