@@ -602,7 +602,15 @@ def update_kv_cache_and_attend(cache, q, k, v, cache_pos, n_rep: int, sliding_wi
     ``pos % capacity``. Multi-token writes at ANY position (initial prefill,
     chunked prefill, speculative verification) attend the pre-write ring
     contents concatenated with the chunk, masked by per-slot positions;
-    single-token decode writes one slot and attends the ring alone."""
+    single-token decode writes one slot and attends the ring alone.
+
+    A :class:`PagedCache` (the serving engine's plain decode tick) is not
+    written here at all: the token's row is scored beside the pool's rows
+    and returned as the cache, for the engine to write
+    (:func:`_paged_kv_attend`)."""
+    if isinstance(cache, PagedCache):
+        return _paged_kv_attend(cache, q, k, v, cache_pos, n_rep, sliding_window,
+                                sm_scale, logit_softcap, alibi_slopes)
     if "pos" not in cache:
         start = (0, cache_pos, 0, 0)
         new_cache = {
@@ -794,7 +802,11 @@ def update_latent_cache_and_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_
     rope]`` into the cache at ``cache_pos`` and attend the queries against
     what they can see of it (:func:`_latent_cached_attention`). Returns
     ``(out [B, S, H, v], new_cache)``: the latent twin of
-    :func:`update_kv_cache_and_attend`."""
+    :func:`update_kv_cache_and_attend`, a :class:`PagedCache` included
+    (:func:`_paged_latent_attend`)."""
+    if isinstance(cache, PagedCache):
+        return _paged_latent_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
+                                    cache_pos, sm_scale)
     new_cache = {
         "latent": jax.lax.dynamic_update_slice(
             cache["latent"], c_kv.astype(cache["latent"].dtype), (0, cache_pos, 0)),
@@ -804,6 +816,259 @@ def update_latent_cache_and_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_
     out = _latent_cached_attention(q_nope, q_rope, new_cache["latent"], new_cache["rope"],
                                    w_uk, w_uv, cache_pos, sm_scale)
     return out, new_cache
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["pool", "scales", "pages", "live"], meta_fields=["dtype"])
+@dataclasses.dataclass(frozen=True)
+class PagedCache:
+    """One layer's cache as the serving engine's plain decode tick hands it
+    to a lane of its vmapped batch-1 forward. Shared by all lanes: the page
+    pool itself (``pool``: leaf name -> ``[pages, 1, P, ...]``, page 0 the
+    scratch page) and, for an int8 pool, its pages' scales (``scales``: leaf
+    name -> ``[pages]`` float32; else None). The lane's own: its row of the
+    page table (``pages [Np]`` pool ids, 0 where nothing is allocated) and
+    whether a stream runs in it (``live``). ``dtype`` (static) is what an
+    int8 pool's rows widen to; None reads each leaf in its own type.
+
+    The two ``update_*_cache_and_attend`` functions read such a cache in
+    place (:func:`_attend_work_list`) and return the token's new row, not
+    a cache: nothing here is written."""
+    pool: dict
+    scales: Optional[dict]
+    pages: Any
+    live: Any
+    dtype: Any = None
+
+    def row_dtype(self, name: str):
+        return self.pool[name].dtype if self.dtype is None else self.dtype
+
+
+#: What one step of the tick's work list may hold in float32 scores (heads x
+#: items x rows). A step has a fixed cost, so fewer, larger steps win until
+#: the step's gathered pages, scores and weighted values no longer fit a v5e
+#: core's VMEM together: 128 heads at 256 x 64, 512 x 32 and 1024 x 16 (8 MiB)
+#: read within 7 % of each other, 1024 x 64 (32 MiB) costs four times as much
+#: (the sweep is in PERF.md section 6, PR 34).
+_TICK_SCORE_BYTES = 8 * 2**20
+
+
+def tick_key_tiles(score_heads: int, lanes: int, L: int, page: int) -> tuple:
+    """``(block, group)`` of the decode tick's work list, from the static
+    shape alone (as :func:`cached_key_block`: no option, no flag): key rows
+    an item covers — a whole number of pages, 512 rows where the page allows
+    it — and items a step scores together: the largest power of two whose
+    ``score_heads x group x block`` float32 scores fit ``_TICK_SCORE_BYTES``,
+    at most the list's capacity ``lanes x ceil(L / block)``."""
+    block = min(page * max(1, 512 // page), L)
+    capacity = lanes * -(-L // block)
+    group = 1
+    while 2 * group * block * score_heads * 4 <= _TICK_SCORE_BYTES and 2 * group <= capacity:
+        group *= 2
+    return block, group
+
+
+def tick_key_extent(pos, live, L: int, block: int, sliding_window=None, lib=jnp):
+    """``(first, count)`` per lane: the key blocks of width ``block`` that
+    hold pool rows one query at ``pos`` can see — the rows before its own,
+    which is in no page yet (:func:`cached_key_extent` at no query rows) —
+    and none for a lane no stream runs in, whatever its stale ``pos``.
+    Traced in the tick (``lib=jnp``), counted on the host by the serving
+    engine (``lib=np``)."""
+    first, last = cached_key_extent(pos, 0, L, block, sliding_window, lib=lib)
+    return lib.broadcast_to(first, lib.shape(last)), lib.where(live, last - first, 0)
+
+
+def _attend_work_list(score, weigh, m, acc, pool, scales, table, pos, live, *,
+                      sliding_window=None, dtype=None):
+    """Softmax attention of one query a lane over the pool rows its stream
+    holds, for all ``S`` lanes of a decode tick at once. The work is laid out
+    across lanes: every running lane's key blocks (:func:`tick_key_extent`)
+    are counted, summed cumulatively and so numbered into one list of live
+    ``(slot, block)`` items — capacity ``S x ceil(L / block)``, the live count
+    ``T`` traced. A loop of ``ceil(T / group)`` steps (traced: one program
+    for every mix of positions) gathers a step's items' pages from the pool
+    (an int8 pool's widen by their scales in the same step), scores them and
+    merges each item's (max, sum, weighted values) into its slot's running
+    triple; several items of a step may belong to one slot, so the merge is
+    a segment reduction over ``[S, group]``, in float32. No lane's view is
+    built; a row outside the list is one the mask gives weight 0 exactly.
+
+    ``score(slot [G], blocks, k_pos [G, K]) -> [G, *heads, K]`` float32
+    logits of the rows ``blocks`` (leaf name -> ``[G, K, ...]``) against the
+    queries of lanes ``slot``; ``weigh(p [G, *heads, K], blocks) -> [G,
+    *heads, width]``. ``m [S, *heads]`` / ``acc [S, *heads, width]`` start
+    the running triple: the token's own row, scored by the caller (its sum
+    is 1), so every lane, idle ones too, ends with a finite value. ``table
+    [S, Np]``, ``pos [S]``, ``live [S]``. Returns ``acc / sum``."""
+    S, Np = table.shape
+    names = sorted(pool)
+    P = pool[names[0]].shape[2]
+    L = Np * P
+    block, G = tick_key_tiles(m[0].size, S, L, P)
+    bp = block // P
+    first, count = tick_key_extent(pos, live, L, block, sliding_window)
+    ends = jnp.cumsum(count)
+    T = ends[-1]
+    heads = (1,) * (m.ndim - 1)
+    lanes = jnp.arange(S, dtype=jnp.int32)
+
+    def one_step(t, carry):
+        m, l, acc = carry
+        idx = t * G + jnp.arange(G, dtype=jnp.int32)
+        valid = idx < T
+        item = jnp.minimum(idx, T - 1)          # past the list: its last item again, masked
+        slot = (item[:, None] >= ends[None, :]).sum(-1).astype(jnp.int32)
+        blk = first[slot] + item - (ends[slot] - count[slot])
+        page = blk[:, None] * bp + jnp.arange(bp, dtype=jnp.int32)
+        ids = jnp.where(page < Np, table[slot[:, None], jnp.minimum(page, Np - 1)], 0)
+        blocks = {}
+        for name in names:
+            rows = pool[name][ids]                                   # [G, bp, 1, P, ...]
+            if scales is not None:
+                s = scales[name][ids].reshape(ids.shape + (1,) * (rows.ndim - 2))
+                rows = (rows.astype(jnp.float32) * s).astype(dtype)
+            blocks[name] = rows.reshape((G, block) + rows.shape[4:])
+        k_pos = blk[:, None] * block + jnp.arange(block, dtype=jnp.int32)
+        q_pos = pos[slot][:, None]
+        mask = valid[:, None] & (k_pos < q_pos)
+        if sliding_window is not None:
+            mask &= k_pos > q_pos - sliding_window
+        logits = score(slot, blocks, k_pos)
+        logits = jnp.where(mask.reshape((G,) + heads + (block,)), logits, -1e30)
+        m_i = logits.max(-1)
+        p = jnp.exp(logits - m_i[..., None])
+        # An item wholly masked (past the list) carries weight exp(0) a row
+        # under its own maximum; against its slot's, which is finite (the
+        # token's own row), that is exp(-1e30 - m) = 0 exactly.
+        mine = ((slot[None, :] == lanes[:, None]) & valid[None, :])  # [S, G]
+        m_new = jnp.maximum(m, jnp.where(mine.reshape((S, G) + heads), m_i[None], -1e30).max(1))
+        w = jnp.exp(m_i - m_new[slot])
+        alpha = jnp.exp(m - m_new)
+        seg = dict(precision=jax.lax.Precision.HIGHEST)
+        mine = mine.astype(jnp.float32)
+        l = l * alpha + jnp.tensordot(mine, w * p.sum(-1), 1, **seg)
+        acc = acc * alpha[..., None] + jnp.tensordot(mine, w[..., None] * weigh(p, blocks), 1,
+                                                      **seg)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(0, (T + G - 1) // G, one_step, (m, jnp.ones_like(m), acc))
+    return acc / l[..., None]
+
+
+def _paged_attention(kind, lane, shared, cache: PagedCache, cache_pos, sliding_window=None):
+    """The bridge between the tick's ``jax.vmap`` over batch-1 forwards and
+    :func:`_attend_work_list` over all lanes: one function whose batching
+    rule IS the work-list form (``jax.custom_batching.custom_vmap``), so the
+    model code stays the one batch-1 path. ``lane`` holds what is a lane's
+    own (queries, the token's row), ``shared`` what all lanes share besides
+    the pool; ``kind(lane, shared, pos)`` -> ``(score, weigh, m, acc)`` over
+    a leading lane axis. Unbatched, it runs the same code with one lane."""
+
+    def over_lanes(lane, shared, pool, scales, table, pos, live):
+        score, weigh, m, acc = kind(lane, shared, pos)
+        return _attend_work_list(score, weigh, m, acc, pool, scales, table, pos, live,
+                                 sliding_window=sliding_window, dtype=cache.dtype)
+
+    @jax.custom_batching.custom_vmap
+    def attend(lane, shared, pool, scales, pages, pos, live):
+        one = jax.tree.map(lambda x: x[None], (lane, pages, pos, live))
+        return over_lanes(one[0], shared, pool, scales, *one[1:])[0]
+
+    @attend.def_vmap
+    def over_the_ticks_lanes(axis_size, in_batched, lane, shared, pool, scales, pages, pos, live):
+        if any(jax.tree.leaves(in_batched[1:4])):
+            raise NotImplementedError("a paged cache's pool is shared by every lane of a vmap")
+        lane, pages, pos, live = jax.tree.map(
+            lambda x, b: x if b else jnp.broadcast_to(x, (axis_size,) + jnp.shape(x)),
+            (lane, pages, pos, live), (in_batched[0],) + tuple(in_batched[4:]))
+        return over_lanes(lane, shared, pool, scales, pages, pos, live), True
+
+    return attend(lane, shared, cache.pool, cache.scales, cache.pages,
+                  jnp.asarray(cache_pos, jnp.int32), cache.live)
+
+
+def _paged_kv_attend(cache: PagedCache, q, k, v, cache_pos, n_rep: int, sliding_window=None,
+                     sm_scale=None, logit_softcap=None, alibi_slopes=None):
+    """:func:`update_kv_cache_and_attend` for one token of one lane against a
+    :class:`PagedCache`: scores as :func:`_grouped_cached_attention` scores a
+    view (grouped einsum in float32, softcap, ALiBi, the window's mask), over
+    the pool's rows in place. Returns ``(out [1, 1, H, hd], {"k", "v"}: the
+    token's row [1, 1, n_kv, hd] in the cache's type)``."""
+    from ..ops.attention import softcap_logits
+
+    B, S, H, hd = q.shape
+    if (B, S) != (1, 1):
+        raise NotImplementedError(f"a paged cache takes one token of one stream a call, got {q.shape}")
+    scale = hd**-0.5 if sm_scale is None else sm_scale
+    row = {"k": k.astype(cache.row_dtype("k")), "v": v.astype(cache.row_dtype("v"))}
+    slopes = None if alibi_slopes is None else alibi_slopes.astype(jnp.float32).reshape(
+        H // n_rep, n_rep)
+
+    def kind(lane, slopes, pos):
+        qg, k_own, v_own = lane                                      # [S, G, rep, hd], [S, G, hd] x 2
+
+        def score(slot, blocks, k_pos):
+            logits = jnp.einsum("igrd,ikgd->igrk", qg[slot], blocks["k"].astype(jnp.float32))
+            logits = softcap_logits(logits, logit_softcap)
+            if slopes is not None:
+                logits = logits + slopes[None, :, :, None] * k_pos.astype(jnp.float32)[:, None, None]
+            return logits
+
+        def weigh(p, blocks):
+            return jnp.einsum("igrk,ikgd->igrd", p, blocks["v"].astype(jnp.float32))
+
+        lanes = jnp.arange(qg.shape[0], dtype=jnp.int32)
+        m = score(lanes, {"k": k_own[:, None]}, pos[:, None])[..., 0]
+        acc = jnp.broadcast_to(v_own.astype(jnp.float32)[:, :, None], qg.shape)
+        return score, weigh, m, acc
+
+    qg = (q * scale).astype(jnp.float32).reshape(H // n_rep, n_rep, hd)
+    out = _paged_attention(kind, (qg, row["k"][0, 0], row["v"][0, 0]), slopes, cache, cache_pos,
+                           sliding_window)
+    return out.reshape(1, 1, H, hd).astype(q.dtype), row
+
+
+def _paged_latent_attend(cache: PagedCache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, cache_pos,
+                         sm_scale: float):
+    """:func:`update_latent_cache_and_attend` for one token of one lane
+    against a :class:`PagedCache`, in the absorbed form (what
+    :func:`latent_attention_form` gives one query a head): ``W_uk`` folded
+    into the query and ``W_uv`` applied after the weighted sum of latents,
+    as :func:`_latent_cached_attention` does, over the pool's rows in place.
+    Returns ``(out [1, 1, H, v], {"latent", "rope"}: the token's row)``."""
+    B, S, H, _ = q_nope.shape
+    if (B, S) != (1, 1):
+        raise NotImplementedError(
+            f"a paged cache takes one token of one stream a call, got {q_nope.shape}")
+    row = {"latent": c_kv.astype(cache.row_dtype("latent")),
+           "rope": k_rope.astype(cache.row_dtype("rope"))}
+    out_dtype = q_nope.dtype
+    cdt = jnp.promote_types(out_dtype, row["latent"].dtype)
+    w_uk, w_uv = w_uk.astype(cdt), w_uv.astype(cdt)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def kind(lane, shared, pos):
+        q_c, q_r, c_own, r_own = lane                                # [S, H, rank], [S, H, rope], [S, rank], [S, rope]
+
+        def score(slot, blocks, k_pos):
+            return (jnp.einsum("ihr,ikr->ihk", q_c[slot], blocks["latent"].astype(cdt), **f32)
+                    + jnp.einsum("ihd,ikd->ihk", q_r[slot], blocks["rope"].astype(cdt), **f32))
+
+        def weigh(p, blocks):
+            return jnp.einsum("ihk,ikr->ihr", p.astype(cdt), blocks["latent"].astype(cdt), **f32)
+
+        lanes = jnp.arange(q_c.shape[0], dtype=jnp.int32)
+        m = score(lanes, {"latent": c_own[:, None], "rope": r_own[:, None]}, pos[:, None])[..., 0]
+        acc = jnp.broadcast_to(c_own.astype(jnp.float32)[:, None], q_c.shape)
+        return score, weigh, m, acc
+
+    q_c = (jnp.einsum("hd,rhd->hr", q_nope[0, 0].astype(cdt), w_uk, **f32) * sm_scale).astype(cdt)
+    q_r = (q_rope[0, 0] * sm_scale).astype(cdt)
+    out = _paged_attention(kind, (q_c, q_r, row["latent"][0, 0], row["rope"][0, 0]), None,
+                           cache, cache_pos)
+    out = jnp.einsum("hr,rhd->hd", out.astype(cdt), w_uv, **f32)
+    return out[None, None].astype(out_dtype), row
 
 
 def _lora_delta(y, x, lora, name):

@@ -22,7 +22,10 @@ compiled programs, no matter how requests arrive or leave:
   streams are bit-identical to offline :func:`generation.generate` for the
   same (prompt, rng, sampling). Slot membership is a host-provided boolean
   mask ARGUMENT, never a shape: admitting or retiring a request changes
-  the mask bits, not the program.
+  the mask bits, not the program. Its attention reads the page pool in
+  place: one work list of the live (slot, key block) pairs of all lanes
+  (``models.llama._attend_work_list``), so a tick reads the rows its
+  streams hold, not the rows their slots reserve.
 * the copy-restore (``_paged_restore_prefix_fn``) — only with an EXTERNAL
   ``prefix_cache=``: one compiled copy of a cached ``[1, prefill_chunk]``
   KV block into freshly allocated pages. The engine's private cache needs
@@ -31,9 +34,10 @@ compiled programs, no matter how requests arrive or leave:
 KV memory is PAGED: a global pool of fixed-size pages (``page_size``
 tokens, default one prefill chunk) plus a host-side ``[max_slots,
 max_pages_per_slot]`` page table. The table rides into the warm
-executables as traced integer data — each program gathers a slot's pages
-into a dense view, runs the unchanged forward, and scatters only the
-written pages back — so page allocation, free, preemption, and
+executables as traced integer data — a chunk and a speculative tick
+gather a slot's pages into a dense view, run the unchanged forward and
+scatter only the written pages back; the plain tick reads the pages where
+they lie and writes one row a slot — so page allocation, free, preemption, and
 prefix-block ALIASING (a private-cache hit is a host table write +
 refcount, zero device copies) all compile nothing. Page 0 is a reserved
 scratch page: unallocated table entries point at it, so clamped
@@ -54,8 +58,8 @@ key contributes exactly 0 probability — finite garbage KV never changes
 a real row's output. A chunk against a long view does not even read it:
 ``models.llama._cached_attention`` scores only the key blocks up to the
 chunk's last query (``hi = offset + C``, rounded out to a block), so the
-mask meets garbage only inside the last block and a tick's one-block
-pass. Positions at/past ``true_len`` are overwritten by
+mask meets garbage only inside the last block, in a speculative tick's
+one-block pass and in the last key block of a plain tick's lane. Positions at/past ``true_len`` are overwritten by
 the first decode write at-or-before the first query that could attend
 them. A tick scatters an inactive or ``PREFILLING`` slot's write to the
 scratch page, and every chunk and restore call writes ``pos[slot] =
@@ -164,8 +168,8 @@ from ..generation import (
     speculative_emit,
 )
 from ..inference import resolve_model_source
-from ..models.llama import (cached_attention_rows, cached_key_block,
-                            cached_key_extent)
+from ..models.llama import (PagedCache, cached_attention_rows,
+                            tick_key_extent, tick_key_tiles)
 from ..observability import FlightRecorder, Tracer, new_trace_id
 from .metrics import ServingStats
 from .request import Request, RequestStatus
@@ -407,7 +411,8 @@ class ServingEngine:
         row is symmetric int8 with one per-page f32 scale held in a
         ``pscale`` state array indexed by page id, written by the same
         executables that write the page (quantize at the page scatter,
-        dequantize at the gather into the dense view). Pages cost half
+        dequantize where pages are gathered: into a dense view, or a
+        plain tick's key blocks). Pages cost half
         the bytes, so the same HBM pool admits ~2x the concurrent
         streams; alloc/free/alias/preempt stay pure host work because
         scales live device-side keyed by page id. ``None`` (default)
@@ -1509,65 +1514,70 @@ class ServingEngine:
         return pool_leaves, scales
 
     def _paged_decode_fn(self, params, state, active, table, bank=None):
-        """One tick: gather every slot's view, run a batch-1 single-token
-        forward vmapped over the slot axis (per-slot scalar cache_pos,
-        per-slot rng chain, :func:`generation._next_token` — bitwise the
-        same selection as offline's scan body), then scatter back ONE
-        page per slot: the page holding ``pos[slot]``, the only position
-        a tick writes. Inactive (and ``PREFILLING``) slots scatter to
-        scratch and their pos/tok/rng/done stay frozen, so a stale ``pos``
-        can't corrupt the pool. The host guarantees an active slot's
-        ``pos`` page is allocated before every tick. Returns ``(state,
-        tokens [S], done [S])``."""
+        """One tick: a batch-1 single-token forward vmapped over the slot
+        axis (per-slot scalar cache_pos, per-slot rng chain,
+        :func:`generation._next_token` — bitwise the same selection as
+        offline's scan body) whose attention reads the page pool IN PLACE.
+        No slot's view is gathered: every lane's cache is a
+        ``models.llama.PagedCache`` — the pool, shared, and the lane's row
+        of ``table`` — and the two cached-attention entry points every
+        family calls attend over one work list of the live ``(slot, key
+        block)`` pairs of all lanes (``_attend_work_list``): the rows the
+        running streams hold, from the window's start on a windowed layer;
+        an inactive (or ``PREFILLING``) slot owns none, whatever its stale
+        ``pos``. The model returns each lane's new row, not a cache, and
+        the tick writes it once: one scatter of ``S`` rows a leaf at
+        ``table[s, pos[s] // P]``, row ``pos[s] % P`` (an int8 pool
+        re-quantises the ``S`` touched pages as one batch). Inactive slots
+        write to scratch and their pos/tok/rng/done stay frozen, so a stale
+        ``pos`` can't corrupt the pool. The host guarantees an active
+        slot's ``pos`` page is allocated before every tick. Returns
+        ``(state, tokens [S], done [S])``."""
         P = self._page
         params = self._dq(params)
         scales = state.get("pscale")
-        views = self._gather_views_all_slots(state["pool"], table,
-                                             scales=scales)
+        scale_rows = (None,) * len(state["pool"]) if scales is None else jax.tree.unflatten(
+            self._cache_struct, list(scales))
 
-        def one_slot(cache, tok, pos, rng, done, aidx=None):
-            logits, cache, counts = self._apply_counted(
+        def one_slot(pages, live, tok, pos, rng, done, aidx=None):
+            cache = tuple(
+                PagedCache(pool=layer, scales=sc, pages=pages, live=live,
+                           dtype=None if scales is None else self._dtype)
+                for layer, sc in zip(state["pool"], scale_rows))
+            logits, rows, counts = self._apply_counted(
                 params, tok[None, None], cache=cache,
                 cache_pos=pos, **self._lora_kwargs(bank, aidx))
             rng, sub = jax.random.split(rng)
             nxt, done = _next_token(logits[:, -1], sub, jnp.zeros((1, 1), bool),
                                     done[None], self._select, self.eos_token_id,
                                     tok.dtype)
-            return cache, nxt[0], rng, done[0], counts
+            return rows, nxt[0], rng, done[0], counts
 
-        vmap_args = [views, state["tok"], state["pos"], state["rng"],
+        vmap_args = [table, active, state["tok"], state["pos"], state["rng"],
                      state["done"]]
         if bank is not None:
             vmap_args.append(state["adapter_idx"])
-        new_views, toks, rngs, dones, counts = jax.vmap(one_slot)(*vmap_args)
+        rows, toks, rngs, dones, counts = jax.vmap(one_slot)(*vmap_args)
         toks_out = toks
         if counts is not None:
             # active slots' counters, summed, ride behind the tokens
             counts = jnp.where(active[:, None], counts, 0).sum(0)
             toks_out = jnp.concatenate([toks, counts.astype(toks.dtype)])
-        nv_leaves = jax.tree.leaves(new_views)
-        pool_leaves = jax.tree.leaves(state["pool"])
-        for s in range(self.max_slots):
-            pg = state["pos"][s] // P
-            tid = jax.lax.dynamic_slice(table[s], (pg,), (1,))[0]
-            tgt = jnp.where(active[s], tid, 0)
-            new_pool = []
-            for i, (pl, vl, ax) in enumerate(zip(pool_leaves, nv_leaves,
-                                                 self._cache_axes)):
-                start = [0] * vl.ndim
-                start[0] = s
-                start[ax + 1] = pg * P
-                sizes = list(vl.shape)
-                sizes[0] = 1
-                sizes[ax + 1] = P
-                pb = jax.lax.dynamic_slice(vl, tuple(start), tuple(sizes))[0]
-                if scales is not None:
-                    pb, sc = self._quant_page(pb)
-                    scales = jax.lax.dynamic_update_slice(
-                        scales, sc.reshape(1, 1), (i, tgt))
-                new_pool.append(jax.lax.dynamic_update_slice(
-                    pl, pb[None].astype(pl.dtype), (tgt,) + (0,) * pb.ndim))
-            pool_leaves = new_pool
+        lanes = jnp.arange(self.max_slots)
+        tgt = jnp.where(active, table[lanes, state["pos"] // P], 0)
+        off = state["pos"] % P
+        pool_leaves = []
+        for i, (pl, rl) in enumerate(zip(jax.tree.leaves(state["pool"]),
+                                         jax.tree.leaves(rows))):
+            if scales is None:
+                pool_leaves.append(pl.at[tgt, 0, off].set(rl[:, 0, 0]))
+                continue
+            pb = (pl[tgt].astype(jnp.float32) * scales[i][tgt].reshape(
+                (-1,) + (1,) * (pl.ndim - 1))).astype(self._dtype)
+            pb, sc = jax.vmap(self._quant_page)(
+                pb.at[lanes, 0, off].set(rl[:, 0, 0]))
+            pool_leaves.append(pl.at[tgt].set(pb))
+            scales = scales.at[i, tgt].set(sc)
         state = dict(
             state,
             pool=jax.tree.unflatten(self._cache_struct, pool_leaves),
@@ -2903,26 +2913,28 @@ class ServingEngine:
 
     def _tick_attn_rows(self, positions: list) -> Optional[tuple]:
         """``(scored, visible, view)`` key rows of one plain tick's
-        attention, summed over its lanes and layers (host arithmetic, the
-        chunk's rule at one query a lane, all lanes in one numpy pass).
-        Every lane of the program scores, whether a stream runs in it or
-        not; a lane without one is counted at position 0 (its rows are not
-        visible to anything)."""
+        attention, summed over its lanes and layers: host arithmetic from
+        the running lanes' positions and the program's own rule
+        (``models.llama.tick_key_tiles`` and ``tick_key_extent``, all lanes
+        in one numpy pass). The rows are the pool's: a token's own row is
+        in no page when it is scored. A lane without a stream scores
+        none; a running lane the key blocks that hold rows before its own
+        (from the window's start on a windowed layer), and the tick whole
+        steps of its work list: the items that fill the last step up are
+        scored under the mask too."""
         heads = self._attn_score_heads
         if heads is None:
             return None
         L = self._pages_per_slot * self._page
-        block = cached_key_block(heads, L)
-        # the running lanes' positions, then one idle lane's
-        pos = np.asarray(list(positions) + [0], np.int64)
-        idle = self.max_slots - len(positions)
+        block, group = tick_key_tiles(heads, self.max_slots, L, self._page)
+        pos = np.asarray(positions, np.int64)
         scored = visible = layers = 0
         for window, n in self._attn_layer_kinds:
-            first, last = cached_key_extent(pos, 1, L, block, window, lib=np)
-            rows = (last - first) * block
+            first, count = tick_key_extent(pos, True, L, block, window, lib=np)
+            items = -(-int(count.sum()) // group) * group
             low = 0 if window is None else np.maximum(pos - window + 1, 0)
-            scored += n * int(rows[:-1].sum() + idle * rows[-1])
-            visible += n * int((np.minimum(pos + 1, L) - low)[:-1].sum())
+            scored += n * items * block
+            visible += n * int((pos - low).sum())
             layers += n
         return scored, visible, layers * L * self.max_slots
 

@@ -291,8 +291,9 @@ class ServingStats:
         ``moe_held_apply``'s three totals), ``kv_rows`` the ``(dead,
         held)`` KV rows of the streams that run on: held = rows written x
         layers, dead = those a windowed layer can never read again;
-        ``attn_rows`` the ``(scored, visible, view)`` key rows of the tick's
-        attention, summed over its lanes and layers."""
+        ``attn_rows`` the ``(scored, visible, view)`` pool rows of the
+        tick's attention, summed over its lanes and layers: scored are the
+        running lanes' key blocks, view what all slots reserve."""
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
@@ -685,9 +686,11 @@ class ServingStats:
                 "prefill_attn_rows_fill": round(
                     self._attn_rows_visible / self._attn_rows_scored, 6)
                     if self._attn_rows_scored else 0.0,
-                # The decode ticks' twin of the pair: key rows the ticks'
-                # attention scored over slots x view rows x layers (1.0:
-                # every tick scored every lane's whole view), and the rows
+                # The decode ticks' twin of the pair: pool rows the ticks'
+                # attention scored (the key blocks of the running lanes,
+                # in whole steps of the work list; a lane without a stream
+                # scores none) over slots x view rows x layers (1.0: every
+                # tick read every row every slot reserves), and the rows
                 # the running streams' positions make visible over the
                 # rows scored. Then what one cached token takes, all
                 # layers, from the cache the model declares.

@@ -65,6 +65,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_memory_properties.py",
         "test_models.py",
         "test_observability.py",
+        "test_paged_tick_attention.py",
         "test_pipeline.py",
         "test_quantization.py",
         "test_serving.py",

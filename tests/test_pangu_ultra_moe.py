@@ -186,26 +186,33 @@ def test_the_paged_engine_serves_the_reference_logits(tiny):
     assert pool["page_bytes"] == 8 * bytes_per_token
     assert per_chip == (pool["pages_total"] + 1) * pool["page_bytes"]
     # (e) the ticks' rows, by hand. Ticks feed token j (j = 1..new-1; one more may have been
-    # dispatched ahead of the retirement) at position prompt + j - 1: one lane of two runs,
-    # each lane scores its whole 64-row view in each of 4 layers, the running lane sees pos + 1.
+    # dispatched ahead of the retirement) at position prompt + j - 1. One lane of two runs: its
+    # one key block of 64 rows is one item, the step of two items is scored whole, in each of
+    # 4 layers; the idle lane owns nothing. The running lane sees the pos rows before its own.
     assert summary["decode_attn_rows_share"] == 1.0
     ticks = [new - 1, new]
-    fills = [sum(prompt + j for j in range(1, t + 1)) / (t * 2 * 64) for t in ticks]
+    fills = [sum(prompt + j - 1 for j in range(1, t + 1)) / (t * 2 * 64) for t in ticks]
     assert any(summary["decode_attn_rows_fill"] == pytest.approx(f, abs=1e-6) for f in fills)
     assert summary["prefill_attn_rows_share"] == 1.0          # a toy chunk is one block
     assert summary["moe_held_pick_share"] == 1.0              # all eight experts are held
 
 
-def test_the_tick_counter_counts_idle_lanes_as_scored_and_not_visible(tiny):
+def test_the_tick_counter_counts_the_running_lanes_blocks_and_no_idle_lane(tiny):
     cfg, model, params = tiny
     eng = ServingEngine(model, params, max_slots=4, max_len=32, prefill_chunk=8, page_size=8,
                         autostart=False, warmup=False)
     try:
+        assert llama.tick_key_tiles(cfg.num_attention_heads, 4, 32, 8) == (32, 4)
         rows = eng._tick_attn_rows([3, 10])
+        none = eng._tick_attn_rows([])
+        start = eng._tick_attn_rows([0, 0, 0])
     finally:
         eng.shutdown(drain=False)
-    # 4 lanes x 32 rows x 4 layers scored; positions 3 and 10 see 4 and 11 rows a layer
-    assert rows == (4 * 32 * 4, (4 + 11) * 4, 4 * 32 * 4)
+    # two running lanes own one 32-row block each: one step of 4 items x 32 rows x 4 layers
+    # scored, of 4 lanes x 32 rows x 4 layers held; positions 3 and 10 see 3 and 10 pool rows
+    assert rows == (4 * 32 * 4, (3 + 10) * 4, 4 * 32 * 4)
+    # no stream, or streams whose only row is their own: no item, no step
+    assert none == start == (0, 0, 4 * 32 * 4)
 
 
 def test_the_counters_merge_and_reset():
@@ -338,22 +345,39 @@ def test_what_is_not_implemented_is_refused(key, value):
 # -- (h) the programs of the families the benchmark already had ---------------
 
 # sha256 of ``jit(...).lower(...).as_text()`` (CPU, toy shapes) of the engine
-# programs of Mixtral, windowed Mixtral and cohere2_moe, taken from the parent
-# commit c545906 (PR 32's tree) and equal on this one: no shared helper that
-# these programs trace was changed by adding the latent cache. A PR that
-# changes one of these programs on purpose re-pins its line and says why.
+# programs of Mixtral, windowed Mixtral, cohere2_moe and this family. The chunk
+# and the two speculative ticks are as commit 885e459 (PR 33's tree) lowers
+# them, and before it c545906: no shared helper that these programs trace has
+# changed since. The plain tick (``decode``) was re-pinned by PR 34, which
+# rewrote it on purpose: it reads the page pool in place over a work list of
+# live (slot, key block) pairs and gathers no view
+# (tests/test_paged_tick_attention.py). A PR that changes one of these
+# programs on purpose re-pins its line and says why.
 PARENT_PROGRAMS = {
-    "mixtral/fp/decode": "2dece7991adbecdf3573c49dff6fdf399bc28c558637c228398f8a173a7b7942",
+    "mixtral/fp/decode": "cfe3cee0d4738767eee56c4ac9a88e8d2e3dce83692890ba5e8dda1eae10093b",      # PR 34
     "mixtral/fp/chunk": "90f38ab0fc0c2b1e8bf36456a6b9be38ba03f935f66867a20f835d56f3392347",
-    "mixtral/int8/decode": "d8880d5ee43eb524d9098bbed9d7afeb73a75231bdabf71392a713782ad2ad9c",
+    "mixtral/int8/decode": "3bd3c45a82dbb872d2cd55574da1eecc54d78d7086752ccdebc4d5e2c686f2ca",      # PR 34
+    "mixtral/int8/chunk": "73ed7b8cba4c384430745115ee7b945600c029ff4a803426cde03e0f70bf1c10",
+    "mixtral/lookup/chunk": "2668e42054dfb9dfe75526d36ebd5c701058640880703859995dc00010e6d173",
     "mixtral/lookup/spec_lookup": "d4cab2eb976b7da973baa568c37b0b444ec4907b2a09d038824b1d00016e46af",
+    "mixtral/draft/chunk": "0f26ece5af0ee5af027ca001598bd43fb2e38f70c75b05d1ee274abaffd56031",
     "mixtral/draft/spec": "df29a110e2d7ee3bb63f758c6d4aab5609e24e37f1af0838193e6fe0c023a83b",
-    "mixtral_window/fp/decode": "aa04b18c2bad9d09d18c970893a1387f466504e5bf08fb4f4a11dbfba699b41a",
+    "mixtral_window/fp/decode": "3d7f02c8f083e2c5300541e8bf04bff004f3d2f5e1a964155a23dacb66dcff0c",      # PR 34
     "mixtral_window/fp/chunk": "a213839db13069fe93423af31073b49b6fd5d4bb6f11f87aefb337d64222bb41",
+    "mixtral_window/int8/decode": "b8ae9da650a4e7a51eb522ba5f537a63469c7750ee4ed7ae70ea867ab8ae219c",      # PR 34
+    "mixtral_window/int8/chunk": "60ab0c97a91ed460cb405ee1617b7a8b3dc63e1d81de4c37d843c5e66d74b2de",
+    "mixtral_window/lookup/chunk": "433d483978531e5788baad65063b9c0b591fd1093304f6c2794aa16e7c6457aa",
     "mixtral_window/lookup/spec_lookup": "fccc57f5d9e2216ca1b9bd1c7024add6f846a76126498360fba1e790f46551ac",
-    "cohere2_moe/fp/decode": "45518467b12ccf7059d335003826d366870bb9fcacd1a1c3484d9a20c8931fce",
+    "cohere2_moe/fp/decode": "4205d411e848735eda95337e35218a99fc1dce4f8a4b64021d6a48fceed683f9",      # PR 34
     "cohere2_moe/fp/chunk": "d2ddfedff7aabf8e803b1f2083008fc8492f1823ed5ebb9bbe60428d453f5d0b",
+    "cohere2_moe/int8/decode": "b879bca9ac898329727dfe3bde6be16358ea5f232b1c552a22c9aababf5f7d0c",      # PR 34
+    "cohere2_moe/int8/chunk": "719483a6a532d7a233e102dac6de0804a781e0c2b978bfc31760e74b7ccbc273",
+    "cohere2_moe/lookup/chunk": "8b7db1e85c77dfb0c78737e0c7839df6404febd948610d77e1beae67f231df68",
     "cohere2_moe/lookup/spec_lookup": "bbe9dab6130261c223c3c1a4ac2192a3e5059eaa128d7f5c06a6ba3196bd962a",
+    "pangu_ultra_moe/fp/decode": "7e4c2629ec755e7ccf63cc429c916d96bc1aa89550baec0a3f625b40bacae3b4",      # PR 34
+    "pangu_ultra_moe/fp/chunk": "7dfaaab512641f1030afdc32870036cd2ea91a00720720ce1ac5f2131dc1dbf4",
+    "pangu_ultra_moe/lookup/chunk": "03bb625a5bf7726d04d24956a1feb04a9f7019d99419d2b5cbbc0ca54554b766",
+    "pangu_ultra_moe/lookup/spec_lookup": "81b930fc94aedfb988aba2311423c4cecc8e84b681fda3e0c2b1f6afa10f3e1f",
 }
 
 
@@ -363,7 +387,8 @@ def lowered_programs(family, variant):
 
     model = {"mixtral": lambda: MixtralForCausalLM(MixtralConfig.tiny_moe()),
              "mixtral_window": lambda: MixtralForCausalLM(MixtralConfig.tiny_moe(sliding_window=8)),
-             "cohere2_moe": lambda: Cohere2MoeForCausalLM(Cohere2MoeConfig.tiny())}[family]()
+             "cohere2_moe": lambda: Cohere2MoeForCausalLM(Cohere2MoeConfig.tiny()),
+             "pangu_ultra_moe": lambda: PanguUltraMoeForCausalLM(PanguUltraMoeConfig.tiny())}[family]()
     params = model.init_params(jax.random.PRNGKey(0))
     kw = {"fp": {}, "int8": {"kv_dtype": "int8"}, "lookup": {"spec_tokens": 3, "spec_lookup": 2},
           "draft": {"spec_tokens": 3, "draft_model": model, "draft_params": params}}[variant]
