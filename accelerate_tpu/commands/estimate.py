@@ -41,6 +41,11 @@ def _model_registry():
 
         return PanguUltraMoeForCausalLM(PanguUltraMoeConfig())
 
+    def _phi4_mini_flash():
+        from ..models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
+
+        return Phi4FlashForCausalLM(Phi4FlashConfig())
+
     reg = {
         "llama3-8b": llama("llama3_8b"),
         "llama-tiny": llama("tiny"),
@@ -52,6 +57,7 @@ def _model_registry():
         "mixtral-8x7b": _mixtral_8x7b,
         "command-a-plus": _command_a_plus,
         "openpangu-ultra-moe": _openpangu_ultra_moe,
+        "phi-4-mini-flash-reasoning": _phi4_mini_flash,
         "gpt-neox-20b": lambda: GPTNeoXForCausalLM(GPTNeoXConfig.neox_20b()),
         "opt-30b": lambda: OPTForCausalLM(OPTConfig.opt_30b()),
         "phi-2": lambda: PhiForCausalLM(PhiConfig.phi_2()),
@@ -206,23 +212,34 @@ def _kv_geometry(module):
 
 
 def _declared_kv(module):
-    """``(bytes a token over all layers in bf16, leaves, label)`` of the cache
-    a module declares itself (``init_cache``: latent rows, no head axis), read
-    from its leaves at lengths 2 and 1; None for the per-head K/V families."""
+    """Of the cache a module declares itself (``init_cache``), read from its
+    leaves at lengths 2 and 1: ``(bytes a token over the layers that keep
+    rows, those layers' leaves, label, bytes a SLOT of the leaves without a
+    length axis, whether the rows are per-head K and V)``. The last is what
+    the engine's refusals go by (serving/engine.py): latent rows have no
+    heads to shard and no one scale a page; a recurrent leaf (a state-space
+    layer's state) is held per slot beside the pool, not paged. None for the
+    per-head K/V families of the ladder."""
     if not hasattr(module, "init_cache"):
         return None
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    two, one = (jax.tree.leaves(jax.eval_shape(lambda n=n: module.init_cache(1, n, jnp.bfloat16)))
-                for n in (2, 1))
-    per_tok = sum((int(np.prod(a.shape)) - int(np.prod(b.shape))) * a.dtype.itemsize
-                  for a, b in zip(two, one))
-    widths = sorted({int(np.prod(a.shape)) - int(np.prod(b.shape)) for a, b in zip(two, one)})
-    label = (f"declared by the model: {len(two)} leaves of "
-             f"{'/'.join(map(str, widths))} values a token, no head axis")
-    return per_tok, len(two), label
+    two, one = (jax.eval_shape(lambda n=n: module.init_cache(1, n, jnp.bfloat16)) for n in (2, 1))
+    size = lambda a: int(np.prod(a.shape))                       # noqa: E731
+    pairs = list(zip(jax.tree.leaves(two), jax.tree.leaves(one)))
+    rows = [(a, size(a) - size(b)) for a, b in pairs if a.shape != b.shape]
+    per_tok = sum(width * a.dtype.itemsize for a, width in rows)
+    per_slot = sum(size(a) * a.dtype.itemsize for a, b in pairs if a.shape == b.shape)
+    per_head = all(
+        isinstance(e2, dict) and set(e2) <= {"k", "v"} for e2, e1 in zip(two, one)
+        if any(x.shape != y.shape for x, y in zip(jax.tree.leaves(e2), jax.tree.leaves(e1))))
+    widths = sorted({width for _, width in rows})
+    label = (f"declared by the model: {len(rows)} leaves of "
+             f"{'/'.join(map(str, widths))} values a token"
+             + ("" if per_head else ", no head axis"))
+    return per_tok, len(rows), label, per_slot, per_head
 
 
 def _fmt(nbytes: float) -> str:
@@ -379,17 +396,26 @@ def estimate_command(args) -> int:
             return 2
         kv_int8 = args.kv_dtype == "int8"
         itemsize = 1 if kv_int8 else 2
+        per_slot = 0                     # recurrent state a slot, beside the pool
         if declared is not None:
             # The engine refuses what assumes per-head K and V for such a
             # cache (serving/engine.py): say so here instead of printing a
             # number it would not serve.
-            if kv_int8 or args.tp > 1:
+            per_tok, n_leaves, geometry, per_slot, per_head = declared
+            if not per_head and (kv_int8 or args.tp > 1):
                 print(f"\nPaged KV pool: {type(module).__name__} declares its own KV cache "
                       "(rows without a head axis); the engine refuses --kv-dtype int8 and "
                       "--tp > 1 for it")
                 return 2
-            per_tok, n_leaves, geometry = declared
-            scale_bytes, fp_page_bytes = 0, per_tok * args.page_size
+            if per_slot and args.tp > 1:
+                print(f"\nPaged KV pool: {type(module).__name__}'s cache holds recurrent state "
+                      "per slot (leaves without a length axis); the engine refuses --tp > 1 "
+                      "for it")
+                return 2
+            # int8 pages: one byte a value and one float32 scale a leaf a page
+            scale_bytes = n_leaves * 4 if kv_int8 else 0
+            fp_page_bytes = per_tok * args.page_size
+            per_tok = per_tok // 2 if kv_int8 else per_tok
             layers = geom[0] if geom is not None else n_leaves
         else:
             layers, kv_heads, head_dim = geom
@@ -424,6 +450,10 @@ def estimate_command(args) -> int:
             print(f"  pool ({args.max_pages} pages): {_fmt(pool)}"
                   + (f"  ({_fmt(pool / div)}/chip at tp={args.tp})"
                      if args.tp > 1 else ""))
+        if per_slot:
+            # beside the pool, not in it: fixed a slot whatever its length
+            print(f"  recurrent state : {_fmt(per_slot)}/slot ({per_slot} B; leaves "
+                  "without a length axis, held per slot beside the pool: x max_slots)")
         print("  pages per request at sequence length "
               "(ceil(len / page_size) — dense reserves the max_len row):")
         for s in args.seq_lens:
@@ -485,8 +515,10 @@ def estimate_command(args) -> int:
         print(f"  training (Adam) per chip  : {_fmt(per_chip * 2 * 2 + per_chip * 4 * 3)}")
         geom = _kv_geometry(module)
         if module is not None and hasattr(module, "init_cache"):
+            *_, per_head = _declared_kv(module)
             print(f"  KV cache per chip         : n/a ({type(module).__name__} declares its "
-                  "own KV cache, rows without a head axis: the engine refuses tp > 1 for it)")
+                  f"own KV cache, {'recurrent state held per slot' if per_head else 'rows without a head axis'}"
+                  ": the engine refuses tp > 1 for it)")
         elif geom is not None:
             layers, kv_heads, head_dim = geom
             # The engine shards the KV heads axis when divisible, else the

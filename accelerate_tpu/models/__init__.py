@@ -5,6 +5,7 @@ from .gpt2 import GPT2Config, GPT2LMHeadModel
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel, PipelinedLlamaForCausalLM, causal_lm_loss
 from .mixtral import MixtralConfig, MixtralForCausalLM, mixtral_lm_loss
 from .pangu_ultra_moe import PanguUltraMoeConfig, PanguUltraMoeForCausalLM
+from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
 from .resnet import ResNet, ResNetConfig
 from .simple import MLP, RegressionModel
 from .t5 import T5Config, T5ForConditionalGeneration, seq2seq_lm_loss

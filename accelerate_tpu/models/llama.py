@@ -20,6 +20,7 @@ end-to-end. Design notes:
 from __future__ import annotations
 
 import dataclasses
+import math
 import functools
 from typing import Any, Optional
 
@@ -853,6 +854,33 @@ class PagedCache:
 _TICK_SCORE_BYTES = 8 * 2**20
 
 
+#: The largest slice the TPU's gather takes whole. Asked for more (a page of
+#: 256 rows x 1280 values is 640 KiB), the compiler halves the OPERAND: the
+#: whole pool leaf copied in two parts in every step that gathers from it
+#: (0.9 ms a copy of a 294 MB leaf, 71 ms of a 161 ms tick and 16 ms of a 42 ms
+#: chunk: PERF.md section 6, PR 35). A page of 256 rows x 1024 values, 512 KiB,
+#: is gathered whole.
+_GATHER_SLICE_BYTES = 512 * 1024
+
+
+def gather_pages(leaf, ids):
+    """``leaf[ids]`` for a pool leaf ``[pages, 1, P, ...]`` and page ids of
+    any shape -> ``[*ids.shape, 1, P, ...]``, gathered in row-parts of at
+    most ``_GATHER_SLICE_BYTES`` each where a page is larger: the leaf read
+    as ``[pages * parts, 1, P / parts, ...]`` (the same bytes in place) and
+    each id as its ``parts`` part ids. A page that fits is one plain gather."""
+    P = leaf.shape[2]
+    page_bytes = math.prod(leaf.shape[1:]) * leaf.dtype.itemsize
+    parts = 1
+    while page_bytes > parts * _GATHER_SLICE_BYTES and P % (2 * parts) == 0:
+        parts *= 2
+    if parts == 1:
+        return leaf[ids]
+    split = leaf.reshape((leaf.shape[0] * parts, 1, P // parts) + leaf.shape[3:])
+    rows = split[ids[..., None] * parts + jnp.arange(parts, dtype=ids.dtype)]
+    return rows.reshape(ids.shape + leaf.shape[1:])
+
+
 def tick_key_tiles(score_heads: int, lanes: int, L: int, page: int) -> tuple:
     """``(block, group)`` of the decode tick's work list, from the static
     shape alone (as :func:`cached_key_block`: no option, no flag): key rows
@@ -924,7 +952,7 @@ def _attend_work_list(score, weigh, m, acc, pool, scales, table, pos, live, *,
         ids = jnp.where(page < Np, table[slot[:, None], jnp.minimum(page, Np - 1)], 0)
         blocks = {}
         for name in names:
-            rows = pool[name][ids]                                   # [G, bp, 1, P, ...]
+            rows = gather_pages(pool[name], ids)                     # [G, bp, 1, P, ...]
             if scales is not None:
                 s = scales[name][ids].reshape(ids.shape + (1,) * (rows.ndim - 2))
                 rows = (rows.astype(jnp.float32) * s).astype(dtype)
@@ -1001,6 +1029,8 @@ def _paged_kv_attend(cache: PagedCache, q, k, v, cache_pos, n_rep: int, sliding_
     if (B, S) != (1, 1):
         raise NotImplementedError(f"a paged cache takes one token of one stream a call, got {q.shape}")
     scale = hd**-0.5 if sm_scale is None else sm_scale
+    if cache.pool["k"].ndim == 4:
+        return _paged_flat_kv_attend(cache, q, k, v, cache_pos, n_rep, scale, sliding_window)
     row = {"k": k.astype(cache.row_dtype("k")), "v": v.astype(cache.row_dtype("v"))}
     slopes = None if alibi_slopes is None else alibi_slopes.astype(jnp.float32).reshape(
         H // n_rep, n_rep)
@@ -1027,6 +1057,70 @@ def _paged_kv_attend(cache: PagedCache, q, k, v, cache_pos, n_rep: int, sliding_
     out = _paged_attention(kind, (qg, row["k"][0, 0], row["v"][0, 0]), slopes, cache, cache_pos,
                            sliding_window)
     return out.reshape(1, 1, H, hd).astype(q.dtype), row
+
+
+def _paged_flat_kv_attend(cache: PagedCache, q, k, v, cache_pos, n_rep: int, scale: float,
+                          sliding_window=None):
+    """:func:`_paged_kv_attend` over a pool whose leaves keep a token's heads
+    side by side in ONE row (``[pages, 1, P, G * hd]``: rows of whole lanes
+    however many heads there are; with a head axis of 10 or 20 the TPU's
+    compiler re-laid the whole pool out in every attention of a tick). A
+    step's gathered rows are multiplied AS THEY LIE: each query is placed in
+    its key head's columns of a ``G * hd``-wide row of zeros (a zero adds
+    nothing to a score), the weighted sum is taken over the whole row and a
+    head keeps its own columns of it. That is ``G`` times the arithmetic of
+    the per-head products, which a tick has to spare, and no copy of the
+    rows into a head-major layout, which it has not. Products in the wider
+    of the queries' and the rows' types, accumulated in float32 (as the
+    latent form). Returns ``(out [1, 1, H, hd], {"k", "v"}: the token's row
+    [1, 1, G * hd])``."""
+    _, _, H, hd = q.shape
+    G = H // n_rep
+    row = {"k": k.astype(cache.row_dtype("k")).reshape(1, 1, G * hd),
+           "v": v.astype(cache.row_dtype("v")).reshape(1, 1, G * hd)}
+    cdt = jnp.promote_types(q.dtype, row["k"].dtype)
+    f32 = dict(preferred_element_type=jnp.float32)
+    own = jnp.eye(G, dtype=bool)[:, None, :, None]                   # head g keeps columns g
+
+    def kind(lane, shared, pos):
+        q_rows, k_own, v_own = lane                                  # [S, G, rep, G * hd], [S, G * hd] x 2
+
+        def score(slot, blocks, k_pos):
+            return jnp.einsum("igrc,ikc->igrk", q_rows[slot], blocks["k"].astype(cdt), **f32)
+
+        def weigh(p, blocks):
+            rows = jnp.einsum("igrk,ikc->igrc", p.astype(cdt), blocks["v"].astype(cdt), **f32)
+            rows = rows.reshape(rows.shape[:3] + (G, hd))
+            return jnp.where(own, rows, 0.0).sum(3)
+
+        lanes = jnp.arange(q_rows.shape[0], dtype=jnp.int32)
+        m = score(lanes, {"k": k_own[:, None]}, pos[:, None])[..., 0]
+        acc = jnp.broadcast_to(v_own.astype(jnp.float32).reshape(-1, G, 1, hd),
+                               q_rows.shape[:3] + (hd,))
+        return score, weigh, m, acc
+
+    qg = (q[0, 0] * scale).reshape(G, n_rep, 1, hd)
+    q_rows = jnp.where(own, qg, jnp.zeros((), qg.dtype)).reshape(G, n_rep, G * hd).astype(cdt)
+    out = _paged_attention(kind, (q_rows, row["k"][0, 0], row["v"][0, 0]), None, cache, cache_pos,
+                           sliding_window)
+    return out.reshape(1, 1, H, hd).astype(q.dtype), row
+
+
+def attend_shared_kv_cache(cache, q, cache_pos, n_rep: int, row=None, sm_scale=None):
+    """Attention of a layer that reads a cache entry ANOTHER layer owns, and
+    writes nothing (a cross-decoder over one layer's K/V, models/phi4flash.py).
+    ``cache`` is that entry as its owner left it in this call: a linear
+    ``{"k", "v"}`` ``[B, L, n_kv, hd]`` that already holds the call's rows (a
+    prefill chunk's view: the bounded blocks of :func:`_cached_attention`),
+    or the serving tick's :class:`PagedCache` with ``row``, the owner's
+    ``{"k", "v"}`` of this token ``[1, 1, n_kv, hd]`` — in no page yet, so the
+    reader scores it beside the pool's rows exactly as the owner did
+    (:func:`_paged_kv_attend`, the work list over the pool in place). Causal,
+    no window. Returns ``out [B, S, H, hd]``."""
+    if isinstance(cache, PagedCache):
+        return _paged_kv_attend(cache, q, row["k"], row["v"], cache_pos, n_rep,
+                                sm_scale=sm_scale)[0]
+    return _cached_attention(q, cache["k"], cache["v"], cache_pos, n_rep, sm_scale=sm_scale)
 
 
 def _paged_latent_attend(cache: PagedCache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, cache_pos,

@@ -168,7 +168,7 @@ from ..generation import (
     speculative_emit,
 )
 from ..inference import resolve_model_source
-from ..models.llama import (PagedCache, cached_attention_rows,
+from ..models.llama import (PagedCache, cached_attention_rows, gather_pages,
                             tick_key_extent, tick_key_tiles)
 from ..observability import FlightRecorder, Tracer, new_trace_id
 from .metrics import ServingStats
@@ -650,23 +650,6 @@ class ServingEngine:
             raise ValueError(
                 f"weights_dtype must be None or 'int8' (got {weights_dtype!r})")
         self._kv_dtype = kv_dtype
-        if hasattr(module, "init_cache"):
-            # A cache the module declares (latent rows: no head axis). Its
-            # leaves are paged as any other's; what assumes per-head K and V
-            # is refused here, by name, rather than run unproven.
-            family = type(module).__name__
-            if self.tp > 1:
-                raise NotImplementedError(
-                    f"{family} declares its own KV cache (init_cache: rows "
-                    "without a head axis); tp > 1 shards cache leaves on a "
-                    "heads axis (mesh_exec.heads_axis), which such rows do "
-                    "not have — serve this family with tp=1")
-            if kv_dtype is not None:
-                raise NotImplementedError(
-                    f"{family} declares its own KV cache (init_cache); "
-                    f"kv_dtype={kv_dtype!r} keeps one scale a page, which "
-                    "would span a latent row's normed part and its rotary "
-                    "key — serve this family with kv_dtype=None")
         self._weights_dtype = weights_dtype
 
         # -- speculative-decoding resolution ------------------------------
@@ -752,7 +735,19 @@ class ServingEngine:
             lambda: self._factory(1, self.max_len, self._dtype))
         has_ring = any(isinstance(layer, dict) and "pos" in layer
                        for layer in slot_shape)
-        self._cache_axes = self._cache_length_axes(self._factory)
+        leaf_axes = self._cache_length_axes(self._factory)
+        #: indices of the cache's RECURRENT entries: top-level entries none
+        #: of whose leaves has a length axis (a state-space layer's state).
+        #: Fixed size whatever ``max_len``, so not paged: the engine holds
+        #: one row a slot of each such leaf in ``state["recurrent"]``; the
+        #: chunk program reads its slot's row (zeros at offset 0) and writes
+        #: it back, a tick advances every running lane's row, and the row is
+        #: dead with the slot — the next prompt's first chunk resets it.
+        self._recurrent_at = self._recurrent_entries(slot_shape, leaf_axes)
+        self._cache_axes = [ax for ax in leaf_axes if ax is not None]
+        self._refuse_unproven_caches(module, slot_shape, draft_model,
+                                     spec_lookup, prefix_cache,
+                                     prefix_cache_mb)
         cfg = getattr(module, "config", None)
         #: each layer's attention window where it is shorter than max_len
         #: (None = the layer reads every row), from the same per-layer rule
@@ -772,15 +767,37 @@ class ServingEngine:
         self._page_window = (
             int(next(iter(kinds))) if len(kinds) == 1 and None not in kinds
             else None)
-        #: (window or None, layers) per layer kind, and the query heads (one
+        #: ``(window or None, cache entry)`` of every attention of a forward
+        #: pass: what the model declares (``attention_layout``: layers
+        #: without attention, layers that read another layer's entry), else
+        #: one attention a layer over the layer's own entry.
+        if hasattr(module, "attention_layout"):
+            layout = [(w if w is not None and w < self.max_len else None, e)
+                      for w, e in module.attention_layout()]
+        else:
+            layout = list(zip(self._layer_windows
+                              or [None] * len(slot_shape),
+                              range(len(slot_shape))))
+        #: (window or None, attentions) per kind, and the query heads (one
         #: float32 score a head, a query and a key row): what the
-        #: prefill_attn_rows_* counters need of the chunk's shape.
-        windows = self._layer_windows or [None] * len(slot_shape)
+        #: *_attn_rows_* counters need of the programs' shapes.
+        windows = [w for w, _ in layout]
         self._attn_layer_kinds = [(w, windows.count(w)) for w in set(windows)]
         self._attn_score_heads = getattr(cfg, "num_attention_heads", None)
-        #: (window, layers) pairs for the kv_dead_rows_share counter.
+        #: the cache entries that hold KV rows, each with the window past
+        #: which no reader of it looks (None where some reader sees all):
+        #: (window, entries) pairs for the kv_dead_rows_share counter, over
+        #: ``_kv_entries`` entries in all.
+        readers: dict = {}
+        for w, e in layout:
+            readers.setdefault(e, []).append(w)
+        entry_windows = [None if None in ws else max(ws)
+                         for ws in readers.values()]
+        self._kv_entries = len(slot_shape) - len(self._recurrent_at)
+        self._kv_readers = len(layout)
         self._dead_row_windows = [
-            (int(w), n) for w, n in self._attn_layer_kinds if w is not None]
+            (int(w), entry_windows.count(w))
+            for w in set(entry_windows) if w is not None]
         #: the variable collection a module sows per-call counters into
         #: (models/cohere2_moe.py, models/mixtral.py: MoE pick counts); the
         #: programs ask for it and return its sum packed behind the tokens.
@@ -792,7 +809,8 @@ class ServingEngine:
         #: a later last chunk, when its copy has long arrived.
         self._late_counts: list = []
 
-        probe = jax.eval_shape(lambda: self._factory(1, 2, self._dtype))
+        probe, recurrent = self._split_cache(
+            jax.eval_shape(lambda: self._factory(1, 2, self._dtype)))
         self._cache_struct = jax.tree.structure(probe)
         K = self._spec_k or 0
         # The view must hold max_len + K positions: a verify near the
@@ -832,6 +850,15 @@ class ServingEngine:
             "rng": jnp.zeros((self.max_slots, 2), jnp.uint32),
             "done": jnp.zeros((self.max_slots,), bool),
         }
+        if recurrent:
+            # one row a slot of every recurrent leaf, in the leaf's own type
+            self._state["recurrent"] = jax.tree.map(
+                lambda sh: jnp.zeros((self.max_slots,) + sh.shape, sh.dtype),
+                recurrent)
+        #: bytes of the recurrent state, all slots (0 without such entries)
+        self._recurrent_bytes = sum(
+            self.max_slots * int(np.prod(sh.shape)) * sh.dtype.itemsize
+            for sh in jax.tree.leaves(recurrent))
         if quant:
             # Per-page dequant scales, one row per pool leaf, indexed
             # by page id like the pool itself — device-resident, so a
@@ -857,6 +884,11 @@ class ServingEngine:
             self._draft_cache_struct = jax.tree.structure(dprobe)
             self._draft_cache_axes = self._cache_length_axes(
                 self._draft_factory)
+            if None in self._draft_cache_axes:
+                raise NotImplementedError(
+                    "the draft model's cache has a leaf without a length "
+                    "axis (a recurrent state): a rejected draft would have "
+                    "to roll it back, and nothing snapshots it")
             dpool_leaves, self._draft_page_bytes = [], 0
             for sh, ax in zip(jax.tree.leaves(dprobe),
                               self._draft_cache_axes):
@@ -1175,13 +1207,106 @@ class ServingEngine:
         for x, y in zip(a, b):
             diff = [i for i, (m, n) in enumerate(zip(x.shape, y.shape))
                     if m != n]
-            if len(diff) != 1:
+            if len(diff) > 1:
                 raise NotImplementedError(
-                    "the page pool needs every KV leaf to carry exactly "
-                    f"one length axis (leaf {x.shape} vs {y.shape} at "
+                    "the page pool needs a KV leaf to carry at most one "
+                    f"length axis (leaf {x.shape} vs {y.shape} at "
                     "lengths 2 / 1)")
-            axes.append(diff[0])
+            axes.append(diff[0] if diff else None)
         return axes
+
+    @staticmethod
+    def _recurrent_entries(cache_shape, leaf_axes) -> tuple:
+        """Indices of the cache's top-level entries whose leaves have NO
+        length axis (``leaf_axes`` None). An entry is of one kind: a layer
+        either keeps rows or keeps a state."""
+        if None not in leaf_axes:
+            return ()
+        out, i = [], 0
+        for e, entry in enumerate(cache_shape):
+            n = len(jax.tree.leaves(entry))
+            kinds = {ax is None for ax in leaf_axes[i:i + n]}
+            i += n
+            if len(kinds) > 1:
+                raise NotImplementedError(
+                    f"cache entry {e} mixes leaves with and without a "
+                    "length axis; an entry is paged whole or held per slot "
+                    "whole")
+            if kinds == {True}:
+                out.append(e)
+        return tuple(out)
+
+    def _split_cache(self, cache) -> tuple:
+        """``(paged entries, recurrent entries)`` of a cache in the model's
+        own entry order; the cache itself and ``()`` where none is
+        recurrent (every family but the state-space ones: their programs
+        are traced exactly as before)."""
+        if not self._recurrent_at:
+            return cache, ()
+        return (tuple(e for i, e in enumerate(cache)
+                      if i not in self._recurrent_at),
+                tuple(cache[i] for i in self._recurrent_at))
+
+    def _join_cache(self, paged, recurrent):
+        """The model's cache from its two kinds of entries."""
+        if not self._recurrent_at:
+            return paged
+        paged, recurrent = iter(paged), iter(recurrent)
+        return tuple(next(recurrent) if i in self._recurrent_at else next(paged)
+                     for i in range(self._kv_entries + len(self._recurrent_at)))
+
+    def _refuse_unproven_caches(self, module, cache_shape, draft_model,
+                                spec_lookup, prefix_cache, prefix_cache_mb):
+        """What the cache a module DECLARES (``init_cache``) cannot be
+        served with, refused at construction with the reason, from what its
+        leaves are and not from the family's name: rows that are not
+        per-head K and V (latent rows), and entries without a length axis
+        (recurrent state)."""
+        family = type(module).__name__
+        paged, _ = self._split_cache(cache_shape)
+        per_head_kv = all(isinstance(e, dict) and set(e) <= {"k", "v", "pos"}
+                          for e in paged)
+        if hasattr(module, "init_cache") and not per_head_kv:
+            if self.tp > 1:
+                raise NotImplementedError(
+                    f"{family} declares its own KV cache (init_cache: rows "
+                    "without a head axis); tp > 1 shards cache leaves on a "
+                    "heads axis (mesh_exec.heads_axis), which such rows do "
+                    "not have — serve this family with tp=1")
+            if self._kv_dtype is not None:
+                raise NotImplementedError(
+                    f"{family} declares its own KV cache (init_cache); "
+                    f"kv_dtype={self._kv_dtype!r} keeps one scale a page, which "
+                    "would span a latent row's normed part and its rotary "
+                    "key — serve this family with kv_dtype=None")
+        if not self._recurrent_at:
+            return
+        what = (f"{family}'s cache has {len(self._recurrent_at)} entries "
+                "without a length axis (recurrent state, held per slot)")
+        if draft_model is not None or spec_lookup is not None:
+            raise NotImplementedError(
+                f"{what}; speculative decoding writes draft tokens through "
+                "the state and a rejected draft would have to roll it back — "
+                "nothing snapshots it: serve without draft_model / "
+                "spec_lookup")
+        if self.tp > 1:
+            raise NotImplementedError(
+                f"{what}; no shard axis is declared for such a leaf "
+                "(mesh_exec shards cache leaves on a heads axis) — serve "
+                "with tp=1")
+        if prefix_cache is not None or prefix_cache_mb > 0:
+            raise NotImplementedError(
+                f"{what}; a prefix hit restores PAGES (by aliasing or by "
+                "copy) and cannot restore the state those tokens left — "
+                "nothing snapshots it at chunk boundaries: serve with "
+                "prefix_cache_mb=0 and no shared prefix_cache")
+        if self._chunk_limit % self._chunk:
+            raise ValueError(
+                f"{what}; a prompt's last chunk is pulled back to end at "
+                f"max_len ({self._chunk_limit}) when prefill_chunk "
+                f"({self._chunk}) does not divide it, which would feed the "
+                "state some tokens twice — choose a chunk that divides "
+                "max_len")
 
     # ------------------------------------------------------------------
     # the compiled programs
@@ -1237,7 +1362,9 @@ class ServingEngine:
         struct = self._cache_struct if struct is None else struct
         leaves = []
         for i, (l, ax) in enumerate(zip(jax.tree.leaves(pool), axes)):
-            rows = l[pages]
+            # a page larger than the gather takes whole comes in row-parts
+            # (models.llama.gather_pages); pages that fit: the plain gather
+            rows = gather_pages(l, pages) if ax == 1 else l[pages]
             if scales is not None:
                 s = scales[i][pages].reshape((-1,) + (1,) * (rows.ndim - 1))
                 rows = (rows.astype(jnp.float32) * s).astype(self._dtype)
@@ -1303,6 +1430,17 @@ class ServingEngine:
             **kwargs)
         return logits, cache, sum(jax.tree.leaves(sown))
 
+    @staticmethod
+    def _slot_recurrent_rows(state, slot, offset):
+        """The slot's rows of the recurrent entries as a chunk at ``offset``
+        takes them up: zeros for a prompt's first chunk, whatever the slot's
+        last stream left behind (a preempted stream's re-prefill of ``prompt
+        + tokens`` starts here too, so it resumes exactly with no
+        snapshot)."""
+        return jax.tree.map(
+            lambda a: jnp.where(offset == 0, jnp.zeros_like(a[0]), a[slot]),
+            state["recurrent"])
+
     def _paged_prefill_chunk_fn(self, params, state, ids_c, slot, pages,
                                 offset, true_len, rng, *extra):
         """ONE chunk of prefill: ids_c ``[1, C]`` (tail chunks edge-padded
@@ -1341,9 +1479,16 @@ class ServingEngine:
         # vanishes, leaving the fp program byte-identical.
         scales = state.get("pscale")
         view = self._gather_view(state["pool"], pages, scales=scales)
-        logits, view, counts = self._apply_counted(
-            params, ids_c, cache=view, cache_pos=offset,
-            **self._lora_kwargs(bank, aidx))
+        recurrent, kwargs = (), self._lora_kwargs(bank, aidx)
+        if self._recurrent_at:
+            # steps past the prompt (the last chunk's edge padding) leave
+            # the recurrent rows as they are
+            recurrent = self._slot_recurrent_rows(state, slot, offset)
+            kwargs["valid_len"] = jnp.minimum(true_len - offset, C)
+        logits, cache, counts = self._apply_counted(
+            params, ids_c, cache=self._join_cache(view, recurrent),
+            cache_pos=offset, **kwargs)
+        view, recurrent = self._split_cache(cache)
         tok, done, rng_carry = _chunk_prefill_token(
             logits, rng, self._select, self.eos_token_id, ids_c.dtype,
             true_len, offset)
@@ -1368,6 +1513,9 @@ class ServingEngine:
         )
         if scales is not None:
             new_state["pscale"] = scales
+        if self._recurrent_at:
+            new_state["recurrent"] = jax.tree.map(
+                lambda a, r: a.at[slot].set(r), state["recurrent"], recurrent)
         if bank is not None:
             new_state["adapter_idx"] = state["adapter_idx"].at[slot].set(aidx)
         if dparams is not None:
@@ -1539,25 +1687,29 @@ class ServingEngine:
         scale_rows = (None,) * len(state["pool"]) if scales is None else jax.tree.unflatten(
             self._cache_struct, list(scales))
 
-        def one_slot(pages, live, tok, pos, rng, done, aidx=None):
+        def one_slot(pages, live, tok, pos, rng, done, recurrent, aidx=None):
             cache = tuple(
                 PagedCache(pool=layer, scales=sc, pages=pages, live=live,
                            dtype=None if scales is None else self._dtype)
                 for layer, sc in zip(state["pool"], scale_rows))
             logits, rows, counts = self._apply_counted(
-                params, tok[None, None], cache=cache,
+                params, tok[None, None],
+                cache=self._join_cache(cache, recurrent),
                 cache_pos=pos, **self._lora_kwargs(bank, aidx))
             rng, sub = jax.random.split(rng)
             nxt, done = _next_token(logits[:, -1], sub, jnp.zeros((1, 1), bool),
                                     done[None], self._select, self.eos_token_id,
                                     tok.dtype)
-            return rows, nxt[0], rng, done[0], counts
+            return self._split_cache(rows), nxt[0], rng, done[0], counts
 
+        # a lane's recurrent rows ride the vmap with it (() where the cache
+        # has none: nothing is added to the program)
         vmap_args = [table, active, state["tok"], state["pos"], state["rng"],
-                     state["done"]]
+                     state["done"], state.get("recurrent", ())]
         if bank is not None:
             vmap_args.append(state["adapter_idx"])
-        rows, toks, rngs, dones, counts = jax.vmap(one_slot)(*vmap_args)
+        (rows, recurrent), toks, rngs, dones, counts = jax.vmap(one_slot)(
+            *vmap_args)
         toks_out = toks
         if counts is not None:
             # active slots' counters, summed, ride behind the tokens
@@ -1588,6 +1740,12 @@ class ServingEngine:
         )
         if scales is not None:
             state["pscale"] = scales
+        if self._recurrent_at:
+            # a lane without a stream keeps its rows, as it keeps its pos
+            state["recurrent"] = jax.tree.map(
+                lambda new, old: jnp.where(
+                    active.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
+                recurrent, state["recurrent"])
         return state, toks_out, dones
 
     def _spec_accept(self, logits, drafts, done, rem, rng):
@@ -2221,6 +2379,14 @@ class ServingEngine:
         the cache the model declares (a page's bytes over its rows)."""
         return self._page_bytes // self._page
 
+    @property
+    def recurrent_state_bytes(self) -> int:
+        """Bytes of the state the cache holds per slot BESIDE the page
+        pool (entries without a length axis: a state-space layer's state),
+        over all slots; 0 for a cache of KV rows alone. Fixed by
+        ``max_slots``, whatever ``max_pages`` and ``max_len``."""
+        return self._recurrent_bytes
+
     def kv_cache_per_chip_bytes(self) -> int:
         """Per-device byte footprint of the decode KV state (max shard per
         leaf): the HBM-planning number, ≈ ``1/tp`` of the single-chip
@@ -2228,7 +2394,8 @@ class ServingEngine:
         the page POOL — the number ``max_pages`` controls directly,
         independent of ``max_slots`` — plus the per-page scale arrays on a
         quantized engine (they're replicated, so they count at full size
-        per chip)."""
+        per chip). The KV rows alone: ``recurrent_state_bytes`` is the line
+        for what the cache holds per slot beside them."""
         tree = self._state["pool"]
         extra = sum(self._state[k].nbytes for k in ("pscale", "dpscale")
                     if k in self._state)
@@ -2256,6 +2423,11 @@ class ServingEngine:
             # Draft pages share the pool's id space but are smaller bytes:
             # capacity planning needs both figures.
             out["draft_page_bytes"] = self._draft_page_bytes
+        if self._recurrent_at:
+            # what a slot holds beside its pages, whatever its length
+            out["recurrent_bytes_per_slot"] = (self._recurrent_bytes
+                                               // self.max_slots)
+            out["recurrent_state_bytes"] = self._recurrent_bytes
         return out
 
     def decode_memory_analysis(self):
@@ -2961,7 +3133,9 @@ class ServingEngine:
         self._stats.record_prefill_chunk(dt_ms, backlog=backlog,
                                          host=self._phases.drain(),
                                          moe_picks=counts,
-                                         attn_rows=attn_rows)
+                                         attn_rows=attn_rows,
+                                         state_reset=bool(self._recurrent_at)
+                                         and offset == 0)
         self._tracer.emit(
             "prefill_chunk", t0, dt_ms / 1e3, trace_id=req.trace_id,
             args={"chunk": i, "of": req._chunks_total, "offset": offset,
@@ -3163,7 +3337,6 @@ class ServingEngine:
         phases.covered_s = 0.0
         committed = accepted = n_valid = 0
         dead_rows = held_rows = 0
-        n_layers = len(self._layer_windows)
         positions = []          # of the streams this tick ran (its query's position)
         for slot, req, epoch in flight.entries:
             if (req.status is not RequestStatus.RUNNING
@@ -3205,7 +3378,7 @@ class ServingEngine:
                     # those of them no later query of a windowed layer can
                     # read (row k is dead once k <= pos - window).
                     pos = req._pos_base + len(req.tokens)
-                    held_rows += pos * n_layers
+                    held_rows += pos * self._kv_entries
                     for w, layers in self._dead_row_windows:
                         if pos >= w:
                             dead_rows += (pos - w + 1) * layers
@@ -3253,7 +3426,10 @@ class ServingEngine:
         self._stats.record_pages(self._pool.free_pages, self._pool.used_pages,
                                  self._pool.num_pages,
                                  freed_total=self._pool.frees,
-                                 kv_bytes_per_token=self.kv_bytes_per_token)
+                                 kv_bytes_per_token=self.kv_bytes_per_token,
+                                 kv_cache_layers=self._kv_entries,
+                                 kv_reader_layers=self._kv_readers,
+                                 recurrent_state_bytes=self._recurrent_bytes)
 
     def _dispatch_spec(self, running, ahead: bool,
                        stale) -> Optional[_TickFlight]:

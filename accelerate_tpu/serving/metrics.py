@@ -192,6 +192,16 @@ class ServingStats:
             self._tick_rows_visible = 0
             self._tick_rows_view = 0
             self._kv_bytes_per_token = 0
+            # What the row counters are sums over (gauges): the cache
+            # entries that hold KV rows and the attentions that read them
+            # (a layer may read another layer's entry, or none); then the
+            # recurrent state a cache holds per slot beside its pages, in
+            # bytes over all slots, and how often a prompt's first chunk
+            # reset a slot's.
+            self._kv_cache_layers = 0
+            self._kv_reader_layers = 0
+            self._recurrent_state_bytes = 0
+            self._recurrent_state_resets = 0
             # Speculative decoding: draft proposals vs target acceptances.
             self._spec_ticks = 0
             self._spec_proposed = 0
@@ -325,17 +335,21 @@ class ServingStats:
 
     def record_prefill_chunk(self, ms: float, backlog: int = 0,
                              host: Optional[dict] = None, moe_picks=None,
-                             attn_rows: Optional[tuple] = None):
+                             attn_rows: Optional[tuple] = None,
+                             state_reset: bool = False):
         """One ``prefill_chunk`` execution; ``backlog`` is the number of
         requests in ``PREFILLING`` at the time of the call (how much
         admission work is still pending behind the per-tick budget);
         ``host`` the phase timings measured since the last record,
         ``moe_picks`` the chunk's expert picks (as in ``record_tick``),
         ``attn_rows`` the ``(scored, visible, view)`` key rows of its
-        attention, summed over the layers."""
+        attention, summed over the layers; ``state_reset``: the chunk set
+        its slot's recurrent state to zero (a prompt's first chunk, where
+        the cache has such state)."""
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
+            self._recurrent_state_resets += bool(state_reset)
             if attn_rows is not None:
                 self._attn_rows_scored += int(attn_rows[0])
                 self._attn_rows_visible += int(attn_rows[1])
@@ -361,13 +375,21 @@ class ServingStats:
             self._prefix_restored_bytes += int(bytes_restored)
 
     def record_pages(self, free: int, used: int, total: int,
-                     freed_total: int = 0, kv_bytes_per_token: int = 0):
+                     freed_total: int = 0, kv_bytes_per_token: int = 0,
+                     kv_cache_layers: int = 0, kv_reader_layers: int = 0,
+                     recurrent_state_bytes: int = 0):
         """Gauge: paged-KV pool occupancy after a tick (page counts).
         ``freed_total`` mirrors the pool's cumulative free count — the
         page-drain observable behind the gateway's pressure Retry-After;
-        ``kv_bytes_per_token`` is a page's bytes over its rows."""
+        ``kv_bytes_per_token`` is a page's bytes over its rows, over
+        ``kv_cache_layers`` cache entries that ``kv_reader_layers``
+        attentions read; ``recurrent_state_bytes`` what the cache holds per
+        slot beside the pages, all slots."""
         with self._lock:
             self._kv_bytes_per_token = int(kv_bytes_per_token)
+            self._kv_cache_layers = int(kv_cache_layers)
+            self._kv_reader_layers = int(kv_reader_layers)
+            self._recurrent_state_bytes = int(recurrent_state_bytes)
             self._pages_free = int(free)
             self._pages_used = int(used)
             self._pages_total = int(total)
@@ -541,11 +563,13 @@ class ServingStats:
                       "_emission_stalls", "_kv_rows_held", "_kv_rows_dead",
                       "_attn_rows_scored", "_attn_rows_visible",
                       "_attn_rows_view", "_tick_rows_scored",
-                      "_tick_rows_visible", "_tick_rows_view"):
+                      "_tick_rows_visible", "_tick_rows_view",
+                      "_recurrent_state_resets", "_recurrent_state_bytes"):
                 setattr(self, k, getattr(self, k) + o[k])
             for k in ("_queue_wait_ms_max", "_ttft_ms_max",
                       "_prefill_backlog_max", "_host_us_max",
-                      "_logprob_drift", "_kv_bytes_per_token"):
+                      "_logprob_drift", "_kv_bytes_per_token",
+                      "_kv_cache_layers", "_kv_reader_layers"):
                 setattr(self, k, max(getattr(self, k), o[k]))
             self._ttft_samples.extend(o_samples)
             if len(self._ttft_samples) > self.MAX_TTFT_SAMPLES:
@@ -701,6 +725,10 @@ class ServingStats:
                     self._tick_rows_visible / self._tick_rows_scored, 6)
                     if self._tick_rows_scored else 0.0,
                 "kv_bytes_per_token": self._kv_bytes_per_token,
+                "kv_cache_layers": self._kv_cache_layers,
+                "kv_reader_layers": self._kv_reader_layers,
+                "recurrent_state_bytes": self._recurrent_state_bytes,
+                "recurrent_state_resets": self._recurrent_state_resets,
             }
             # The host path by phase ("host_us/<phase>", slash-pathed like
             # the adapter keys; the gateway re-emits them as one labeled

@@ -55,6 +55,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_cached_attention_bounded.py",
         "test_cohere2_moe.py",
         "test_pangu_ultra_moe.py",
+        "test_phi4flash.py",
         "test_fp8.py",
         "test_generation.py",
         "test_hf_interop.py",

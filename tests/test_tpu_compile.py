@@ -339,3 +339,86 @@ def test_pangu_ultra_moe_serving_program_compiles_for_v5e(program, one_chip):
         assert "[32,1,8192,128,128]" not in text and "[32,8192,128,128]" not in text
         # no lane's view is copied or laid out anew: the products read the gathered leaves
         assert not re.search(r"= bf16\[32,(1,)?8192,512\]\S* (copy|transpose)\(", text)
+
+
+# ---------------------------------------------------------------------------
+# The phi4flash family's serving programs at the benchmark cell's widths
+# ---------------------------------------------------------------------------
+
+def _phi4flash_programs(one_chip):
+    """Phi-4-mini-flash-reasoning whole (32 layers, hidden 2560, 40/20 heads of
+    64, inner width 5120 x 16 states, vocabulary 200064) as the paged engine
+    runs it in ``serve-phi4flash-closed48-reason``: 48 slots x 4096, 448 pages
+    of 256 rows. The chunk: one slot's gathered views of the 9 K/V entries and
+    its 9 recurrent entries, a padded prompt (``valid_len``). The tick:
+    ``jax.vmap`` over 48 lanes of a batch-1 forward whose K/V entries are
+    ``PagedCache`` (the pool shared, read in place) and whose recurrent entries
+    are the lane's own rows."""
+    from accelerate_tpu.models.llama import PagedCache
+    from accelerate_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
+
+    cfg = Phi4FlashConfig()
+    model = Phi4FlashForCausalLM(cfg)
+    slots, max_len, chunk, pages, page = 48, 4096, 256, 448, 256
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda a: struct(a.shape, jnp.bfloat16), shapes)
+    entries = jax.eval_shape(lambda: model.init_cache(1, max_len, jnp.bfloat16))
+    paged = [i for i, e in enumerate(entries) if "k" in e]
+
+    def place(tree, lead=()):
+        return jax.tree.map(lambda a: struct(lead + a.shape, a.dtype), tree)
+
+    views = tuple(place(e) for e in entries)                    # the chunk's cache
+    pool = tuple(jax.tree.map(lambda a: struct((pages + 1, 1, page) + a.shape[2:], a.dtype),
+                              entries[i]) for i in paged)
+    recurrent = tuple(place(e, (slots,)) for i, e in enumerate(entries) if i not in paged)
+
+    def chunk_fn(params, ids, cache, pos, valid):
+        return model.apply({"params": params}, ids, cache=cache, cache_pos=pos, valid_len=valid)
+
+    def tick_fn(params, toks, pool, recurrent, table, positions, live):
+        def one_slot(tok, rec, pages_row, pos, alive):
+            kv, rec = iter(pool), iter(rec)
+            cache = tuple(PagedCache(pool=next(kv), scales=None, pages=pages_row, live=alive)
+                          if i in paged else next(rec) for i in range(len(entries)))
+            logits, rows = model.apply({"params": params}, tok[None, None], cache=cache,
+                                       cache_pos=pos)
+            return jnp.argmax(logits[0, -1]), rows
+
+        return jax.vmap(one_slot)(toks, recurrent, table, positions, live)
+
+    i32 = jnp.int32
+    return {
+        "prefill_chunk": (chunk_fn, (params, struct((1, chunk), i32), views, struct((), i32),
+                                     struct((), i32))),
+        "decode_tick": (tick_fn, (params, struct((slots,), i32), pool, recurrent,
+                                  struct((slots, max_len // page), i32), struct((slots,), i32),
+                                  struct((slots,), jnp.bool_))),
+    }
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
+def test_phi4flash_serving_program_compiles_for_v5e(program, one_chip):
+    fn, args = _phi4flash_programs(one_chip)[program]
+    compiled = jax.jit(fn).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    weights = 7.70e9                                             # 3.85 G parameters, bf16
+    state = 48 * 3225600                                         # 0.15 GB of recurrent rows
+    held = (9 * 4096 * 2560 * 2 + 3225600 if program == "prefill_chunk"
+            else 449 * 256 * 46080 + state)                      # one slot's views / the pool
+    assert weights * 0.98 < memory.argument_size_in_bytes - held < weights * 1.02
+    text = compiled.as_text()
+    assert " while(" in text
+    if program == "prefill_chunk":
+        # attention over 1024-row key blocks, 40 score heads; never the whole view's scores
+        assert "f32[1,10,4,256,1024]" in text and "256,4096]" not in text
+        assert memory.temp_size_in_bytes < 1.5e9, memory
+    else:
+        # no lane's view is gathered: the pool's pages are read in the work list's steps
+        assert "[48,1,4096," not in text and "[48,4096," not in text
+        assert memory.temp_size_in_bytes < 1.0e9, memory
