@@ -17,6 +17,12 @@ One layer, from the published config keys:
   (``shared_expert_combination_strategy`` "average");
 * final LayerNorm, logits against the tied embedding times ``logit_scale``.
 
+The serving engine holds the q and k projection kernels in another form than
+the published one (``Cohere2MoeForCausalLM.served_form``): each head's
+columns in half-split order, so that models/llama.py ``apply_rotary`` turns
+the same pairs, and the q kernel ``[heads x head_dim, hidden]``. Checkpoints,
+``generate``, the trainer and models/reference read the published form.
+
 Attention's cache path is models/llama.py's (``update_kv_cache_and_attend``:
 linear caches, rings for sliding layers outside the paged engine, the window
 mask); the rotary layout and the absence of it on full layers are this
@@ -29,13 +35,15 @@ trace. The model follows the generation contract of ``MixtralForCausalLM``:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .llama import multi_head_attention, rotary_embedding, update_kv_cache_and_attend
+from .llama import (ServedForm, apply_rotary, multi_head_attention, rotary_embedding,
+                    update_kv_cache_and_attend)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -120,19 +128,43 @@ def apply_rotary_interleaved(x, cos, sin):
 
 class _Kernel(nn.Module):
     """A bias-free projection stored as ``<name>/kernel``, the layout
-    nn.Dense gives (and hf_interop maps); computed in the input's type."""
+    nn.Dense gives (and hf_interop maps); computed in the input's type.
+    ``outputs_first`` holds it ``[features, in]`` and contracts its second
+    axis: the orientation the TPU compiler asks of the q kernel (16384 x 4096),
+    which it otherwise copies into that orientation in every call (0.41 ms a
+    layer in both serving programs: PERF.md section 6, PR 36)."""
     features: int
+    outputs_first: bool = False
 
     @nn.compact
     def __call__(self, x):
+        if self.outputs_first:
+            kernel = self.param("kernel", nn.initializers.lecun_normal(in_axis=-1, out_axis=-2),
+                                (self.features, x.shape[-1]), jnp.float32)
+            return jnp.einsum("...h,nh->...n", x, kernel.astype(x.dtype))
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (x.shape[-1], self.features), jnp.float32)
         return x @ kernel.astype(x.dtype)
 
 
+def regroup_rotary_pairs(kernel, head_dim: int, half_split: bool):
+    """A kernel ``[in, heads x head_dim]`` with every head's columns regrouped:
+    the interleaved rotary's pairs ``(2i, 2i+1)`` moved to where the half-split
+    rotary looks for them, ``(i, i + head_dim/2)`` — a head's even columns,
+    then its odd ones — or, ``half_split=False``, back. Applied to q and k
+    alike it changes no score (a dot product does not depend on the order of
+    its terms), and ``apply_rotary`` on the regrouped projection is
+    ``apply_rotary_interleaved`` on the published one, regrouped."""
+    split = (head_dim // 2, 2) if half_split else (2, head_dim // 2)
+    heads = kernel.reshape(kernel.shape[0], -1, *split)
+    return jnp.swapaxes(heads, -1, -2).reshape(kernel.shape)
+
+
 class Cohere2Attention(nn.Module):
     config: Cohere2MoeConfig
     layer_idx: int = 0
+    #: read the kernels in the served form (``served_form``)
+    served: bool = False
 
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_pos=None):
@@ -141,13 +173,15 @@ class Cohere2Attention(nn.Module):
         n_q, n_kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         window = cfg.window_for(self.layer_idx)
         with jax.named_scope("attn_local" if window is not None else "attn_global"):
-            q = _Kernel(n_q * hd, name="q_proj")(x).reshape(B, S, n_q, hd)
+            q = _Kernel(n_q * hd, outputs_first=self.served,
+                        name="q_proj")(x).reshape(B, S, n_q, hd)
             k = _Kernel(n_kv * hd, name="k_proj")(x).reshape(B, S, n_kv, hd)
             v = _Kernel(n_kv * hd, name="v_proj")(x).reshape(B, S, n_kv, hd)
             if window is not None:          # full layers: no positional encoding
                 cos, sin = rotary_embedding(positions, hd, cfg.rope_theta)     # float32
-                q = apply_rotary_interleaved(q.astype(jnp.float32), cos, sin).astype(x.dtype)
-                k = apply_rotary_interleaved(k.astype(jnp.float32), cos, sin).astype(x.dtype)
+                rotate = apply_rotary if self.served else apply_rotary_interleaved
+                q = rotate(q.astype(jnp.float32), cos, sin).astype(x.dtype)
+                k = rotate(k.astype(jnp.float32), cos, sin).astype(x.dtype)
             new_cache = None
             if cache is not None:
                 out, new_cache = update_kv_cache_and_attend(
@@ -206,12 +240,13 @@ class Cohere2MoeMLP(nn.Module):
 class Cohere2MoeBlock(nn.Module):
     config: Cohere2MoeConfig
     layer_idx: int = 0
+    served: bool = False
 
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_pos=None):
         cfg = self.config
         normed = ScaleLayerNorm(cfg.layer_norm_eps, name="input_norm")(x)
-        attn, new_cache = Cohere2Attention(cfg, self.layer_idx, name="self_attn")(
+        attn, new_cache = Cohere2Attention(cfg, self.layer_idx, self.served, name="self_attn")(
             normed, positions, cache=cache, cache_pos=cache_pos)
         routed, shared = Cohere2MoeMLP(cfg, name="mlp")(normed)
         return x + attn + routed + shared, new_cache          # the parallel block
@@ -219,6 +254,8 @@ class Cohere2MoeBlock(nn.Module):
 
 class Cohere2MoeForCausalLM(nn.Module):
     config: Cohere2MoeConfig
+    #: the tree is in the served form (``served_form``), not the published
+    served: bool = False
 
     #: the variable collection the MoE layers sow their pick counts into; the
     #: serving engine asks for it and folds it into its counters.
@@ -236,7 +273,7 @@ class Cohere2MoeForCausalLM(nn.Module):
         x = embed(input_ids)
         new_caches = []
         for i in range(cfg.num_hidden_layers):
-            x, layer_cache = Cohere2MoeBlock(cfg, layer_idx=i, name=f"layers_{i}")(
+            x, layer_cache = Cohere2MoeBlock(cfg, layer_idx=i, served=self.served, name=f"layers_{i}")(
                 x, positions, cache=None if cache is None else cache[i], cache_pos=cache_pos)
             new_caches.append(layer_cache)
         x = ScaleLayerNorm(cfg.layer_norm_eps, name="norm")(x)
@@ -251,3 +288,41 @@ class Cohere2MoeForCausalLM(nn.Module):
     def init_params(self, rng, batch_size=1, seq_len=8):
         dummy = jnp.zeros((batch_size, seq_len), jnp.int32)
         return self.init(rng, dummy)["params"]
+
+    def served_form(self) -> Optional[ServedForm]:
+        """The form in which the serving engine holds this family's weights
+        (it asks once, at load): on sliding layers the columns of every q and
+        k head in half-split order, on every layer the q kernel transposed to
+        ``[heads x head_dim, hidden]``. In the published form both serving
+        programs laid each 134 MB q kernel out anew in every call, once for
+        the orientation and once more for the interleaved pairs. The K pages
+        of a served engine hold keys in the permuted order; v, ``o_proj`` and
+        every logit are the published form's. None for the module that
+        already reads the served tree."""
+        if self.served:
+            return None
+        cfg = self.config
+
+        def reform(params, served: bool):
+            # a leaf may be a pytree of arrays laid out alike (an int8 kernel
+            # and its per-column scales): every array of it moves the same way
+            out = dict(params)
+            for i in range(cfg.num_hidden_layers):
+                layer = params[f"layers_{i}"]
+                attn = dict(layer["self_attn"])
+                if cfg.window_for(i) is not None:            # full layers: no rotary
+                    regroup = lambda a: regroup_rotary_pairs(a, cfg.head_dim, half_split=served)
+                    attn["k_proj"] = {"kernel": jax.tree.map(regroup, attn["k_proj"]["kernel"])}
+                else:
+                    regroup = lambda a: a
+                turn = (lambda a: regroup(a).T) if served else (lambda a: regroup(a.T))
+                attn["q_proj"] = {"kernel": jax.tree.map(turn, attn["q_proj"]["kernel"])}
+                out[f"layers_{i}"] = dict(layer, self_attn=attn)
+            return out
+
+        to_served = functools.partial(reform, served=True)
+        to_published = functools.partial(reform, served=False)
+
+        # parent=None: a module made inside a method would else become its child
+        return ServedForm(Cohere2MoeForCausalLM(cfg, served=True, parent=None),
+                          to_served, to_published)

@@ -22,7 +22,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -817,6 +817,23 @@ def update_latent_cache_and_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_
     out = _latent_cached_attention(q_nope, q_rope, new_cache["latent"], new_cache["rope"],
                                    w_uk, w_uv, cache_pos, sm_scale)
     return out, new_cache
+
+
+class ServedForm(NamedTuple):
+    """What a family's ``served_form()`` hands the serving engine: the form in
+    which its weights are held on the device while they are served, where that
+    is not the published one (the layout checkpoints, ``generate`` and the
+    trainer read). ``to_served`` and ``to_published`` are pure functions
+    ``params -> params``, each the other's inverse bit for bit; a leaf they do
+    not move comes back as the same object, and every array of a leaf that is
+    itself a pytree (an int8 kernel with its scales) moves alike. ``module``
+    reads the served tree and computes what the family's own module computes
+    on the published one. The engine runs ``to_served`` once, at load
+    (serving/engine.py ``_hold_in_served_form``); a family without the hook
+    is served as published."""
+    module: Any
+    to_served: Callable
+    to_published: Callable
 
 
 @functools.partial(jax.tree_util.register_dataclass,

@@ -152,6 +152,7 @@ import itertools
 import os
 import threading
 import time
+import weakref
 from typing import Optional
 
 import jax
@@ -187,6 +188,63 @@ __all__ = ["ServingEngine"]
 
 #: distinct tracer/flight-recorder identities per engine in one process.
 _ENGINE_SEQ = itertools.count()
+
+#: ``id(published leaf) -> (weak reference to it, its served twin)`` for the
+#: leaves :func:`_hold_in_served_form` took. A tree's owner keeps handing the
+#: same tree to every engine it builds (a fleet's retained factory: replicas,
+#: restarts), and on an accelerator the first engine DONATED those leaves: the
+#: later ones find the twins here. An entry lives as long as the published
+#: leaf object does, as the published weights themselves did before.
+_SERVED_TWINS: dict = {}
+
+
+def _served_form_of(module):
+    """The family's :class:`~accelerate_tpu.models.llama.ServedForm`, or None
+    where its weights are served as published (no hook, or ``module`` already
+    reads the served tree)."""
+    return module.served_form() if hasattr(module, "served_form") else None
+
+
+def _hold_in_served_form(module, params):
+    """Put ``params`` into the form their family serves them in
+    (``module.served_form()``, models/llama.py ``ServedForm``), once, before
+    any program is staged -> ``(module that reads them, params, leaves moved,
+    their bytes)``. A module without the hook (or already the served one) is
+    returned with its tree as they came, counts 0.
+
+    One jitted call over the leaves the form moves, found by tracing it once
+    abstractly (a leaf it does not move comes back as the same object). On an
+    accelerator those leaves are DONATED, so the published 134 MB kernels do
+    not stay on the device beside their twins: the caller's tree must not be
+    read there afterwards — only handed to further engines, which find the
+    twins in ``_SERVED_TWINS``."""
+    form = _served_form_of(module)
+    if form is None:
+        return module, params, 0, 0
+    flat, treedef = jax.tree.flatten(params)
+    moved: list = []
+
+    def moved_only(*leaves):
+        out = jax.tree.leaves(form.to_served(jax.tree.unflatten(treedef, leaves)))
+        moved[:] = [i for i, (l, o) in enumerate(zip(leaves, out)) if o is not l]
+        return [out[i] for i in moved]
+
+    jax.eval_shape(moved_only, *flat)
+    twins = [_SERVED_TWINS.get(id(flat[i])) for i in moved]
+    if all(t is not None and t[0]() is flat[i] for t, i in zip(twins, moved)):
+        served = [t[1] for t in twins]
+    else:
+        donate = () if jax.default_backend() == "cpu" else tuple(moved)
+        served = jax.jit(moved_only, donate_argnums=donate)(*flat)
+        for i, twin in zip(moved, served):
+            key = id(flat[i])
+            _SERVED_TWINS[key] = (
+                weakref.ref(flat[i], lambda _, key=key: _SERVED_TWINS.pop(key, None)),
+                twin)
+    nbytes = sum(int(t.size) * t.dtype.itemsize for t in served)
+    for i, twin in zip(moved, served):
+        flat[i] = twin
+    return form.module, jax.tree.unflatten(treedef, flat), len(moved), nbytes
 
 
 class _TickFlight:
@@ -398,7 +456,14 @@ class ServingEngine:
     Args:
       model: an accelerate_tpu ``Model``/``AcceleratedModel`` or a bare
         cache-threading flax module (see ``generation.supports_kv_cache``).
-      params: parameter pytree (defaults to the prepared model's).
+      params: parameter pytree (defaults to the prepared model's), in the
+        published layout. A family that states a served form
+        (``module.served_form()``, e.g. ``cohere2_moe``) is held in it:
+        ``engine.module`` / ``engine.params`` are then the served pair
+        (``served_form().to_published(engine.params)`` is the published
+        tree), and on an accelerator the leaves the form moves are DONATED
+        out of the tree passed here — hand it to further engines, do not
+        read it.
       max_slots: decode lanes — the fixed batch dimension of the tick.
       max_len: longest stream a slot can hold; every request must satisfy
         ``prompt_len + max_new_tokens <= max_len``.
@@ -913,6 +978,14 @@ class ServingEngine:
         # byte-identical programs to the pre-adapter engine.
         self._adapters = adapters
         if adapters is not None:
+            if _served_form_of(module) is not None:
+                raise NotImplementedError(
+                    f"{type(module).__name__} is served in another form than "
+                    "the published one (served_form): an AdapterBank's "
+                    "factors on q_proj / k_proj are columns in the published "
+                    "order, which the served kernels no longer have, and "
+                    "nothing re-forms a bank row as it is registered — "
+                    "serve this family without adapters=")
             self._state["adapter_idx"] = jnp.zeros((self.max_slots,),
                                                    jnp.int32)
 
@@ -926,6 +999,25 @@ class ServingEngine:
         if self._weights_dtype is not None:
             from ..adapters.quantize import quantize_base_weights
             self.params = params = quantize_base_weights(params)
+
+        # The family's served form, likewise ONCE and before placement: the
+        # programs then read the kernels as they lie (no kernel is laid out
+        # anew in every tick and every chunk). After quantization, so that an
+        # int8 kernel keeps the published form's per-output-channel scales:
+        # its ints and its scales move together. From here on ``self.module``
+        # reads ``self.params`` and neither is the published pair; the K
+        # pages hold what that module's attention writes (permuted keys, for
+        # a form that reorders a head's columns) and nothing else reads them.
+        # A draft model goes the same way.
+        self.module, self.params, leaves, nbytes = _hold_in_served_form(
+            module, params)
+        module, params = self.module, self.params
+        if self._draft_module is not None:
+            self._draft_module, self._draft_params, dleaves, dbytes = (
+                _hold_in_served_form(self._draft_module, self._draft_params))
+            leaves, nbytes = leaves + dleaves, nbytes + dbytes
+        #: leaves (and their bytes) held in a form other than the published
+        self._served_form_leaves, self._served_form_bytes = leaves, nbytes
 
         # CPU jit warns (and ignores) donation; donate only where it works.
         donate = () if jax.default_backend() == "cpu" else (1,)
@@ -3429,7 +3521,9 @@ class ServingEngine:
                                  kv_bytes_per_token=self.kv_bytes_per_token,
                                  kv_cache_layers=self._kv_entries,
                                  kv_reader_layers=self._kv_readers,
-                                 recurrent_state_bytes=self._recurrent_bytes)
+                                 recurrent_state_bytes=self._recurrent_bytes,
+                                 weights_served_form_leaves=self._served_form_leaves,
+                                 weights_served_form_bytes=self._served_form_bytes)
 
     def _dispatch_spec(self, running, ahead: bool,
                        stale) -> Optional[_TickFlight]:

@@ -202,6 +202,11 @@ class ServingStats:
             self._kv_reader_layers = 0
             self._recurrent_state_bytes = 0
             self._recurrent_state_resets = 0
+            # Weight leaves the engine holds in another form than the
+            # published one (the family's served form), and their bytes
+            # (gauges, set at load: 0 for a family served as published).
+            self._weights_served_form_leaves = 0
+            self._weights_served_form_bytes = 0
             # Speculative decoding: draft proposals vs target acceptances.
             self._spec_ticks = 0
             self._spec_proposed = 0
@@ -377,19 +382,24 @@ class ServingStats:
     def record_pages(self, free: int, used: int, total: int,
                      freed_total: int = 0, kv_bytes_per_token: int = 0,
                      kv_cache_layers: int = 0, kv_reader_layers: int = 0,
-                     recurrent_state_bytes: int = 0):
+                     recurrent_state_bytes: int = 0,
+                     weights_served_form_leaves: int = 0,
+                     weights_served_form_bytes: int = 0):
         """Gauge: paged-KV pool occupancy after a tick (page counts).
         ``freed_total`` mirrors the pool's cumulative free count — the
         page-drain observable behind the gateway's pressure Retry-After;
         ``kv_bytes_per_token`` is a page's bytes over its rows, over
         ``kv_cache_layers`` cache entries that ``kv_reader_layers``
         attentions read; ``recurrent_state_bytes`` what the cache holds per
-        slot beside the pages, all slots."""
+        slot beside the pages, all slots; ``weights_served_form_leaves`` /
+        ``_bytes`` the weights held in their family's served form."""
         with self._lock:
             self._kv_bytes_per_token = int(kv_bytes_per_token)
             self._kv_cache_layers = int(kv_cache_layers)
             self._kv_reader_layers = int(kv_reader_layers)
             self._recurrent_state_bytes = int(recurrent_state_bytes)
+            self._weights_served_form_leaves = int(weights_served_form_leaves)
+            self._weights_served_form_bytes = int(weights_served_form_bytes)
             self._pages_free = int(free)
             self._pages_used = int(used)
             self._pages_total = int(total)
@@ -564,7 +574,9 @@ class ServingStats:
                       "_attn_rows_scored", "_attn_rows_visible",
                       "_attn_rows_view", "_tick_rows_scored",
                       "_tick_rows_visible", "_tick_rows_view",
-                      "_recurrent_state_resets", "_recurrent_state_bytes"):
+                      "_recurrent_state_resets", "_recurrent_state_bytes",
+                      "_weights_served_form_leaves",
+                      "_weights_served_form_bytes"):
                 setattr(self, k, getattr(self, k) + o[k])
             for k in ("_queue_wait_ms_max", "_ttft_ms_max",
                       "_prefill_backlog_max", "_host_us_max",
@@ -729,6 +741,8 @@ class ServingStats:
                 "kv_reader_layers": self._kv_reader_layers,
                 "recurrent_state_bytes": self._recurrent_state_bytes,
                 "recurrent_state_resets": self._recurrent_state_resets,
+                "weights_served_form_leaves": self._weights_served_form_leaves,
+                "weights_served_form_bytes": self._weights_served_form_bytes,
             }
             # The host path by phase ("host_us/<phase>", slash-pathed like
             # the adapter keys; the gateway re-emits them as one labeled

@@ -1,7 +1,7 @@
 """chip_smoke.py — the quickest proof that the trainer and the server still
 start on the chip, through the entry points a user calls.
 
-    python chip_smoke.py                 # one TPU chip: trainer, kernels, server
+    python chip_smoke.py                 # one TPU chip: trainer, kernels, server, served_form
     python chip_smoke.py --four-chips    # one four-chip host: the sharded paths only
     python chip_smoke.py --rehearse-cpu  # the same control flow at toy size on the CPU
 
@@ -30,6 +30,11 @@ Configurations (depth is the only cut; weights are random, made from --seed):
   (1.45 B parameters per sparse layer; 3 layers + embeddings/head is 9.2 GB in
   bf16 on a 16 GB chip, the rest is KV pages and workspace), built the way
   ``accelerate-tpu serve`` builds it and driven over HTTP (JSON and SSE).
+* Served form — the ``cohere2_moe`` family at Command A+'s published widths
+  (hidden 4096, 128 query / 8 KV heads of 128, 16 of 128 experts held, four
+  layers) at the benchmark cell's engine shapes, weights all zero: nothing
+  runs, the tick and the chunk are COMPILED and their text is read. A TPU
+  layout is nothing a CPU test can see.
 """
 
 from __future__ import annotations
@@ -39,6 +44,7 @@ import contextlib
 import gc
 import http.client
 import json
+import re
 import sys
 import threading
 import time
@@ -99,6 +105,12 @@ SIZES = {
         "server": dict(num_hidden_layers=3, max_slots=8, max_len=1024,
                        prefill_chunk=256, max_pages=512,
                        prompt_lens=(300, 520, 700, 330), max_new_tokens=64),
+        "served_form": dict(vocab_size=32768, hidden_size=4096, intermediate_size=4096,
+                            num_hidden_layers=4, num_attention_heads=128,
+                            num_key_value_heads=8, head_dim=128, sliding_window=4096,
+                            num_experts=128, num_experts_per_tok=8, num_shared_experts=4,
+                            held_experts=(0, 16), max_slots=16, max_len=8192,
+                            prefill_chunk=256, max_pages=513),
     },
     # The rehearsal: the same control flow at sizes the CPU finishes in
     # seconds. Widths here mean nothing.
@@ -113,6 +125,12 @@ SIZES = {
                        num_experts=4, max_slots=4, max_len=128,
                        prefill_chunk=16, max_pages=None,
                        prompt_lens=(20, 37, 50, 24), max_new_tokens=8),
+        "served_form": dict(vocab_size=256, hidden_size=64, intermediate_size=32,
+                            num_hidden_layers=4, num_attention_heads=8,
+                            num_key_value_heads=2, head_dim=16, sliding_window=8,
+                            num_experts=8, num_experts_per_tok=2, num_shared_experts=2,
+                            held_experts=(0, 4), max_slots=4, max_len=64,
+                            prefill_chunk=8, max_pages=None),
     },
 }
 
@@ -570,6 +588,74 @@ def score_against_reference(module, params, prompts, tokens) -> dict:
     return out
 
 
+# ---------------------------------------------------------------------------
+# The served form of the q and k kernels (compiled, not run)
+# ---------------------------------------------------------------------------
+
+def kernel_relayouts(text: str, elements: int) -> list:
+    """The instructions of a compiled program that only move a bfloat16 array
+    of ``elements`` values (the weights' type here; float32 arrays are
+    activations): a ``copy``, ``reshape`` or ``transpose`` whose result is
+    that large (a product that reads the array is a fusion or a convolution,
+    a free reinterpretation a ``bitcast``)."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= bf16\[([\d,]+)\]\S* (copy|reshape|transpose)\(", line)
+        if m and int(np.prod([int(d) for d in m.group(1).split(",")])) == elements:
+            found.append(line.strip()[:200])
+    return found
+
+
+def run_served_form(size: dict) -> dict:
+    """Compile the ``cohere2_moe`` tick and chunk at the benchmark cell's
+    shapes and read their text: in the published form both laid every 134 MB
+    q kernel out anew in every call (a ``copy`` for its orientation, on
+    sliding layers a ``reshape`` for the interleaved pairs: 3.2 ms of a
+    17.3 ms tick). The engine holds the kernels in the family's served form;
+    no instruction of either program may only move a whole q kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM
+    from accelerate_tpu.serving import ServingEngine
+
+    engine_keys = ("max_slots", "max_len", "prefill_chunk", "max_pages")
+    cfg = Cohere2MoeConfig(**{k: v for k, v in size.items() if k not in engine_keys})
+    module = Cohere2MoeForCausalLM(cfg)
+    shapes = jax.eval_shape(lambda: module.init_params(jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.bfloat16), shapes)
+    eng = ServingEngine(module, params, **{k: size[k] for k in engine_keys},
+                        autostart=False, warmup=False)
+    del params
+    try:
+        S, C = eng.max_slots, size["prefill_chunk"]
+        table = np.zeros((S, eng._pages_per_slot), np.int32)
+        t0 = time.perf_counter()
+        texts = {
+            "tick": eng._decode.lower(eng.params, eng._state, np.ones((S,), bool), table),
+            "chunk": eng._prefill_chunk.lower(
+                eng.params, eng._state, np.zeros((1, C), np.int32), np.int32(0), table[0],
+                np.int32(0), np.int32(C), jax.random.PRNGKey(0)),
+        }
+        texts = {name: low.compile().as_text() for name, low in texts.items()}
+        q_kernel = cfg.num_attention_heads * cfg.head_dim * cfg.hidden_size
+        out = {"weights_served_form_leaves": eng._served_form_leaves,
+               "weights_served_form_bytes": eng._served_form_bytes,
+               "compile_s": round(time.perf_counter() - t0, 2), "compile_s_is": NOT_A_MEASUREMENT,
+               "q_kernel_relayouts": {name: kernel_relayouts(text, q_kernel)
+                                      for name, text in texts.items()},
+               "peak_bytes": peak_bytes(jax.devices()[0])}
+    finally:
+        eng.shutdown(drain=False)
+    sliding = sum(cfg.window_for(i) is not None for i in range(cfg.num_hidden_layers))
+    require(out["weights_served_form_leaves"] == cfg.num_hidden_layers + sliding,
+            f"the engine did not put every q kernel and the sliding layers' k kernels "
+            f"into the served form: {out}")
+    require(not any(out["q_kernel_relayouts"].values()),
+            f"a compiled serving program lays a whole q kernel out anew in every call: {out}")
+    return out
+
+
 def run_server(size: dict, seed: int) -> dict:
     import jax
 
@@ -725,7 +811,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phases", default=None,
                     help="comma-separated subset of the mode's phases "
-                         "(one chip: trainer,kernels,server; four: trainer,serving)")
+                         "(one chip: trainer,kernels,server,served_form; four: "
+                         "trainer,serving)")
     opts = ap.parse_args()
     need = 4 if opts.four_chips else 1
 
@@ -777,7 +864,8 @@ def main() -> int:
 
         phases = {"trainer": trainer,
                   "kernels": lambda: run_kernels(size, opts.seed),
-                  "server": lambda: run_server(size["server"], opts.seed)}
+                  "server": lambda: run_server(size["server"], opts.seed),
+                  "served_form": lambda: run_served_form(size["served_form"])}
     wanted = opts.phases.split(",") if opts.phases else list(phases)
     unknown = [p for p in wanted if p not in phases]
     if unknown:

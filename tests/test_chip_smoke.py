@@ -51,7 +51,7 @@ class TestOneChipRehearsal:
     def test_last_line_is_the_result_object_and_nothing_else(self, one_chip_rehearsal):
         phases, last = one_chip_rehearsal
         assert last == {"ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
-        assert list(phases) == ["start", "trainer", "kernels", "server"]
+        assert list(phases) == ["start", "trainer", "kernels", "server", "served_form"]
 
     def test_start_names_versions_cache_and_native_library(self, one_chip_rehearsal):
         start = one_chip_rehearsal[0]["start"]
@@ -68,6 +68,24 @@ class TestOneChipRehearsal:
         # The CPU takes the einsum path by design; the chip run asserts True.
         assert t["pallas_call_in_step_hlo"] is False
         assert t["times"] == "smoke, not a measurement"
+
+    def test_served_form_compiles_both_programs_and_reads_their_text(self, one_chip_rehearsal):
+        f = one_chip_rehearsal[0]["served_form"]
+        assert f["weights_served_form_leaves"] == 7 and f["weights_served_form_bytes"] > 0
+        assert f["q_kernel_relayouts"] == {"tick": [], "chunk": []}
+
+    def test_a_whole_kernel_copy_or_reshape_is_found_in_a_compiled_text(self):
+        """The reader itself, on the parent's instructions (my chip run, PR 34)."""
+        import chip_smoke
+
+        text = "\n".join([
+            "%copy.136 = bf16[16384,4096]{1,0:T(8,128)(2,1)} copy(%bitcast.175)",
+            "%reshape.805 = bf16[128,64,2,4096]{3,2,1,0:T(2,128)(2,1)} reshape(%copy.136)",
+            "%fusion.9 = bf16[8,16,128,4096]{3,2,1,0:T(8,128)(2,1)} fusion(%p), kind=kLoop, calls=%bitcast_fusion.2",
+            "%copy.95 = f32[1,8,16,256,128]{3,4,2,1,0:T(8,128)S(1)} copy(%while.55)",
+            "%copy.84 = bf16[1024,4096]{1,0:T(8,128)(2,1)S(1)} copy(%bitcast.780)"])
+        found = chip_smoke.kernel_relayouts(text, 16384 * 4096)
+        assert [f.split(" ")[0] for f in found] == ["%copy.136", "%reshape.805"]
 
     def test_kernels_agree_in_the_interpreter(self, one_chip_rehearsal):
         k = one_chip_rehearsal[0]["kernels"]

@@ -416,3 +416,162 @@ def test_a_whole_view_chunk_reads_a_share_of_one(tiny):
     assert s["prefill_attn_rows_share"] == 1.0
     # offsets 0 and 8 of a 32-row view: a full layer sees 8 and 16 rows, a windowed one 8 and 15
     assert s["prefill_attn_rows_fill"] == pytest.approx((4 * 8 + 16 + 3 * 15) / (2 * 4 * 32), abs=1e-6)
+
+
+# -- (i) the served form: what the engine holds instead of the published q/k --
+
+def _kernels(params, cfg, which):
+    return [params[f"layers_{i}"]["self_attn"][which]["kernel"] for i in range(cfg.num_hidden_layers)]
+
+
+def test_the_served_forms_logits_are_the_published_forms_past_the_window(tiny):
+    """Through the modules alone: the same products over the same weights, a
+    head's columns in another order (q and k alike) and the q kernel turned."""
+    cfg, model, params = tiny
+    form = model.served_form()
+    ids = ids_of(5 * WINDOW, seed=40)[None]
+    want = model.apply({"params": params}, ids)
+    got = form.module.apply({"params": form.to_served(params)}, ids)
+    assert float(jnp.abs(got - want).max()) < TOL
+    assert float(jnp.abs(want - ref.forward(params, ids[0], cfg)).max()) < TOL
+    # the served tree is the served module's own: what its init gives, shape for shape
+    own = jax.eval_shape(lambda: form.module.init_params(jax.random.PRNGKey(0)))
+    assert jax.tree.map(lambda a, b: a.shape == b.shape and a.dtype == b.dtype,
+                        form.to_served(params), own) == jax.tree.map(lambda _: True, own)
+    assert form.module.served_form() is None           # already in that form: nothing to do
+
+
+def test_to_served_then_to_published_is_the_tree_bit_for_bit_and_moves_q_and_k_alone(tiny):
+    cfg, model, params = tiny
+    form = model.served_form()
+    served = form.to_served(params)
+    moved = {jax.tree_util.keystr(path) for (path, a), b in
+             zip(jax.tree_util.tree_flatten_with_path(params)[0], jax.tree.leaves(served)) if a is not b}
+    # every q kernel (turned), the k kernels of the three sliding layers (their pairs)
+    assert moved == ({f"['layers_{i}']['self_attn']['q_proj']['kernel']" for i in range(4)}
+                     | {f"['layers_{i}']['self_attn']['k_proj']['kernel']" for i in range(3)})
+    back = form.to_published(served)
+    assert jax.tree.structure(back) == jax.tree.structure(params)
+    assert all(bool((a == b).all()) for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(back)))
+    q, k = _kernels(params, cfg, "q_proj")[0], _kernels(params, cfg, "k_proj")[0]
+    sq, sk = _kernels(served, cfg, "q_proj")[0], _kernels(served, cfg, "k_proj")[0]
+    d = cfg.head_dim
+    assert sq.shape == q.shape[::-1] and sk.shape == k.shape
+    # head 1, pair 3: the published columns (d + 6, d + 7) lie at (d + 3, d + 3 + d / 2)
+    np.testing.assert_array_equal(sq[d + 3], q[:, d + 6])
+    np.testing.assert_array_equal(sq[d + 3 + d // 2], q[:, d + 7])
+    np.testing.assert_array_equal(sk[:, d + 3 + d // 2], k[:, d + 7])
+    np.testing.assert_array_equal(_kernels(served, cfg, "q_proj")[3], _kernels(params, cfg, "q_proj")[3].T)
+
+
+def _same_buffer(a, b):
+    """Placement hands back a new array object over the buffer that was there."""
+    return a.unsafe_buffer_pointer() == b.unsafe_buffer_pointer()
+
+
+def _serve(model, params, ids, new, **kw):
+    eng = ServingEngine(model, params, max_slots=2, max_len=64, prefill_chunk=8, page_size=8, **kw)
+    try:
+        req = eng.submit(ids, max_new_tokens=new, ignore_eos=True, block=True)
+        assert req.wait(300)
+        return np.asarray(req.tokens), eng.stats.summary(), eng
+    finally:
+        eng.shutdown(drain=False)
+
+
+HOLDERS = {
+    "plain": {},
+    "tp2": {"tp": 2},                          # the kernels sharded over a slice of two
+    "int8_weights": {"weights_dtype": "int8"},
+    "int8_pages": {"kv_dtype": "int8"},        # the K pages hold permuted keys
+    "lookup": {"spec_lookup": 2, "spec_tokens": 2},
+    "draft": "a draft of the same family",
+}
+
+
+@pytest.mark.parametrize("holder", sorted(HOLDERS))
+def test_an_engine_given_the_published_tree_serves_generates_tokens(tiny, holder):
+    """Chunks, then ticks (or verifies), past the window, against offline
+    ``generate`` on the published form; with whatever else holds q/k columns."""
+    from accelerate_tpu.generation import generate
+    from accelerate_tpu.utils.quantization import dequantize_params
+
+    cfg, model, params = tiny
+    kw = HOLDERS[holder]
+    if holder == "draft":           # a draft's cache has to be linear: no window inside max_len
+        cfg = dataclasses.replace(cfg, sliding_window=128)
+        model = Cohere2MoeForCausalLM(cfg)
+        kw = {"draft_model": model, "draft_params": params, "spec_tokens": 2}
+    prompt, new = 3 * WINDOW + 3, WINDOW + 5
+    ids = np.asarray(ids_of(prompt, seed=41))[None]
+    served, summary, eng = _serve(model, params, ids, new, **kw)
+    reference = params
+    if holder == "int8_weights":    # the same ints and the same per-column scales, moved together
+        from accelerate_tpu.adapters.quantize import quantize_base_weights
+        quantized = quantize_base_weights(params)
+        back = model.served_form().to_published(eng.params)
+        assert all(bool((a == b).all()) for a, b in zip(jax.tree.leaves(quantized), jax.tree.leaves(back)))
+        reference = dequantize_params(quantized, jnp.float32)
+    if holder == "int8_pages":      # rounded rows: near-ties may flip, as for any family
+        logits = ref.forward(params, jnp.asarray(np.concatenate([ids[0], served])), cfg)[prompt - 1:-1]
+        assert float((logits.max(-1) - logits[jnp.arange(new), jnp.asarray(served)]).max()) < 0.05
+    else:
+        offline = np.asarray(generate(model, reference, jnp.asarray(ids), max_new_tokens=new))[0, prompt:]
+        np.testing.assert_array_equal(served, offline)
+    # what an engine hands back out: its tree, through the inverse, is the published one
+    if holder in ("plain", "tp2"):
+        back = model.served_form().to_published(eng.params)
+        assert all(bool((np.asarray(a) == np.asarray(b)).all())
+                   for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(back)))
+        assert eng.module.served and not model.served
+    q, k = _kernels(params, cfg, "q_proj"), _kernels(params, cfg, "k_proj")
+    moved = q + [k[i] for i in range(cfg.num_hidden_layers) if cfg.window_for(i) is not None]
+    twice = 2 if holder == "draft" else 1
+    if holder == "int8_weights":    # one byte a weight and a float32 scale a column
+        assert summary["weights_served_form_bytes"] == sum(a.size + 4 * a.shape[1] for a in moved)
+        assert summary["weights_served_form_leaves"] == 2 * len(moved)
+    else:
+        assert summary["weights_served_form_bytes"] == twice * sum(a.nbytes for a in moved)
+        assert summary["weights_served_form_leaves"] == twice * len(moved)
+
+
+def test_an_adapter_bank_is_refused_by_name_and_a_family_without_the_hook_moves_nothing(tiny):
+    from accelerate_tpu.adapters import AdapterBank, LoRAConfig
+    from accelerate_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+
+    cfg, model, params = tiny
+    bank = AdapterBank(params, config=LoRAConfig(rank=2, target_modules=("q_proj", "k_proj")),
+                       max_adapters=2)
+    with pytest.raises(NotImplementedError, match="AdapterBank.*q_proj / k_proj.*published"):
+        ServingEngine(model, params, max_slots=2, max_len=32, prefill_chunk=8, adapters=bank,
+                      autostart=False, warmup=False)
+    mixtral = MixtralForCausalLM(MixtralConfig.tiny_moe())
+    mparams = mixtral.init_params(jax.random.PRNGKey(0))
+    _, summary, eng = _serve(mixtral, mparams, np.asarray(ids_of(11, seed=42))[None], 3)
+    assert (summary["weights_served_form_bytes"], summary["weights_served_form_leaves"]) == (0, 0)
+    assert eng.module is mixtral
+    assert all(_same_buffer(a, b) for a, b in zip(jax.tree.leaves(mparams), jax.tree.leaves(eng.params)))
+
+
+def test_a_second_engine_from_the_same_tree_finds_the_first_ones_served_leaves(tiny):
+    """A fleet's factory hands one tree to every engine it builds (replicas,
+    restarts); on an accelerator the first engine donated the leaves it moved."""
+    from accelerate_tpu.serving import engine as engine_mod
+
+    cfg, model, params = tiny
+    tree = jax.tree.map(jnp.copy, params)
+    keys = [id(a) for a in _kernels(tree, cfg, "q_proj") + _kernels(tree, cfg, "k_proj")[:3]]
+    build = lambda: ServingEngine(model, tree, max_slots=2, max_len=32, prefill_chunk=8,
+                                  autostart=False, warmup=False)
+    first, second = build(), build()
+    try:
+        a, b = _kernels(first.params, cfg, "q_proj"), _kernels(second.params, cfg, "q_proj")
+        assert all(_same_buffer(x, y) for x, y in zip(a, b))
+        assert all(key in engine_mod._SERVED_TWINS for key in keys)
+    finally:
+        first.shutdown(drain=False)
+        second.shutdown(drain=False)
+    del tree, first, second, a, b
+    import gc
+    gc.collect()
+    assert not any(key in engine_mod._SERVED_TWINS for key in keys)      # gone with the published leaves

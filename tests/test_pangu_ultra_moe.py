@@ -351,8 +351,14 @@ def test_what_is_not_implemented_is_refused(key, value):
 # changed since. The plain tick (``decode``) was re-pinned by PR 34, which
 # rewrote it on purpose: it reads the page pool in place over a work list of
 # live (slot, key block) pairs and gathers no view
-# (tests/test_paged_tick_attention.py). A PR that changes one of these
-# programs on purpose re-pins its line and says why.
+# (tests/test_paged_tick_attention.py). The six ``cohere2_moe`` lines were
+# re-pinned by PR 36, which changed them on purpose: the engine holds that
+# family's q and k kernels in its served form (``served_form``: a head's
+# columns half-split, the q kernel ``[heads x head_dim, hidden]``) and its
+# programs trace the module that reads them, so the q product contracts the
+# kernel's second axis and the rotary is models/llama.py ``apply_rotary``; the
+# 18 lines of the families without the hook stand. A PR that changes one of
+# these programs on purpose re-pins its line and says why.
 PARENT_PROGRAMS = {
     "mixtral/fp/decode": "cfe3cee0d4738767eee56c4ac9a88e8d2e3dce83692890ba5e8dda1eae10093b",      # PR 34
     "mixtral/fp/chunk": "90f38ab0fc0c2b1e8bf36456a6b9be38ba03f935f66867a20f835d56f3392347",
@@ -368,12 +374,12 @@ PARENT_PROGRAMS = {
     "mixtral_window/int8/chunk": "60ab0c97a91ed460cb405ee1617b7a8b3dc63e1d81de4c37d843c5e66d74b2de",
     "mixtral_window/lookup/chunk": "433d483978531e5788baad65063b9c0b591fd1093304f6c2794aa16e7c6457aa",
     "mixtral_window/lookup/spec_lookup": "fccc57f5d9e2216ca1b9bd1c7024add6f846a76126498360fba1e790f46551ac",
-    "cohere2_moe/fp/decode": "4205d411e848735eda95337e35218a99fc1dce4f8a4b64021d6a48fceed683f9",      # PR 34
-    "cohere2_moe/fp/chunk": "d2ddfedff7aabf8e803b1f2083008fc8492f1823ed5ebb9bbe60428d453f5d0b",
-    "cohere2_moe/int8/decode": "b879bca9ac898329727dfe3bde6be16358ea5f232b1c552a22c9aababf5f7d0c",      # PR 34
-    "cohere2_moe/int8/chunk": "719483a6a532d7a233e102dac6de0804a781e0c2b978bfc31760e74b7ccbc273",
-    "cohere2_moe/lookup/chunk": "8b7db1e85c77dfb0c78737e0c7839df6404febd948610d77e1beae67f231df68",
-    "cohere2_moe/lookup/spec_lookup": "bbe9dab6130261c223c3c1a4ac2192a3e5059eaa128d7f5c06a6ba3196bd962a",
+    "cohere2_moe/fp/decode": "0ae5166c24b07dca7e521f57e3137efa01fa7a86b8874daeafd59c02eb88b37f",      # PR 36
+    "cohere2_moe/fp/chunk": "edbb18314a6008443775c7d6b3e101a5e91810842ed318aa0bcb2d3b23d96500",      # PR 36
+    "cohere2_moe/int8/decode": "ae3cf36ccb6815255c9ee049cc5d52b9fa45f32d758d29c64b5ed7864fef12d3",      # PR 36
+    "cohere2_moe/int8/chunk": "f86d1128ff264e42d41c0c32b16739b55ca139b2434e14541648b5f34a8c9f05",      # PR 36
+    "cohere2_moe/lookup/chunk": "422b85c030da65ade72f3fdf5bb142a38168b03edef04166c20062a9ee035853",      # PR 36
+    "cohere2_moe/lookup/spec_lookup": "72efeba7c28a509cbb692737933a53cbd5f8c27495449d5546e2f8434b36049b",      # PR 36
     "pangu_ultra_moe/fp/decode": "7e4c2629ec755e7ccf63cc429c916d96bc1aa89550baec0a3f625b40bacae3b4",      # PR 34
     "pangu_ultra_moe/fp/chunk": "7dfaaab512641f1030afdc32870036cd2ea91a00720720ce1ac5f2131dc1dbf4",
     "pangu_ultra_moe/lookup/chunk": "03bb625a5bf7726d04d24956a1feb04a9f7019d99419d2b5cbbc0ca54554b766",
