@@ -38,6 +38,7 @@ from .data_loader import DataLoaderShard, batch_sharding, prepare_data_loader, s
 from .optimizer import AcceleratedOptimizer
 from .parallel.mesh import MeshConfig
 from .parallel.sharding import infer_param_shardings, replicated_sharding, shard_params, sharding_summary
+from .observability.program_parts import program_part
 from .precision import Policy, policy_for, scale_loss
 from .scheduler import AcceleratedScheduler, LRScheduler
 from .state import AcceleratorState, GradientState, PartialState
@@ -954,6 +955,7 @@ class Accelerator:
             (scaled, loss), grads = jax.value_and_grad(compute, has_aux=True)(params)
             return loss, grads
 
+        @program_part("loss")
         def grad_phase(params, loss_scale, batch, rng):
             scale = loss_scale.scale if has_scale else None
             if accum > 1:
@@ -1024,8 +1026,11 @@ class Accelerator:
             else:
                 finite = jnp.asarray(True)
 
-            gnorm = None
-            if max_grad_norm is not None:
+            def clip(grads):
+                """``(clipped gradients, their global norm)``; ``(grads, None)``
+                where no ``max_grad_norm`` is set."""
+                if max_grad_norm is None:
+                    return grads, None
                 # fp8 statistics leaves carry updated amax/scale values in
                 # their "gradients" (ops/quant.py): they must neither enter
                 # the norm nor be scaled by the clip factor.
@@ -1050,9 +1055,12 @@ class Accelerator:
                     )
                 else:
                     grads = jax.tree_util.tree_map(lambda g: (g * factor).astype(g.dtype), grads)
+                return grads, gnorm
 
-            updates, new_opt_state = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with program_part("optimizer"):
+                grads, gnorm = clip(grads)
+                updates, new_opt_state = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             if has_scale:
                 from .precision import update_loss_scale as _uls
 

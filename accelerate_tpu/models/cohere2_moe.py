@@ -26,9 +26,10 @@ the same pairs, and the q kernel ``[heads x head_dim, hidden]``. Checkpoints,
 Attention's cache path is models/llama.py's (``update_kv_cache_and_attend``:
 linear caches, rings for sliding layers outside the paged engine, the window
 mask); the rotary layout and the absence of it on full layers are this
-file's. ``jax.named_scope`` names ``attn_local`` / ``attn_global`` /
-``moe_router`` / ``moe_experts`` / ``moe_shared`` / ``lm_head`` in a device
-trace. The model follows the generation contract of ``MixtralForCausalLM``:
+file's. The program's parts (observability/program_parts.py): ``embed``,
+``attn_local`` / ``attn_global`` (models/llama.py's ``kv_attn`` / ``kv_write``
+inside them), ``moe_router`` / ``moe_experts`` / ``moe_shared``, ``lm_head``;
+``chipbench/op_scopes.py`` reads them out of a device trace. The model follows the generation contract of ``MixtralForCausalLM``:
 ``(input_ids, positions, cache, cache_pos) -> logits, cache``.
 """
 
@@ -42,6 +43,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability.program_parts import program_part
 from .llama import (ServedForm, apply_rotary, multi_head_attention, rotary_embedding,
                     update_kv_cache_and_attend)
 
@@ -172,7 +174,7 @@ class Cohere2Attention(nn.Module):
         B, S, _ = x.shape
         n_q, n_kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         window = cfg.window_for(self.layer_idx)
-        with jax.named_scope("attn_local" if window is not None else "attn_global"):
+        with program_part("attn_local" if window is not None else "attn_global"):
             q = _Kernel(n_q * hd, outputs_first=self.served,
                         name="q_proj")(x).reshape(B, S, n_q, hd)
             k = _Kernel(n_kv * hd, name="k_proj")(x).reshape(B, S, n_kv, hd)
@@ -232,7 +234,7 @@ class Cohere2MoeMLP(nn.Module):
             scores=cfg.expert_selection_fn, normalize_gates=cfg.norm_topk_prob,
             held=(first, count))
         self.sow("moe_stats", "picks", stats["picks"])
-        with jax.named_scope("moe_shared"):
+        with program_part("moe_shared"):
             shared_out = averaged_experts_apply(shared, x)
         return routed, shared_out
 
@@ -270,14 +272,15 @@ class Cohere2MoeForCausalLM(nn.Module):
             positions = jnp.broadcast_to(positions, input_ids.shape)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
                          param_dtype=jnp.float32)
-        x = embed(input_ids)
+        with program_part("embed"):
+            x = embed(input_ids)
         new_caches = []
         for i in range(cfg.num_hidden_layers):
             x, layer_cache = Cohere2MoeBlock(cfg, layer_idx=i, served=self.served, name=f"layers_{i}")(
                 x, positions, cache=None if cache is None else cache[i], cache_pos=cache_pos)
             new_caches.append(layer_cache)
-        x = ScaleLayerNorm(cfg.layer_norm_eps, name="norm")(x)
-        with jax.named_scope("lm_head"):
+        with program_part("lm_head"):
+            x = ScaleLayerNorm(cfg.layer_norm_eps, name="norm")(x)
             emb = self.variables["params"]["embed_tokens"]["embedding"]
             logits = jnp.einsum("bsh,vh->bsv", x, emb.astype(x.dtype),
                                 preferred_element_type=jnp.float32) * cfg.logit_scale

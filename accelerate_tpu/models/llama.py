@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 import numpy as np
 
+from ..observability.program_parts import program_part
+
 
 @dataclasses.dataclass
 class LlamaConfig:
@@ -614,13 +616,15 @@ def update_kv_cache_and_attend(cache, q, k, v, cache_pos, n_rep: int, sliding_wi
                                 sm_scale, logit_softcap, alibi_slopes)
     if "pos" not in cache:
         start = (0, cache_pos, 0, 0)
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), start),
-            "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), start),
-        }
-        out = _cached_attention(q, new_cache["k"], new_cache["v"], cache_pos, n_rep,
-                                sliding_window=sliding_window, sm_scale=sm_scale,
-                                logit_softcap=logit_softcap, alibi_slopes=alibi_slopes)
+        with program_part("kv_write"):
+            new_cache = {
+                "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), start),
+                "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), start),
+            }
+        with program_part("kv_attn"):
+            out = _cached_attention(q, new_cache["k"], new_cache["v"], cache_pos, n_rep,
+                                    sliding_window=sliding_window, sm_scale=sm_scale,
+                                    logit_softcap=logit_softcap, alibi_slopes=alibi_slopes)
         return out, new_cache
 
     window = cache["k"].shape[1]
@@ -654,33 +658,37 @@ def update_kv_cache_and_attend(cache, q, k, v, cache_pos, n_rep: int, sliding_wi
             & (pos_comb[:, None, :] <= q_pos[None, :, None])
             & (pos_comb[:, None, :] > q_pos[None, :, None] - eff_window)
         )  # [B, S, W+S]
-        out = _grouped_cached_attention(q, k_comb, v_comb, mask, n_rep,
-                                        sm_scale=sm_scale, logit_softcap=logit_softcap,
-                                        alibi_slopes=alibi_slopes, k_positions=pos_comb)
+        with program_part("kv_attn"):
+            out = _grouped_cached_attention(q, k_comb, v_comb, mask, n_rep,
+                                            sm_scale=sm_scale, logit_softcap=logit_softcap,
+                                            alibi_slopes=alibi_slopes, k_positions=pos_comb)
         # Scatter the last `window` entries (unique slots) into the ring.
         take = min(S, window)
         idx = cache_pos + jnp.arange(S - take, S, dtype=jnp.int32)   # global positions
         slots = idx % window
-        new_cache = {
-            "k": cache["k"].at[:, slots].set(k[:, S - take:].astype(cache["k"].dtype)),
-            "v": cache["v"].at[:, slots].set(v[:, S - take:].astype(cache["v"].dtype)),
-            "pos": cache["pos"].at[:, slots].set(jnp.broadcast_to(idx, (B, take))),
-        }
+        with program_part("kv_write"):
+            new_cache = {
+                "k": cache["k"].at[:, slots].set(k[:, S - take:].astype(cache["k"].dtype)),
+                "v": cache["v"].at[:, slots].set(v[:, S - take:].astype(cache["v"].dtype)),
+                "pos": cache["pos"].at[:, slots].set(jnp.broadcast_to(idx, (B, take))),
+            }
         return out, new_cache
 
     slot = jax.lax.rem(cache_pos, window)
-    new_cache = {
-        "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                          (0, slot, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                          (0, slot, 0, 0)),
-        "pos": jax.lax.dynamic_update_slice(
-            cache["pos"], jnp.broadcast_to(cache_pos, (B, 1)).astype(jnp.int32), (0, slot)),
-    }
-    out = _ring_cached_attention(q, new_cache, cache_pos, n_rep,
-                                 window=min(sliding_window or window, window),
-                                 sm_scale=sm_scale, logit_softcap=logit_softcap,
-                                 alibi_slopes=alibi_slopes)
+    with program_part("kv_write"):
+        new_cache = {
+            "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
+                                              (0, slot, 0, 0)),
+            "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
+                                              (0, slot, 0, 0)),
+            "pos": jax.lax.dynamic_update_slice(
+                cache["pos"], jnp.broadcast_to(cache_pos, (B, 1)).astype(jnp.int32), (0, slot)),
+        }
+    with program_part("kv_attn"):
+        out = _ring_cached_attention(q, new_cache, cache_pos, n_rep,
+                                     window=min(sliding_window or window, window),
+                                     sm_scale=sm_scale, logit_softcap=logit_softcap,
+                                     alibi_slopes=alibi_slopes)
     return out, new_cache
 
 
@@ -808,14 +816,16 @@ def update_latent_cache_and_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_
     if isinstance(cache, PagedCache):
         return _paged_latent_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
                                     cache_pos, sm_scale)
-    new_cache = {
-        "latent": jax.lax.dynamic_update_slice(
-            cache["latent"], c_kv.astype(cache["latent"].dtype), (0, cache_pos, 0)),
-        "rope": jax.lax.dynamic_update_slice(
-            cache["rope"], k_rope.astype(cache["rope"].dtype), (0, cache_pos, 0)),
-    }
-    out = _latent_cached_attention(q_nope, q_rope, new_cache["latent"], new_cache["rope"],
-                                   w_uk, w_uv, cache_pos, sm_scale)
+    with program_part("kv_write"):
+        new_cache = {
+            "latent": jax.lax.dynamic_update_slice(
+                cache["latent"], c_kv.astype(cache["latent"].dtype), (0, cache_pos, 0)),
+            "rope": jax.lax.dynamic_update_slice(
+                cache["rope"], k_rope.astype(cache["rope"].dtype), (0, cache_pos, 0)),
+        }
+    with program_part("kv_attn"):
+        out = _latent_cached_attention(q_nope, q_rope, new_cache["latent"], new_cache["rope"],
+                                       w_uk, w_uv, cache_pos, sm_scale)
     return out, new_cache
 
 
@@ -1029,8 +1039,9 @@ def _paged_attention(kind, lane, shared, cache: PagedCache, cache_pos, sliding_w
             (lane, pages, pos, live), (in_batched[0],) + tuple(in_batched[4:]))
         return over_lanes(lane, shared, pool, scales, pages, pos, live), True
 
-    return attend(lane, shared, cache.pool, cache.scales, cache.pages,
-                  jnp.asarray(cache_pos, jnp.int32), cache.live)
+    with program_part("kv_attn"):
+        return attend(lane, shared, cache.pool, cache.scales, cache.pages,
+                      jnp.asarray(cache_pos, jnp.int32), cache.live)
 
 
 def _paged_kv_attend(cache: PagedCache, q, k, v, cache_pos, n_rep: int, sliding_window=None,
@@ -1137,7 +1148,8 @@ def attend_shared_kv_cache(cache, q, cache_pos, n_rep: int, row=None, sm_scale=N
     if isinstance(cache, PagedCache):
         return _paged_kv_attend(cache, q, row["k"], row["v"], cache_pos, n_rep,
                                 sm_scale=sm_scale)[0]
-    return _cached_attention(q, cache["k"], cache["v"], cache_pos, n_rep, sm_scale=sm_scale)
+    with program_part("kv_attn"):
+        return _cached_attention(q, cache["k"], cache["v"], cache_pos, n_rep, sm_scale=sm_scale)
 
 
 def _paged_latent_attend(cache: PagedCache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, cache_pos,
@@ -1213,54 +1225,56 @@ class LlamaAttention(nn.Module):
     def __call__(self, x, positions, causal=True, cache=None, cache_pos=None,
                  segment_ids=None, lora=None):
         cfg = self.config
-        B, S, _ = x.shape
-        n_q, n_kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        dense = _dense_factory(cfg, x.dtype)
-        qkv_bias = cfg.attention_qkv_bias
-        q = dense(n_q * hd, "q_proj", use_bias=qkv_bias)(x)
-        k = dense(n_kv * hd, "k_proj", use_bias=qkv_bias)(x)
-        v = dense(n_kv * hd, "v_proj", use_bias=qkv_bias)(x)
-        q = _lora_delta(q, x, lora, "q_proj").reshape(B, S, n_q, hd)
-        k = _lora_delta(k, x, lora, "k_proj").reshape(B, S, n_kv, hd)
-        v = _lora_delta(v, x, lora, "v_proj").reshape(B, S, n_kv, hd)
-
-        cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, dtype=x.dtype,
-                                    rope_scaling=cfg.rope_scaling)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
-
         window = cfg.sliding_window if self.window == "config" else self.window
-        # query_pre_attn_scalar / softcap default to the vanilla scale / no
-        # cap, so non-Gemma2 configs hit the identical fast paths as before.
-        sm_scale = None if cfg.query_pre_attn_scalar is None else cfg.sm_scale
-        softcap = cfg.attn_logit_softcapping
+        with program_part("attn_local" if window is not None else "attn_global"):
+            B, S, _ = x.shape
+            n_q, n_kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+            dense = _dense_factory(cfg, x.dtype)
+            qkv_bias = cfg.attention_qkv_bias
+            q = dense(n_q * hd, "q_proj", use_bias=qkv_bias)(x)
+            k = dense(n_kv * hd, "k_proj", use_bias=qkv_bias)(x)
+            v = dense(n_kv * hd, "v_proj", use_bias=qkv_bias)(x)
+            q = _lora_delta(q, x, lora, "q_proj").reshape(B, S, n_q, hd)
+            k = _lora_delta(k, x, lora, "k_proj").reshape(B, S, n_kv, hd)
+            v = _lora_delta(v, x, lora, "v_proj").reshape(B, S, n_kv, hd)
 
-        if cache is not None:
-            # KV-cached path (generate).
-            out, new_cache = update_kv_cache_and_attend(
-                cache, q, k, v, cache_pos, n_q // n_kv,
-                sliding_window=window, sm_scale=sm_scale, logit_softcap=softcap)
+            cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, dtype=x.dtype,
+                                        rope_scaling=cfg.rope_scaling)
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
+
+            # query_pre_attn_scalar / softcap default to the vanilla scale / no
+            # cap, so non-Gemma2 configs hit the identical fast paths as before.
+            sm_scale = None if cfg.query_pre_attn_scalar is None else cfg.sm_scale
+            softcap = cfg.attn_logit_softcapping
+
+            if cache is not None:
+                # KV-cached path (generate).
+                out, new_cache = update_kv_cache_and_attend(
+                    cache, q, k, v, cache_pos, n_q // n_kv,
+                    sliding_window=window, sm_scale=sm_scale, logit_softcap=softcap)
+                out = out.reshape(B, S, n_q * hd)
+                proj = dense(cfg.hidden_size, "o_proj", use_bias=cfg.attention_out_bias)(out)
+                return _lora_delta(proj, out, lora, "o_proj"), new_cache
+
+            # GQA KV goes in unrepeated: every dense path is narrow-KV-native,
+            # and CP strategies move G-wide KV over ICI.
+            out = multi_head_attention(
+                q, k, v, causal=causal, use_flash=cfg.use_flash_attention,
+                segment_ids=segment_ids,
+                backend=cfg.attention_backend, sliding_window=window,
+                sm_scale=sm_scale, logit_softcap=softcap,
+            )
             out = out.reshape(B, S, n_q * hd)
             proj = dense(cfg.hidden_size, "o_proj", use_bias=cfg.attention_out_bias)(out)
-            return _lora_delta(proj, out, lora, "o_proj"), new_cache
-
-        # GQA KV goes in unrepeated: every dense path is narrow-KV-native,
-        # and CP strategies move G-wide KV over ICI.
-        out = multi_head_attention(
-            q, k, v, causal=causal, use_flash=cfg.use_flash_attention,
-            segment_ids=segment_ids,
-            backend=cfg.attention_backend, sliding_window=window,
-            sm_scale=sm_scale, logit_softcap=softcap,
-        )
-        out = out.reshape(B, S, n_q * hd)
-        proj = dense(cfg.hidden_size, "o_proj", use_bias=cfg.attention_out_bias)(out)
-        return _lora_delta(proj, out, lora, "o_proj")
+            return _lora_delta(proj, out, lora, "o_proj")
 
 
 class LlamaMLP(nn.Module):
     config: LlamaConfig
 
     @nn.compact
+    @program_part("mlp_dense")
     def __call__(self, x, lora=None):
         cfg = self.config
         dense = _dense_factory(cfg, x.dtype)
@@ -1327,13 +1341,14 @@ class LlamaModel(nn.Module):
                 "segment_ids (packed sequences) is a training feature; the "
                 "KV-cache decode path does not apply segment masking")
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens", param_dtype=jnp.float32)
-        x = embed(input_ids)
-        if cfg.scale_embeddings:
-            # Gemma: activations enter the stack scaled by sqrt(hidden). HF
-            # rounds the scalar to the activations' dtype (bf16 under
-            # torch_dtype=bfloat16, fp32 here where embeddings run fp32), so
-            # casting to x.dtype reproduces HF exactly at matching dtypes.
-            x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+        with program_part("embed"):
+            x = embed(input_ids)
+            if cfg.scale_embeddings:
+                # Gemma: activations enter the stack scaled by sqrt(hidden). HF
+                # rounds the scalar to the activations' dtype (bf16 under
+                # torch_dtype=bfloat16, fp32 here where embeddings run fp32), so
+                # casting to x.dtype reproduces HF exactly at matching dtypes.
+                x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
         block_cls = LlamaBlock
         if cfg.remat:
             from ..parallel.sharding import resolve_remat_policy
@@ -1351,7 +1366,8 @@ class LlamaModel(nn.Module):
                     lora=layer_lora,
                 )
                 new_caches.append(layer_cache)
-        x = RMSNorm(cfg.rms_norm_eps, unit_offset=cfg.rms_norm_unit_offset, name="norm")(x)
+        with program_part("lm_head"):             # the final norm goes with the head
+            x = RMSNorm(cfg.rms_norm_eps, unit_offset=cfg.rms_norm_unit_offset, name="norm")(x)
         return x if cache is None else (x, tuple(new_caches))
 
 
@@ -1372,17 +1388,18 @@ class LlamaForCausalLM(nn.Module):
             # Pre-head normed hidden states (fused LM-head losses compute
             # logits chunk-by-chunk themselves; ops/fused_loss.py).
             return x if cache is None else (x, new_cache)
-        if cfg.tie_word_embeddings:
-            embed = self.variables["params"]["model"]["embed_tokens"]["embedding"]
-            logits = x @ embed.T.astype(x.dtype)
-        else:
-            # The lm_head stays high-precision even under fp8 — its output
-            # feeds the softmax directly (standard TE practice).
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, name="lm_head", dtype=x.dtype,
-                              param_dtype=jnp.float32)(x)
         from ..ops.attention import softcap_logits
 
-        logits = softcap_logits(logits, cfg.final_logit_softcapping)
+        with program_part("lm_head"):
+            if cfg.tie_word_embeddings:
+                embed = self.variables["params"]["model"]["embed_tokens"]["embedding"]
+                logits = x @ embed.T.astype(x.dtype)
+            else:
+                # The lm_head stays high-precision even under fp8 — its output
+                # feeds the softmax directly (standard TE practice).
+                logits = nn.Dense(cfg.vocab_size, use_bias=False, name="lm_head", dtype=x.dtype,
+                                  param_dtype=jnp.float32)(x)
+            logits = softcap_logits(logits, cfg.final_logit_softcapping)
         return logits if cache is None else (logits, new_cache)
 
     def init_params(self, rng, batch_size=1, seq_len=8):

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from ..observability.program_parts import program_part
 from .llama import LlamaAttention, LlamaConfig, RMSNorm
 
 
@@ -140,11 +141,12 @@ class MixtralSparseMLP(nn.Module):
             Fs = cfg.shared_expert_intermediate_size
             dense = lambda feats, name: nn.Dense(  # noqa: E731
                 feats, use_bias=False, name=name, dtype=x.dtype, param_dtype=jnp.float32)
-            gate_h = dense(Fs, "shared_gate_proj")(x)
-            up_h = dense(Fs, "shared_up_proj")(x)
-            shared = dense(D, "shared_down_proj")(jax.nn.silu(gate_h) * up_h)
-            gate_logit = dense(1, "shared_expert_gate")(x)
-            out = out + jax.nn.sigmoid(gate_logit.astype(jnp.float32)).astype(out.dtype) * shared
+            with program_part("moe_shared"):
+                gate_h = dense(Fs, "shared_gate_proj")(x)
+                up_h = dense(Fs, "shared_up_proj")(x)
+                shared = dense(D, "shared_down_proj")(jax.nn.silu(gate_h) * up_h)
+                gate_logit = dense(1, "shared_expert_gate")(x)
+                out = out + jax.nn.sigmoid(gate_logit.astype(jnp.float32)).astype(out.dtype) * shared
         return out, aux
 
 
@@ -198,7 +200,8 @@ class MixtralForCausalLM(nn.Module):
             positions = start + jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None, :]
             positions = jnp.broadcast_to(positions, input_ids.shape)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens", param_dtype=jnp.float32)
-        x = embed(input_ids)
+        with program_part("embed"):
+            x = embed(input_ids)
         block_cls = MixtralBlock
         if cfg.remat:
             from ..parallel.sharding import resolve_remat_policy
@@ -217,14 +220,15 @@ class MixtralForCausalLM(nn.Module):
                 new_caches.append(layer_cache)
             lb = lb + aux["load_balance_loss"]
             zl = zl + aux["router_z_loss"]
-        x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
-        if cfg.tie_word_embeddings:
-            emb = self.variables["params"]["embed_tokens"]["embedding"]
-            logits = x @ emb.T.astype(x.dtype)
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, name="lm_head", dtype=x.dtype, param_dtype=jnp.float32
-            )(x)
+        with program_part("lm_head"):
+            x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
+            if cfg.tie_word_embeddings:
+                emb = self.variables["params"]["embed_tokens"]["embedding"]
+                logits = x @ emb.T.astype(x.dtype)
+            else:
+                logits = nn.Dense(
+                    cfg.vocab_size, use_bias=False, name="lm_head", dtype=x.dtype,
+                    param_dtype=jnp.float32)(x)
         n = cfg.num_hidden_layers
         if cache is not None:
             # Decode path: router losses are a training quantity; return the

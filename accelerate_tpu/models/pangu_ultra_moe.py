@@ -30,10 +30,13 @@ correction bias; rotary pairs are ``(i, i + rope/2)`` (models/llama.py
 ``(nope + rope) ** -0.5``. The multi-token-prediction module
 (``num_nextn_predict_layers``) is not part of this model.
 
-``jax.named_scope`` names ``attn_mla`` (inside it ``mla_q`` / ``mla_latent``
-/ ``mla_scores`` / ``mla_out``), ``mlp_dense``, ``moe_router`` /
-``moe_experts`` (ops/moe.py), ``moe_shared`` and ``lm_head`` in a device
-trace. The model follows the generation contract of ``MixtralForCausalLM``:
+The program's parts (observability/program_parts.py): ``embed``, ``attn_mla``
+(inside it ``mla_q`` / ``mla_latent`` / ``mla_out``, and models/llama.py's
+``kv_attn`` / ``kv_write`` around the cache), ``mlp_dense``, ``moe_router`` /
+``moe_experts`` (ops/moe.py), ``moe_shared`` and ``lm_head``. They are on
+every operation's ``op_name``; a profiler trace keeps them in each event's
+metadata (``tf_op``), which ``chipbench/op_scopes.py`` reads into device time
+by part. The model follows the generation contract of ``MixtralForCausalLM``:
 ``(input_ids, positions, cache, cache_pos) -> logits, cache``.
 """
 
@@ -46,6 +49,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability.program_parts import program_part
 from .cohere2_moe import _ExpertStacks, _Kernel
 from .llama import (RMSNorm, apply_rotary, init_latent_cache, rotary_embedding,
                     update_latent_cache_and_attend)
@@ -113,15 +117,15 @@ class PanguMLAttention(nn.Module):
         B, S, _ = x.shape
         H, rank = cfg.num_attention_heads, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        with jax.named_scope("attn_mla"):
+        with program_part("attn_mla"):
             cos, sin = rotary_embedding(positions, dr, cfg.rope_theta)         # float32
-            with jax.named_scope("mla_q"):
+            with program_part("mla_q"):
                 c_q = RMSNorm(cfg.rms_norm_eps, name="q_a_norm")(
                     _Kernel(cfg.q_lora_rank, name="q_a_proj")(x))
                 q = _Kernel(H * (dn + dr), name="q_b_proj")(c_q).reshape(B, S, H, dn + dr)
                 q_nope = q[..., :dn]
                 q_rope = apply_rotary(q[..., dn:].astype(jnp.float32), cos, sin).astype(x.dtype)
-            with jax.named_scope("mla_latent"):
+            with program_part("mla_latent"):
                 kv = _Kernel(rank + dr, name="kv_a_proj")(x)
                 c_kv = RMSNorm(cfg.rms_norm_eps, name="kv_a_norm")(kv[..., :rank])
                 k_r = apply_rotary(kv[..., None, rank:].astype(jnp.float32), cos, sin)[:, :, 0]
@@ -129,18 +133,17 @@ class PanguMLAttention(nn.Module):
             w_ukv = _RawKernel(rank, H * (dn + dv), name="kv_b_proj")().reshape(rank, H, dn + dv)
             w_uk, w_uv = w_ukv[..., :dn], w_ukv[..., dn:]
             new_cache = None
-            with jax.named_scope("mla_scores"):
-                if cache is not None:
-                    out, new_cache = update_latent_cache_and_attend(
-                        cache, q_nope, q_rope, c_kv, k_r, w_uk, w_uv, cache_pos, cfg.sm_scale)
-                else:                                # the plain form: every head's key and value
-                    k_nope = jnp.einsum("bsr,rhd->bshd", c_kv, w_uk.astype(x.dtype))
-                    k = jnp.concatenate(
-                        [k_nope, jnp.broadcast_to(k_r[:, :, None], (B, S, H, dr))], -1)
-                    v = jnp.einsum("bsr,rhd->bshd", c_kv, w_uv.astype(x.dtype))
-                    out = _einsum_attention(jnp.concatenate([q_nope, q_rope], -1), k, v,
-                                            causal=True, sm_scale=cfg.sm_scale)
-            with jax.named_scope("mla_out"):
+            if cache is not None:                    # kv_attn and kv_write: models/llama.py
+                out, new_cache = update_latent_cache_and_attend(
+                    cache, q_nope, q_rope, c_kv, k_r, w_uk, w_uv, cache_pos, cfg.sm_scale)
+            else:                                    # the plain form: every head's key and value
+                k_nope = jnp.einsum("bsr,rhd->bshd", c_kv, w_uk.astype(x.dtype))
+                k = jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(k_r[:, :, None], (B, S, H, dr))], -1)
+                v = jnp.einsum("bsr,rhd->bshd", c_kv, w_uv.astype(x.dtype))
+                out = _einsum_attention(jnp.concatenate([q_nope, q_rope], -1), k, v,
+                                        causal=True, sm_scale=cfg.sm_scale)
+            with program_part("mla_out"):
                 out = _Kernel(cfg.hidden_size, name="o_proj")(out.reshape(B, S, H * dv))
         return out, new_cache
 
@@ -189,7 +192,7 @@ class PanguMoeMLP(nn.Module):
             experts, router, x, top_k=cfg.num_experts_per_tok, scores="sigmoid",
             normalize_gates=cfg.norm_topk_prob, held=(first, count))
         self.sow("moe_stats", "picks", stats["picks"])
-        with jax.named_scope("moe_shared"):
+        with program_part("moe_shared"):
             shared = _SwiGLU(cfg.n_shared_experts * F, name="shared_experts")(x)
         return routed * cfg.routed_scaling_factor + shared
 
@@ -207,7 +210,7 @@ class PanguBlock(nn.Module):
         x = x + norm("post_attn_norm")(attn)
         n = norm("pre_mlp_norm")(x)
         if self.layer_idx < cfg.first_k_dense_replace:
-            with jax.named_scope("mlp_dense"):
+            with program_part("mlp_dense"):
                 mlp = _SwiGLU(cfg.intermediate_size, name="mlp")(n)
         else:
             mlp = PanguMoeMLP(cfg, name="mlp")(n)
@@ -237,15 +240,16 @@ class PanguUltraMoeForCausalLM(nn.Module):
             start = 0 if cache_pos is None else cache_pos
             positions = start + jnp.arange(input_ids.shape[1], dtype=jnp.int32)[None, :]
             positions = jnp.broadcast_to(positions, input_ids.shape)
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
-                     param_dtype=jnp.float32)(input_ids)
+        with program_part("embed"):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
+                         param_dtype=jnp.float32)(input_ids)
         new_caches = []
         for i in range(cfg.num_hidden_layers):
             x, layer_cache = PanguBlock(cfg, layer_idx=i, name=f"layers_{i}")(
                 x, positions, cache=None if cache is None else cache[i], cache_pos=cache_pos)
             new_caches.append(layer_cache)
-        x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
-        with jax.named_scope("lm_head"):
+        with program_part("lm_head"):
+            x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
             head = _RawKernel(cfg.hidden_size, cfg.vocab_size, name="lm_head")()
             logits = jnp.einsum("bsh,hv->bsv", x, head.astype(x.dtype),
                                 preferred_element_type=jnp.float32)

@@ -54,9 +54,12 @@ the convolution has a bias, the Mamba projections none; the attention
 projections have biases; ``lambda_init(i) = 0.8 - 0.6 exp(-0.3 i)`` with ``i``
 the layer's index; a query's own position counts among the window's keys.
 
-``jax.named_scope`` names in a device trace: ``ssm_in``, ``ssm_conv``,
-``ssm_scan``, ``ssm_out``, ``gmu``, ``attn_diff_local``, ``attn_diff_global``,
-``attn_cross_shared``, ``mlp_dense``, ``lm_head``. Generation contract:
+The program's parts (observability/program_parts.py): ``embed``, ``ssm_in``,
+``ssm_conv``, ``ssm_scan``, ``ssm_out``, ``gmu``, ``attn_diff_local``,
+``attn_diff_global``, ``attn_cross_shared`` (models/llama.py's ``kv_attn`` /
+``kv_write`` inside them), ``mlp_dense``, ``lm_head``: on every operation's
+``op_name`` and so in the metadata of a device trace's events, where
+``chipbench/op_scopes.py`` reads them. Generation contract:
 ``(input_ids, positions, cache, cache_pos) -> logits, cache``.
 """
 
@@ -70,6 +73,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability.program_parts import program_part
 from .cohere2_moe import _Kernel
 from .llama import (PagedCache, _cached_attention, attend_shared_kv_cache,
                     update_kv_cache_and_attend)
@@ -233,10 +237,10 @@ class Mamba(nn.Module):
         B, S, _ = u.shape
         d, N, R, K = cfg.d_inner, cfg.mamba_d_state, cfg.dt_rank, cfg.mamba_d_conv
         f32 = jnp.float32
-        with jax.named_scope("ssm_in"):
+        with program_part("ssm_in"):
             xz = _Kernel(2 * d, name="in_proj")(u)
             x, z = xz[..., :d], xz[..., d:]
-        with jax.named_scope("ssm_conv"):
+        with program_part("ssm_conv"):
             w = self.param("conv_kernel", nn.initializers.lecun_normal(), (K, d), f32)
             b = self.param("conv_bias", nn.initializers.zeros, (d,), f32)
             before = (jnp.zeros((B, K - 1, d), x.dtype) if state is None
@@ -248,7 +252,7 @@ class Mamba(nn.Module):
                 new_conv = xp[:, S:]
             else:                                    # the last K - 1 inputs that are real
                 new_conv = jax.lax.dynamic_slice_in_dim(xp, valid_len, K - 1, axis=1)
-        with jax.named_scope("ssm_scan"):
+        with program_part("ssm_scan"):
             dbc = _Kernel(R + 2 * N, name="x_proj")(xc.astype(u.dtype))
             dt_w = self.param("dt_proj", nn.initializers.lecun_normal(), (R, d), f32)
             dt_b = self.param("dt_bias", nn.initializers.zeros, (d,), f32)
@@ -264,7 +268,7 @@ class Mamba(nn.Module):
             y, h = selective_scan(xc, dt, A, dbc[..., R:R + N].astype(f32),
                                   dbc[..., R + N:].astype(f32), h0)
             y = y + D * xc
-        with jax.named_scope("ssm_out"):
+        with program_part("ssm_out"):
             out = _Kernel(cfg.hidden_size, name="out_proj")(
                 (y * jax.nn.silu(z.astype(f32))).astype(u.dtype))
         conv_dtype = x.dtype if state is None else state["conv"].dtype
@@ -276,7 +280,7 @@ class GatedMemoryUnit(nn.Module):
 
     @nn.compact
     def __call__(self, u, memory):
-        with jax.named_scope("gmu"):
+        with program_part("gmu"):
             gate = jax.nn.silu(_Kernel(self.config.d_inner, name="in_proj")(u).astype(jnp.float32))
             return _Kernel(self.config.hidden_size, name="out_proj")(
                 (gate * memory).astype(u.dtype))
@@ -305,7 +309,7 @@ class DiffAttention(nn.Module):
         widen = lambda q: (q.reshape(B, S, H, 1, hd) * half.astype(q.dtype)).reshape(B, S, H, wide)  # noqa: E731
         attend = dict(n_rep=H // pairs, sm_scale=hd ** -0.5)
         new_cache = None
-        with jax.named_scope(scope):
+        with program_part(scope):
             if kind == "cross":
                 q = widen(_BiasKernel(H * hd, name="q_proj")(u))
                 by_head = lambda t: jax.tree.map(                                  # noqa: E731
@@ -352,7 +356,7 @@ class Phi4FlashMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         f = self.config.intermediate_size
-        with jax.named_scope("mlp_dense"):
+        with program_part("mlp_dense"):
             gu = _Kernel(2 * f, name="gate_up_proj")(x)
             return _Kernel(x.shape[-1], name="down_proj")(jax.nn.silu(gu[..., :f]) * gu[..., f:])
 
@@ -419,7 +423,8 @@ class Phi4FlashForCausalLM(nn.Module):
         del positions                                 # no positional encoding
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
                          param_dtype=jnp.float32)
-        x = embed(input_ids)
+        with program_part("embed"):
+            x = embed(input_ids)
         new_cache, memory, shared = [], None, None
         for i in range(cfg.num_hidden_layers):
             entry = None if cache is None or i > cfg.shared_kv_layer else cache[i]
@@ -433,8 +438,8 @@ class Phi4FlashForCausalLM(nn.Module):
                 # entry with the token's row (in no page yet) beside it, else
                 # the view this layer has just written
                 shared = (entry, new_entry) if isinstance(entry, PagedCache) else (new_entry, None)
-        x = LayerNorm(cfg.layer_norm_eps, name="norm")(x)
-        with jax.named_scope("lm_head"):
+        with program_part("lm_head"):
+            x = LayerNorm(cfg.layer_norm_eps, name="norm")(x)
             logits = jnp.einsum("bsh,vh->bsv", x, embed.embedding.astype(x.dtype),
                                 preferred_element_type=jnp.float32)
         if cache is not None:

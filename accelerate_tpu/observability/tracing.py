@@ -36,7 +36,11 @@ was doing in it (``chipbench/host_spans.py``, which also corrects the
 millisecond or so by which a TPU trace's device plane lags its host
 plane). With no session the annotation costs well under a
 microsecond; a disabled tracer hands back one shared no-op span and
-makes neither record nor annotation.
+makes neither record nor annotation. The DEVICE side of that timeline
+names itself too: every compiled program's operations carry the parts
+they were made under (:mod:`.program_parts`) on their ``op_name``, the
+trace keeps it as the stat ``tf_op`` of each ``XLA Ops`` event's
+metadata, and ``chipbench/op_scopes.py`` books device time to parts.
 """
 
 from __future__ import annotations

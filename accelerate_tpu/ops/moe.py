@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..observability.program_parts import program_part
+
 
 def default_num_groups(num_tokens: int, mesh=None) -> int:
     """One routing group per data shard when it divides the token count."""
@@ -187,24 +189,26 @@ def moe_mlp_apply(
     tokens = x.reshape(G, n, D)
     tokens = _constrain(tokens, (("dp", "fsdp", "ep"), None, None), mesh)
 
-    logits = tokens.astype(jnp.float32) @ router_kernel.astype(jnp.float32)  # [G, n, E]
-    if router_noise_rng is not None and router_noise_eps > 0.0:
-        noise = jax.random.uniform(
-            router_noise_rng, logits.shape, jnp.float32,
-            1.0 - router_noise_eps, 1.0 + router_noise_eps,
-        )
-        logits = logits * noise
-    dispatch, combine, aux = top_k_routing(logits, top_k, C, normalize_gates=normalize_gates)
+    with program_part("moe_router"):
+        logits = tokens.astype(jnp.float32) @ router_kernel.astype(jnp.float32)  # [G, n, E]
+        if router_noise_rng is not None and router_noise_eps > 0.0:
+            noise = jax.random.uniform(
+                router_noise_rng, logits.shape, jnp.float32,
+                1.0 - router_noise_eps, 1.0 + router_noise_eps,
+            )
+            logits = logits * noise
+        dispatch, combine, aux = top_k_routing(logits, top_k, C, normalize_gates=normalize_gates)
 
     cdt = x.dtype
-    expert_in = jnp.einsum("gnec,gnd->egcd", dispatch.astype(cdt), tokens)
-    expert_in = _constrain(expert_in, ("ep", ("dp", "fsdp"), None, None), mesh)
-    h = jax.nn.silu(jnp.einsum("egcd,edf->egcf", expert_in, wg.astype(cdt)))
-    h = h * jnp.einsum("egcd,edf->egcf", expert_in, wu.astype(cdt))
-    out_e = jnp.einsum("egcf,efd->egcd", h, wd.astype(cdt))
-    out_e = _constrain(out_e, ("ep", ("dp", "fsdp"), None, None), mesh)
-    out = jnp.einsum("gnec,egcd->gnd", combine.astype(jnp.float32), out_e.astype(jnp.float32))
-    out = _constrain(out, (("dp", "fsdp", "ep"), None, None), mesh)
+    with program_part("moe_experts"):
+        expert_in = jnp.einsum("gnec,gnd->egcd", dispatch.astype(cdt), tokens)
+        expert_in = _constrain(expert_in, ("ep", ("dp", "fsdp"), None, None), mesh)
+        h = jax.nn.silu(jnp.einsum("egcd,edf->egcf", expert_in, wg.astype(cdt)))
+        h = h * jnp.einsum("egcd,edf->egcf", expert_in, wu.astype(cdt))
+        out_e = jnp.einsum("egcf,efd->egcd", h, wd.astype(cdt))
+        out_e = _constrain(out_e, ("ep", ("dp", "fsdp"), None, None), mesh)
+        out = jnp.einsum("gnec,egcd->gnd", combine.astype(jnp.float32), out_e.astype(jnp.float32))
+        out = _constrain(out, (("dp", "fsdp", "ep"), None, None), mesh)
     return out.reshape(B, S, D).astype(x.dtype), aux
 
 
@@ -380,7 +384,7 @@ def moe_held_apply(
                          f"router over {E}")
     T = B * S
     tokens = x.reshape(T, D)
-    with jax.named_scope("moe_router"):
+    with program_part("moe_router"):
         logits = tokens.astype(jnp.float32) @ router_kernel.astype(jnp.float32)
         gates, experts = route_top_k(logits, top_k, scores=scores,
                                      normalize_gates=normalize_gates)
@@ -390,7 +394,7 @@ def moe_held_apply(
                   .sum((0, 1))[:count])
     cdt = x.dtype
     wg, wu, wd = wg.astype(cdt), wu.astype(cdt), wd.astype(cdt)
-    with jax.named_scope("moe_experts"):
+    with program_part("moe_experts"):
         if T <= HELD_DENSE_TOKENS:
             out = _held_dense(tokens, wg, wu, wd, gates, jnp.where(is_held, local, -1), count)
             routed = computed = jnp.zeros((), jnp.int32)

@@ -172,6 +172,7 @@ from ..inference import resolve_model_source
 from ..models.llama import (PagedCache, cached_attention_rows, gather_pages,
                             tick_key_extent, tick_key_tiles)
 from ..observability import FlightRecorder, Tracer, new_trace_id
+from ..observability.program_parts import program_part
 from .metrics import ServingStats
 from .request import Request, RequestStatus
 from .control import PriorityPolicy
@@ -1439,6 +1440,7 @@ class ServingEngine:
         q = jnp.clip(jnp.round(f / s), -127, 127).astype(jnp.int8)
         return q, s
 
+    @program_part("kv_view")
     def _gather_view(self, pool, pages, axes=None, struct=None, scales=None):
         """One slot's dense cache VIEW from the pool: gather its page rows
         (``pages`` [Np] i32 pool ids, 0 = scratch for unallocated entries)
@@ -1493,6 +1495,7 @@ class ServingEngine:
                 pl, pb[None].astype(pl.dtype), (tgt,) + (0,) * pb.ndim))
         return out, scales
 
+    @program_part("kv_write")
     def _scatter_chunk_pages(self, pool_leaves, view_leaves, axes, pages,
                              offset, C, scales=None):
         """Scatter a chunk's writes (positions ``[offset, offset + C)``)
@@ -1581,9 +1584,10 @@ class ServingEngine:
             params, ids_c, cache=self._join_cache(view, recurrent),
             cache_pos=offset, **kwargs)
         view, recurrent = self._split_cache(cache)
-        tok, done, rng_carry = _chunk_prefill_token(
-            logits, rng, self._select, self.eos_token_id, ids_c.dtype,
-            true_len, offset)
+        with program_part("sample"):
+            tok, done, rng_carry = _chunk_prefill_token(
+                logits, rng, self._select, self.eos_token_id, ids_c.dtype,
+                true_len, offset)
         view_leaves = jax.tree.leaves(view)
         # The block is sliced from the DEQUANTIZED view — full precision,
         # so external prefix caches stay layout-compatible across engines
@@ -1595,19 +1599,22 @@ class ServingEngine:
         pool_leaves, scales = self._scatter_chunk_pages(
             jax.tree.leaves(state["pool"]), view_leaves, self._cache_axes,
             pages, offset, C, scales)
+        with program_part("sample"):
+            slot_rows = dict(
+                pos=state["pos"].at[slot].set(true_len),
+                tok=state["tok"].at[slot].set(tok[0].astype(jnp.int32)),
+                rng=state["rng"].at[slot].set(rng_carry),
+                done=state["done"].at[slot].set(done[0]),
+            )
         new_state = dict(
-            state,
-            pool=jax.tree.unflatten(self._cache_struct, pool_leaves),
-            pos=state["pos"].at[slot].set(true_len),
-            tok=state["tok"].at[slot].set(tok[0].astype(jnp.int32)),
-            rng=state["rng"].at[slot].set(rng_carry),
-            done=state["done"].at[slot].set(done[0]),
-        )
+            state, pool=jax.tree.unflatten(self._cache_struct, pool_leaves),
+            **slot_rows)
         if scales is not None:
             new_state["pscale"] = scales
         if self._recurrent_at:
-            new_state["recurrent"] = jax.tree.map(
-                lambda a, r: a.at[slot].set(r), state["recurrent"], recurrent)
+            with program_part("kv_write"):
+                new_state["recurrent"] = jax.tree.map(
+                    lambda a, r: a.at[slot].set(r), state["recurrent"], recurrent)
         if bank is not None:
             new_state["adapter_idx"] = state["adapter_idx"].at[slot].set(aidx)
         if dparams is not None:
@@ -1694,6 +1701,7 @@ class ServingEngine:
             new_state["pscale"] = scales
         return new_state
 
+    @program_part("kv_view")
     def _gather_views_all_slots(self, pool, table, axes=None, struct=None,
                                 scales=None):
         """Batched :meth:`_gather_view`: ``table`` [S, Np] → per-leaf
@@ -1717,6 +1725,7 @@ class ServingEngine:
             leaves.append(g.reshape(shape))
         return jax.tree.unflatten(struct, leaves)
 
+    @program_part("kv_write")
     def _scatter_slot_pages(self, pool_leaves, nv_leaves, axes, table,
                             active, pos, last_off, steps, scales=None):
         """Scatter every slot's speculative writes back into the pool: the
@@ -1773,7 +1782,6 @@ class ServingEngine:
         ``pos`` can't corrupt the pool. The host guarantees an active
         slot's ``pos`` page is allocated before every tick. Returns
         ``(state, tokens [S], done [S])``."""
-        P = self._page
         params = self._dq(params)
         scales = state.get("pscale")
         scale_rows = (None,) * len(state["pool"]) if scales is None else jax.tree.unflatten(
@@ -1788,10 +1796,11 @@ class ServingEngine:
                 params, tok[None, None],
                 cache=self._join_cache(cache, recurrent),
                 cache_pos=pos, **self._lora_kwargs(bank, aidx))
-            rng, sub = jax.random.split(rng)
-            nxt, done = _next_token(logits[:, -1], sub, jnp.zeros((1, 1), bool),
-                                    done[None], self._select, self.eos_token_id,
-                                    tok.dtype)
+            with program_part("sample"):
+                rng, sub = jax.random.split(rng)
+                nxt, done = _next_token(logits[:, -1], sub, jnp.zeros((1, 1), bool),
+                                        done[None], self._select, self.eos_token_id,
+                                        tok.dtype)
             return self._split_cache(rows), nxt[0], rng, done[0], counts
 
         # a lane's recurrent rows ride the vmap with it (() where the cache
@@ -1807,6 +1816,34 @@ class ServingEngine:
             # active slots' counters, summed, ride behind the tokens
             counts = jnp.where(active[:, None], counts, 0).sum(0)
             toks_out = jnp.concatenate([toks, counts.astype(toks.dtype)])
+        pool_leaves, scales = self._write_tick_rows(state, rows, active, table, scales)
+        with program_part("sample"):
+            slot_rows = dict(
+                pos=jnp.where(active, state["pos"] + 1, state["pos"]),
+                tok=jnp.where(active, toks, state["tok"]),
+                rng=jnp.where(active[:, None], rngs, state["rng"]),
+                done=jnp.where(active, dones, state["done"]),
+            )
+        new_state = dict(
+            state, pool=jax.tree.unflatten(self._cache_struct, pool_leaves),
+            **slot_rows)
+        if scales is not None:
+            new_state["pscale"] = scales
+        if self._recurrent_at:
+            # a lane without a stream keeps its rows, as it keeps its pos
+            with program_part("kv_write"):
+                new_state["recurrent"] = jax.tree.map(
+                    lambda new, old: jnp.where(
+                        active.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
+                    recurrent, state["recurrent"])
+        return new_state, toks_out, dones
+
+    @program_part("kv_write")
+    def _write_tick_rows(self, state, rows, active, table, scales):
+        """The tick's one scatter of ``S`` rows a leaf (an int8 pool
+        re-quantises the ``S`` touched pages as one batch). Returns
+        ``(pool_leaves, scales)``."""
+        P = self._page
         lanes = jnp.arange(self.max_slots)
         tgt = jnp.where(active, table[lanes, state["pos"] // P], 0)
         off = state["pos"] % P
@@ -1822,24 +1859,9 @@ class ServingEngine:
                 pb.at[lanes, 0, off].set(rl[:, 0, 0]))
             pool_leaves.append(pl.at[tgt].set(pb))
             scales = scales.at[i, tgt].set(sc)
-        state = dict(
-            state,
-            pool=jax.tree.unflatten(self._cache_struct, pool_leaves),
-            pos=jnp.where(active, state["pos"] + 1, state["pos"]),
-            tok=jnp.where(active, toks, state["tok"]),
-            rng=jnp.where(active[:, None], rngs, state["rng"]),
-            done=jnp.where(active, dones, state["done"]),
-        )
-        if scales is not None:
-            state["pscale"] = scales
-        if self._recurrent_at:
-            # a lane without a stream keeps its rows, as it keeps its pos
-            state["recurrent"] = jax.tree.map(
-                lambda new, old: jnp.where(
-                    active.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
-                recurrent, state["recurrent"])
-        return state, toks_out, dones
+        return pool_leaves, scales
 
+    @program_part("sample")
     def _spec_accept(self, logits, drafts, done, rem, rng):
         """Per-slot accept epilogue shared by BOTH speculative programs
         (draft-model and prompt-lookup): run the factored accept rule

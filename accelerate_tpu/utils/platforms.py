@@ -66,12 +66,22 @@ def enable_compilation_cache() -> str:
     :data:`DEFAULT_COMPILATION_CACHE`. The one place the package touches
     ``jax_compilation_cache_dir``: ``serve``, the ``Accelerator``,
     ``bench.py`` and ``chip_smoke.py`` all come through here.
+
+    An entry is keyed with the program's metadata. jax leaves it out by
+    default, and the parts a program names (``observability/program_parts.py``)
+    are metadata: an executable that a tree without a scope cached would be
+    handed to the tree that declares it, old ``op_name``s and all, and a device
+    trace would show the other tree's parts (seen on the chip, PERF.md
+    section 6, PR 37). The price: source locations are metadata too, so an
+    entry is found again by the same files at the same path, as a program
+    with Mosaic kernels always was.
     """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILATION_CACHE)
     return DEFAULT_COMPILATION_CACHE
 

@@ -70,10 +70,13 @@ GROUPS = [
     ("observability", "Observability",
      ["accelerate_tpu.observability.tracing",
       "accelerate_tpu.observability.flight_recorder",
-      "accelerate_tpu.observability.promlint"],
+      "accelerate_tpu.observability.promlint",
+      "accelerate_tpu.observability.program_parts"],
      "Request-scoped tracing (trace ids, per-thread span rings, "
      "Chrome-trace export), the per-replica flight recorder behind "
-     "failover postmortems, and the Prometheus exposition linter."),
+     "failover postmortems, the Prometheus exposition linter, and the "
+     "vocabulary of named parts every compiled program carries into a "
+     "device trace."),
     ("adapters", "LoRA adapters",
      ["accelerate_tpu.adapters.lora", "accelerate_tpu.adapters.registry"],
      "Multi-tenant LoRA: config/init/merge and the frozen-base training "
@@ -120,6 +123,11 @@ GROUPS = [
     ("native", "Native IO", ["accelerate_tpu.native.io"],
      "The C++ parallel safetensors reader and token-bin prefetch ring."),
 ]
+
+
+#: modules whose docstring is rendered whole (a table the reference should
+#: carry), the rest of it as preformatted text under the first paragraph
+WHOLE_DOCSTRING = {"accelerate_tpu.observability.program_parts"}
 
 
 def first_paragraph(obj) -> str:
@@ -194,6 +202,9 @@ def render_module(path: str) -> list[str]:
     if not classes and not functions:
         return []
     lines = [f"## `{path}`", "", first_paragraph(mod), ""]
+    if path in WHOLE_DOCSTRING:
+        rest = inspect.getdoc(mod).split("\n\n", 1)[1:]
+        lines += ["```text", *rest, "```", ""]
     for name, cls in classes:
         lines += render_class(name, cls)
     for name, fn in functions:

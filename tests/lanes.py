@@ -66,8 +66,10 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_memory_properties.py",
         "test_models.py",
         "test_observability.py",
+        "test_op_scopes.py",
         "test_paged_tick_attention.py",
         "test_pipeline.py",
+        "test_program_parts.py",
         "test_quantization.py",
         "test_serving.py",
         "test_serving_async.py",

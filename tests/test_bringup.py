@@ -18,6 +18,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # compile cache placement
 # ---------------------------------------------------------------------------
 
+#: an entry is keyed with the program's metadata, the named parts among it
+METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
 class TestCompilationCachePlacement:
     @pytest.fixture
     def config_writes(self, monkeypatch):
@@ -26,12 +30,12 @@ class TestCompilationCachePlacement:
         monkeypatch.setattr(jax.config, "update", lambda k, v: writes.append((k, v)))
         return writes
 
-    def test_variable_set_means_no_config_write(self, monkeypatch, config_writes, tmp_path):
+    def test_variable_set_means_the_directory_is_not_written(self, monkeypatch, config_writes, tmp_path):
         from accelerate_tpu.utils.platforms import enable_compilation_cache
 
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert enable_compilation_cache() == str(tmp_path)
-        assert config_writes == []
+        assert config_writes == [METADATA_IN_KEY]
 
     def test_unset_means_the_fixed_checkout_path(self, monkeypatch, config_writes):
         from accelerate_tpu.utils import platforms
@@ -40,7 +44,7 @@ class TestCompilationCachePlacement:
         want = os.path.join(REPO, ".jax_cache")
         assert platforms.DEFAULT_COMPILATION_CACHE == want
         assert platforms.enable_compilation_cache() == want
-        assert config_writes == [("jax_compilation_cache_dir", want)]
+        assert config_writes == [METADATA_IN_KEY, ("jax_compilation_cache_dir", want)]
 
     def test_accelerator_goes_through_the_helper(self, monkeypatch, config_writes, tmp_path):
         from accelerate_tpu import Accelerator
