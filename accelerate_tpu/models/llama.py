@@ -847,7 +847,8 @@ class ServedForm(NamedTuple):
 
 
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=["pool", "scales", "pages", "live"], meta_fields=["dtype"])
+                   data_fields=["pool", "scales", "pages", "live"],
+                   meta_fields=["dtype", "sharded"])
 @dataclasses.dataclass(frozen=True)
 class PagedCache:
     """One layer's cache as the serving engine's plain decode tick hands it
@@ -858,6 +859,8 @@ class PagedCache:
     page table (``pages [Np]`` pool ids, 0 where nothing is allocated) and
     whether a stream runs in it (``live``). ``dtype`` (static) is what an
     int8 pool's rows widen to; None reads each leaf in its own type.
+    ``sharded`` (static) says that a mesh axis splits the pool's leaves, which
+    a trace cannot see and a Mosaic kernel cannot be partitioned over.
 
     The two ``update_*_cache_and_attend`` functions read such a cache in
     place (:func:`_attend_work_list`) and return the token's new row, not
@@ -867,6 +870,7 @@ class PagedCache:
     pages: Any
     live: Any
     dtype: Any = None
+    sharded: bool = False
 
     def row_dtype(self, name: str):
         return self.pool[name].dtype if self.dtype is None else self.dtype
@@ -1012,18 +1016,27 @@ def _attend_work_list(score, weigh, m, acc, pool, scales, table, pos, live, *,
 
 
 def _paged_attention(kind, lane, shared, cache: PagedCache, cache_pos, sliding_window=None):
-    """The bridge between the tick's ``jax.vmap`` over batch-1 forwards and
-    :func:`_attend_work_list` over all lanes: one function whose batching
-    rule IS the work-list form (``jax.custom_batching.custom_vmap``), so the
-    model code stays the one batch-1 path. ``lane`` holds what is a lane's
+    """:func:`_attend_work_list` over all lanes of the tick, from a batch-1
+    forward (:func:`_over_the_ticks_lanes`). ``lane`` holds what is a lane's
     own (queries, the token's row), ``shared`` what all lanes share besides
     the pool; ``kind(lane, shared, pos)`` -> ``(score, weigh, m, acc)`` over
-    a leading lane axis. Unbatched, it runs the same code with one lane."""
+    a leading lane axis."""
 
     def over_lanes(lane, shared, pool, scales, table, pos, live):
         score, weigh, m, acc = kind(lane, shared, pos)
         return _attend_work_list(score, weigh, m, acc, pool, scales, table, pos, live,
                                  sliding_window=sliding_window, dtype=cache.dtype)
+
+    return _over_the_ticks_lanes(over_lanes, lane, shared, cache, cache_pos)
+
+
+def _over_the_ticks_lanes(over_lanes, lane, shared, cache: PagedCache, cache_pos):
+    """The bridge between the tick's ``jax.vmap`` over batch-1 forwards and
+    an attention over all lanes at once: one function whose batching rule IS
+    ``over_lanes(lane, shared, pool, scales, table, pos, live)`` over a
+    leading lane axis (``jax.custom_batching.custom_vmap``), so the model
+    code stays the one batch-1 path. Unbatched, it runs the same code with
+    one lane."""
 
     @jax.custom_batching.custom_vmap
     def attend(lane, shared, pool, scales, pages, pos, live):
@@ -1031,7 +1044,7 @@ def _paged_attention(kind, lane, shared, cache: PagedCache, cache_pos, sliding_w
         return over_lanes(one[0], shared, pool, scales, *one[1:])[0]
 
     @attend.def_vmap
-    def over_the_ticks_lanes(axis_size, in_batched, lane, shared, pool, scales, pages, pos, live):
+    def batched(axis_size, in_batched, lane, shared, pool, scales, pages, pos, live):
         if any(jax.tree.leaves(in_batched[1:4])):
             raise NotImplementedError("a paged cache's pool is shared by every lane of a vmap")
         lane, pages, pos, live = jax.tree.map(
@@ -1101,13 +1114,28 @@ def _paged_flat_kv_attend(cache: PagedCache, q, k, v, cache_pos, n_rep: int, sca
     rows into a head-major layout, which it has not. Products in the wider
     of the queries' and the rows' types, accumulated in float32 (as the
     latent form). Returns ``(out [1, 1, H, hd], {"k", "v"}: the token's row
-    [1, 1, G * hd])``."""
+    [1, 1, G * hd])``.
+
+    On the TPU, over a pool without scales that no mesh axis shards
+    (``ops.paged_attention.paged_attention_available``: what the code can
+    see, no option), the pool's rows are read by ONE Mosaic kernel call
+    instead of that list's loop: each live page fetched once for both
+    products, multiplied as it lies in VMEM (:func:`_paged_flat_kernel`).
+    The list is that kernel's reference, and what every other backend runs."""
+    from ..ops.paged_attention import paged_attention_available
+
     _, _, H, hd = q.shape
     G = H // n_rep
     row = {"k": k.astype(cache.row_dtype("k")).reshape(1, 1, G * hd),
            "v": v.astype(cache.row_dtype("v")).reshape(1, 1, G * hd)}
     cdt = jnp.promote_types(q.dtype, row["k"].dtype)
     f32 = dict(preferred_element_type=jnp.float32)
+    if paged_attention_available(cache.pool, cache.scales, cache.sharded):
+        lane = ((q[0, 0] * scale).astype(cdt), row["k"][0, 0], row["v"][0, 0])
+        out = _over_the_ticks_lanes(
+            functools.partial(_paged_flat_kernel, n_rep=n_rep, sliding_window=sliding_window),
+            lane, None, cache, cache_pos)
+        return out.reshape(1, 1, H, hd).astype(q.dtype), row
     own = jnp.eye(G, dtype=bool)[:, None, :, None]                   # head g keeps columns g
 
     def kind(lane, shared, pos):
@@ -1132,6 +1160,33 @@ def _paged_flat_kv_attend(cache: PagedCache, q, k, v, cache_pos, n_rep: int, sca
     out = _paged_attention(kind, (q_rows, row["k"][0, 0], row["v"][0, 0]), None, cache, cache_pos,
                            sliding_window)
     return out.reshape(1, 1, H, hd).astype(q.dtype), row
+
+
+def _paged_flat_kernel(lane, shared, pool, scales, table, pos, live, *, n_rep: int,
+                       sliding_window=None):
+    """All lanes' attention over a flat-row pool through the Mosaic kernel
+    (``ops/paged_attention.py``): the kernel's unnormalised (max, sum,
+    weighted values) over the rows the pool holds, merged with the token's
+    own row — in no page yet, scored here as the work list scores it — and
+    normalised. ``lane`` = ``(q [S, H, hd] scaled, k_own, v_own [S, G *
+    hd])``. Returns ``[S, H, hd]`` float32; a lane that holds no row (idle
+    ones too) ends with its own value row."""
+    from ..ops.paged_attention import paged_flat_attention
+
+    del shared, scales
+    q, k_own, v_own = lane
+    S, H, hd = q.shape
+    G = H // n_rep
+    m_pool, l_pool, acc_pool = paged_flat_attention(
+        q, pool["k"], pool["v"], table, pos, live, n_rep=n_rep, sliding_window=sliding_window)
+    m_own = jnp.einsum("sgrd,sgd->sgr", q.reshape(S, G, n_rep, hd),
+                       k_own.reshape(S, G, hd).astype(q.dtype),
+                       preferred_element_type=jnp.float32).reshape(S, H)
+    v_own = jnp.repeat(v_own.astype(jnp.float32).reshape(S, G, hd), n_rep, axis=1)
+    m = jnp.maximum(m_own, m_pool)
+    w_own, w_pool = jnp.exp(m_own - m), jnp.exp(m_pool - m)
+    acc = w_own[..., None] * v_own + w_pool[..., None] * acc_pool
+    return acc / (w_own + w_pool * l_pool)[..., None]
 
 
 def attend_shared_kv_cache(cache, q, cache_pos, n_rep: int, row=None, sm_scale=None):

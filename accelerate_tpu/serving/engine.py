@@ -171,6 +171,7 @@ from ..generation import (
 from ..inference import resolve_model_source
 from ..models.llama import (PagedCache, cached_attention_rows, gather_pages,
                             tick_key_extent, tick_key_tiles)
+from ..ops.paged_attention import live_pages, paged_attention_available
 from ..observability import FlightRecorder, Tracer, new_trace_id
 from ..observability.program_parts import program_part
 from .metrics import ServingStats
@@ -933,6 +934,17 @@ class ServingEngine:
             # scratch-page gathers finite before any real write.
             self._state["pscale"] = jnp.ones(
                 (len(pool_leaves), usable + 1), jnp.float32)
+        #: the plain tick's attentions by (window or None, whether the
+        #: Mosaic paged-attention kernel reads the entry's pages in place of
+        #: the XLA work list: ops/paged_attention.py's own rule, from the
+        #: pool this engine has just built) -> how many there are
+        paged_at = [i for i in range(len(slot_shape)) if i not in self._recurrent_at]
+        self._tick_attn_kinds = collections.Counter(
+            (w, paged_attention_available(
+                self._state["pool"][paged_at.index(e)], self._state.get("pscale"), self.tp > 1))
+            for w, e in layout)
+        self._tick_kernel_readers = sum(
+            n for (_, kernel), n in self._tick_attn_kinds.items() if kernel)
         if self._spec_mode == "draft":
             dshape = jax.eval_shape(lambda: self._draft_factory(
                 1, self.max_len + self._spec_k, self._dtype))
@@ -1790,7 +1802,8 @@ class ServingEngine:
         def one_slot(pages, live, tok, pos, rng, done, recurrent, aidx=None):
             cache = tuple(
                 PagedCache(pool=layer, scales=sc, pages=pages, live=live,
-                           dtype=None if scales is None else self._dtype)
+                           dtype=None if scales is None else self._dtype,
+                           sharded=self.tp > 1)
                 for layer, sc in zip(state["pool"], scale_rows))
             logits, rows, counts = self._apply_counted(
                 params, tok[None, None],
@@ -2492,6 +2505,21 @@ class ServingEngine:
         """Bytes one cached token takes over all layers, from the leaves of
         the cache the model declares (a page's bytes over its rows)."""
         return self._page_bytes // self._page
+
+    @property
+    def tick_attn_kernel_readers(self) -> int:
+        """Attentions of one plain decode tick that read the page pool
+        through the Mosaic paged-attention kernel (``ops/paged_attention.py``:
+        one call a reader, each live page fetched once) instead of the XLA
+        work list's loop. Decided at build from what the engine holds
+        (``ops.paged_attention.paged_attention_available``): the backend is
+        the TPU, the cache entry's leaves are flat ``k`` / ``v`` rows
+        ``[pages, 1, P, G * hd]`` of whole lane tiles, the pool has no int8
+        scales and ``tp == 1``. Every other engine — every engine off the TPU
+        — reads 0 and runs the work list, which is also the kernel's
+        reference; the speculative ticks and the prefill chunk gather views
+        either way."""
+        return self._tick_kernel_readers
 
     @property
     def recurrent_state_bytes(self) -> int:
@@ -3207,7 +3235,9 @@ class ServingEngine:
         none; a running lane the key blocks that hold rows before its own
         (from the window's start on a windowed layer), and the tick whole
         steps of its work list: the items that fill the last step up are
-        scored under the mask too."""
+        scored under the mask too. An attention that runs the
+        paged-attention kernel scores its lanes' live PAGES, whole, and
+        nothing to fill a step up."""
         heads = self._attn_score_heads
         if heads is None:
             return None
@@ -3215,11 +3245,16 @@ class ServingEngine:
         block, group = tick_key_tiles(heads, self.max_slots, L, self._page)
         pos = np.asarray(positions, np.int64)
         scored = visible = layers = 0
-        for window, n in self._attn_layer_kinds:
-            first, count = tick_key_extent(pos, True, L, block, window, lib=np)
-            items = -(-int(count.sum()) // group) * group
+        for (window, kernel), n in self._tick_attn_kinds.items():
+            if kernel:
+                _, count = live_pages(pos, True, self._pages_per_slot, self._page, window,
+                                      lib=np)
+                rows = int(count.sum()) * self._page
+            else:
+                _, count = tick_key_extent(pos, True, L, block, window, lib=np)
+                rows = -(-int(count.sum()) // group) * group * block
             low = 0 if window is None else np.maximum(pos - window + 1, 0)
-            scored += n * items * block
+            scored += n * rows
             visible += n * int((pos - low).sum())
             layers += n
         return scored, visible, layers * L * self.max_slots
@@ -3545,7 +3580,8 @@ class ServingEngine:
                                  kv_reader_layers=self._kv_readers,
                                  recurrent_state_bytes=self._recurrent_bytes,
                                  weights_served_form_leaves=self._served_form_leaves,
-                                 weights_served_form_bytes=self._served_form_bytes)
+                                 weights_served_form_bytes=self._served_form_bytes,
+                                 tick_attn_kernel_readers=self.tick_attn_kernel_readers)
 
     def _dispatch_spec(self, running, ahead: bool,
                        stale) -> Optional[_TickFlight]:

@@ -207,6 +207,10 @@ class ServingStats:
             # (gauges, set at load: 0 for a family served as published).
             self._weights_served_form_leaves = 0
             self._weights_served_form_bytes = 0
+            # Attentions of one plain tick that read the page pool through
+            # the Mosaic paged-attention kernel (gauge, set at build: 0 where
+            # every reader runs the XLA work list).
+            self._tick_attn_kernel_readers = 0
             # Speculative decoding: draft proposals vs target acceptances.
             self._spec_ticks = 0
             self._spec_proposed = 0
@@ -384,7 +388,8 @@ class ServingStats:
                      kv_cache_layers: int = 0, kv_reader_layers: int = 0,
                      recurrent_state_bytes: int = 0,
                      weights_served_form_leaves: int = 0,
-                     weights_served_form_bytes: int = 0):
+                     weights_served_form_bytes: int = 0,
+                     tick_attn_kernel_readers: int = 0):
         """Gauge: paged-KV pool occupancy after a tick (page counts).
         ``freed_total`` mirrors the pool's cumulative free count — the
         page-drain observable behind the gateway's pressure Retry-After;
@@ -392,7 +397,9 @@ class ServingStats:
         ``kv_cache_layers`` cache entries that ``kv_reader_layers``
         attentions read; ``recurrent_state_bytes`` what the cache holds per
         slot beside the pages, all slots; ``weights_served_form_leaves`` /
-        ``_bytes`` the weights held in their family's served form."""
+        ``_bytes`` the weights held in their family's served form;
+        ``tick_attn_kernel_readers`` the attentions of a plain tick that run
+        the paged-attention kernel."""
         with self._lock:
             self._kv_bytes_per_token = int(kv_bytes_per_token)
             self._kv_cache_layers = int(kv_cache_layers)
@@ -400,6 +407,7 @@ class ServingStats:
             self._recurrent_state_bytes = int(recurrent_state_bytes)
             self._weights_served_form_leaves = int(weights_served_form_leaves)
             self._weights_served_form_bytes = int(weights_served_form_bytes)
+            self._tick_attn_kernel_readers = int(tick_attn_kernel_readers)
             self._pages_free = int(free)
             self._pages_used = int(used)
             self._pages_total = int(total)
@@ -581,7 +589,8 @@ class ServingStats:
             for k in ("_queue_wait_ms_max", "_ttft_ms_max",
                       "_prefill_backlog_max", "_host_us_max",
                       "_logprob_drift", "_kv_bytes_per_token",
-                      "_kv_cache_layers", "_kv_reader_layers"):
+                      "_kv_cache_layers", "_kv_reader_layers",
+                      "_tick_attn_kernel_readers"):
                 setattr(self, k, max(getattr(self, k), o[k]))
             self._ttft_samples.extend(o_samples)
             if len(self._ttft_samples) > self.MAX_TTFT_SAMPLES:
@@ -743,6 +752,7 @@ class ServingStats:
                 "recurrent_state_resets": self._recurrent_state_resets,
                 "weights_served_form_leaves": self._weights_served_form_leaves,
                 "weights_served_form_bytes": self._weights_served_form_bytes,
+                "tick_attn_kernel_readers": self._tick_attn_kernel_readers,
             }
             # The host path by phase ("host_us/<phase>", slash-pathed like
             # the adapter keys; the gateway re-emits them as one labeled

@@ -105,6 +105,10 @@ SIZES = {
         "server": dict(num_hidden_layers=3, max_slots=8, max_len=1024,
                        prefill_chunk=256, max_pages=512,
                        prompt_lens=(300, 520, 700, 330), max_new_tokens=64),
+        # serve-phi4flash-closed48-reason's tick: 48 lanes, 40 query heads over 10 key pairs
+        # of 128, 449 pages of 256 flat rows, 16 pages a lane, the layers' window
+        "paged_attention": dict(slots=48, q_heads=40, n_rep=4, head_dim=128, page=256,
+                                pages_per_slot=16, pages=449, window=512),
         "served_form": dict(vocab_size=32768, hidden_size=4096, intermediate_size=4096,
                             num_hidden_layers=4, num_attention_heads=128,
                             num_key_value_heads=8, head_dim=128, sliding_window=4096,
@@ -125,6 +129,8 @@ SIZES = {
                        num_experts=4, max_slots=4, max_len=128,
                        prefill_chunk=16, max_pages=None,
                        prompt_lens=(20, 37, 50, 24), max_new_tokens=8),
+        "paged_attention": dict(slots=4, q_heads=8, n_rep=4, head_dim=128, page=16,
+                                pages_per_slot=4, pages=17, window=24),
         "served_form": dict(vocab_size=256, hidden_size=64, intermediate_size=32,
                             num_hidden_layers=4, num_attention_heads=8,
                             num_key_value_heads=2, head_dim=16, sliding_window=8,
@@ -368,7 +374,69 @@ def run_kernels(size: dict, seed: int) -> dict:
     require(errs["out"] <= KERNEL_TOL["fwd"], f"flash forward disagrees with einsum: {errs}")
     require(max(errs["dq"], errs["dk"], errs["dv"]) <= KERNEL_TOL["bwd"], (
         f"flash backward disagrees with einsum: {errs}"))
+    out["paged_attention"] = run_paged_attention(size["paged_attention"], seed)
     return out
+
+
+def run_paged_attention(p: dict, seed: int) -> dict:
+    """The decode tick's paged-attention kernel (ops/paged_attention.py) once
+    at the cell's shapes, with and without the window, against the XLA work
+    list it takes the place of — both through ``update_kv_cache_and_attend``
+    under the tick's vmap, the pool an argument. Lanes at ragged positions,
+    one of them idle; pages handed out in order as the engine's pool would."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.ops import paged_attention
+
+    S, H, rep, hd, P, Np = (p[k] for k in ("slots", "q_heads", "n_rep", "head_dim", "page",
+                                           "pages_per_slot"))
+    G = H // rep
+    rng = np.random.default_rng(seed)
+    pos = rng.integers(P, Np * P - 1, size=S)
+    pos[:3] = (1, P, Np * P - 1)
+    live = np.ones((S,), bool)
+    live[S // 2] = False
+    handed = iter(rng.permutation(np.arange(1, p["pages"])))
+    table = np.zeros((S, Np), np.int32)
+    for s in range(S):
+        for j in range(pos[s] // P + 1):
+            table[s, j] = next(handed, 0) or 1 + (s * Np + j) % (p["pages"] - 1)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    pool = {n: jax.random.normal(k, (p["pages"], 1, P, G * hd), jnp.bfloat16)
+            for n, k in zip(("k", "v"), keys)}
+    q = jax.random.normal(keys[2], (S, 1, 1, H, hd), jnp.bfloat16)
+    own = jax.random.normal(keys[3], (S, 1, 1, G, hd), jnp.bfloat16)
+
+    def attend(window, on_kernel):
+        def tick(pool, q, own, table, pos, live):
+            def one_lane(q, own, pages, at, alive):
+                cache = llama.PagedCache(pool=pool, scales=None, pages=pages, live=alive)
+                return llama.update_kv_cache_and_attend(cache, q, own, own, at, rep,
+                                                        sliding_window=window)[0]
+            return jax.vmap(one_lane)(q, own, table, pos, live)
+
+        probe = paged_attention.tpu_backend
+        paged_attention.tpu_backend = lambda: on_kernel      # which branch this trace takes
+        try:
+            return jax.block_until_ready(jax.jit(tick)(
+                pool, q, own, jnp.asarray(table), jnp.asarray(pos, jnp.int32), jnp.asarray(live)))
+        finally:
+            paged_attention.tpu_backend = probe
+
+    errs = {}
+    for name, window in (("full", None), ("window", p["window"])):
+        got, want = (attend(window, on).astype(jnp.float32) for on in (True, False))
+        errs[name] = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+        require(bool(jnp.isfinite(got).all()) and errs[name] <= KERNEL_TOL["fwd"], (
+            f"the paged-attention kernel disagrees with the XLA work list: {errs}"))
+    _, count = paged_attention.live_pages(pos, live, Np, P, None, lib=np)
+    return {"shape": {**p, "dtype": "bfloat16"}, "interpreted": paged_attention._interpret(),
+            "live_pages_full": int(count.sum()),
+            "max_rel_err_to_work_list": {k: round(e, 6) for k, e in errs.items()},
+            "tolerance": KERNEL_TOL["fwd"]}
 
 
 # ---------------------------------------------------------------------------

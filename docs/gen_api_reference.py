@@ -98,9 +98,11 @@ GROUPS = [
      "The mesh, sharding rules, the pipeline scan, and host offload."),
     ("ops", "Ops & kernels",
      ["accelerate_tpu.ops.attention", "accelerate_tpu.ops.flash_pallas",
+      "accelerate_tpu.ops.paged_attention",
       "accelerate_tpu.ops.ring_attention", "accelerate_tpu.ops.moe",
       "accelerate_tpu.ops.quant", "accelerate_tpu.ops.fused_loss"],
-     "Pallas flash attention, ring/Ulysses attention, MoE dispatch, fp8 matmul."),
+     "Pallas flash attention, the decode tick's paged attention, ring/Ulysses attention, MoE "
+     "dispatch, fp8 matmul."),
     ("models", "Model zoo",
      ["accelerate_tpu.models.llama", "accelerate_tpu.models.mixtral",
       "accelerate_tpu.models.gpt2", "accelerate_tpu.models.gptj",
@@ -149,8 +151,10 @@ def signature_of(obj) -> str:
         sig = str(inspect.signature(obj))
     except (ValueError, TypeError):
         return "(...)"
-    # Default-value reprs can embed memory addresses; strip them so
-    # regeneration is deterministic (no address-only doc churn).
+    # Default-value reprs can embed memory addresses, and a module's its
+    # install path; strip them so regeneration is deterministic (no
+    # address-only or machine-only doc churn).
+    sig = re.sub(r"<module '([\w.]+)' from '[^']*'>", r"\1", sig)
     return re.sub(r" at 0x[0-9a-f]+", "", sig)
 
 

@@ -67,6 +67,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_models.py",
         "test_observability.py",
         "test_op_scopes.py",
+        "test_paged_attention_kernel.py",
         "test_paged_tick_attention.py",
         "test_pipeline.py",
         "test_program_parts.py",

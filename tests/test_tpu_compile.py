@@ -402,8 +402,20 @@ def _phi4flash_programs(one_chip):
     }
 
 
+@pytest.fixture
+def paged_kernel(monkeypatch):
+    """The paged-attention kernel's dispatch on its TPU branch, lowered by
+    Mosaic: the code asks ``jax.default_backend()``, which is the CPU here."""
+    from accelerate_tpu.ops import paged_attention
+
+    monkeypatch.setattr(paged_attention, "tpu_backend", lambda: True)
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+
+
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
-def test_phi4flash_serving_program_compiles_for_v5e(program, one_chip):
+def test_phi4flash_serving_program_compiles_for_v5e(program, one_chip, paged_kernel):
+    import re
+
     fn, args = _phi4flash_programs(one_chip)[program]
     compiled = jax.jit(fn).lower(*args).compile()
     memory = compiled.memory_analysis()
@@ -413,12 +425,28 @@ def test_phi4flash_serving_program_compiles_for_v5e(program, one_chip):
             else 449 * 256 * 46080 + state)                      # one slot's views / the pool
     assert weights * 0.98 < memory.argument_size_in_bytes - held < weights * 1.02
     text = compiled.as_text()
-    assert " while(" in text
     if program == "prefill_chunk":
         # attention over 1024-row key blocks, 40 score heads; never the whole view's scores
+        assert " while(" in text
         assert "f32[1,10,4,256,1024]" in text and "256,4096]" not in text
         assert memory.temp_size_in_bytes < 1.5e9, memory
     else:
-        # no lane's view is gathered: the pool's pages are read in the work list's steps
+        # no lane's view is gathered: each of the 16 attentions is one Mosaic kernel call that
+        # reads the pool's live pages in place ...
         assert "[48,1,4096," not in text and "[48,4096," not in text
         assert memory.temp_size_in_bytes < 1.0e9, memory
+        calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == 16 and all("kv_attn/paged_flat_attention" in c for c in calls)
+        # ... as it lies: a call's two pool operands are the program's own parameters, and no
+        # other instruction reads, copies, reshapes or slices a whole pool leaf
+        leaf = r"bf16\[449,1,256,1280\]"
+        pool = set(re.findall(rf"(%\S+) = {leaf}\S* parameter\(", text))
+        assert len(pool) == 18
+        for call in calls:
+            operands = re.search(r"custom-call\(([^)]*)\)", call).group(1).split(", ")
+            assert len(pool & {op.split("*/")[-1] for op in operands}) == 2, call
+        for line in text.splitlines():
+            if re.search(leaf, line) and "parameter(" not in line and line not in calls:
+                assert line.lstrip().startswith(("HloModule", "ENTRY")), line
+        # the work list's loops and its 1280-wide weighted rows are gone
+        assert not re.search(rf"\swhile\(.*{leaf}", text) and "f32[64,10,4,1280]" not in text
