@@ -11,6 +11,7 @@ master + 2 moments), and the per-chip share under an FSDP mesh axis.
 from __future__ import annotations
 
 import argparse
+from typing import NamedTuple
 
 
 def _model_registry():
@@ -41,6 +42,14 @@ def _model_registry():
 
         return PanguUltraMoeForCausalLM(PanguUltraMoeConfig())
 
+    def _longcat_flash_omni():
+        # the benchmark cell's pipeline stage: 4 of the 28 double layers and
+        # 1/8 of the vocabulary, every width as published (the experts held
+        # here: --held-experts)
+        from ..models.longcat_flash import LongcatFlashConfig, LongcatFlashForCausalLM
+
+        return LongcatFlashForCausalLM(LongcatFlashConfig(num_layers=4, vocab_size=16384))
+
     def _phi4_mini_flash():
         from ..models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
 
@@ -57,6 +66,7 @@ def _model_registry():
         "mixtral-8x7b": _mixtral_8x7b,
         "command-a-plus": _command_a_plus,
         "openpangu-ultra-moe": _openpangu_ultra_moe,
+        "longcat-flash-omni": _longcat_flash_omni,
         "phi-4-mini-flash-reasoning": _phi4_mini_flash,
         "gpt-neox-20b": lambda: GPTNeoXForCausalLM(GPTNeoXConfig.neox_20b()),
         "opt-30b": lambda: OPTForCausalLM(OPTConfig.opt_30b()),
@@ -211,15 +221,24 @@ def _kv_geometry(module):
     return int(layers), int(kv), int(head_dim)
 
 
+class DeclaredKV(NamedTuple):
+    """The cache a module declares itself, as :func:`_declared_kv` reads it."""
+    per_tok: int        # bytes a token over the entries that keep rows
+    leaves: int         # those entries' leaves
+    label: str
+    per_slot: int       # bytes a SLOT of the leaves without a length axis
+    per_head: bool      # the rows are per-head K and V
+    entries: int        # cache entries that keep rows
+
+
 def _declared_kv(module):
     """Of the cache a module declares itself (``init_cache``), read from its
-    leaves at lengths 2 and 1: ``(bytes a token over the layers that keep
-    rows, those layers' leaves, label, bytes a SLOT of the leaves without a
-    length axis, whether the rows are per-head K and V)``. The last is what
-    the engine's refusals go by (serving/engine.py): latent rows have no
-    heads to shard and no one scale a page; a recurrent leaf (a state-space
-    layer's state) is held per slot beside the pool, not paged. None for the
-    per-head K/V families of the ladder."""
+    leaves at lengths 2 and 1: a :class:`DeclaredKV`. An entry is what one
+    attention writes: a layer may hold two, or none. Whether the rows are
+    per-head is what the engine's refusals go by (serving/engine.py): latent
+    rows have no heads to shard and no one scale a page; a recurrent leaf (a
+    state-space layer's state) is held per slot beside the pool, not paged.
+    None for the per-head K/V families of the ladder."""
     if not hasattr(module, "init_cache"):
         return None
     import jax
@@ -232,14 +251,14 @@ def _declared_kv(module):
     rows = [(a, size(a) - size(b)) for a, b in pairs if a.shape != b.shape]
     per_tok = sum(width * a.dtype.itemsize for a, width in rows)
     per_slot = sum(size(a) * a.dtype.itemsize for a, b in pairs if a.shape == b.shape)
-    per_head = all(
-        isinstance(e2, dict) and set(e2) <= {"k", "v"} for e2, e1 in zip(two, one)
-        if any(x.shape != y.shape for x, y in zip(jax.tree.leaves(e2), jax.tree.leaves(e1))))
+    paged = [e2 for e2, e1 in zip(two, one)
+             if any(x.shape != y.shape for x, y in zip(jax.tree.leaves(e2), jax.tree.leaves(e1)))]
+    per_head = all(isinstance(e, dict) and set(e) <= {"k", "v"} for e in paged)
     widths = sorted({width for _, width in rows})
     label = (f"declared by the model: {len(rows)} leaves of "
              f"{'/'.join(map(str, widths))} values a token"
              + ("" if per_head else ", no head axis"))
-    return per_tok, len(rows), label, per_slot, per_head
+    return DeclaredKV(per_tok, len(rows), label, per_slot, per_head, len(paged))
 
 
 def _fmt(nbytes: float) -> str:
@@ -401,7 +420,7 @@ def estimate_command(args) -> int:
             # The engine refuses what assumes per-head K and V for such a
             # cache (serving/engine.py): say so here instead of printing a
             # number it would not serve.
-            per_tok, n_leaves, geometry, per_slot, per_head = declared
+            per_tok, n_leaves, geometry, per_slot, per_head, entries = declared
             if not per_head and (kv_int8 or args.tp > 1):
                 print(f"\nPaged KV pool: {type(module).__name__} declares its own KV cache "
                       "(rows without a head axis); the engine refuses --kv-dtype int8 and "
@@ -416,7 +435,7 @@ def estimate_command(args) -> int:
             scale_bytes = n_leaves * 4 if kv_int8 else 0
             fp_page_bytes = per_tok * args.page_size
             per_tok = per_tok // 2 if kv_int8 else per_tok
-            layers = geom[0] if geom is not None else n_leaves
+            layers = entries                 # what a draft proxy's rows pair with
         else:
             layers, kv_heads, head_dim = geom
             per_tok = 2 * layers * kv_heads * head_dim * itemsize  # k+v
@@ -515,7 +534,7 @@ def estimate_command(args) -> int:
         print(f"  training (Adam) per chip  : {_fmt(per_chip * 2 * 2 + per_chip * 4 * 3)}")
         geom = _kv_geometry(module)
         if module is not None and hasattr(module, "init_cache"):
-            *_, per_head = _declared_kv(module)
+            per_head = _declared_kv(module).per_head
             print(f"  KV cache per chip         : n/a ({type(module).__name__} declares its "
                   f"own KV cache, {'recurrent state held per slot' if per_head else 'rows without a head axis'}"
                   ": the engine refuses tp > 1 for it)")

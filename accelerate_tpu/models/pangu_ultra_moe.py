@@ -107,7 +107,14 @@ class PanguUltraMoeConfig:
 
 
 class PanguMLAttention(nn.Module):
+    """Latent attention. Reads ``num_attention_heads``, ``q_lora_rank``,
+    ``kv_lora_rank``, the three head widths, ``rope_theta``, ``rms_norm_eps``,
+    ``hidden_size`` and ``sm_scale`` of its config (models/longcat_flash.py
+    hands it its own). ``latent_scale`` multiplies the normed latent before
+    it is cached and expanded (LongCat-Flash's ``mla_scale_kv_lora``), so
+    the cache holds the scaled row."""
     config: PanguUltraMoeConfig
+    latent_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, positions, cache=None, cache_pos=None):
@@ -128,6 +135,8 @@ class PanguMLAttention(nn.Module):
             with program_part("mla_latent"):
                 kv = _Kernel(rank + dr, name="kv_a_proj")(x)
                 c_kv = RMSNorm(cfg.rms_norm_eps, name="kv_a_norm")(kv[..., :rank])
+                if self.latent_scale != 1.0:
+                    c_kv = (c_kv.astype(jnp.float32) * self.latent_scale).astype(x.dtype)
                 k_r = apply_rotary(kv[..., None, rank:].astype(jnp.float32), cos, sin)[:, :, 0]
                 k_r = k_r.astype(x.dtype)                 # c_kv and k_r are what is cached
             w_ukv = _RawKernel(rank, H * (dn + dv), name="kv_b_proj")().reshape(rank, H, dn + dv)

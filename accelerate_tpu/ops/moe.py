@@ -247,12 +247,14 @@ def held_tile_rows(tokens: int, top_k: int, num_experts: int) -> int:
 
 
 def route_top_k(router_logits: jnp.ndarray, top_k: int, *, scores: str = "softmax",
-                normalize_gates: bool = True):
+                normalize_gates: bool = True, selection_bias: Optional[jnp.ndarray] = None):
     """``[T, E]`` router logits -> ``(gates [T, k] f32, experts [T, k] i32)``.
 
     ``scores`` is ``"softmax"`` (Mixtral) or ``"sigmoid"`` (each expert scored
     on its own); the k largest scores are kept and, with ``normalize_gates``,
-    divided by their sum."""
+    divided by their sum. ``selection_bias`` ``[E]`` (a load-balancing
+    correction) moves which experts are picked and not their gates: the k
+    largest of ``score + bias`` are picked, each gated by its score alone."""
     logits = router_logits.astype(jnp.float32)
     if scores == "softmax":
         s = jax.nn.softmax(logits, axis=-1)
@@ -260,7 +262,11 @@ def route_top_k(router_logits: jnp.ndarray, top_k: int, *, scores: str = "softma
         s = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"scores must be 'softmax' or 'sigmoid' (got {scores!r})")
-    gates, experts = jax.lax.top_k(s, top_k)
+    if selection_bias is None:
+        gates, experts = jax.lax.top_k(s, top_k)
+    else:
+        _, experts = jax.lax.top_k(s + selection_bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(s, experts, axis=-1)
     if normalize_gates:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     return gates, experts.astype(jnp.int32)
@@ -354,6 +360,8 @@ def moe_held_apply(
     scores: str = "softmax",
     normalize_gates: bool = True,
     held: Optional[tuple] = None,
+    identity_experts: int = 0,
+    selection_bias: Optional[jnp.ndarray] = None,
 ):
     """The part of a sparse expert MLP that the experts held here give, with no
     token dropped and only routed rows computed: ``(out [B, S, D], stats)``,
@@ -363,12 +371,21 @@ def moe_held_apply(
     rows; both 0 on the dense path).
 
     ``router_kernel`` ``[D, E]`` has the model's full width: every token is
-    routed over all ``E`` experts (:func:`route_top_k`). ``expert_params``
+    routed over all ``E`` experts (:func:`route_top_k`; ``selection_bias``
+    ``[E]`` moves the picks and not the gates). ``expert_params``
     holds SwiGLU stacks ``[count, D, F]`` / ``[count, F, D]`` of the experts
     ``first .. first + count - 1`` (``held = (first, count)``; None = all
     ``E``, starting at 0: the Mixtral family under a cache). The result is
     ``sum_{e in top-k, e held} g_e F_e(x)``: what the absent experts would
-    add is left out, and nothing stands in for them. ``T <=
+    add is left out, and nothing stands in for them.
+
+    The last ``identity_experts`` of the router's columns are ZERO-COMPUTE
+    experts: one that is picked returns the token itself, so a pick adds
+    ``g_e x`` — one multiply-add a token, no weights, computed wherever the
+    token is. Held experts are then among the first ``E - identity_experts``,
+    the result has the identity term in it, and ``stats["zero_picks"]`` (an
+    int32 scalar) counts those picks; ``picks`` keeps its layout (its "all
+    picks" counts them too). ``T <=
     HELD_DENSE_TOKENS`` tokens (a decode tick, also under ``jax.vmap`` over
     serving slots) go through every held expert in one batched product that
     reads each expert's weights once; more tokens are sorted by expert into
@@ -378,16 +395,18 @@ def moe_held_apply(
     B, S, D = x.shape
     wg, wu, wd = expert_params["gate_proj"], expert_params["up_proj"], expert_params["down_proj"]
     E = router_kernel.shape[-1]
-    first, count = (0, E) if held is None else (int(held[0]), int(held[1]))
-    if wg.shape[0] != count or first < 0 or first + count > E:
+    real = E - int(identity_experts)                 # experts with weights
+    first, count = (0, real) if held is None else (int(held[0]), int(held[1]))
+    if wg.shape[0] != count or first < 0 or first + count > real:
         raise ValueError(f"held={held} does not match {wg.shape[0]} expert stacks of a "
-                         f"router over {E}")
+                         f"router over {real} experts with weights")
     T = B * S
     tokens = x.reshape(T, D)
     with program_part("moe_router"):
         logits = tokens.astype(jnp.float32) @ router_kernel.astype(jnp.float32)
         gates, experts = route_top_k(logits, top_k, scores=scores,
-                                     normalize_gates=normalize_gates)
+                                     normalize_gates=normalize_gates,
+                                     selection_bias=selection_bias)
         local = experts - first
         is_held = (local >= 0) & (local < count)
         counts = (jax.nn.one_hot(jnp.where(is_held, local, count), count + 1, dtype=jnp.int32)
@@ -402,5 +421,11 @@ def moe_held_apply(
             tile = held_tile_rows(T, top_k, E)
             out, n_tiles = _held_sorted(tokens, wg, wu, wd, gates, local, is_held, counts, tile)
             routed, computed = counts.sum(), n_tiles * tile
-    picks = jnp.concatenate([counts, jnp.stack([jnp.int32(T * top_k), routed, computed])])
-    return out.reshape(B, S, D).astype(x.dtype), {"picks": picks}
+        stats = {}
+        if identity_experts:
+            is_zero = experts >= real
+            out = out + (jnp.where(is_zero, gates, 0.0).sum(-1, keepdims=True)
+                         * tokens.astype(jnp.float32))
+            stats["zero_picks"] = is_zero.sum(dtype=jnp.int32)
+    stats["picks"] = jnp.concatenate([counts, jnp.stack([jnp.int32(T * top_k), routed, computed])])
+    return out.reshape(B, S, D).astype(x.dtype), stats

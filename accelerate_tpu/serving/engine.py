@@ -871,6 +871,10 @@ class ServingEngine:
         #: None for modules that sow nothing.
         self._stats_collection = getattr(module, "serving_stats_collection",
                                          None)
+        #: the module's counters end with one more total behind ops/moe.py's
+        #: pick vector: the picks on zero-compute experts (models/
+        #: longcat_flash.py); ``moe_zero_picks`` in the stats.
+        self._zero_picks = bool(getattr(module, "serving_zero_picks", False))
         #: results of prefill chunks whose counters are not folded yet: a
         #: chunk that is not its prompt's last is read on the host only at
         #: a later last chunk, when its copy has long arrived.
@@ -1536,6 +1540,14 @@ class ServingEngine:
             {"params": params}, ids, mutable=[self._stats_collection],
             **kwargs)
         return logits, cache, sum(jax.tree.leaves(sown))
+
+    def _moe_counts(self, counts) -> dict:
+        """A record's expert-pick keywords from the counters a program
+        returned (host side): a family with zero-compute experts
+        (``serving_zero_picks``) sows their picks behind the pick vector."""
+        if counts is None or not self._zero_picks:
+            return {"moe_picks": counts}
+        return {"moe_picks": counts[:-1], "moe_zero_picks": int(counts[-1])}
 
     @staticmethod
     def _slot_recurrent_rows(state, slot, offset):
@@ -3281,7 +3293,7 @@ class ServingEngine:
                 tok, counts = rows[-1][0], sum(r[1:] for r in rows)
         self._stats.record_prefill_chunk(dt_ms, backlog=backlog,
                                          host=self._phases.drain(),
-                                         moe_picks=counts,
+                                         **self._moe_counts(counts),
                                          attn_rows=attn_rows,
                                          state_reset=bool(self._recurrent_at)
                                          and offset == 0)
@@ -3544,7 +3556,7 @@ class ServingEngine:
                                 max_slots=self.max_slots, seconds=interval,
                                 host_us=host_s * 1e6,
                                 other_us=other_s * 1e6,
-                                host=phases.drain(), moe_picks=counts,
+                                host=phases.drain(), **self._moe_counts(counts),
                                 kv_rows=(dead_rows, held_rows),
                                 attn_rows=None if spec else
                                 self._tick_attn_rows(positions))

@@ -177,6 +177,9 @@ class ServingStats:
             # sorted tiles, rows computed there. KV rows held / dead behind
             # a layer's window.
             self._moe_picks = np.zeros((0,), np.int64)
+            # ... and the picks that landed on zero-compute experts (a token
+            # returned as it is: no weights read), a share of "all picks".
+            self._moe_zero_picks = 0
             self._kv_rows_held = 0
             self._kv_rows_dead = 0
             # Key rows a prefill chunk's attention scored / rows some query
@@ -294,7 +297,7 @@ class ServingStats:
                     host_us: Optional[float] = None,
                     other_us: float = 0.0, host: Optional[dict] = None,
                     moe_picks=None, kv_rows: Optional[tuple] = None,
-                    attn_rows: Optional[tuple] = None):
+                    attn_rows: Optional[tuple] = None, moe_zero_picks: int = 0):
         """One ``decode_step_all_slots`` execution.
 
         ``seconds`` is the device-complete→device-complete interval for
@@ -307,7 +310,8 @@ class ServingStats:
         ``other_us`` is the part of it no named phase covered, and
         ``host`` the phase timings measured since the last record.
         ``moe_picks`` are the tick's expert picks (per held expert, then
-        ``moe_held_apply``'s three totals), ``kv_rows`` the ``(dead,
+        ``moe_held_apply``'s three totals), ``moe_zero_picks`` those of
+        them on zero-compute experts, ``kv_rows`` the ``(dead,
         held)`` KV rows of the streams that run on: held = rows written x
         layers, dead = those a windowed layer can never read again;
         ``attn_rows`` the ``(scored, visible, view)`` pool rows of the
@@ -316,6 +320,7 @@ class ServingStats:
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
+            self._moe_zero_picks += int(moe_zero_picks)
             if attn_rows is not None:
                 self._tick_rows_scored += int(attn_rows[0])
                 self._tick_rows_visible += int(attn_rows[1])
@@ -345,12 +350,13 @@ class ServingStats:
     def record_prefill_chunk(self, ms: float, backlog: int = 0,
                              host: Optional[dict] = None, moe_picks=None,
                              attn_rows: Optional[tuple] = None,
-                             state_reset: bool = False):
+                             state_reset: bool = False, moe_zero_picks: int = 0):
         """One ``prefill_chunk`` execution; ``backlog`` is the number of
         requests in ``PREFILLING`` at the time of the call (how much
         admission work is still pending behind the per-tick budget);
         ``host`` the phase timings measured since the last record,
-        ``moe_picks`` the chunk's expert picks (as in ``record_tick``),
+        ``moe_picks`` / ``moe_zero_picks`` the chunk's expert picks (as in
+        ``record_tick``),
         ``attn_rows`` the ``(scored, visible, view)`` key rows of its
         attention, summed over the layers; ``state_reset``: the chunk set
         its slot's recurrent state to zero (a prompt's first chunk, where
@@ -358,6 +364,7 @@ class ServingStats:
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
+            self._moe_zero_picks += int(moe_zero_picks)
             self._recurrent_state_resets += bool(state_reset)
             if attn_rows is not None:
                 self._attn_rows_scored += int(attn_rows[0])
@@ -578,7 +585,8 @@ class ServingStats:
                       "_spec_accepted", "_spec_lookup_slots",
                       "_spec_lookup_hits", "_host_us_sum",
                       "_host_us_ticks", "_host_other_us_sum",
-                      "_emission_stalls", "_kv_rows_held", "_kv_rows_dead",
+                      "_emission_stalls", "_moe_zero_picks",
+                      "_kv_rows_held", "_kv_rows_dead",
                       "_attn_rows_scored", "_attn_rows_visible",
                       "_attn_rows_view", "_tick_rows_scored",
                       "_tick_rows_visible", "_tick_rows_view",
@@ -714,6 +722,11 @@ class ServingStats:
                     if moe_held.any() else 0.0,
                 "moe_tile_fill": round(float(moe_routed / moe_rows), 6)
                     if moe_rows else 0.0,
+                # picks on zero-compute experts, and their share of all
+                # picks (zero on a router without such experts)
+                "moe_zero_picks": self._moe_zero_picks,
+                "moe_zero_pick_share": round(self._moe_zero_picks / moe_all, 6)
+                    if moe_all else 0.0,
                 # KV rows of windowed layers no later query can read, over
                 # all KV rows held, summed over ticks: what an allocator
                 # with a class of pages per layer kind would free.

@@ -61,6 +61,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_hf_interop.py",
         "test_host_offload.py",
         "test_loadgen.py",
+        "test_longcat_flash.py",
         "test_moe_held.py",
         "test_loadtest_smoke.py",
         "test_memory_properties.py",

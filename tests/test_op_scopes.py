@@ -248,7 +248,11 @@ def test_the_metrics_read_the_parts_and_give_none_where_there_are_none(small, mo
     manifest = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     new = [m for m in manifest["per_layer"] if (harness.PACKAGE / "layer_metrics" / f"{m['name']}.py")
            .read_text().count("op_scopes")]
-    assert [m["name"] for m in new] == [m["name"] for m in manifest["per_layer"][-8:]]
+    # the eight metrics that read the parts, in the manifest's order (later
+    # entries are appended after them)
+    assert [m["name"] for m in new] == [
+        "tick_kv_attn_ms", "chunk_kv_attn_ms", "tick_experts_ms", "chunk_experts_ms",
+        "chunk_ssm_ms", "tick_relayout_ms", "chunk_relayout_ms", "program_parts_named.serve"]
     assert all(m["source"] == "device_trace" for m in new)
 
     def read(trace):

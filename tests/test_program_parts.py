@@ -38,6 +38,11 @@ def _pangu_ultra_moe():
     return PanguUltraMoeForCausalLM(PanguUltraMoeConfig.tiny())
 
 
+def _longcat_flash():
+    from accelerate_tpu.models.longcat_flash import LongcatFlashConfig, LongcatFlashForCausalLM
+    return LongcatFlashForCausalLM(LongcatFlashConfig.tiny())
+
+
 def _phi4flash():
     from accelerate_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
     return Phi4FlashForCausalLM(Phi4FlashConfig.tiny())
@@ -52,6 +57,9 @@ FAMILIES = {
     "pangu_ultra_moe": (_pangu_ultra_moe, {"attn_mla"},
                         {"mla_q", "mla_latent", "mla_out", "mlp_dense", "moe_router",
                          "moe_experts", "moe_shared"}, {}),
+    "longcat_flash": (_longcat_flash, {"attn_mla"},
+                      {"mla_q", "mla_latent", "mla_out", "mlp_dense", "moe_router",
+                       "moe_experts"}, {}),
     "phi4flash": (_phi4flash, {"attn_diff_local", "attn_diff_global", "attn_cross_shared"},
                   {"ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "gmu", "mlp_dense"},
                   {"prefix_cache_mb": 0}),

@@ -450,3 +450,86 @@ def test_phi4flash_serving_program_compiles_for_v5e(program, one_chip, paged_ker
                 assert line.lstrip().startswith(("HloModule", "ENTRY")), line
         # the work list's loops and its 1280-wide weighted rows are gone
         assert not re.search(rf"\swhile\(.*{leaf}", text) and "f32[64,10,4,1280]" not in text
+
+
+# ---------------------------------------------------------------------------
+# The longcat_flash family's serving programs at the benchmark cell's widths
+# ---------------------------------------------------------------------------
+
+def _longcat_flash_programs(one_chip):
+    """At the widths of ``longcat-flash-omni-l4e16`` (hidden 6144, 64 heads
+    over a 512 + 64 wide latent row, two MLAs and two dense MLPs of 12288 a
+    double layer, 16 of 512 routed experts held beside 256 zero-compute ones,
+    4 double layers, vocab slice 16384) as the paged engine runs them in
+    ``serve-longcat-closed48-chat``: 48 slots x 4096, 769 pages of 256. The
+    chunk: one slot's gathered views of the 8 latent entries. The tick:
+    ``jax.vmap`` over 48 lanes of a batch-1 forward whose entries are
+    ``PagedCache`` (the pool shared, read in place). Both ask for the pick
+    counters, as the engine does."""
+    from accelerate_tpu.models.llama import PagedCache
+    from accelerate_tpu.models.longcat_flash import LongcatFlashConfig, LongcatFlashForCausalLM
+
+    model = LongcatFlashForCausalLM(LongcatFlashConfig(num_layers=4, vocab_size=16384,
+                                                       held_experts=(0, 16)))
+    slots, max_len, chunk, pages, page = 48, 4096, 256, 769, 256
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda a: struct(a.shape, jnp.bfloat16), shapes)
+    entries = jax.eval_shape(lambda: model.init_cache(1, max_len, jnp.bfloat16))
+    views = jax.tree.map(lambda a: struct(a.shape, a.dtype), entries)
+    pool = tuple(jax.tree.map(lambda a: struct((pages + 1, 1, page) + a.shape[2:], a.dtype), e)
+                 for e in entries)
+
+    def counted(ids, cache, pos, params):
+        (logits, cache), sown = model.apply({"params": params}, ids, cache=cache, cache_pos=pos,
+                                            mutable=["moe_stats"])
+        return logits, cache, sum(jax.tree.leaves(sown))
+
+    def chunk_fn(params, ids, cache, pos):
+        return counted(ids, cache, pos, params)
+
+    def tick_fn(params, toks, pool, table, positions, live):
+        def one_slot(tok, pages_row, pos, alive):
+            cache = tuple(PagedCache(pool=leaf, scales=None, pages=pages_row, live=alive)
+                          for leaf in pool)
+            logits, rows, picks = counted(tok[None, None], cache, pos, params)
+            return jnp.argmax(logits[0, -1]), rows, picks
+
+        return jax.vmap(one_slot)(toks, table, positions, live)
+
+    i32 = jnp.int32
+    return {
+        "prefill_chunk": (chunk_fn, (params, struct((1, chunk), i32), views, struct((), i32))),
+        "decode_tick": (tick_fn, (params, struct((slots,), i32), pool,
+                                  struct((slots, max_len // page), i32), struct((slots,), i32),
+                                  struct((slots,), jnp.bool_))),
+    }
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
+def test_longcat_flash_serving_program_compiles_for_v5e(program, one_chip):
+    fn, args = _longcat_flash_programs(one_chip)[program]
+    compiled = jax.jit(fn).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    weights = 10.35e9                        # 5.173 G parameters, bf16
+    held = (8 * 4096 * 576 * 2 if program == "prefill_chunk"
+            else 8 * 770 * 256 * 576 * 2)    # one slot's 8 views / the 1.82 GB pool
+    assert weights * 0.99 < memory.argument_size_in_bytes - held < weights * 1.01
+    # beside weights, pool, the chunk's views and the rotary leaves' lane padding
+    # (~12.4 GB in all) what a program needs fits many times over
+    assert memory.temp_size_in_bytes < 0.5e9, memory
+    text = compiled.as_text()
+    if program == "prefill_chunk":
+        # 256 queries share their rows: the expanded form, 1024 key rows at a time, in a loop
+        # over the visible blocks; never the whole view's scores
+        assert " while(" in text
+        assert "f32[1,64,256,1024]" in text and "f32[1,64,256,4096]" not in text
+    else:
+        # no lane's view is gathered: the 8 attentions read the pool in place, one work-list
+        # loop each, and the experts run as one batched product (no loop over tiles)
+        assert "[48,1,4096," not in text and "[48,4096," not in text
+        assert text.count(" while(") == 8
