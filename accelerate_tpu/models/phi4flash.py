@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from ..observability.program_parts import program_part
+from ..ops.selective_scan import selective_scan as selective_scan_kernel
 from .cohere2_moe import _Kernel
 from .llama import (PagedCache, _cached_attention, attend_shared_kv_cache,
                     update_kv_cache_and_attend)
@@ -179,50 +180,23 @@ class _BiasKernel(nn.Module):
 # The selective scan
 # ---------------------------------------------------------------------------
 
-def scan_block(S: int) -> int:
-    """Steps :func:`selective_scan` combines in one associative pass, from
-    the static length alone: the decay and input terms of a block are
-    ``[block, d_state, d_inner]`` float32 each, and the blocks follow each
-    other in a ``lax.scan`` that carries the state. PERF.md section 6 (PR 35)
-    has the chip's readings for a 256-step chunk at the published widths."""
-    block = 32
-    return block if S % block == 0 and S > block else S
-
-
 def selective_scan(x, dt, A, B, C, h0):
     """Mamba-1 recurrence over ``S`` steps from a carried state, float32:
     ``h_t = exp(dt_t * A) * h_{t-1} + (dt_t * x_t) * B_t``, ``y_t = h_t . C_t``.
+    By the shape: one step (a decode tick) is the plain update in XLA;
+    several (a prefill chunk, a prompt) are one call of the Pallas kernel
+    ``ops.selective_scan.selective_scan``, which holds the state on the chip
+    for all of them (the interpreter on the CPU).
 
     ``x``, ``dt`` ``[b, S, d]``; ``A`` ``[N, d]`` (negative); ``B``, ``C``
     ``[b, S, N]``; ``h0`` ``[b, N, d]``. Returns ``(y [b, S, d], h_S)``. A
-    step with ``dt == 0`` leaves the state as it is. One step is the plain
-    update; several are an associative scan over blocks of
-    :func:`scan_block` steps."""
-    def terms(x, dt, B):
-        return jnp.exp(dt[:, :, None, :] * A), (dt * x)[:, :, None, :] * B[..., None]
-
-    S = x.shape[1]
-    if S == 1:
-        a, b = terms(x, dt, B)
-        h = a[:, 0] * h0 + b[:, 0]
-        return jnp.einsum("bnd,bn->bd", h, C[:, 0])[:, None], h
-
-    def combine(left, right):
-        return left[0] * right[0], right[0] * left[1] + right[1]
-
-    def one_block(h, blk):
-        xb, dtb, Bb, Cb = blk                                   # [b, T, ...]
-        decay, inp = jax.lax.associative_scan(combine, terms(xb, dtb, Bb), axis=1)
-        hs = inp + decay * h[:, None]
-        return hs[:, -1], jnp.einsum("btnd,btn->btd", hs, Cb)
-
-    T = scan_block(S)
-    if T == S:
-        h, y = one_block(h0, (x, dt, B, C))
-        return y, h
-    split = lambda t: jnp.moveaxis(t.reshape(t.shape[0], S // T, T, *t.shape[2:]), 1, 0)  # noqa: E731
-    h, ys = jax.lax.scan(one_block, h0, tuple(map(split, (x, dt, B, C))))
-    return jnp.moveaxis(ys, 0, 1).reshape(x.shape), h
+    step with ``dt == 0`` leaves the state as it is. Forward only: the
+    kernel has no derivative."""
+    if x.shape[1] > 1:
+        return selective_scan_kernel(x, dt, A, B, C, h0)
+    a, b = jnp.exp(dt[:, :, None, :] * A), (dt * x)[:, :, None, :] * B[..., None]
+    h = a[:, 0] * h0 + b[:, 0]
+    return jnp.einsum("bnd,bn->bd", h, C[:, 0])[:, None], h
 
 
 class Mamba(nn.Module):

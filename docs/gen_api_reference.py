@@ -98,17 +98,17 @@ GROUPS = [
      "The mesh, sharding rules, the pipeline scan, and host offload."),
     ("ops", "Ops & kernels",
      ["accelerate_tpu.ops.attention", "accelerate_tpu.ops.flash_pallas",
-      "accelerate_tpu.ops.paged_attention",
+      "accelerate_tpu.ops.paged_attention", "accelerate_tpu.ops.selective_scan",
       "accelerate_tpu.ops.ring_attention", "accelerate_tpu.ops.moe",
       "accelerate_tpu.ops.quant", "accelerate_tpu.ops.fused_loss"],
-     "Pallas flash attention, the decode tick's paged attention, ring/Ulysses attention, MoE "
-     "dispatch, fp8 matmul."),
+     "Pallas flash attention, the decode tick's paged attention, the prefill's selective scan, "
+     "ring/Ulysses attention, MoE dispatch, fp8 matmul."),
     ("models", "Model zoo",
      ["accelerate_tpu.models.llama", "accelerate_tpu.models.mixtral",
       "accelerate_tpu.models.gpt2", "accelerate_tpu.models.gptj",
       "accelerate_tpu.models.gpt_neox", "accelerate_tpu.models.bloom",
       "accelerate_tpu.models.opt",
-      "accelerate_tpu.models.phi",
+      "accelerate_tpu.models.phi", "accelerate_tpu.models.phi4flash",
       "accelerate_tpu.models.bert", "accelerate_tpu.models.t5",
       "accelerate_tpu.models.vit", "accelerate_tpu.models.resnet"],
      "Flax model families, all shardable by the same mesh rules and loadable "

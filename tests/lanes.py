@@ -70,6 +70,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_op_scopes.py",
         "test_paged_attention_kernel.py",
         "test_paged_tick_attention.py",
+        "test_selective_scan_kernel.py",
         "test_pipeline.py",
         "test_program_parts.py",
         "test_quantization.py",

@@ -127,6 +127,10 @@ def test_chunks_then_single_tokens_through_a_cache_are_the_references(tiny, chun
 
 
 def test_the_blocked_scan_is_the_stepped_recurrence_across_chunk_edges():
+    """``selective_scan`` over several steps is one call of the Pallas kernel
+    (the interpreter here; ``tests/test_selective_scan_kernel.py`` has its own
+    cases), one step the plain update; a state handed from call to call is
+    the stepped recurrence's."""
     k = jax.random.split(jax.random.PRNGKey(3), 6)
     S, N, d = 96, 4, 24
     x, dt = jax.random.normal(k[0], (2, S, d)), jax.nn.softplus(jax.random.normal(k[1], (2, S, d)))
@@ -138,17 +142,17 @@ def test_the_blocked_scan_is_the_stepped_recurrence_across_chunk_edges():
         h = jnp.exp(dt[:, t, None, :] * A) * h + (dt[:, t] * x[:, t])[:, None, :] * B[:, t, :, None]
         ys.append(jnp.einsum("bnd,bn->bd", h, C[:, t]))
     want = jnp.stack(ys, 1)
-    assert phi4flash.scan_block(96) == 32 and phi4flash.scan_block(48) == 48
-    got, last = phi4flash.selective_scan(x, dt, A, B, C, h0)   # three blocks of 32
+    scan = jax.jit(phi4flash.selective_scan)
+    got, last = scan(x, dt, A, B, C, h0)                       # one kernel call
     assert float(jnp.abs(got - want).max()) < 1e-4 and float(jnp.abs(last - h).max()) < 1e-4
-    # two chunks of 48 (one block each) with the state carried, then single steps
-    y1, h1 = phi4flash.selective_scan(x[:, :48], dt[:, :48], A, B[:, :48], C[:, :48], h0)
-    y2, h2 = phi4flash.selective_scan(x[:, 48:95], dt[:, 48:95], A, B[:, 48:95], C[:, 48:95], h1)
-    y3, h3 = phi4flash.selective_scan(x[:, 95:], dt[:, 95:], A, B[:, 95:], C[:, 95:], h2)
+    # two chunks of 48 and 47 steps with the state carried, then a single step
+    y1, h1 = scan(x[:, :48], dt[:, :48], A, B[:, :48], C[:, :48], h0)
+    y2, h2 = scan(x[:, 48:95], dt[:, 48:95], A, B[:, 48:95], C[:, 48:95], h1)
+    y3, h3 = scan(x[:, 95:], dt[:, 95:], A, B[:, 95:], C[:, 95:], h2)
     assert float(jnp.abs(jnp.concatenate([y1, y2, y3], 1) - want).max()) < 1e-4
     assert float(jnp.abs(h3 - h).max()) < 1e-4
     # a step of size 0 leaves the state as it is (a chunk's padding)
-    _, kept = phi4flash.selective_scan(x[:, :8], dt[:, :8] * 0, A, B[:, :8], C[:, :8], h0)
+    _, kept = scan(x[:, :8], dt[:, :8] * 0, A, B[:, :8], C[:, :8], h0)
     assert float(jnp.abs(kept - h0).max()) == 0.0
 
 

@@ -404,12 +404,14 @@ def _phi4flash_programs(one_chip):
 
 @pytest.fixture
 def paged_kernel(monkeypatch):
-    """The paged-attention kernel's dispatch on its TPU branch, lowered by
-    Mosaic: the code asks ``jax.default_backend()``, which is the CPU here."""
-    from accelerate_tpu.ops import paged_attention
+    """The paged-attention kernel's dispatch on its TPU branch, and it and the
+    selective scan lowered by Mosaic: the code asks ``jax.default_backend()``,
+    which is the CPU here."""
+    from accelerate_tpu.ops import paged_attention, selective_scan
 
     monkeypatch.setattr(paged_attention, "tpu_backend", lambda: True)
     monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(selective_scan, "_interpret", lambda: False)
 
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
@@ -425,17 +427,26 @@ def test_phi4flash_serving_program_compiles_for_v5e(program, one_chip, paged_ker
             else 449 * 256 * 46080 + state)                      # one slot's views / the pool
     assert weights * 0.98 < memory.argument_size_in_bytes - held < weights * 1.02
     text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    scans = [c for c in calls if re.search(r"/ssm_scan/jit\(\w+\)/selective_scan/", c)]
     if program == "prefill_chunk":
         # attention over 1024-row key blocks, 40 score heads; never the whole view's scores
         assert " while(" in text
         assert "f32[1,10,4,256,1024]" in text and "256,4096]" not in text
         assert memory.temp_size_in_bytes < 1.5e9, memory
+        # each of the 9 Mamba layers scans its 256 steps in one Mosaic call that takes the
+        # carried state in and hands it back as it lies, float32 [1, 16, 5120]; no step's
+        # decay or input term [.., 16, 5120] is an array of the program
+        assert len(scans) == len(calls) == 9
+        for call in scans:
+            assert re.search(r"\(f32\[1,32,8,5120\]\S*, f32\[1,16,5120\]\S*\) custom-call\(", call)
+        assert not re.search(r"f32\[1,\d+,16,5120\]", text)
     else:
+        assert not scans                                     # a tick's step is the plain update
         # no lane's view is gathered: each of the 16 attentions is one Mosaic kernel call that
         # reads the pool's live pages in place ...
         assert "[48,1,4096," not in text and "[48,4096," not in text
         assert memory.temp_size_in_bytes < 1.0e9, memory
-        calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
         assert len(calls) == 16 and all("kv_attn/paged_flat_attention" in c for c in calls)
         # ... as it lies: a call's two pool operands are the program's own parameters, and no
         # other instruction reads, copies, reshapes or slices a whole pool leaf
