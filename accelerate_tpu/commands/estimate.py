@@ -50,6 +50,17 @@ def _model_registry():
 
         return LongcatFlashForCausalLM(LongcatFlashConfig(num_layers=4, vocab_size=16384))
 
+    def _glm_5_2():
+        # the benchmark cell's pipeline stage: published layers 2-6 (one dense,
+        # four expert layers, indexers [full, shared, shared, shared, full])
+        # and 1/8 of the vocabulary, every width as published (the experts
+        # held here: --held-experts)
+        from ..models.glm_moe_dsa import GlmMoeDsaConfig, GlmMoeDsaForCausalLM
+
+        return GlmMoeDsaForCausalLM(GlmMoeDsaConfig(
+            num_hidden_layers=5, vocab_size=19360, mlp_layer_types=("dense",) + ("sparse",) * 4,
+            indexer_types=("full", "shared", "shared", "shared", "full")))
+
     def _phi4_mini_flash():
         from ..models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
 
@@ -67,6 +78,7 @@ def _model_registry():
         "command-a-plus": _command_a_plus,
         "openpangu-ultra-moe": _openpangu_ultra_moe,
         "longcat-flash-omni": _longcat_flash_omni,
+        "glm-5.2": _glm_5_2,
         "phi-4-mini-flash-reasoning": _phi4_mini_flash,
         "gpt-neox-20b": lambda: GPTNeoXForCausalLM(GPTNeoXConfig.neox_20b()),
         "opt-30b": lambda: OPTForCausalLM(OPTConfig.opt_30b()),

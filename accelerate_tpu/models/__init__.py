@@ -1,6 +1,7 @@
 from .bloom import BloomConfig, BloomForCausalLM
 from .cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM
 from .bert import BertConfig, BertForSequenceClassification, classification_loss
+from .glm_moe_dsa import GlmMoeDsaConfig, GlmMoeDsaForCausalLM
 from .gpt2 import GPT2Config, GPT2LMHeadModel
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel, PipelinedLlamaForCausalLM, causal_lm_loss
 from .longcat_flash import LongcatFlashConfig, LongcatFlashForCausalLM

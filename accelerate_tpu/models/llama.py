@@ -727,7 +727,8 @@ def latent_attention_form(S: int, rank: int, nope_dim: int, v_dim: int) -> str:
     return "expanded" if S * saved > 2 * rank * (nope_dim + v_dim) else "absorbed"
 
 
-def _latent_cached_attention(q_nope, q_rope, latent, k_rope, w_uk, w_uv, cache_pos, sm_scale):
+def _latent_cached_attention(q_nope, q_rope, latent, k_rope, w_uk, w_uv, cache_pos, sm_scale,
+                             select=None):
     """Attention of ``S`` queries a head against the latent cache (``latent
     [B, L, rank]``, ``k_rope [B, L, rope]``): ``q_nope [B, S, H, nope]``,
     ``q_rope [B, S, H, rope]`` (rotated), ``w_uk [rank, H, nope]`` / ``w_uv
@@ -742,7 +743,9 @@ def _latent_cached_attention(q_nope, q_rope, latent, k_rope, w_uk, w_uv, cache_p
     :func:`cached_key_block` gives one, else only the blocks
     :func:`cached_key_extent` names, under a running maximum and sum.
     Products take their operands in the wider of the queries' and the
-    cache's types and accumulate in float32."""
+    cache's types and accumulate in float32. ``select`` ``[B, S, L]`` bool
+    (learned sparse attention: :func:`update_index_cache_and_select`) keeps
+    the rows each query may read beside the causal mask; None keeps them all."""
     B, S, H, _ = q_nope.shape
     L, rank = latent.shape[1], w_uk.shape[0]
     out_dtype = q_nope.dtype
@@ -777,6 +780,8 @@ def _latent_cached_attention(q_nope, q_rope, latent, k_rope, w_uk, w_uv, cache_p
     if block >= L:
         logits, values = scores_and_values(latent, k_rope)
         mask = jnp.arange(L, dtype=jnp.int32)[None, :] <= q_pos
+        if select is not None:
+            mask = mask & select[:, None]
         out = weigh(jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1), values)
     else:
         first, last = cached_key_extent(cache_pos, S, L, block)
@@ -788,7 +793,10 @@ def _latent_cached_attention(q_nope, q_rope, latent, k_rope, w_uk, w_uv, cache_p
                 jax.lax.dynamic_slice_in_dim(latent, start, block, axis=1),
                 jax.lax.dynamic_slice_in_dim(k_rope, start, block, axis=1))
             k_pos = (start + jnp.arange(block, dtype=jnp.int32))[None, :]
-            logits = jnp.where((k_pos >= j * block) & (k_pos <= q_pos), logits, -1e30)
+            mask = (k_pos >= j * block) & (k_pos <= q_pos)
+            if select is not None:
+                mask = mask & jax.lax.dynamic_slice_in_dim(select, start, block, axis=2)[:, None]
+            logits = jnp.where(mask, logits, -1e30)
             m_new = jnp.maximum(m, logits.max(-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(logits - m_new)
@@ -806,14 +814,21 @@ def _latent_cached_attention(q_nope, q_rope, latent, k_rope, w_uk, w_uv, cache_p
 
 
 def update_latent_cache_and_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, cache_pos,
-                                   sm_scale: float):
+                                   sm_scale: float, select=None):
     """Write this call's latents ``[B, S, rank]`` and rotated keys ``[B, S,
     rope]`` into the cache at ``cache_pos`` and attend the queries against
     what they can see of it (:func:`_latent_cached_attention`). Returns
     ``(out [B, S, H, v], new_cache)``: the latent twin of
     :func:`update_kv_cache_and_attend`, a :class:`PagedCache` included
-    (:func:`_paged_latent_attend`)."""
+    (:func:`_paged_latent_attend`). ``select`` is what
+    :func:`update_index_cache_and_select` gave for the same cache: a row mask
+    of a view, or a :class:`PagedCache`'s selected row ids
+    (:func:`_paged_selected_latent_attend`); the cache's other leaves (the
+    index keys) are left to that function."""
     if isinstance(cache, PagedCache):
+        if select is not None:
+            return _paged_selected_latent_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk,
+                                                 w_uv, cache_pos, sm_scale, select)
         return _paged_latent_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
                                     cache_pos, sm_scale)
     with program_part("kv_write"):
@@ -825,7 +840,7 @@ def update_latent_cache_and_attend(cache, q_nope, q_rope, c_kv, k_rope, w_uk, w_
         }
     with program_part("kv_attn"):
         out = _latent_cached_attention(q_nope, q_rope, new_cache["latent"], new_cache["rope"],
-                                       w_uk, w_uv, cache_pos, sm_scale)
+                                       w_uk, w_uv, cache_pos, sm_scale, select)
     return out, new_cache
 
 
@@ -1246,6 +1261,167 @@ def _paged_latent_attend(cache: PagedCache, q_nope, q_rope, c_kv, k_rope, w_uk, 
     out = _paged_attention(kind, (q_c, q_r, row["latent"][0, 0], row["rope"][0, 0]), None,
                            cache, cache_pos)
     out = jnp.einsum("hr,rhd->hd", out.astype(cdt), w_uv, **f32)
+    return out[None, None].astype(out_dtype), row
+
+
+def _index_scores(q_idx, w_idx, keys, cache_pos):
+    """The learned indexer's scores of ``S`` queries against ``L`` key rows:
+    ``I(t, s) = sum_j w[t, j] * relu(q[t, j] . k[s])`` for ``s <= t`` (query
+    ``t`` at ``cache_pos + t``), ``-inf`` elsewhere. ``q_idx [B, S, Hi, D]``,
+    ``w_idx [B, S, Hi]`` (float32, its scales in it), ``keys [B, L, D]``.
+    Products in the wider of the two types, accumulated in float32; key
+    blocks of :func:`cached_key_block` rows, only those some query can see
+    (a loop whose trip count follows ``cache_pos``), so that the ``[S, Hi,
+    L]`` product of a long view never exists whole. Returns ``[B, S, L]``
+    float32."""
+    B, S, Hi, _ = q_idx.shape
+    L = keys.shape[1]
+    cdt = jnp.promote_types(q_idx.dtype, keys.dtype)
+    q_idx, w_idx = q_idx.astype(cdt), w_idx.astype(jnp.float32)
+    q_pos = (cache_pos + jnp.arange(S, dtype=jnp.int32))[:, None]
+
+    def score(k, k_pos):
+        dots = jnp.einsum("bshd,bkd->bshk", q_idx, k.astype(cdt),
+                          preferred_element_type=jnp.float32)
+        s = jnp.einsum("bshk,bsh->bsk", jax.nn.relu(dots), w_idx)
+        return jnp.where(k_pos <= q_pos, s, -jnp.inf)
+
+    block = cached_key_block(B * S * Hi, L)
+    if block >= L:
+        return score(keys, jnp.arange(L, dtype=jnp.int32)[None, :])
+    _, last = cached_key_extent(cache_pos, S, L, block)
+
+    def one_block(j, out):
+        start = jnp.minimum(j * block, L - block)      # an overhang scores rows again, alike
+        k_pos = (start + jnp.arange(block, dtype=jnp.int32))[None, :]
+        part = score(jax.lax.dynamic_slice_in_dim(keys, start, block, axis=1), k_pos)
+        return jax.lax.dynamic_update_slice_in_dim(out, part, start, axis=2)
+
+    return jax.lax.fori_loop(0, last, one_block, jnp.full((B, S, L), -jnp.inf, jnp.float32))
+
+
+def top_rows_mask(scores, topk: int):
+    """``[B, S, L]`` bool: for each query the ``topk`` rows of largest
+    score among those it can see (``scores > -inf``), or all of them where
+    it sees no more — ``jax.lax.top_k``'s picks (of two equal scores, the
+    earlier row), as the decode tick takes them, without a sort: the
+    ``topk``-th largest score is found bit by bit (32 passes of a compare and
+    a count over the scores, in an order of their bits that is the order of
+    the floats), the rows above it are kept, and of the rows equal to it the
+    earliest that make up ``topk``."""
+    visible = scores > -jnp.inf
+    if scores.shape[-1] <= topk:
+        return visible
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))  # float order
+
+    def one_bit(i, kth):
+        trial = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where((key >= trial[..., None]).sum(-1) >= topk, trial, kth)
+
+    kth = jax.lax.fori_loop(0, 32, one_bit, jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above, tied = key > kth, key == kth
+    room = topk - above.sum(-1, keepdims=True)
+    return visible & (above | (tied & (jnp.cumsum(tied, axis=-1) <= room)))
+
+
+def update_index_cache_and_select(cache, q_idx, w_idx, k_idx, cache_pos, topk: int):
+    """The learned indexer's half of sparse attention (DeepSeek-V3.2's DSA,
+    models/glm_moe_dsa.py): write this call's index keys ``k_idx [B, S, D]``
+    into ``cache["index_k"]`` at ``cache_pos`` and pick, for every query, the
+    ``topk`` visible rows of the largest :func:`_index_scores`. Returns
+    ``(select, index_k)``:
+
+    * a linear cache (a chunk's view, ``generate``'s cache): the row mask
+      ``[B, S, L]`` (:func:`top_rows_mask`) and the new ``index_k`` leaf;
+    * ``cache`` None (a forward without a cache): the mask ``[B, S, S]`` of
+      the call's own rows, and None;
+    * a :class:`PagedCache` (the decode tick, one token of one lane): the
+      selected row ids ``[1, 1, topk]`` and the token's index key row
+      (:func:`_paged_index_select`).
+
+    ``select`` goes to :func:`update_latent_cache_and_attend` of this layer
+    and of the layers that share its indices."""
+    if isinstance(cache, PagedCache):
+        return _paged_index_select(cache, q_idx, w_idx, k_idx, cache_pos, topk)
+    if cache is None:
+        keys, new = k_idx, None
+        cache_pos = 0
+    else:
+        with program_part("kv_write"):
+            keys = new = jax.lax.dynamic_update_slice(
+                cache["index_k"], k_idx.astype(cache["index_k"].dtype), (0, cache_pos, 0))
+    with program_part("dsa_index"):
+        scores = _index_scores(q_idx, w_idx, keys, cache_pos)
+    with program_part("dsa_select"):
+        return top_rows_mask(scores, topk), new
+
+
+def _paged_index_select(cache: PagedCache, q_idx, w_idx, k_idx, cache_pos, topk: int):
+    """:func:`update_index_cache_and_select` for one token of one lane
+    against a :class:`PagedCache`: every index key row of the lane's pages
+    (``pool["index_k"]`` through its row of the page table) is scored, the
+    token's own key — in no page yet — standing in at row ``cache_pos``; rows
+    past it score ``-inf``. Returns ``(ids [1, 1, k], {"index_k": the
+    token's row})``, ``k = min(topk, L)``: ids past ``cache_pos`` are picks
+    of rows nobody can see (a context shorter than ``topk``), which the
+    attention masks."""
+    row = k_idx.astype(cache.row_dtype("index_k"))
+    pool = cache.pool["index_k"]
+    Np, P = cache.pages.shape[0], pool.shape[2]
+    L = Np * P
+    cdt = jnp.promote_types(q_idx.dtype, row.dtype)
+    q, w = q_idx[0, 0].astype(cdt), w_idx[0, 0].astype(jnp.float32)
+    with program_part("dsa_index"):
+        keys = gather_pages(pool, cache.pages).reshape(L, pool.shape[-1])
+
+        def score(k):
+            return jax.nn.relu(jnp.einsum("hd,kd->kh", q, k.astype(cdt),
+                                          preferred_element_type=jnp.float32)) @ w
+
+        k_pos = jnp.arange(L, dtype=jnp.int32)
+        scores = jnp.where(k_pos < cache_pos, score(keys),
+                           jnp.where(k_pos == cache_pos, score(row[0])[0], -jnp.inf))
+    with program_part("dsa_select"):
+        ids = jax.lax.top_k(scores, min(topk, L))[1].astype(jnp.int32)
+    return ids[None, None], {"index_k": row}
+
+
+def _paged_selected_latent_attend(cache: PagedCache, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
+                                  cache_pos, sm_scale: float, ids):
+    """:func:`_paged_latent_attend` over the rows a learned indexer picked
+    (``ids [1, 1, k]``, :func:`_paged_index_select`) instead of every row the
+    lane holds: the picked ``latent`` and ``rope`` rows are gathered from the
+    pool by (page, offset) through the lane's row of the page table — the
+    token's own row, in no page yet, where an id is ``cache_pos`` — and
+    attended in the absorbed form; an id past ``cache_pos`` weighs 0.
+    Returns ``(out [1, 1, H, v], {"latent", "rope"}: the token's row)``."""
+    B, S, H, _ = q_nope.shape
+    if (B, S) != (1, 1):
+        raise NotImplementedError(
+            f"a paged cache takes one token of one stream a call, got {q_nope.shape}")
+    row = {"latent": c_kv.astype(cache.row_dtype("latent")),
+           "rope": k_rope.astype(cache.row_dtype("rope"))}
+    out_dtype = q_nope.dtype
+    cdt = jnp.promote_types(out_dtype, row["latent"].dtype)
+    w_uk, w_uv = w_uk.astype(cdt), w_uv.astype(cdt)
+    f32 = dict(preferred_element_type=jnp.float32)
+    ids = ids[0, 0]
+    P = cache.pool["latent"].shape[2]
+    with program_part("dsa_select"):
+        own = (ids == cache_pos)[:, None]
+        page, off = cache.pages[ids // P], ids % P
+        lat = jnp.where(own, row["latent"][0, 0], cache.pool["latent"][page, 0, off])
+        rope = jnp.where(own, row["rope"][0, 0], cache.pool["rope"][page, 0, off])
+    with program_part("kv_attn"):
+        q_c = (jnp.einsum("hd,rhd->hr", q_nope[0, 0].astype(cdt), w_uk, **f32)
+               * sm_scale).astype(cdt)
+        q_r = (q_rope[0, 0] * sm_scale).astype(cdt)
+        logits = (jnp.einsum("hr,kr->hk", q_c, lat.astype(cdt), **f32)
+                  + jnp.einsum("hd,kd->hk", q_r, rope.astype(cdt), **f32))
+        p = jax.nn.softmax(jnp.where(ids <= cache_pos, logits, -1e30), axis=-1)
+        out = jnp.einsum("hk,kr->hr", p.astype(cdt), lat.astype(cdt), **f32)
+        out = jnp.einsum("hr,rhd->hd", out.astype(cdt), w_uv, **f32)
     return out[None, None].astype(out_dtype), row
 
 

@@ -51,8 +51,8 @@ import jax.numpy as jnp
 
 from ..observability.program_parts import program_part
 from .cohere2_moe import _ExpertStacks, _Kernel
-from .llama import (RMSNorm, apply_rotary, init_latent_cache, rotary_embedding,
-                    update_latent_cache_and_attend)
+from .llama import (RMSNorm, _latent_cached_attention, apply_rotary, init_latent_cache,
+                    rotary_embedding, update_latent_cache_and_attend)
 
 
 @dataclasses.dataclass
@@ -112,12 +112,23 @@ class PanguMLAttention(nn.Module):
     ``hidden_size`` and ``sm_scale`` of its config (models/longcat_flash.py
     hands it its own). ``latent_scale`` multiplies the normed latent before
     it is cached and expanded (LongCat-Flash's ``mla_scale_kv_lora``), so
-    the cache holds the scaled row."""
+    the cache holds the scaled row.
+
+    Learned sparse attention (models/glm_moe_dsa.py): ``indexer`` is the
+    layer's indexer module (``indexer(config, name="indexer")(x, c_q,
+    positions, cache, cache_pos) -> (selection, index_k)``, handed the normed
+    low-rank query); ``selection`` (models/llama.py
+    ``update_index_cache_and_select``: a row mask, or a paged lane's row ids)
+    restricts the attention to the rows it keeps — the indexer's own, or
+    another layer's where this one has none. With either the call returns
+    ``(out, new_cache, selection)``, the cache with the indexer's ``index_k``
+    in it."""
     config: PanguUltraMoeConfig
     latent_scale: float = 1.0
+    indexer: Optional[type] = None
 
     @nn.compact
-    def __call__(self, x, positions, cache=None, cache_pos=None):
+    def __call__(self, x, positions, cache=None, cache_pos=None, selection=None):
         from ..ops.attention import _einsum_attention
 
         cfg = self.config
@@ -141,10 +152,20 @@ class PanguMLAttention(nn.Module):
                 k_r = k_r.astype(x.dtype)                 # c_kv and k_r are what is cached
             w_ukv = _RawKernel(rank, H * (dn + dv), name="kv_b_proj")().reshape(rank, H, dn + dv)
             w_uk, w_uv = w_ukv[..., :dn], w_ukv[..., dn:]
-            new_cache = None
+            new_cache = index_k = None
+            if self.indexer is not None:             # dsa_index and dsa_select: models/llama.py
+                selection, index_k = self.indexer(cfg, name="indexer")(
+                    x, c_q, positions, cache, cache_pos)
             if cache is not None:                    # kv_attn and kv_write: models/llama.py
                 out, new_cache = update_latent_cache_and_attend(
-                    cache, q_nope, q_rope, c_kv, k_r, w_uk, w_uv, cache_pos, cfg.sm_scale)
+                    cache, q_nope, q_rope, c_kv, k_r, w_uk, w_uv, cache_pos, cfg.sm_scale,
+                    select=selection)
+                if index_k is not None:
+                    new_cache = dict(new_cache, index_k=index_k)
+            elif selection is not None:              # the call's own rows, under the selection
+                with program_part("kv_attn"):
+                    out = _latent_cached_attention(q_nope, q_rope, c_kv, k_r, w_uk, w_uv, 0,
+                                                   cfg.sm_scale, selection)
             else:                                    # the plain form: every head's key and value
                 k_nope = jnp.einsum("bsr,rhd->bshd", c_kv, w_uk.astype(x.dtype))
                 k = jnp.concatenate(
@@ -154,6 +175,8 @@ class PanguMLAttention(nn.Module):
                                         causal=True, sm_scale=cfg.sm_scale)
             with program_part("mla_out"):
                 out = _Kernel(cfg.hidden_size, name="o_proj")(out.reshape(B, S, H * dv))
+        if self.indexer is not None or selection is not None:
+            return out, new_cache, selection
         return out, new_cache
 
 

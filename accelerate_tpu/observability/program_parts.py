@@ -1,6 +1,7 @@
 """The names of a compiled program's parts, one vocabulary for every family
 (:data:`PROGRAM_PARTS`): ``embed``; ``attn_local`` / ``attn_global``,
-``attn_mla`` (``mla_q``, ``mla_latent``, ``mla_out`` inside),
+``attn_mla`` (``mla_q``, ``mla_latent``, ``mla_out``, ``dsa_index``,
+``dsa_select`` inside),
 ``attn_diff_local`` / ``attn_diff_global`` / ``attn_cross_shared``, and inside
 each of them ``kv_attn`` and ``kv_write``; ``kv_view``; ``moe_router``,
 ``moe_experts``, ``moe_shared``; ``mlp_dense``; ``ssm_in``, ``ssm_conv``,
@@ -54,6 +55,9 @@ attn_local,        a whole attention block (projections, rotary, cached
 attn_global        attention, ``o_proj``) of a windowed / a full layer
 attn_mla           the same for latent attention; inside it ``mla_q``,
                    ``mla_latent``, ``mla_out``
+dsa_index,         learned sparse attention, inside ``attn_mla``: the
+dsa_select         indexer's projections and its scores of the visible rows;
+                   the top-k and the gather of the selected rows
 attn_diff_local,   the same for differential attention and for the
 attn_diff_global,  cross-attentions that read another layer's cache entry
 attn_cross_shared
@@ -89,7 +93,7 @@ __all__ = ["PROGRAM_PARTS", "program_part"]
 PROGRAM_PARTS = (
     "embed",
     "attn_local", "attn_global",
-    "attn_mla", "mla_q", "mla_latent", "mla_out",
+    "attn_mla", "mla_q", "mla_latent", "mla_out", "dsa_index", "dsa_select",
     "attn_diff_local", "attn_diff_global", "attn_cross_shared",
     "kv_attn", "kv_write", "kv_view",
     "moe_router", "moe_experts", "moe_shared",

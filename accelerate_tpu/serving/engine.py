@@ -875,6 +875,10 @@ class ServingEngine:
         #: pick vector: the picks on zero-compute experts (models/
         #: longcat_flash.py); ``moe_zero_picks`` in the stats.
         self._zero_picks = bool(getattr(module, "serving_zero_picks", False))
+        #: rows a query's latent attention reads at most, where a learned
+        #: indexer picks them (models/glm_moe_dsa.py); None elsewhere. The
+        #: ``dsa_*`` counters and the tick's rows scored follow it.
+        self._index_topk = getattr(module, "serving_index_topk", None)
         #: results of prefill chunks whose counters are not folded yet: a
         #: chunk that is not its prompt's last is read on the host only at
         #: a later last chunk, when its copy has long arrived.
@@ -3254,6 +3258,10 @@ class ServingEngine:
         if heads is None:
             return None
         L = self._pages_per_slot * self._page
+        if self._index_topk is not None:
+            # every attention scores its lanes' selected rows, and sees no other
+            selected = self._tick_dsa_rows(positions)[0]
+            return selected, selected, self._kv_readers * L * self.max_slots
         block, group = tick_key_tiles(heads, self.max_slots, L, self._page)
         pos = np.asarray(positions, np.int64)
         scored = visible = layers = 0
@@ -3270,6 +3278,18 @@ class ServingEngine:
             visible += n * int((pos - low).sum())
             layers += n
         return scored, visible, layers * L * self.max_slots
+
+    def _tick_dsa_rows(self, positions: list) -> Optional[tuple]:
+        """``(selected, visible)`` rows of one plain tick's sparse attention,
+        summed over its running lanes and its attentions: a query at ``pos``
+        sees ``pos + 1`` rows (its own among them) and its latent attention
+        reads ``min(index_topk, pos + 1)`` of them. None for a family
+        without a learned indexer."""
+        if self._index_topk is None:
+            return None
+        visible = np.asarray(positions, np.int64) + 1
+        return (self._kv_readers * int(np.minimum(visible, self._index_topk).sum()),
+                self._kv_readers * int(visible.sum()))
 
     def _commit_chunk(self, req: Request, i: int, offset: int, final: bool,
                       tok, block, t0: float, attn_rows=None):
@@ -3559,7 +3579,8 @@ class ServingEngine:
                                 host=phases.drain(), **self._moe_counts(counts),
                                 kv_rows=(dead_rows, held_rows),
                                 attn_rows=None if spec else
-                                self._tick_attn_rows(positions))
+                                self._tick_attn_rows(positions),
+                                dsa_rows=None if spec else self._tick_dsa_rows(positions))
         tracer = self._tracer
         if tracer.enabled:
             targs = {"active": len(flight.entries), "committed": committed,

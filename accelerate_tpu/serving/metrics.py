@@ -194,6 +194,11 @@ class ServingStats:
             self._tick_rows_scored = 0
             self._tick_rows_visible = 0
             self._tick_rows_view = 0
+            # Rows the ticks' sparse attention read (a learned indexer's
+            # picks) and rows its queries could see, summed over the ticks'
+            # running lanes and attentions; 0 for families without one.
+            self._dsa_selected_rows = 0
+            self._dsa_visible_rows = 0
             self._kv_bytes_per_token = 0
             # What the row counters are sums over (gauges): the cache
             # entries that hold KV rows and the attentions that read them
@@ -297,7 +302,8 @@ class ServingStats:
                     host_us: Optional[float] = None,
                     other_us: float = 0.0, host: Optional[dict] = None,
                     moe_picks=None, kv_rows: Optional[tuple] = None,
-                    attn_rows: Optional[tuple] = None, moe_zero_picks: int = 0):
+                    attn_rows: Optional[tuple] = None, moe_zero_picks: int = 0,
+                    dsa_rows: Optional[tuple] = None):
         """One ``decode_step_all_slots`` execution.
 
         ``seconds`` is the device-complete→device-complete interval for
@@ -316,7 +322,9 @@ class ServingStats:
         layers, dead = those a windowed layer can never read again;
         ``attn_rows`` the ``(scored, visible, view)`` pool rows of the
         tick's attention, summed over its lanes and layers: scored are the
-        running lanes' key blocks, view what all slots reserve."""
+        running lanes' key blocks, view what all slots reserve; ``dsa_rows``
+        the ``(selected, visible)`` rows of a learned indexer's sparse
+        attention, summed likewise."""
         with self._lock:
             self._fold_host(host)
             self._fold_moe(moe_picks)
@@ -325,6 +333,9 @@ class ServingStats:
                 self._tick_rows_scored += int(attn_rows[0])
                 self._tick_rows_visible += int(attn_rows[1])
                 self._tick_rows_view += int(attn_rows[2])
+            if dsa_rows is not None:
+                self._dsa_selected_rows += int(dsa_rows[0])
+                self._dsa_visible_rows += int(dsa_rows[1])
             if kv_rows is not None:
                 self._kv_rows_dead += int(kv_rows[0])
                 self._kv_rows_held += int(kv_rows[1])
@@ -590,6 +601,7 @@ class ServingStats:
                       "_attn_rows_scored", "_attn_rows_visible",
                       "_attn_rows_view", "_tick_rows_scored",
                       "_tick_rows_visible", "_tick_rows_view",
+                      "_dsa_selected_rows", "_dsa_visible_rows",
                       "_recurrent_state_resets", "_recurrent_state_bytes",
                       "_weights_served_form_leaves",
                       "_weights_served_form_bytes"):
@@ -758,6 +770,14 @@ class ServingStats:
                 "decode_attn_rows_fill": round(
                     self._tick_rows_visible / self._tick_rows_scored, 6)
                     if self._tick_rows_scored else 0.0,
+                # A learned indexer's picks: rows the ticks' sparse
+                # attention read, rows their queries could see, and the
+                # share read.
+                "dsa_selected_rows": self._dsa_selected_rows,
+                "dsa_visible_rows": self._dsa_visible_rows,
+                "dsa_selected_share": round(
+                    self._dsa_selected_rows / self._dsa_visible_rows, 6)
+                    if self._dsa_visible_rows else 0.0,
                 "kv_bytes_per_token": self._kv_bytes_per_token,
                 "kv_cache_layers": self._kv_cache_layers,
                 "kv_reader_layers": self._kv_reader_layers,

@@ -109,6 +109,7 @@ GROUPS = [
       "accelerate_tpu.models.gpt_neox", "accelerate_tpu.models.bloom",
       "accelerate_tpu.models.opt",
       "accelerate_tpu.models.phi", "accelerate_tpu.models.phi4flash",
+      "accelerate_tpu.models.glm_moe_dsa",
       "accelerate_tpu.models.bert", "accelerate_tpu.models.t5",
       "accelerate_tpu.models.vit", "accelerate_tpu.models.resnet"],
      "Flax model families, all shardable by the same mesh rules and loadable "

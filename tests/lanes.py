@@ -62,6 +62,7 @@ LANES: dict[str, tuple[int, list[str]]] = {
         "test_host_offload.py",
         "test_loadgen.py",
         "test_longcat_flash.py",
+        "test_glm_moe_dsa.py",
         "test_moe_held.py",
         "test_loadtest_smoke.py",
         "test_memory_properties.py",

@@ -248,11 +248,12 @@ def test_the_metrics_read_the_parts_and_give_none_where_there_are_none(small, mo
     manifest = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     new = [m for m in manifest["per_layer"] if (harness.PACKAGE / "layer_metrics" / f"{m['name']}.py")
            .read_text().count("op_scopes")]
-    # the eight metrics that read the parts, in the manifest's order (later
-    # entries are appended after them)
+    # the metrics that read the parts, in the manifest's order: the eight that
+    # came with the vocabulary, then the glm_moe_dsa cell's selection roofline
     assert [m["name"] for m in new] == [
         "tick_kv_attn_ms", "chunk_kv_attn_ms", "tick_experts_ms", "chunk_experts_ms",
-        "chunk_ssm_ms", "tick_relayout_ms", "chunk_relayout_ms", "program_parts_named.serve"]
+        "chunk_ssm_ms", "tick_relayout_ms", "chunk_relayout_ms", "program_parts_named.serve",
+        "dsa_index_roofline"]
     assert all(m["source"] == "device_trace" for m in new)
 
     def read(trace):
@@ -264,9 +265,10 @@ def test_the_metrics_read_the_parts_and_give_none_where_there_are_none(small, mo
     assert got["tick_relayout_ms"] * 1e6 == pytest.approx(90)
     assert got["program_parts_named.serve"] == pytest.approx(100 * 750 / 900)
     assert "device time by part: decode" in capsys.readouterr().err
-    # no chunk ran, and nothing here is an expert or a Mamba layer
+    # no chunk ran, and nothing here is an expert, a Mamba layer or a learned indexer
     assert {k for k, v in got.items() if v is None} == {
-        "chunk_kv_attn_ms", "tick_experts_ms", "chunk_experts_ms", "chunk_ssm_ms", "chunk_relayout_ms"}
+        "chunk_kv_attn_ms", "tick_experts_ms", "chunk_experts_ms", "chunk_ssm_ms", "chunk_relayout_ms",
+        "dsa_index_roofline"}
     # a program from before the vocabulary, and a run without a trace
     monkeypatch.setattr(op_scopes, "PARTS", ())
     assert set(read(trace_reduce.load(path)).values()) == {None}

@@ -544,3 +544,80 @@ def test_longcat_flash_serving_program_compiles_for_v5e(program, one_chip):
         # loop each, and the experts run as one batched product (no loop over tiles)
         assert "[48,1,4096," not in text and "[48,4096," not in text
         assert text.count(" while(") == 8
+
+
+# ---------------------------------------------------------------------------
+# The glm_moe_dsa family's serving programs at the benchmark cell's widths
+# ---------------------------------------------------------------------------
+
+def _glm_moe_dsa_programs(one_chip):
+    """At the widths of ``glm-5.2-l5e16`` (hidden 6144, 64 heads over a
+    512 + 64 wide latent row, indexers of 32 heads of 128 on layers 0 and 4,
+    a dense MLP of 12288, 16 of 256 routed experts held, vocab slice 19360) as
+    the paged engine runs them in ``serve-glm5-closed16-longctx``: 16 slots x
+    32768, 2048 pages of 256, 512-token chunks. The chunk: one slot's gathered
+    views; the tick: ``jax.vmap`` over 16 lanes of a batch-1 forward whose
+    entries are ``PagedCache``."""
+    from accelerate_tpu.models.glm_moe_dsa import GlmMoeDsaConfig, GlmMoeDsaForCausalLM
+    from accelerate_tpu.models.llama import PagedCache
+
+    model = GlmMoeDsaForCausalLM(GlmMoeDsaConfig(
+        num_hidden_layers=5, vocab_size=19360, held_experts=(0, 16),
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        indexer_types=("full", "shared", "shared", "shared", "full")))
+    slots, max_len, chunk, pages, page = 16, 32768, 512, 2048, 256
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree.map(lambda a: struct(a.shape, jnp.bfloat16), shapes)
+    entries = jax.eval_shape(lambda: model.init_cache(1, max_len, jnp.bfloat16))
+    views = jax.tree.map(lambda a: struct(a.shape, a.dtype), entries)
+    pool = tuple(jax.tree.map(lambda a: struct((pages + 1, 1, page) + a.shape[2:], a.dtype), e)
+                 for e in entries)
+
+    def counted(ids, cache, pos, params):
+        (logits, cache), sown = model.apply({"params": params}, ids, cache=cache, cache_pos=pos,
+                                            mutable=["moe_stats"])
+        return logits, cache, sum(jax.tree.leaves(sown))
+
+    def chunk_fn(params, ids, cache, pos):
+        return counted(ids, cache, pos, params)
+
+    def tick_fn(params, toks, pool, table, positions, live):
+        def one_slot(tok, pages_row, pos, alive):
+            cache = tuple(PagedCache(pool=leaf, scales=None, pages=pages_row, live=alive)
+                          for leaf in pool)
+            logits, rows, picks = counted(tok[None, None], cache, pos, params)
+            return jnp.argmax(logits[0, -1]), rows, picks
+
+        return jax.vmap(one_slot)(toks, table, positions, live)
+
+    i32 = jnp.int32
+    return {
+        "prefill_chunk": (chunk_fn, (params, struct((1, chunk), i32), views, struct((), i32))),
+        "decode_tick": (tick_fn, (params, struct((slots,), i32), pool,
+                                  struct((slots, max_len // page), i32), struct((slots,), i32),
+                                  struct((slots,), jnp.bool_))),
+    }
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_tick"])
+def test_glm_moe_dsa_serving_program_compiles_for_v5e(program, one_chip):
+    fn, args = _glm_moe_dsa_programs(one_chip)[program]
+    compiled = jax.jit(fn).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    weights = 7.763e9                        # 3.8815 G parameters, bf16
+    held = (32768 * 6272 if program == "prefill_chunk"    # one slot's views / the 3.29 GB pool
+            else 2049 * 256 * 6272)
+    assert weights * 0.99 < memory.argument_size_in_bytes - held < weights * 1.01
+    text = compiled.as_text()
+    if program == "prefill_chunk":
+        # the index scores and the masked attention loop over key blocks: never [512, 32, L]
+        assert " while(" in text and "f32[1,512,32,32768]" not in text
+    else:
+        # the index keys of every lane's pages, scored; 2048 latent rows a lane gathered
+        assert "[16,2048,512]" in text
+    assert memory.temp_size_in_bytes < 2.5e9, memory
